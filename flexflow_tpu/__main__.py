@@ -36,15 +36,11 @@ def main() -> int:
         return 0 if len(sys.argv) >= 2 else 2
     script = sys.argv[1]
     sys.argv = sys.argv[1:]
-    # --compile-cache-dir takes effect before the user script runs (and
-    # before any jit dispatch), so EVERY compile of this process — not
+    # before the user script runs, so EVERY compile of this process — not
     # just those after FFModel construction — is cacheable
-    if "--compile-cache-dir" in sys.argv:
-        from flexflow_tpu.config import apply_compile_cache
+    from flexflow_tpu.config import apply_compile_cache
 
-        apply_compile_cache(
-            sys.argv[sys.argv.index("--compile-cache-dir") + 1]
-        )
+    apply_compile_cache()
     runpy.run_path(script, run_name="__main__")
     return 0
 

@@ -240,7 +240,7 @@ def eqn_where(eqn) -> str:
 def walk_jaxpr_eqns(jaxpr):
     """Yield every eqn in ``jaxpr`` and all nested sub-jaxprs (pjit
     bodies, scan/while/cond branches, custom_vjp closures)."""
-    from jax import core
+    from jax.extend import core
 
     closed = getattr(jaxpr, "jaxpr", None)
     inner = closed if closed is not None and hasattr(closed, "eqns") else jaxpr

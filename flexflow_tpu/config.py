@@ -178,12 +178,6 @@ class FFConfig:
     # weights always keep the fused path; pipelined chains and data-axis
     # extent 1 decline.
     grad_overlap: str = "off"  # off | auto | ring
-    # JAX persistent compilation cache directory (--compile-cache-dir):
-    # compiled step programs are written to / served from disk, so
-    # repeated bench/search runs skip recompiles entirely; a compile
-    # served from disk emits the jit_cache.persistent_hit tracer counter
-    # (docs/OBSERVABILITY.md).  None = in-memory jit cache only.
-    compile_cache_dir: Optional[str] = None
     # post-compile static analysis (docs/ANALYSIS.md): run the ffcheck
     # registry over every compiled program.  "warn" records violations
     # (ffmetrics `analysis_violations` + the analysis.violations tracer
@@ -320,8 +314,6 @@ class FFConfig:
                 self.microbatches = int(take())
             elif a == "--grad-overlap":
                 self.grad_overlap = take()
-            elif a == "--compile-cache-dir":
-                self.compile_cache_dir = take()
             elif a == "--verify-compiled":
                 self.verify_compiled = take()
             elif a == "--enable-parameter-parallel":
@@ -468,45 +460,29 @@ class FFConfig:
         return rest
 
 
-def apply_compile_cache(cache_dir: Optional[str]) -> bool:
-    """Enable JAX's persistent compilation cache at ``cache_dir``
-    (``--compile-cache-dir``): compiled executables are keyed by program
-    hash and served from disk across processes, so repeated bench/search
-    runs skip recompiles entirely.  The min-size/min-time gates are
-    zeroed so even smoke-scale step programs cache.  Returns whether the
-    cache was enabled (False when ``cache_dir`` is falsy); unsupported
-    knobs on older jax are skipped silently — the cache then simply
-    applies its defaults."""
-    if not cache_dir:
-        return False
-    import jax as _jax
+# where compiled programs persist when JAX_COMPILATION_CACHE_DIR does not
+# say: one fixed directory in the checkout (the path is part of the cache
+# key, so a temp name, pid or timestamp would never hit)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
-    os.makedirs(cache_dir, exist_ok=True)
-    changed = (
-        getattr(_jax.config, "jax_compilation_cache_dir", None) != cache_dir
-    )
-    _jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for opt, val in (
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ):
-        try:
-            _jax.config.update(opt, val)
-        except Exception:  # noqa: BLE001 — knob absent on this jax
-            pass
-    if changed:
-        # jax latches the cache location at the process's FIRST compile;
-        # enabling the dir later (the common case — FFModel parses flags
-        # well after import-time jit use) silently no-ops without a reset
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
 
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — API moved on this jax
-            pass
-    return True
+def apply_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR`` places it from
+    outside (jax reads the variable itself; nothing here overrides it);
+    otherwise it lives at :data:`DEFAULT_COMPILE_CACHE_DIR`.  The
+    min-size/min-time gates are zeroed so every program caches — the
+    serve programs unroll depth and a cold call pays the whole compile.
+    Called by every entry point before its first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR
+        )
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def cpu_mesh_env(n: int = 8) -> None:

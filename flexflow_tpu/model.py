@@ -249,12 +249,10 @@ class FFModel:
         from flexflow_tpu.runtime.faults import configure_faults_from_config
 
         configure_faults_from_config(self.config)
-        # persistent compilation cache (--compile-cache-dir): must be
-        # enabled before the first jit dispatch so every compile of this
-        # run is cacheable (docs/OBSERVABILITY.md)
+        # persistent compilation cache, before this model's first compile
         from flexflow_tpu.config import apply_compile_cache
 
-        apply_compile_cache(self.config.compile_cache_dir)
+        apply_compile_cache()
         # multi-host bootstrap before any device query (the reference starts
         # the Legion/GASNet runtime in the FFModel ctor, model.cc:1160).
         # Unconditional: initialize_distributed is a no-op when neither

@@ -177,6 +177,7 @@ class GPTDecodeSession:
         # mirror the executor's mixed-precision rule (FFConfig.compute_dtype)
         dt = model.executor.compute_dtype
         cast = make_cast(jnp, dt)
+        unstack = model.executor.unstack_tree
 
         def ln(p, x):
             return layer_norm(jax, jnp, p, x, eps)
@@ -184,7 +185,8 @@ class GPTDecodeSession:
         def step(params, cache_k, cache_v, tok, t):
             # tok (B,) int32; t () int32; caches (L, B, H, S, D)
             self._trace_count += 1  # traced once; calls replay the jit
-            params = jax.tree.map(cast, params)  # cast-at-use, like Executor
+            # per-layer view of scan-stacked chains, then cast-at-use
+            params = jax.tree.map(cast, unstack(params))
             x = params["tok_embed"]["kernel"][tok]  # (B, hidden)
             x = x + params["pos_embed"]["value"][t]
             mask = (jnp.arange(S) <= t)[None, None, :]
@@ -244,7 +246,7 @@ class GPTDecodeSession:
             # cast points — so cache contents and the last row's probs
             # are bit-identical to the per-token loop (pinned in tests).
             P = toks.shape[1]
-            params = jax.tree.map(cast, params)
+            params = jax.tree.map(cast, unstack(params))
             pos = start + jnp.arange(P)  # (P,)
             x = params["tok_embed"]["kernel"][toks]  # (B, P, hidden)
             x = x + params["pos_embed"]["value"][pos]

@@ -147,7 +147,7 @@ def gpt_decoder(
     return model.softmax(t, name="lm_softmax")
 
 
-# BERT configs (for BASELINE.md config 3)
+# BERT configs (the training cells of chip_smoke.py and bench.py)
 BERT_BASE = dict(hidden=768, heads=12, ff_dim=3072, num_layers=12)
 BERT_LARGE = dict(hidden=1024, heads=16, ff_dim=4096, num_layers=24)
 # GPT-2 configs (causal-LM family for the decoder path)

@@ -23,7 +23,6 @@ from typing import List
 import jax
 import jax.numpy as jnp
 
-from flexflow_tpu import _compat
 from flexflow_tpu.fftype import AggrMode, DataType, OperatorType
 from flexflow_tpu.initializer import NormInitializer
 from flexflow_tpu.ops.base import OpContext, OpDef, ShapeDtype, WeightSpec, register_op
@@ -122,7 +121,7 @@ class Embedding(OpDef):
         ids_spec = P(dp_axis)  # P(None) == replicated
         out_rank = ids.ndim + (1 if aggr is AggrMode.NONE else 0)
         out_spec = P(dp_axis, *([None] * (out_rank - 1)))
-        f = _compat.shard_map(
+        f = jax.shard_map(
             body,
             mesh=ctx.mesh,
             in_specs=(ids_spec, P(vp_axis, None)),

@@ -25,7 +25,6 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 
-from flexflow_tpu import _compat
 from flexflow_tpu.fftype import OperatorType
 from flexflow_tpu.ops.base import OpContext, OpDef, ShapeDtype, register_op
 from flexflow_tpu.tensor import Layer
@@ -316,7 +315,7 @@ class Experts(OpDef):
             out = gather_combine(y, slot, within, gts)
             return out.astype(xs.dtype)
 
-        f = _compat.shard_map(
+        f = jax.shard_map(
             body,
             mesh=ctx.mesh,
             in_specs=(

@@ -68,7 +68,11 @@ def _uniform01(seed_u32, bh_u32, q_pos, k_pos):
     h = h ^ (h >> 15)
     h = h * jnp.uint32(0x846CA68B)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # h >> 8 < 2**24, so it is exact as int32; Mosaic has no
+    # uint32 -> float32 cast
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32) * jnp.float32(
+        1.0 / (1 << 24)
+    )
 
 
 def _positions(q_start, k_start, block_q, block_k):
@@ -296,7 +300,7 @@ def _clamp_enabled() -> bool:
     in isolation (tools/bench_attention.py).  PROCESS-START-ONLY: the env
     var is read at trace time and the jit cache keys on shapes, so
     toggling it mid-process silently reuses the first variant's compiled
-    kernel — A/B each setting in its own process (chip_recovery.sh does)."""
+    kernel — A/B each setting in its own process."""
     import os
 
     return os.environ.get("FFTPU_NO_CAUSAL_CLAMP") != "1"

@@ -73,23 +73,11 @@ __all__ = [
 # FFTPU_PALLAS_INTERPRET env var sets the import-time default.
 INTERPRET = env_interpret()
 
-# jax 0.4.x spells the TPU compiler params class differently across
-# minors; resolve whichever this install carries (only touched when
-# lowering for a real TPU — interpret mode passes None).
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams", None
-)
-
 
 def supported() -> bool:
     """Can the paged kernel run here?  TPU backends lower natively;
     anything else needs interpreter mode."""
-    if INTERPRET:
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return INTERPRET or jax.default_backend() == "tpu"
 
 
 def resolve_serve_attn(mode: str) -> str:
@@ -125,7 +113,7 @@ def _kernel(
     q_ref,  # VMEM (1, G, H, D)
     k_ref,  # VMEM (1, H, BS, D) — page table[b, min(i, last)]
     v_ref,  # VMEM (1, H, BS, D)
-    *rest,  # [sk_ref, sv_ref (VMEM (1, BS) f32)], o_ref, 3 scratch refs
+    *rest,  # [sk_ref, sv_ref (VMEM (1, BS, 1) f32)], o_ref, 3 scratch refs
     G: int,
     BS: int,
     MB: int,
@@ -161,8 +149,8 @@ def _kernel(
             # (kvcache.dequantize_kv), applied before the f32 online-
             # softmax carry — elementwise, so the two paths agree
             # bit-for-bit
-            k = k * sk_ref[0][None, :, None]  # scales (BS,) per position
-            v = v * sv_ref[0][None, :, None]
+            k = k * sk_ref[0][None]  # scales (BS, 1) per position
+            v = v * sv_ref[0][None]
         # the dense path's mul+reduce contraction, one page at a time
         s = (q[:, :, None, :] * k[None]).sum(-1) * scale  # (G, H, BS)
         k_pos = i * BS + jax.lax.broadcasted_iota(
@@ -215,7 +203,7 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
     def sc_map(b, i, pos_ref, bt_ref):
         # the scale row rides the same physical-block index as its page
         last = jnp.minimum((pos_ref[b] + G - 1) // BS, MB - 1)
-        return (bt_ref[b, jnp.minimum(i, last)], 0)
+        return (bt_ref[b, jnp.minimum(i, last)], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, G, H, D), q_map),
@@ -224,11 +212,15 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
     ]
     operands = [positions, block_tables, q, pool_k, pool_v]
     if quantized:
+        # one scale row per page, as a (BS, 1) column: a (1, BS) block of
+        # the (N, BS) array breaks Mosaic's (8, 128) block rule, while
+        # trailing block dims that EQUAL the array's are always legal —
+        # and the column broadcasts across the page's lanes as it is
         in_specs += [
-            pl.BlockSpec((1, BS), sc_map),
-            pl.BlockSpec((1, BS), sc_map),
+            pl.BlockSpec((1, BS, 1), sc_map),
+            pl.BlockSpec((1, BS, 1), sc_map),
         ]
-        operands += [scale_k, scale_v]
+        operands += [scale_k[..., None], scale_v[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -244,19 +236,15 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
     kernel = functools.partial(
         _kernel, G=G, BS=BS, MB=MB, scale=scale, quantized=quantized
     )
-    interpret = INTERPRET
-    compiler_params = None
-    if not interpret and _COMPILER_PARAMS is not None:
-        # pages chain a carry per lane: both grid dims are sequential
-        compiler_params = _COMPILER_PARAMS(
-            dimension_semantics=("arbitrary", "arbitrary")
-        )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, H, D), q.dtype),
-        compiler_params=compiler_params,
-        interpret=interpret,
+        # pages chain a carry per lane: both grid dims are sequential
+        compiler_params=None if INTERPRET else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
+        interpret=INTERPRET,
     )(*operands)
 
 

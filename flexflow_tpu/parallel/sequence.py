@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-from flexflow_tpu import _compat
 
 _NEG = -1e30  # finite mask value: keeps online-softmax nan-free
 
@@ -187,7 +186,7 @@ def ring_attention(
             other_axes=tuple(a for a in (batch_axis, head_axis) if a),
         )
     )
-    f = _compat.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, PartitionSpec()),
         out_specs=spec, check_vma=False,
@@ -239,7 +238,7 @@ def ulysses_attention(
         dropout_rate=dropout_rate,
         other_axes=tuple(a for a in (batch_axis, head_axis) if a),
     )
-    f = _compat.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, PartitionSpec()),
         out_specs=spec, check_vma=False,

@@ -15,8 +15,10 @@ Env/flag contract (either works; flags win):
   * ``--coordinator-address host:port`` / ``FF_COORDINATOR_ADDRESS``
   * ``--num-nodes N``                  / ``FF_NUM_NODES``
   * ``--node-id I``                    / ``FF_NODE_ID``
-On real TPU pods all three are auto-detected by jax from the TPU metadata
-server, so ``initialize_distributed()`` with no args is correct there.
+On a TPU pod (more than one entry in ``TPU_WORKER_HOSTNAMES``, or a
+``MEGASCALE_COORDINATOR_ADDRESS``) all three are auto-detected by jax, so
+``initialize_distributed()`` with no args is correct there; on one host it
+starts nothing.
 """
 
 from __future__ import annotations
@@ -81,56 +83,18 @@ def initialize_distributed(
     if process_id is None and os.environ.get("FF_NODE_ID"):
         process_id = int(os.environ["FF_NODE_ID"])
     if coordinator_address is None and num_processes is None:
-        # single-process or TPU-pod auto-detection: only call into
-        # jax.distributed when the TPU runtime can self-configure.
-        # Best-effort: pod-ish env vars may be present on single-chip
-        # setups (e.g. tunneled dev chips) where autodetection cannot
-        # complete — stay single-process then.
-        if os.environ.get("TPU_WORKER_HOSTNAMES") or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
-            try:
-                import jax._src.xla_bridge as _xb
-
-                backends_up = _xb.backends_are_initialized()
-            except (ImportError, AttributeError):
-                backends_up = False  # unknown — attempt init, let jax decide
-            if backends_up:
-                # too late to bootstrap (something touched jax first).
-                # Single-chip dev envs with pod-ish shim vars land here
-                # benignly (1 process); on a real pod this is a
-                # misconfiguration worth flagging.
-                if os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
-                    import warnings
-
-                    warnings.warn(
-                        "pod env detected but JAX was already initialized; "
-                        "running single-process. Construct FFModel (or call "
-                        "initialize_distributed) before any other JAX use, "
-                        "or pass --coordinator-address/--num-nodes/--node-id."
-                    )
-                return
-            try:
-                jax.distributed.initialize()
-                _initialized = True
-            except ValueError as e:
-                # pod-ish env vars but nothing to autodetect — usually a
-                # tunneled single-chip dev setup (benign), occasionally
-                # malformed pod metadata (not benign).  Info-level so a
-                # debugging session can see it without spamming dev envs.
-                import logging
-
-                logging.getLogger("flexflow_tpu").info(
-                    "multi-host autodetection found nothing (%s); "
-                    "continuing single-process. On a real pod pass "
-                    "--coordinator-address/--num-nodes/--node-id.", e
-                )
-            except RuntimeError as e:
-                import warnings
-
-                warnings.warn(
-                    f"multi-host auto-detection failed ({e}); continuing "
-                    "single-process. If this is a real pod, pass "
-                    "--coordinator-address/--num-nodes/--node-id explicitly."
-                )
+        # nothing configured.  A TPU pod announces itself through the
+        # runtime's own variables — more than one worker hostname, or a
+        # multi-slice coordinator — and jax.distributed autodetects the
+        # rest; a failure there is a broken pod and raises.  One host
+        # (or neither variable) is a single process: nothing to start.
+        workers = [
+            h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+            if h.strip()
+        ]
+        if len(workers) > 1 or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"):
+            jax.distributed.initialize()
+            _initialized = True
         return
     attempts = []
     for attempt in range(max(0, retries) + 1):

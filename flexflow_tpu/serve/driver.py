@@ -212,7 +212,16 @@ def main(argv: Optional[List[str]] = None) -> int:
         num_layers=opts["num_layers"], vocab=opts["vocab"],
         use_flash=False,
     )
-    model.compile(seed=cfg.rng_seed)
+    # one engine runs on one device.  Unless a mesh or a search was asked
+    # for, compile on the one-device mesh it actually uses: the default
+    # all-devices mesh would replicate the weights and the KV pool over
+    # every chip of the host and do the same work on each
+    mesh = None
+    if cfg.mesh_shape is None and cfg.search_budget <= 0:
+        from flexflow_tpu import MachineMesh
+
+        mesh = MachineMesh((1, 1), ("data", "model"))
+    model.compile(seed=cfg.rng_seed, mesh=mesh)
 
     if fleet:
         from flexflow_tpu.serve import FleetRouter
@@ -375,6 +384,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         "num_blocks": geo.kv.num_blocks,
         "sync_every": geo.sync_every,
         "attn_kernel": geo.attn_kernel,
+        "attn_interpret": geo.attn_interpret,
+        "device": geo.device_info(),
         "kv_dtype": geo.kv.kv_dtype,
         "weight_dtype": geo.weight_dtype,
         "kv_bytes_per_token": geo.kv.bytes_per_token,

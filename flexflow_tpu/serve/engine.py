@@ -175,6 +175,9 @@ class ServeReport:
     # slot count (prefill_chunks keeps counting per-slot logical chunks)
     prefill_dispatches: int = 0
     prefill_attn_kernel: Optional[str] = None  # kernel prefill ran on
+    # --- what it ran on (ServeEngine.device_info) ---
+    attn_interpret: bool = False  # paged kernel ran in the Pallas interpreter
+    device: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -260,6 +263,10 @@ class ServeEngine:
         from flexflow_tpu.ops.pallas import paged_attention as _pattn
 
         self.attn_kernel = _pattn.resolve_serve_attn(attn)
+        # the flag is read when the programs trace — at the warmup below
+        self.attn_interpret = (
+            self.attn_kernel == "paged" and bool(_pattn.INTERPRET)
+        )
         dt = model.executor.compute_dtype
         # quantized serving arms (docs/SERVING.md "Quantized KV cache
         # and weight-only decode"): kv_dtype picks the pool element
@@ -352,9 +359,17 @@ class ServeEngine:
         # thing — the jitted signature changes, the math after the
         # dequant edge does not
         wq = self.weight_dtype == "int8"
+        # the programs below index params by LAYER name; a model whose
+        # blocks the executor scan-stacked (--stack-blocks, any chain of
+        # depth >= 4 under "auto") stores one (depth, ...) array per
+        # template layer.  The per-layer view is taken INSIDE the
+        # programs (static slices XLA reads in place, no second copy of
+        # the weights at rest); int8 quantizes that view on the host, so
+        # scales stay per layer
+        unstack = model.executor.unstack_tree
         if wq:
             self._params_arg = quantize_weights_int8(
-                jnp, model.executor.params
+                jnp, unstack(model.executor.params)
             )
         else:
             self._params_arg = model.executor.params
@@ -363,6 +378,8 @@ class ServeEngine:
             if wq:
                 qp, qs = params
                 params = dequantize_weights_int8(jax, jnp, qp, qs)
+            else:
+                params = unstack(params)
             return jax.tree.map(cast, params)
 
         def ln(p, x):
@@ -900,6 +917,25 @@ class ServeEngine:
     def _now(self) -> float:
         return time.perf_counter()
 
+    def device_info(self) -> Dict[str, Any]:
+        """Where this engine's weights and KV pool actually live: the
+        platform as JAX reports it, how many devices the process sees,
+        how many of them hold the engine's arrays, and the mesh the
+        model was compiled under."""
+        jax = self._jax
+        used = set()
+        for leaf in jax.tree.leaves((self._params_arg, self._kvs())):
+            used |= leaf.devices()
+        d0 = jax.devices()[0]
+        mesh = self.model.strategy.mesh
+        return {
+            "platform": d0.platform,
+            "device_kind": d0.device_kind,
+            "device_count": len(jax.devices()),
+            "devices_used": len(used),
+            "mesh": dict(zip(mesh.axis_names, mesh.shape)),
+        }
+
     # --- pool-buffer threading ---------------------------------------------
     def _kvs(self):
         """The live pool buffers in program-argument order: (ck, cv)
@@ -1182,10 +1218,12 @@ class ServeEngine:
                 bt_pf[slot] = self.kv.table_row(slot)
 
             def place(arrs):
-                # jnp.asarray copies out of the persistent buffers, so
-                # next window's refill never races the H2D transfer
+                # the dispatch gets its OWN copy of each staging buffer:
+                # they are refilled next window while this window's
+                # program may still be queued, and the CPU backend
+                # aliases an aligned numpy buffer instead of copying it
                 return tuple(
-                    self._jax.device_put(jnp.asarray(a)) for a in arrs
+                    self._jax.device_put(jnp.asarray(a.copy())) for a in arrs
                 )
 
             (staged,) = list(DevicePrefetcher(
@@ -1289,9 +1327,11 @@ class ServeEngine:
             else:
                 steps = max(1, min(self.sync_every, min(remaining)))
                 for _ in range(steps):
+                    # a copy of pos: it is advanced in place below while
+                    # this step may still be queued (see place() above)
                     res = self._decode(
                         self._params_arg, *self._kvs(),
-                        cur_d, jnp.asarray(pos), bt_d,
+                        cur_d, jnp.asarray(pos.copy()), bt_d,
                     )
                     nxt, probs_last = res[0], res[1]
                     self._store_kvs(res[2:])
@@ -1658,6 +1698,8 @@ class ServeEngine:
             watchdog_fires=self.watchdog_fires,
             prefill_dispatches=self.prefill_dispatches,
             prefill_attn_kernel=self.attn_kernel,
+            attn_interpret=self.attn_interpret,
+            device=self.device_info(),
         )
         self.metrics.close()
         if self.spans is not None and self._owns_spans:

@@ -174,12 +174,14 @@ def test_quantized_divergence_vs_fp32_truthful_and_bounded(model):
     """Quantization is LOSSY: the int8/fp8 arms' greedy streams are NOT
     promised equal to fp32, and this test states the measured truth on
     the smoke shape (fixed seeds, deterministic CPU fp32 math): int8
-    diverges on 2 of 6 streams, fp8 (fewer mantissa bits at this
-    amplitude) on 4 of 6.  Every request still completes with its full
-    token budget — quantization must never change completion
+    diverges on 0 of 6 streams, fp8 (fewer mantissa bits at this
+    amplitude) on 2 of 6 — re-pinned in PR 21, whose staging-buffer
+    fix made the serve loop deterministic; the earlier 2/6 and 4/6
+    were counted under that race.  Every request still completes with
+    its full token budget — quantization must never change completion
     semantics, only (boundedly) which greedy tokens come out."""
     _, _, fp32 = _run(model)
-    for kv_dtype, expected in (("int8", 2), ("fp8", 4)):
+    for kv_dtype, expected in (("int8", 0), ("fp8", 2)):
         _, rep, arm = _run(model, kv_dtype=kv_dtype)
         assert rep.requests_finished == N_REQ
         assert set(arm) == set(fp32)

@@ -6,7 +6,6 @@ dtypes), chain detection on the BERT PCG, stacked-vs-unrolled parity
 and under a dp x tp strategy), checkpoint round-trip in BOTH directions
 across layouts, the --stack-blocks off/auto/on gating, the
 block-collapsed search (winners unchanged, costs identical), the
-persistent compilation cache (+ jit_cache.persistent_hit), the
 bench_compare compile gate / stack_blocks metadata, and the
 trace_report block_scan rollup.
 """
@@ -365,42 +364,6 @@ def test_memory_estimate_unchanged_by_memo():
     assert total2 > total * 1.5
 
 
-# ------------------------------------------------------ persistent cache
-def test_compile_cache_dir_and_persistent_hit(tmp_path):
-    from flexflow_tpu.obs import Tracer, get_tracer, set_tracer
-
-    cache = str(tmp_path / "jitcache")
-    old = get_tracer()
-    try:
-        set_tracer(Tracer(level="step"))
-        m1 = _bert(stack="off", layers=2, compile_cache_dir=cache)
-        x, y = _batch()
-        m1.executor.train_step([x], y)  # instrumented: AOT compile
-        entries = [f for f in os.listdir(cache) if f.endswith("-cache")]
-        if not entries:
-            pytest.skip("persistent compilation cache unsupported here")
-        # same program, cold in-memory cache -> served from disk
-        jax.clear_caches()
-        set_tracer(Tracer(level="step"))
-        m2 = _bert(stack="off", layers=2, compile_cache_dir=cache)
-        m2.executor.train_step([x], y)
-        counters = get_tracer().summary()["counters"]
-        assert counters.get("jit_cache.persistent_hit", 0) >= 1
-    finally:
-        set_tracer(old)
-        jax.config.update("jax_compilation_cache_dir", None)
-
-
-def test_compile_cache_flag_parsing():
-    cfg = FFConfig()
-    rest = cfg.parse_args(
-        ["--compile-cache-dir", "/tmp/x", "--stack-blocks", "off", "-b", "8"]
-    )
-    assert cfg.compile_cache_dir == "/tmp/x"
-    assert cfg.stack_blocks == "off"
-    assert cfg.batch_size == 8
-    assert rest == []
-
 
 # -------------------------------------------------- block_scan telemetry
 def test_block_scan_span_emitted():
@@ -443,6 +406,14 @@ def test_trace_report_block_scan_rollup():
     out = trace_report.render(doc)
     assert "block_scan rollup" in out
     assert "depth=24 x 7 layers" in out
+
+
+def test_stack_blocks_flag_parsing():
+    cfg = FFConfig()
+    rest = cfg.parse_args(["--stack-blocks", "off", "-b", "8"])
+    assert cfg.stack_blocks == "off"
+    assert cfg.batch_size == 8
+    assert rest == []
 
 
 # ------------------------------------------------------- bench_compare

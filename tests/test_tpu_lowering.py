@@ -1,0 +1,108 @@
+"""Chip-free guard for the Pallas kernels: lower every entry point for
+``platforms=["tpu"]`` on the CPU, at the shapes ``chip_smoke.py`` runs.
+
+``jax.export`` runs the Pallas -> Mosaic lowering without a device, which
+is where a bad BlockSpec or an unsupported cast fails.  Where libtpu can
+describe a chip-less v5e topology the same function is also compiled by
+the real TPU compiler, which is where Mosaic refuses a layout.  Neither
+step executes anything: numerics are ``chip_smoke.py``'s job.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from flexflow_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+from flexflow_tpu.ops.pallas import paged_attention as pa  # noqa: E402
+from flexflow_tpu.serve.kvcache import kv_pool_dtype  # noqa: E402
+
+# GPT-2-small serving geometry: 8 slots, 12 heads of 64, 16-position
+# blocks, 64 blocks per 1024-token sequence, full provisioning + trash
+B, H, D, BS, MB = 8, 12, 64, 16, 64
+N = B * MB + 1
+
+
+@functools.lru_cache(maxsize=None)
+def _v5e_sharding():
+    """Single-device sharding on a compile-only v5e topology, or None
+    when this libtpu cannot describe one without a chip."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception:  # noqa: BLE001 — any libtpu refusal means "skip tier 2"
+        return None
+    mesh = Mesh(np.array(topo.devices[:1]), ("x",))
+    return NamedSharding(mesh, PartitionSpec())
+
+
+def _lower_for_tpu(fn, *avals):
+    jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
+    sh = _v5e_sharding()
+    if sh is not None:
+        placed = [
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh) for a in avals
+        ]
+        jax.jit(fn).lower(*placed).compile()
+
+
+@pytest.fixture(autouse=True)
+def _compiled_not_interpreted(monkeypatch):
+    monkeypatch.setattr(pa, "INTERPRET", False)
+    monkeypatch.setattr(fa, "INTERPRET", False)
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8", "fp8"])
+@pytest.mark.parametrize("G", [1, 32])
+def test_paged_attention_lowers_for_tpu(G, kv_dtype):
+    pool_dt = kv_pool_dtype(jnp, kv_dtype, fallback=jnp.float32)
+    sds = jax.ShapeDtypeStruct
+    avals = [
+        sds((B, G, H, D), jnp.bfloat16),
+        sds((N, H, BS, D), pool_dt),
+        sds((N, H, BS, D), pool_dt),
+        sds((B,), jnp.int32),
+        sds((B, MB), jnp.int32),
+    ]
+    if kv_dtype in ("int8", "fp8"):
+        avals += [sds((N, BS), jnp.float32)] * 2
+
+        def fn(q, k, v, pos, bt, sk, sv):
+            return pa.paged_decode_attention(
+                q, k, v, pos, bt, scale_k=sk, scale_v=sv
+            )
+    else:
+        fn = pa.paged_decode_attention
+    _lower_for_tpu(fn, *avals)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_lowers_for_tpu(causal, dropout):
+    # b2 h12 s8192 d64: 6 GiB of f32 scores, past the dispatcher's 4 GiB
+    # threshold (ops/attention.py::_flash_ok) — a shape flash really gets
+    q = jax.ShapeDtypeStruct((2, 12, 8192, 64), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=causal, dropout_rate=dropout, seed=7
+        )
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    _lower_for_tpu(fwd, q, q, q)
+    _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)

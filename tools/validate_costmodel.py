@@ -66,10 +66,8 @@ def measure_collectives(sizes_kb=(256, 1024, 4096), n_dev=8, iters=20,
                 n = max(n, n_dev * n_dev)
                 n -= n % (n_dev * n_dev)
 
-            from flexflow_tpu._compat import shard_map
-
             f = jax.jit(
-                shard_map(
+                jax.shard_map(
                     lambda x: jnp.sum(body(x)).reshape(1),
                     mesh=mesh, in_specs=P("x"), out_specs=P("x"),
                     check_vma=False,

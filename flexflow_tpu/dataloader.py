@@ -26,6 +26,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
+from flexflow_tpu.obs.trace import get_tracer
 from flexflow_tpu.parallel.spec import TensorSharding
 
 
@@ -192,9 +193,19 @@ class DevicePrefetcher:
             reset()
 
     def __iter__(self):
+        # ff.input.batch_wait is the time this stage waited for stage 1
+        # (the loader), ff.input.h2d_place the time it spent issuing the
+        # placement; both close before the yield
+        tracer = get_tracer()
         staged: collections.deque = collections.deque()
-        for batch in self.it:
-            staged.append(self.place_fn(batch))
+        it, done = iter(self.it), object()
+        while True:
+            with tracer.span("batch_wait", cat="input"):
+                batch = next(it, done)
+            if batch is done:
+                break
+            with tracer.span("h2d_place", cat="input"):
+                staged.append(self.place_fn(batch))
             if len(staged) >= self.depth:
                 yield staged.popleft()
         while staged:

@@ -1384,10 +1384,11 @@ class FFModel:
         loop's ONE deliberate host sync per K steps, counted and timed."""
         if acc.count == 0:
             return
-        t0 = time.perf_counter()
-        sums, count = acc.drain()
-        self.executor.count_host_sync(1, stall_s=time.perf_counter() - t0)
-        pm.merge_sums(sums, count)
+        with tracer.span("metric_flush", cat="fit"):
+            t0 = time.perf_counter()
+            sums, count = acc.drain()
+            self.executor.count_host_sync(1, stall_s=time.perf_counter() - t0)
+            pm.merge_sums(sums, count)
         tracer.counter("fit.metric_flushes")
 
     def fit(
@@ -1585,9 +1586,12 @@ class FFModel:
                             # loader past it without training
                             continue
                         try:
+                            # step_dispatch: the fast path's place +
+                            # enqueue (with the tracer on, the whole
+                            # blocking instrumented step)
                             with tracer.span(
                                 "batch", cat="fit", level="op", batch=bi
-                            ):
+                            ), tracer.span("step_dispatch", cat="fit"):
                                 loss, m = self.executor.train_step(
                                     inputs, labels
                                 )
@@ -1639,7 +1643,8 @@ class FFModel:
                             # device sync — counted truthfully; the npz
                             # serialize + fsync run on the writer thread
                             t0 = time.perf_counter()
-                            flat, manifest = self._snapshot_checkpoint()
+                            with tracer.span("checkpoint_snapshot", cat="fit"):
+                                flat, manifest = self._snapshot_checkpoint()
                             self.executor.count_host_sync(
                                 1, stall_s=time.perf_counter() - t0
                             )

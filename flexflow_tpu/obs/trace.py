@@ -13,9 +13,21 @@ plus a machine-readable ``summary()`` dict that ``bench.py`` consumers
 and ``tools/trace_report.py`` read.
 
 Design constraints:
-  * Near-zero overhead when disabled: every instrumentation site either
-    checks ``tracer.enabled`` (one attr read) or receives the shared
-    ``_NULL_SPAN`` singleton — no allocation, no clock read, no event.
+  * One span call, two sinks.  A ``step``-level span always enters a
+    ``jax.profiler.TraceAnnotation`` named ``ff.<cat>.<name>`` (``ff.<cat>``
+    when the two are equal): inert without a profiler session (one
+    activity check, no clock read), and under one
+    (``jax.profiler.start_trace``) an event in the ``/host:CPU`` plane
+    on the profiler's clock, beside the device's ``XLA Ops``.  When the
+    process tracer is on, the same object also records the Chrome event.
+    The program runs the same either way: nothing reads the tracer's
+    state to pick a path at these sites.  The NAME is the contract with
+    readers (docs/OBSERVABILITY.md lists the vocabulary); ``args`` go to
+    the Chrome event only.
+  * Near-zero overhead when disabled: counters, samples, instants and
+    ``op``-level spans check ``tracer.enabled`` (one attr read) or
+    receive the shared ``_NULL_SPAN`` singleton — no allocation, no
+    clock read, no event.
   * Levels: ``off`` (default) < ``step`` (step/compile/search/epoch
     spans) < ``op`` (adds per-op / per-frontier detail).  A span or
     sample declared at ``level="op"`` is dropped unless the tracer runs
@@ -54,8 +66,6 @@ CORE_COUNTERS = (
     "checkpoint.bytes_written",
     "network.ring_collectives",
     "network.hierarchical_collectives",
-    "serve.windows",
-    "serve.decode_steps",
     # --verify-compiled ffcheck pass (docs/ANALYSIS.md): violation count
     # from the last analyzed program (0 after a clean verify)
     "analysis.violations",
@@ -80,10 +90,47 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One live span; records an 'X' event at exit."""
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-    __slots__ = ("tracer", "name", "cat", "args", "_t0")
+_ANNOTATION = None  # the profiler-only span class, built at the first span
+
+
+def _annotation(name: str, cat: str):
+    """The profiler's sink of one span: a ``jax.profiler.TraceAnnotation``
+    that also answers ``.set()``.  jax is imported here, at the first
+    span, so ``obs`` imports without a backend; the same moment registers
+    the one listener that marks compiles."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        import jax
+
+        class _Annotation(jax.profiler.TraceAnnotation):
+            __slots__ = ()
+
+            def set(self, **args) -> None:
+                pass  # annotation arguments are event stats no reader keeps
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _ANNOTATION = _Annotation
+    return _ANNOTATION(f"ff.{cat}" if name == cat else f"ff.{cat}.{name}")
+
+
+def _on_jax_duration(event: str, duration_s: float, **_kw) -> None:
+    """A zero-length ``ff.compile`` mark whenever XLA builds (or loads
+    from the persistent cache) a program: a compile inside a measured
+    window shows in the trace at the end of the gap it caused.  Runs
+    only when something compiles — nothing on a warm call."""
+    if event == COMPILE_EVENT:
+        with _annotation("compile", "compile"):
+            pass
+        _TRACER.instant("compile", cat="compile", seconds=duration_s)
+
+
+class _Span:
+    """One live span of an enabled tracer: the profiler annotation for
+    its whole life, and an 'X' event at exit."""
+
+    __slots__ = ("tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
         self.tracer = tracer
@@ -91,12 +138,14 @@ class _Span:
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._ann = _annotation(name, cat)
 
     def set(self, **args) -> None:
         """Attach/override args mid-span (e.g. a result computed inside)."""
         self.args.update(args)
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -104,6 +153,7 @@ class _Span:
         self.tracer._record_span(
             self.name, self.cat, self._t0, time.perf_counter(), self.args
         )
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -134,10 +184,14 @@ class Tracer:
     # --- recording ---------------------------------------------------------
     def span(self, name: str, cat: str = "step", level: str = "step", **args):
         """Context manager timing one phase.  ``cat`` is the Chrome-trace
-        category AND the summary phase bucket; ``level='op'`` spans are
-        recorded only when the tracer runs at op level."""
-        if not self.enabled or (level == "op" and not self.op_level):
+        category AND the summary phase bucket; ``level='op'`` spans exist
+        only when the tracer runs at op level.  Every other span is a
+        profiler annotation ``ff.<cat>.<name>`` whether or not the tracer
+        is on, and a Chrome event besides when it is."""
+        if level == "op" and not self.op_level:
             return _NULL_SPAN
+        if not self.enabled:
+            return _annotation(name, cat)
         return _Span(self, name, cat, args)
 
     def _record_span(self, name, cat, t0, t1, args) -> None:

@@ -1787,7 +1787,7 @@ class Executor:
                     seq_length=str(seq_length),
                 )
         else:
-            cm = tracer.span("forward")  # disabled tracer -> shared null span
+            cm = tracer.span("forward")  # tracer off: the profiler's sink alone
         with cm:
             inputs = [
                 self._place(x, self._input_pspec(t), t.shape[0])

@@ -1009,6 +1009,9 @@ class ServeEngine:
         except ValueError:
             pass
         n_sub = 0
+        # window-phase spans (docs/OBSERVABILITY.md, "ff.* spans"): they go
+        # to the profiler's trace whether or not the process tracer is on
+        tracer = get_tracer()
         try:
             while True:
                 if self._drain_requested:
@@ -1018,20 +1021,22 @@ class ServeEngine:
                     self.drained = True
                     break
                 now = self._now() - t0
-                while (n_sub < len(pending)
-                       and pending[n_sub].arrival_s <= now):
-                    r = pending[n_sub]
-                    self.sched.submit(r, now=now)
-                    r.arrival_abs_s = t0 + r.arrival_s
-                    n_sub += 1
-                self.sched.admit(now=now)
+                with tracer.span("admit", cat="serve"):
+                    while (n_sub < len(pending)
+                           and pending[n_sub].arrival_s <= now):
+                        r = pending[n_sub]
+                        self.sched.submit(r, now=now)
+                        r.arrival_abs_s = t0 + r.arrival_s
+                        n_sub += 1
+                    self.sched.admit(now=now)
                 if self.sched.idle:
                     if n_sub >= len(pending):
                         break
                     # open loop: idle until the next arrival is due
                     dt_next = pending[n_sub].arrival_s - (self._now() - t0)
                     if dt_next > 0:
-                        time.sleep(min(dt_next, 0.05))
+                        with tracer.span("idle", cat="serve"):
+                            time.sleep(min(dt_next, 0.05))
                     continue
                 self._window()
         finally:
@@ -1081,54 +1086,55 @@ class ServeEngine:
         run's, which the drain/restart test pins byte for byte."""
         sched = self.sched
         tracer = get_tracer()
-        reqs: List[Request] = []
-        spilled = 0
-        for slot in sorted(sched.active):
-            req = sched.active.pop(slot)
-            if req.state is RequestState.DECODE and req.done_tokens > 0:
-                # positions with live KV: the full prompt + one write per
-                # decode step taken (the latest token is the next step's
-                # input — no KV yet); same arithmetic as _preempt_one
-                live = req.prompt_len + max(0, req.done_tokens - 1)
-                req.kv_spill = self.kv.spill(slot, live)
-                req.state = RequestState.PREEMPTED
-                spilled += 1
-            else:
-                self.kv.release(slot)
-                req.kv_spill = None
-                req.prefill_pos = 0
-                req.state = RequestState.QUEUED
-            sched.free_slots.append(slot)
-            req.slot = -1
-            reqs.append(req)
-        reqs.extend(sched.queue)  # admission order, interactive first
-        for q in sched._queues.values():
-            q.clear()
-        if tracer.enabled:
-            tracer.instant(
-                "serve_drain", cat="health",
-                requests=len(reqs), spilled=spilled,
-            )
-            tracer.counter("serve.drains")
-        return {
-            "schema": DRAIN_SCHEMA,
-            "requests": [
-                {
-                    "id": int(r.id),
-                    "prompt": np.asarray(r.prompt, np.int32),
-                    "max_new_tokens": int(r.max_new_tokens),
-                    "eos_id": r.eos_id,
-                    "tenant": r.tenant,
-                    "tier": r.tier,
-                    "deadline_ms": r.deadline_ms,
-                    "session": r.session,
-                    "preemptions": int(r.preemptions),
-                    "tokens": list(r.tokens),
-                    "kv_spill": r.kv_spill,
-                }
-                for r in reqs
-            ],
-        }
+        with tracer.span("drain", cat="serve"):
+            reqs: List[Request] = []
+            spilled = 0
+            for slot in sorted(sched.active):
+                req = sched.active.pop(slot)
+                if req.state is RequestState.DECODE and req.done_tokens > 0:
+                    # positions with live KV: the full prompt + one write per
+                    # decode step taken (the latest token is the next step's
+                    # input — no KV yet); same arithmetic as _preempt_one
+                    live = req.prompt_len + max(0, req.done_tokens - 1)
+                    req.kv_spill = self.kv.spill(slot, live)
+                    req.state = RequestState.PREEMPTED
+                    spilled += 1
+                else:
+                    self.kv.release(slot)
+                    req.kv_spill = None
+                    req.prefill_pos = 0
+                    req.state = RequestState.QUEUED
+                sched.free_slots.append(slot)
+                req.slot = -1
+                reqs.append(req)
+            reqs.extend(sched.queue)  # admission order, interactive first
+            for q in sched._queues.values():
+                q.clear()
+            if tracer.enabled:
+                tracer.instant(
+                    "serve_drain", cat="health",
+                    requests=len(reqs), spilled=spilled,
+                )
+                tracer.counter("serve.drains")
+            return {
+                "schema": DRAIN_SCHEMA,
+                "requests": [
+                    {
+                        "id": int(r.id),
+                        "prompt": np.asarray(r.prompt, np.int32),
+                        "max_new_tokens": int(r.max_new_tokens),
+                        "eos_id": r.eos_id,
+                        "tenant": r.tenant,
+                        "tier": r.tier,
+                        "deadline_ms": r.deadline_ms,
+                        "session": r.session,
+                        "preemptions": int(r.preemptions),
+                        "tokens": list(r.tokens),
+                        "kv_spill": r.kv_spill,
+                    }
+                    for r in reqs
+                ],
+            }
 
     def resume_from_drain(self, payload: Dict[str, Any]) -> List[Request]:
         """Reload a :meth:`drain` payload into this engine's queues.
@@ -1179,416 +1185,417 @@ class ServeEngine:
         ex = self.model.executor
         tracer = get_tracer()
         spans = self.spans
-        t_win = self._now()
-        B, MB = self.slots, self.kv.max_blocks_per_seq
-        fin_before = len(self.sched.finished)
-        # admission happened just before this window — sample the high-
-        # water mark now, before any in-window finishes release slots
-        self.peak_active = max(self.peak_active, len(self.sched.active))
+        with tracer.span("window", cat="serve"):
+            t_win = self._now()
+            B, MB = self.slots, self.kv.max_blocks_per_seq
+            fin_before = len(self.sched.finished)
+            # admission happened just before this window — sample the high-
+            # water mark now, before any in-window finishes release slots
+            self.peak_active = max(self.peak_active, len(self.sched.active))
 
-        # 1) prefill: ONE batched dispatch covers every mid-prefill
-        #    slot (r20) — per-lane block tables/start/n_valid, idle
-        #    lanes ride with zero rows and write the trash block, so
-        #    the window streams the decode weights once per chunk-batch
-        #    instead of once per slot.  Chunk arrays are assembled into
-        #    the engine's persistent host buffers (no per-slot np.zeros
-        #    churn) and staged H2D once per window through the shared
-        #    DevicePrefetcher.
-        prefill_done: List[Any] = []  # (req, slot) — lanes read at flush
-        chunks = []  # (slot, lo, hi) — per-slot logical chunks
-        pf_nxt = pf_probs = None
-        for slot in self.sched.prefill_slots():
-            req = self.sched.active[slot]
-            lo = req.prefill_pos
-            hi = min(lo + self.prefill_chunk, req.prompt_len)
-            chunks.append((slot, lo, hi))
-        if chunks:
-            toks, start, n_valid, bt_pf = (
-                self._pf_toks, self._pf_start, self._pf_n, self._pf_bt,
-            )
-            toks.fill(0)
-            start.fill(0)
-            n_valid.fill(0)
-            bt_pf.fill(0)
-            for slot, lo, hi in chunks:
+            # 1) prefill: ONE batched dispatch covers every mid-prefill
+            #    slot (r20) — per-lane block tables/start/n_valid, idle
+            #    lanes ride with zero rows and write the trash block, so
+            #    the window streams the decode weights once per chunk-batch
+            #    instead of once per slot.  Chunk arrays are assembled into
+            #    the engine's persistent host buffers (no per-slot np.zeros
+            #    churn) and staged H2D once per window through the shared
+            #    DevicePrefetcher.
+            prefill_done: List[Any] = []  # (req, slot) — lanes read at flush
+            chunks = []  # (slot, lo, hi) — per-slot logical chunks
+            pf_nxt = pf_probs = None
+            for slot in self.sched.prefill_slots():
                 req = self.sched.active[slot]
-                toks[slot, : hi - lo] = req.prompt[lo:hi]
-                start[slot] = lo
-                n_valid[slot] = hi - lo
-                bt_pf[slot] = self.kv.table_row(slot)
-
-            def place(arrs):
-                # the dispatch gets its OWN copy of each staging buffer:
-                # they are refilled next window while this window's
-                # program may still be queued, and the CPU backend
-                # aliases an aligned numpy buffer instead of copying it
-                return tuple(
-                    self._jax.device_put(jnp.asarray(a.copy())) for a in arrs
-                )
-
-            (staged,) = list(DevicePrefetcher(
-                [(toks, start, n_valid, bt_pf)], place,
-                depth=self.prefetch_depth,
-            ))
-            t_c0 = spans.now() if spans is not None else 0.0
-            res = self._prefill(self._params_arg, *self._kvs(), *staged)
-            pf_nxt, pf_probs = res[0], res[1]
-            self._store_kvs(res[2:])
-            self.prefill_chunks += len(chunks)
-            self.prefill_dispatches += 1
-            t_c1 = spans.now() if spans is not None else 0.0
-            for slot, lo, hi in chunks:
-                req = self.sched.active[slot]
-                req.prefill_pos = hi
-                if spans is not None:
-                    # host dispatch wall of the batched chunk (device
-                    # completion is async by design — no fetch, no
-                    # added sync); buffered
-                    spans.span(
-                        "prefill", req, t_c0, t_c1, pool=self.phase,
-                        slot=slot, lo=lo, n=hi - lo,
+                lo = req.prefill_pos
+                hi = min(lo + self.prefill_chunk, req.prompt_len)
+                chunks.append((slot, lo, hi))
+            if chunks:
+                with tracer.span("prefill_dispatch", cat="serve"):
+                    toks, start, n_valid, bt_pf = (
+                        self._pf_toks, self._pf_start, self._pf_n, self._pf_bt,
                     )
-                # register the chunk's fully-written prompt blocks in
-                # the prefix index NOW (not at prefill end): a request
-                # arriving in the next admit round with the same system
-                # prompt re-attaches them instead of allocating —
-                # concurrent sharing, not just warm-cache sharing
-                self.kv.commit_prefix(
-                    req.slot, req.prompt, req.prefill_pos
-                )
-                if req.prefill_pos >= req.prompt_len:
-                    prefill_done.append((req, slot))
+                    toks.fill(0)
+                    start.fill(0)
+                    n_valid.fill(0)
+                    bt_pf.fill(0)
+                    for slot, lo, hi in chunks:
+                        req = self.sched.active[slot]
+                        toks[slot, : hi - lo] = req.prompt[lo:hi]
+                        start[slot] = lo
+                        n_valid[slot] = hi - lo
+                        bt_pf[slot] = self.kv.table_row(slot)
 
-        # 2) decode: chain device tokens for an adaptive window
-        dec_slots = self.sched.decode_slots()
-        # span bookkeeping: request refs + token counts BEFORE the
-        # window, so per-request decode_window/spec spans can be emitted
-        # after the flush without touching the dispatch path
-        dec_reqs = (
-            [(s, self.sched.active[s]) for s in dec_slots]
-            if spans is not None else []
-        )
-        done_before = {s: r.done_tokens for s, r in dec_reqs}
-        spec_w: Dict[int, List[int]] = {}
-        t_dec0 = spans.now() if spans is not None else 0.0
-        buffered: List[Any] = []  # per-step (B,) next-token device arrays
-        spec_buf: List[Any] = []  # per-macro (n (B,W), acc (B,)) pairs
-        probs_last = None
-        steps = 0
-        if dec_slots:
-            remaining = [
-                self.sched.active[s].max_new_tokens
-                - self.sched.active[s].done_tokens
-                for s in dec_slots
-            ]
-            cur = np.zeros((B,), np.int32)
-            pos = np.zeros((B,), np.int32)
-            bt = np.zeros((B, MB), np.int32)
-            for s in dec_slots:
-                r = self.sched.active[s]
-                cur[s] = r.tokens[-1]
-                pos[s] = r.prompt_len + r.done_tokens - 1
-                bt[s] = self.kv.tables[s]
-            bt_d = self._jax.device_put(jnp.asarray(bt))
-            cur_d = self._jax.device_put(jnp.asarray(cur))
-            if self.spec_k:
-                # speculative macro steps: k chained draft calls on the
-                # shallow slice, ONE full-depth verify over the k+1 rows.
-                # verify returns the next macro's (token, position) as
-                # device arrays, so macros chain with NO host fetch —
-                # still one sync per window
-                k = self.spec_k
-                W = k + 1
-                macros = max(
-                    1, min(self.sync_every, -(-min(remaining) // W))
-                )
-                pos_d = self._jax.device_put(jnp.asarray(pos))
-                for _ in range(macros):
-                    cur_j, pos_j = cur_d, pos_d
-                    drafts = []
-                    for _j in range(k):
-                        res = self._draft(
-                            self._params_arg, *self._kvs(),
-                            cur_j, pos_j, bt_d,
+                    def place(arrs):
+                        # the dispatch gets its OWN copy of each staging buffer:
+                        # they are refilled next window while this window's
+                        # program may still be queued, and the CPU backend
+                        # aliases an aligned numpy buffer instead of copying it
+                        return tuple(
+                            self._jax.device_put(jnp.asarray(a.copy())) for a in arrs
                         )
-                        dn = res[0]
-                        self._store_kvs(res[1:])
-                        drafts.append(dn)
-                        cur_j, pos_j = dn, pos_j + 1
-                    toks = jnp.stack([cur_d] + drafts, axis=1)  # (B, W)
-                    res = self._verify(
-                        self._params_arg, *self._kvs(),
-                        toks, pos_d, bt_d,
-                    )
-                    n, acc, cur_d, pos_d = res[:4]
-                    self._store_kvs(res[4:])
-                    spec_buf.append((n, acc))
-                steps = macros * W  # program invocations this window
-            else:
-                steps = max(1, min(self.sync_every, min(remaining)))
-                for _ in range(steps):
-                    # a copy of pos: it is advanced in place below while
-                    # this step may still be queued (see place() above)
-                    res = self._decode(
-                        self._params_arg, *self._kvs(),
-                        cur_d, jnp.asarray(pos.copy()), bt_d,
-                    )
-                    nxt, probs_last = res[0], res[1]
+
+                    (staged,) = list(DevicePrefetcher(
+                        [(toks, start, n_valid, bt_pf)], place,
+                        depth=self.prefetch_depth,
+                    ))
+                    t_c0 = spans.now() if spans is not None else 0.0
+                    res = self._prefill(self._params_arg, *self._kvs(), *staged)
+                    pf_nxt, pf_probs = res[0], res[1]
                     self._store_kvs(res[2:])
-                    buffered.append(nxt)
-                    cur_d = nxt  # device-to-device chain: NO host fetch
-                    for s in dec_slots:
-                        pos[s] += 1
-            self.decode_steps += steps
+                    self.prefill_chunks += len(chunks)
+                    self.prefill_dispatches += 1
+                    t_c1 = spans.now() if spans is not None else 0.0
+                    for slot, lo, hi in chunks:
+                        req = self.sched.active[slot]
+                        req.prefill_pos = hi
+                        if spans is not None:
+                            # host dispatch wall of the batched chunk (device
+                            # completion is async by design — no fetch, no
+                            # added sync); buffered
+                            spans.span(
+                                "prefill", req, t_c0, t_c1, pool=self.phase,
+                                slot=slot, lo=lo, n=hi - lo,
+                            )
+                        # register the chunk's fully-written prompt blocks in
+                        # the prefix index NOW (not at prefill end): a request
+                        # arriving in the next admit round with the same system
+                        # prompt re-attaches them instead of allocating —
+                        # concurrent sharing, not just warm-cache sharing
+                        self.kv.commit_prefix(
+                            req.slot, req.prompt, req.prefill_pos
+                        )
+                        if req.prefill_pos >= req.prompt_len:
+                            prefill_done.append((req, slot))
 
-        # 3) flush: the window's ONE deliberate host sync
-        t_sync = self._now()
-        host_tok = [np.asarray(b) for b in buffered]
-        host_spec = [
-            (np.asarray(n), np.asarray(a)) for n, a in spec_buf
-        ]
-        if prefill_done:
-            # ONE fetch of the batched dispatch's lanes, inside the
-            # window's single sync — indexed per finishing slot
-            pf_nxt_h = np.asarray(pf_nxt)
-            pf_probs_h = np.asarray(pf_probs)
-            host_pre = [
-                (req, int(pf_nxt_h[slot]), pf_probs_h[slot])
-                for req, slot in prefill_done
-            ]
-        else:
-            host_pre = []
-        stall = self._now() - t_sync
-        ex.count_host_sync(1, stall)
-        flushed_tokens = 0
-        spec_drafted_w = spec_accepted_w = 0
-
-        # decode lanes: assign buffered tokens in step order
-        for ki in range(len(host_tok)):
-            for s in dec_slots:
-                req = self.sched.active.get(s)
-                if req is None or req.state is not RequestState.DECODE:
-                    continue  # finished earlier in this flush (EOS)
-                if self.temperature > 0.0 and probs_last is not None:
-                    # sampling mode runs 1-step windows; draw on host
-                    from flexflow_tpu.models.transformer import sample_next
-
-                    tok = int(sample_next(
-                        np.asarray(probs_last)[s][None],
-                        self.temperature, self._rng,
-                    )[0])
-                else:
-                    tok = int(host_tok[ki][s])
-                req.tokens.append(tok)
-                flushed_tokens += 1
-                self._finish_if_done(req, tok)
-
-        # speculative lanes: each macro contributes its accepted prefix
-        # (acc drafts + the verify row's own argmax); tokens past an
-        # EOS/budget finish are overshoot and are discarded exactly like
-        # the plain-decode overshoot above
-        for n_h, acc_h in host_spec:
-            for s in dec_slots:
-                req = self.sched.active.get(s)
-                if req is None or req.state is not RequestState.DECODE:
-                    continue
-                a = int(acc_h[s])
-                spec_drafted_w += self.spec_k
-                spec_accepted_w += a
-                if spans is not None:
-                    e = spec_w.setdefault(s, [0, 0])
-                    e[0] += self.spec_k
-                    e[1] += a
-                for j in range(a + 1):
-                    tok = int(n_h[s, j])
-                    req.tokens.append(tok)
-                    flushed_tokens += 1
-                    self._finish_if_done(req, tok)
-                    if req.state is not RequestState.DECODE:
-                        break
-        self.spec_drafted += spec_drafted_w
-        self.spec_accepted += spec_accepted_w
-
-        # prefill completions: first generated token becomes visible now
-        for req, tok, probs in host_pre:
-            if self.temperature > 0.0:
-                from flexflow_tpu.models.transformer import sample_next
-
-                tok = int(sample_next(
-                    probs[None], self.temperature, self._rng,
-                )[0])
-            req.state = RequestState.DECODE
-            req.tokens.append(int(tok))
-            flushed_tokens += 1
-            req.t_first_token = self._now()
-            if spans is not None:
-                tt = spans.rel(req.t_first_token)
-                spans.span("first_token", req, tt, tt, pool=self.phase)
-            self._finish_if_done(req, int(tok))
-
-        # per-request decode/spec spans for this window — emitted after
-        # the flush (post-sync), from counts the flush already computed
-        if spans is not None and dec_reqs:
-            t_dec1 = spans.now()
-            for s, r in dec_reqs:
-                spans.span(
-                    "decode_window", r, t_dec0, t_dec1, pool=self.phase,
-                    window=self.windows, steps=steps, slot=s,
-                    tokens=r.done_tokens - done_before[s],
-                )
-                sw = spec_w.get(s)
-                if sw is not None:
-                    spans.span(
-                        "spec", r, t_dec0, t_dec1, pool=self.phase,
-                        k=self.spec_k, drafted=sw[0], accepted=sw[1],
-                    )
-
-        self.windows += 1
-        self._occ_sum += self.sched.occupancy
-        win_wall = self._now() - t_win
-        # window watchdog (--serve-watchdog-s): a window slower than the
-        # budget is flagged loudly — a stalled loader, a GC pause, or a
-        # degraded DCN link shows up here long before SLO percentiles do
-        if self.watchdog_s and win_wall > self.watchdog_s:
-            self.watchdog_fires += 1
-            if tracer.enabled:
-                tracer.counter("serve.watchdog_fires")
-                tracer.instant(
-                    "serve_watchdog", cat="health",
-                    window=self.windows - 1,
-                    wall_s=round(win_wall, 6),
-                    budget_s=self.watchdog_s,
-                )
-        # graceful shedding (--serve-shed-windows): after N CONSECUTIVE
-        # windows over the per-token SLO, reject the queued batch tier
-        # with a truthful reason — shrinking the backlog instead of
-        # letting every tier's latency collapse together
-        if self.shed_after_windows and flushed_tokens:
-            per_tok_ms = win_wall / flushed_tokens * 1e3
-            if per_tok_ms > self.slo_ms:
-                self._slo_breach_windows += 1
-            else:
-                self._slo_breach_windows = 0
-            if self._slo_breach_windows >= self.shed_after_windows:
-                now_rel = self._now() - (self._t0 or 0.0)
-                n = self.sched.shed_batch_queue(
-                    now_rel,
-                    f"sustained SLO pressure: per-token "
-                    f"{per_tok_ms:.1f} ms > {self.slo_ms:.1f} ms SLO "
-                    f"for {self._slo_breach_windows} consecutive windows",
-                )
-                self._slo_breach_windows = 0
-                if n and tracer.enabled:
-                    tracer.counter("serve.shed", float(n))
-        if tracer.enabled:
-            tracer.counter("serve.windows", 1.0)
-            if steps:
-                tracer.counter("serve.decode_steps", float(steps))
-        # the window record is built once and fanned out: the metrics
-        # stream (when recording), the SLO engine, and the status
-        # snapshot all see the IDENTICAL dict — what the file says is
-        # what the alerts and endpoints say
-        if (self.metrics.enabled or self.slo is not None
-                or self.publish_status):
-            fin = [
-                {
-                    "id": r.id, "tokens": r.done_tokens,
-                    "reason": r.finish_reason, "tenant": r.tenant,
-                    "tier": r.tier, "preempted": r.preemptions,
-                    **r.latency_ms(),
-                }
-                for r in self.sched.finished[fin_before:]
-            ]
-            # per-tenant fairness snapshot: occupancy share + progress
-            # (ADDITIVE ffmetrics/1 vocabulary — old readers ignore it)
-            tenants: Dict[str, Dict[str, Any]] = {}
-            for r in list(self.sched.active.values()) + self.sched.queue:
-                d = tenants.setdefault(r.tenant, {
-                    "tier": r.tier, "active": 0, "queued": 0,
-                })
-                d["active" if r.slot >= 0 else "queued"] += 1
-            serve_m: Dict[str, Any] = {
-                "queue_depth": self.sched.queue_depth,
-                "occupancy": self.sched.occupancy,
-                "decode_steps": steps,
-                "prefill_chunks": len(chunks),
-                "active": len(self.sched.active),
-                "finished": fin,
-                "rejected_total": len(self.sched.rejected),
-                "expired_total": self.sched.expired,
-                "shed_total": self.sched.shed,
-                "prefix_hit_rate": self.kv.prefix_hit_rate,
-                "cached_blocks": self.kv.cached_blocks,
-                "preemptions_total": self.sched.preemptions,
-                "tenants": tenants,
-                # which decode-attention kernel served this window
-                # (ADDITIVE ffmetrics/1 vocabulary — r14, old readers
-                # ignore it, old streams simply lack it)
-                "attn_kernel": self.attn_kernel,
-                # which kernel CHUNKED PREFILL ran on + how many
-                # batched dispatches this window issued (ADDITIVE —
-                # r20; pre-r20 streams simply lack both and
-                # tools/serve_report.py stays silent)
-                "prefill_attn_kernel": self.attn_kernel,
-                "prefill_dispatches": 1 if chunks else 0,
-                # quantized-serving vocabulary (ADDITIVE — r19): the
-                # pool/weight formats and the per-position HBM cost
-                "kv_dtype": self.kv_dtype,
-                "weight_dtype": self.weight_dtype,
-                "kv_bytes_per_token": self.kv.bytes_per_token,
-            }
-            # disaggregated-pool vocabulary (ADDITIVE — absent on
-            # colocated engines, so pre-r13 streams are unchanged)
-            if self.phase is not None:
-                serve_m["phase"] = self.phase
-            if self._handoff_ms_w:
-                serve_m["handoff_ms"] = [
-                    round(x, 4) for x in self._handoff_ms_w
-                ]
-                serve_m["migrated_blocks"] = self._migrated_blocks_w
-                serve_m["handoff_bytes"] = self._migrated_bytes_w
-                # measured send→deliver transit beside the priced value
-                # (PR 16, ADDITIVE — absent unless the router measured)
-                if self._handoff_obs_w:
-                    serve_m["handoff_observed_ms"] = [
-                        round(x, 4) for x in self._handoff_obs_w
-                    ]
-            if self.spec_k:
-                serve_m["spec"] = {
-                    "k": self.spec_k,
-                    "draft_layers": self.spec_draft_layers,
-                    "drafted": spec_drafted_w,
-                    "accepted": spec_accepted_w,
-                }
-            rec = step_record(
-                step=self.windows - 1,
-                t=time.time(),
-                step_wall_s=win_wall,
-                host_stall_s=stall,
-                tokens=flushed_tokens,
-                samples=len(dec_slots),
-                predicted_step_s=self.predicted_step_s,
-                predicted_tok_s=self.predicted_tok_s,
-                metrics={"serve": serve_m},
+            # 2) decode: chain device tokens for an adaptive window
+            dec_slots = self.sched.decode_slots()
+            # span bookkeeping: request refs + token counts BEFORE the
+            # window, so per-request decode_window/spec spans can be emitted
+            # after the flush without touching the dispatch path
+            dec_reqs = (
+                [(s, self.sched.active[s]) for s in dec_slots]
+                if spans is not None else []
             )
-            if self.metrics.enabled:
-                self.metrics.append(rec)
-            if self.slo is not None:
-                self.slo.observe_record(rec)
-            if self.publish_status:
-                # immutable snapshot, published by atomic reference
-                # swap — the introspection server reads it lock-free
-                self.status_snapshot = self._status_snapshot(rec)
-        # handoff accumulators are per-window whether or not a metrics
-        # stream is attached
-        self._handoff_ms_w = []
-        self._handoff_obs_w = []
-        self._migrated_blocks_w = 0
-        self._migrated_bytes_w = 0
-        # batched span flush — strictly after the window's one host
-        # sync, so tracing adds file writes but never a device wait
-        if spans is not None:
-            spans.flush()
+            done_before = {s: r.done_tokens for s, r in dec_reqs}
+            spec_w: Dict[int, List[int]] = {}
+            t_dec0 = spans.now() if spans is not None else 0.0
+            buffered: List[Any] = []  # per-step (B,) next-token device arrays
+            spec_buf: List[Any] = []  # per-macro (n (B,W), acc (B,)) pairs
+            probs_last = None
+            steps = 0
+            if dec_slots:
+                with tracer.span("decode_dispatch", cat="serve"):
+                    remaining = [
+                        self.sched.active[s].max_new_tokens
+                        - self.sched.active[s].done_tokens
+                        for s in dec_slots
+                    ]
+                    cur = np.zeros((B,), np.int32)
+                    pos = np.zeros((B,), np.int32)
+                    bt = np.zeros((B, MB), np.int32)
+                    for s in dec_slots:
+                        r = self.sched.active[s]
+                        cur[s] = r.tokens[-1]
+                        pos[s] = r.prompt_len + r.done_tokens - 1
+                        bt[s] = self.kv.tables[s]
+                    bt_d = self._jax.device_put(jnp.asarray(bt))
+                    cur_d = self._jax.device_put(jnp.asarray(cur))
+                    if self.spec_k:
+                        # speculative macro steps: k chained draft calls on the
+                        # shallow slice, ONE full-depth verify over the k+1 rows.
+                        # verify returns the next macro's (token, position) as
+                        # device arrays, so macros chain with NO host fetch —
+                        # still one sync per window
+                        k = self.spec_k
+                        W = k + 1
+                        macros = max(
+                            1, min(self.sync_every, -(-min(remaining) // W))
+                        )
+                        pos_d = self._jax.device_put(jnp.asarray(pos))
+                        for _ in range(macros):
+                            cur_j, pos_j = cur_d, pos_d
+                            drafts = []
+                            for _j in range(k):
+                                res = self._draft(
+                                    self._params_arg, *self._kvs(),
+                                    cur_j, pos_j, bt_d,
+                                )
+                                dn = res[0]
+                                self._store_kvs(res[1:])
+                                drafts.append(dn)
+                                cur_j, pos_j = dn, pos_j + 1
+                            toks = jnp.stack([cur_d] + drafts, axis=1)  # (B, W)
+                            res = self._verify(
+                                self._params_arg, *self._kvs(),
+                                toks, pos_d, bt_d,
+                            )
+                            n, acc, cur_d, pos_d = res[:4]
+                            self._store_kvs(res[4:])
+                            spec_buf.append((n, acc))
+                        steps = macros * W  # program invocations this window
+                    else:
+                        steps = max(1, min(self.sync_every, min(remaining)))
+                        for _ in range(steps):
+                            # a copy of pos: it is advanced in place below while
+                            # this step may still be queued (see place() above)
+                            res = self._decode(
+                                self._params_arg, *self._kvs(),
+                                cur_d, jnp.asarray(pos.copy()), bt_d,
+                            )
+                            nxt, probs_last = res[0], res[1]
+                            self._store_kvs(res[2:])
+                            buffered.append(nxt)
+                            cur_d = nxt  # device-to-device chain: NO host fetch
+                            for s in dec_slots:
+                                pos[s] += 1
+                    self.decode_steps += steps
+
+            # 3) flush: the window's ONE deliberate host sync
+            with tracer.span("sync", cat="serve"):
+                t_sync = self._now()
+                host_tok = [np.asarray(b) for b in buffered]
+                host_spec = [
+                    (np.asarray(n), np.asarray(a)) for n, a in spec_buf
+                ]
+                if prefill_done:
+                    # ONE fetch of the batched dispatch's lanes, inside the
+                    # window's single sync — indexed per finishing slot
+                    pf_nxt_h = np.asarray(pf_nxt)
+                    pf_probs_h = np.asarray(pf_probs)
+                    host_pre = [
+                        (req, int(pf_nxt_h[slot]), pf_probs_h[slot])
+                        for req, slot in prefill_done
+                    ]
+                else:
+                    host_pre = []
+                stall = self._now() - t_sync
+            with tracer.span("flush", cat="serve"):
+                ex.count_host_sync(1, stall)
+                flushed_tokens = 0
+                spec_drafted_w = spec_accepted_w = 0
+
+                # decode lanes: assign buffered tokens in step order
+                for ki in range(len(host_tok)):
+                    for s in dec_slots:
+                        req = self.sched.active.get(s)
+                        if req is None or req.state is not RequestState.DECODE:
+                            continue  # finished earlier in this flush (EOS)
+                        if self.temperature > 0.0 and probs_last is not None:
+                            # sampling mode runs 1-step windows; draw on host
+                            from flexflow_tpu.models.transformer import sample_next
+
+                            tok = int(sample_next(
+                                np.asarray(probs_last)[s][None],
+                                self.temperature, self._rng,
+                            )[0])
+                        else:
+                            tok = int(host_tok[ki][s])
+                        req.tokens.append(tok)
+                        flushed_tokens += 1
+                        self._finish_if_done(req, tok)
+
+                # speculative lanes: each macro contributes its accepted prefix
+                # (acc drafts + the verify row's own argmax); tokens past an
+                # EOS/budget finish are overshoot and are discarded exactly like
+                # the plain-decode overshoot above
+                for n_h, acc_h in host_spec:
+                    for s in dec_slots:
+                        req = self.sched.active.get(s)
+                        if req is None or req.state is not RequestState.DECODE:
+                            continue
+                        a = int(acc_h[s])
+                        spec_drafted_w += self.spec_k
+                        spec_accepted_w += a
+                        if spans is not None:
+                            e = spec_w.setdefault(s, [0, 0])
+                            e[0] += self.spec_k
+                            e[1] += a
+                        for j in range(a + 1):
+                            tok = int(n_h[s, j])
+                            req.tokens.append(tok)
+                            flushed_tokens += 1
+                            self._finish_if_done(req, tok)
+                            if req.state is not RequestState.DECODE:
+                                break
+                self.spec_drafted += spec_drafted_w
+                self.spec_accepted += spec_accepted_w
+
+                # prefill completions: first generated token becomes visible now
+                for req, tok, probs in host_pre:
+                    if self.temperature > 0.0:
+                        from flexflow_tpu.models.transformer import sample_next
+
+                        tok = int(sample_next(
+                            probs[None], self.temperature, self._rng,
+                        )[0])
+                    req.state = RequestState.DECODE
+                    req.tokens.append(int(tok))
+                    flushed_tokens += 1
+                    req.t_first_token = self._now()
+                    if spans is not None:
+                        tt = spans.rel(req.t_first_token)
+                        spans.span("first_token", req, tt, tt, pool=self.phase)
+                    self._finish_if_done(req, int(tok))
+
+                # per-request decode/spec spans for this window — emitted after
+                # the flush (post-sync), from counts the flush already computed
+                if spans is not None and dec_reqs:
+                    t_dec1 = spans.now()
+                    for s, r in dec_reqs:
+                        spans.span(
+                            "decode_window", r, t_dec0, t_dec1, pool=self.phase,
+                            window=self.windows, steps=steps, slot=s,
+                            tokens=r.done_tokens - done_before[s],
+                        )
+                        sw = spec_w.get(s)
+                        if sw is not None:
+                            spans.span(
+                                "spec", r, t_dec0, t_dec1, pool=self.phase,
+                                k=self.spec_k, drafted=sw[0], accepted=sw[1],
+                            )
+
+                self.windows += 1
+                self._occ_sum += self.sched.occupancy
+                win_wall = self._now() - t_win
+                # window watchdog (--serve-watchdog-s): a window slower than the
+                # budget is flagged loudly — a stalled loader, a GC pause, or a
+                # degraded DCN link shows up here long before SLO percentiles do
+                if self.watchdog_s and win_wall > self.watchdog_s:
+                    self.watchdog_fires += 1
+                    if tracer.enabled:
+                        tracer.counter("serve.watchdog_fires")
+                        tracer.instant(
+                            "serve_watchdog", cat="health",
+                            window=self.windows - 1,
+                            wall_s=round(win_wall, 6),
+                            budget_s=self.watchdog_s,
+                        )
+                # graceful shedding (--serve-shed-windows): after N CONSECUTIVE
+                # windows over the per-token SLO, reject the queued batch tier
+                # with a truthful reason — shrinking the backlog instead of
+                # letting every tier's latency collapse together
+                if self.shed_after_windows and flushed_tokens:
+                    per_tok_ms = win_wall / flushed_tokens * 1e3
+                    if per_tok_ms > self.slo_ms:
+                        self._slo_breach_windows += 1
+                    else:
+                        self._slo_breach_windows = 0
+                    if self._slo_breach_windows >= self.shed_after_windows:
+                        now_rel = self._now() - (self._t0 or 0.0)
+                        n = self.sched.shed_batch_queue(
+                            now_rel,
+                            f"sustained SLO pressure: per-token "
+                            f"{per_tok_ms:.1f} ms > {self.slo_ms:.1f} ms SLO "
+                            f"for {self._slo_breach_windows} consecutive windows",
+                        )
+                        self._slo_breach_windows = 0
+                        if n and tracer.enabled:
+                            tracer.counter("serve.shed", float(n))
+                # the window record is built once and fanned out: the metrics
+                # stream (when recording), the SLO engine, and the status
+                # snapshot all see the IDENTICAL dict — what the file says is
+                # what the alerts and endpoints say
+                if (self.metrics.enabled or self.slo is not None
+                        or self.publish_status):
+                    fin = [
+                        {
+                            "id": r.id, "tokens": r.done_tokens,
+                            "reason": r.finish_reason, "tenant": r.tenant,
+                            "tier": r.tier, "preempted": r.preemptions,
+                            **r.latency_ms(),
+                        }
+                        for r in self.sched.finished[fin_before:]
+                    ]
+                    # per-tenant fairness snapshot: occupancy share + progress
+                    # (ADDITIVE ffmetrics/1 vocabulary — old readers ignore it)
+                    tenants: Dict[str, Dict[str, Any]] = {}
+                    for r in list(self.sched.active.values()) + self.sched.queue:
+                        d = tenants.setdefault(r.tenant, {
+                            "tier": r.tier, "active": 0, "queued": 0,
+                        })
+                        d["active" if r.slot >= 0 else "queued"] += 1
+                    serve_m: Dict[str, Any] = {
+                        "queue_depth": self.sched.queue_depth,
+                        "occupancy": self.sched.occupancy,
+                        "decode_steps": steps,
+                        "prefill_chunks": len(chunks),
+                        "active": len(self.sched.active),
+                        "finished": fin,
+                        "rejected_total": len(self.sched.rejected),
+                        "expired_total": self.sched.expired,
+                        "shed_total": self.sched.shed,
+                        "prefix_hit_rate": self.kv.prefix_hit_rate,
+                        "cached_blocks": self.kv.cached_blocks,
+                        "preemptions_total": self.sched.preemptions,
+                        "tenants": tenants,
+                        # which decode-attention kernel served this window
+                        # (ADDITIVE ffmetrics/1 vocabulary — r14, old readers
+                        # ignore it, old streams simply lack it)
+                        "attn_kernel": self.attn_kernel,
+                        # which kernel CHUNKED PREFILL ran on + how many
+                        # batched dispatches this window issued (ADDITIVE —
+                        # r20; pre-r20 streams simply lack both and
+                        # tools/serve_report.py stays silent)
+                        "prefill_attn_kernel": self.attn_kernel,
+                        "prefill_dispatches": 1 if chunks else 0,
+                        # quantized-serving vocabulary (ADDITIVE — r19): the
+                        # pool/weight formats and the per-position HBM cost
+                        "kv_dtype": self.kv_dtype,
+                        "weight_dtype": self.weight_dtype,
+                        "kv_bytes_per_token": self.kv.bytes_per_token,
+                    }
+                    # disaggregated-pool vocabulary (ADDITIVE — absent on
+                    # colocated engines, so pre-r13 streams are unchanged)
+                    if self.phase is not None:
+                        serve_m["phase"] = self.phase
+                    if self._handoff_ms_w:
+                        serve_m["handoff_ms"] = [
+                            round(x, 4) for x in self._handoff_ms_w
+                        ]
+                        serve_m["migrated_blocks"] = self._migrated_blocks_w
+                        serve_m["handoff_bytes"] = self._migrated_bytes_w
+                        # measured send→deliver transit beside the priced value
+                        # (PR 16, ADDITIVE — absent unless the router measured)
+                        if self._handoff_obs_w:
+                            serve_m["handoff_observed_ms"] = [
+                                round(x, 4) for x in self._handoff_obs_w
+                            ]
+                    if self.spec_k:
+                        serve_m["spec"] = {
+                            "k": self.spec_k,
+                            "draft_layers": self.spec_draft_layers,
+                            "drafted": spec_drafted_w,
+                            "accepted": spec_accepted_w,
+                        }
+                    rec = step_record(
+                        step=self.windows - 1,
+                        t=time.time(),
+                        step_wall_s=win_wall,
+                        host_stall_s=stall,
+                        tokens=flushed_tokens,
+                        samples=len(dec_slots),
+                        predicted_step_s=self.predicted_step_s,
+                        predicted_tok_s=self.predicted_tok_s,
+                        metrics={"serve": serve_m},
+                    )
+                    if self.metrics.enabled:
+                        self.metrics.append(rec)
+                    if self.slo is not None:
+                        self.slo.observe_record(rec)
+                    if self.publish_status:
+                        # immutable snapshot, published by atomic reference
+                        # swap — the introspection server reads it lock-free
+                        self.status_snapshot = self._status_snapshot(rec)
+                # handoff accumulators are per-window whether or not a metrics
+                # stream is attached
+                self._handoff_ms_w = []
+                self._handoff_obs_w = []
+                self._migrated_blocks_w = 0
+                self._migrated_bytes_w = 0
+                # batched span flush — strictly after the window's one host
+                # sync, so tracing adds file writes but never a device wait
+                if spans is not None:
+                    spans.flush()
 
     def _status_snapshot(self, rec: Dict[str, Any]) -> Dict[str, Any]:
         """One immutable per-window snapshot for the introspection

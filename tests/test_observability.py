@@ -120,14 +120,17 @@ def test_no_dead_config_flags():
 # --------------------------------------------------- unified tracing layer
 import numpy as np
 
-from flexflow_tpu.obs import Tracer, get_tracer, set_tracer
+from flexflow_tpu.obs import HealthMonitor, Tracer, get_tracer, set_monitor, set_tracer
 
 
 @pytest.fixture(autouse=True)
 def _reset_tracer():
     """The tracer is process-wide: restore the disabled default after every
     test so an enabled tracer never leaks into other test modules (it
-    switches the executor onto the instrumented step path)."""
+    switches the executor onto the instrumented step path).  The monitor
+    does the same switch: start from the disabled one, whatever a test
+    file that ran earlier on this worker left behind."""
+    set_monitor(HealthMonitor())
     yield
     set_tracer(Tracer())
 

@@ -414,12 +414,19 @@ def test_unity_search_objective_serve_2slice_golden(model):
 def test_serve_driver_cli(tmp_path, capsys):
     from flexflow_tpu.serve.driver import main as serve_main
 
+    from flexflow_tpu.obs import HealthMonitor, set_monitor
+
     out = tmp_path / "drv.jsonl"
-    rc = serve_main([
-        "--requests", "3", "--serve-slots", "2", "--seq", "32",
-        "--prompt-len", "2:4", "--gen-len", "2:4",
-        "--metrics-out", str(out),
-    ])
+    try:
+        rc = serve_main([
+            "--requests", "3", "--serve-slots", "2", "--seq", "32",
+            "--prompt-len", "2:4", "--gen-len", "2:4",
+            "--metrics-out", str(out),
+        ])
+    finally:
+        # --metrics-out installs the process monitor; left on it would put
+        # every later fit of this worker on the instrumented path
+        set_monitor(HealthMonitor())
     assert rc == 0
     line = capsys.readouterr().out.strip().splitlines()[-1]
     doc = json.loads(line)

@@ -276,8 +276,10 @@ def analyze_serve_engine(
     zero-sync-serve and paged-KV-donation guarantees.
 
     Additionally audits copy-on-write safety (``serve_cow``): every
-    serve program DONATES the whole paged K/V pool and scatters into
-    blocks its tables name, so a block mapped by a slot's writable
+    serve program DONATES the whole paged K/V pool and writes, in
+    place, the pages its tables name (the page-write kernel on a paged
+    engine, the XLA scatter on a gather one — ``engine.kv_write``), so
+    a block mapped by a slot's writable
     region while still shared (refcount > 1) or prefix-indexed would be
     silently corrupted for every other table that maps it.  The
     allocator's :meth:`PagedKVCache.shared_write_hazards` must therefore
@@ -371,7 +373,7 @@ def analyze_serve_engine(
         report.extend(analyze_program(art, checks))
     # serve_cow: CoW safety as an ffcheck invariant — a live allocator
     # state where a shared/indexed block sits in a slot's writable
-    # region means a donated scatter would corrupt other tables
+    # region means a donated in-place write would corrupt other tables
     if checks is None or "serve_cow" in checks:
         report.add_program("serve.kvcache")
         try:
@@ -387,7 +389,7 @@ def analyze_serve_engine(
                     f"slot {slot} may write logical block {idx} -> "
                     f"physical {blk} which is shared "
                     f"(refcount {kv.refcount(blk)}) or prefix-indexed; "
-                    "donated scatters would corrupt every other table "
+                    "donated in-place writes would corrupt every other table "
                     "mapping it (copy-on-write discipline breached)"
                 ),
                 where=f"slot{slot}/block{idx}",

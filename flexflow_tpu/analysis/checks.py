@@ -236,15 +236,20 @@ def check_paged_attn(artifact: ProgramArtifact) -> List[Violation]:
     the batched chunk program claiming ``paged`` must not lower the
     dense fallback's per-layer ``pool[tables]`` either — its output is
     ``slots`` lanes of virtual-length K/V, the exact O(S^2) hazard the
-    prefill kernel extension deletes.  Because a legitimate batched
-    prefill gathers (slots, chunk, hidden) token embeddings that can
-    exceed one LANE's K/V bytes at smoke scale, the prefill role
-    additionally requires the gather's operand to be pool-shaped
-    (ndim >= 4) — embedding tables are 2-D and never match.
+    prefill kernel extension deletes.
+
+    Only a gather FROM THE POOL counts: its operand is a layer of the
+    pool, ``(N, H, BS, D)``, or the whole of it.  A paged program has
+    other gathers that outgrow one lane's K/V bytes at some scale and
+    are none of the fallback's: the batched token-embedding lookup
+    (a 2-D table), and the page-write path's gather of each lane's new
+    rows into page shape (``paged_kv_write``: its operand is the
+    chunk's rows, (2, slots, chunk, H, D), and its size follows the
+    chunk, not the virtual length).
 
     Total: artifacts without a ``serve_attn: "paged"`` detail (gather
     engines, non-serve programs), without a jaxpr, or without a K/V
-    pool input all skip.  Small gathers (embedding lookups, per-page
+    pool input all skip.  Small gathers from the pool (per-page
     dynamic slices from the kernel's own lowering) sit far below the
     threshold and pass."""
     det = artifact.details or {}
@@ -267,20 +272,16 @@ def check_paged_attn(artifact: ProgramArtifact) -> List[Violation]:
     )
     if not mb or pool is None:
         return []
-    (_, _, h, bs, d), pool_dtype = pool
+    (_, n, h, bs, d), pool_dtype = pool
     lane_bytes = int(mb) * h * bs * d * _dtype_bytes(pool_dtype)
     out: List[Violation] = []
     for eqn in walk_jaxpr_eqns(artifact.jaxpr):
         if eqn.primitive.name not in ("gather", "take"):
             continue
-        if artifact.role == "prefill":
-            # pool-shaped operand only (see docstring): the batched
-            # token-embedding gather is big but 2-D-sourced and benign
-            aval0 = getattr(
-                eqn.invars[0] if eqn.invars else None, "aval", None
-            )
-            if aval0 is None or len(getattr(aval0, "shape", ())) < 4:
-                continue
+        # a gather from the pool only (see docstring)
+        aval0 = getattr(eqn.invars[0] if eqn.invars else None, "aval", None)
+        if tuple(getattr(aval0, "shape", ()))[-4:] != (n, h, bs, d):
+            continue
         for var in eqn.outvars:
             aval = getattr(var, "aval", None)
             if aval is None or not hasattr(aval, "shape"):

@@ -1,4 +1,5 @@
-"""Pallas paged decode attention — block-table-native K/V reads.
+"""Pallas paged decode attention — block-table-native K/V reads, and
+the page-write kernel that lands new K/V rows in the pool in place.
 
 The serving hot path (``serve/engine.py``) keeps each slot's K/V in a
 :class:`~flexflow_tpu.serve.kvcache.PagedKVCache` pool of fixed-size
@@ -43,7 +44,17 @@ clamped index (no DMA) and skips the compute, so per-layer traffic is
 O(chunk x visible) instead of the dense gather's O(chunk x SV), and
 the O(S^2)-in-SV prefill materialization never exists.
 
-Off-TPU the kernel runs in interpreter mode only (``INTERPRET``,
+The write side is :func:`paged_kv_write`: the serve programs hand it
+the WHOLE aliased pools and each lane's new rows, and it rewrites only
+the pages those rows fall in (decode G=1, verify G=k+1, prefill G=P
+with a padded tail).  Write, then attend — row ``g`` sees rows
+``0..g`` of its own chunk.  Both kernels take the pools row-major and
+whole, with the layer a static index in their index_maps, so nothing
+between a serve program's boundary and its kernels wants the pool in
+another layout or copies a layer out of it (an XLA scatter and a
+``pool[i]`` slice each did; PERF.md PR 27).
+
+Off-TPU the kernels run in interpreter mode only (``INTERPRET``,
 default from ``FFTPU_PALLAS_INTERPRET`` — see ``__init__.py``);
 :func:`supported` is the predicate ``ServeEngine``'s ``attn="auto"``
 consults before declining to the dense gather.
@@ -64,6 +75,7 @@ from flexflow_tpu.ops.pallas import env_interpret
 __all__ = [
     "INTERPRET",
     "paged_decode_attention",
+    "paged_kv_write",
     "paged_prefill_attention",
     "supported",
     "resolve_serve_attn",
@@ -181,12 +193,17 @@ def _kernel(
 
 
 def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
-                scale_k=None, scale_v=None):
+                scale_k=None, scale_v=None, layer=0):
     # NOT jitted here: the callers (the serve programs) are jitted
     # closures, and an own-cache jit would pin the INTERPRET flag at
     # first trace — tests flip it per engine build.
+    #
+    # The pools come in WHOLE, (L, N, H, BS, D), and ``layer`` is a
+    # static index inside the index_maps: a ``pool[layer]`` slice in
+    # front of a custom call is a copy of the layer (76 MB a layer at
+    # GPT-2-small width and 24 slots), not a view.
     B, G, H, D = q.shape
-    N, _, BS, _ = pool_k.shape
+    _, N, _, BS, _ = pool_k.shape
     MB = block_tables.shape[1]
     quantized = scale_k is not None
 
@@ -198,17 +215,17 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
         # an unchanged DMA (Mosaic skips it) and the i > last compute
         # is pl.when-gated off, so masked pages are never fetched
         last = jnp.minimum((pos_ref[b] + G - 1) // BS, MB - 1)
-        return (bt_ref[b, jnp.minimum(i, last)], 0, 0, 0)
+        return (layer, bt_ref[b, jnp.minimum(i, last)], 0, 0, 0)
 
     def sc_map(b, i, pos_ref, bt_ref):
         # the scale row rides the same physical-block index as its page
         last = jnp.minimum((pos_ref[b] + G - 1) // BS, MB - 1)
-        return (bt_ref[b, jnp.minimum(i, last)], 0, 0)
+        return (layer, bt_ref[b, jnp.minimum(i, last)], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, G, H, D), q_map),
-        pl.BlockSpec((1, H, BS, D), kv_map),
-        pl.BlockSpec((1, H, BS, D), kv_map),
+        pl.BlockSpec((None, 1, H, BS, D), kv_map),
+        pl.BlockSpec((None, 1, H, BS, D), kv_map),
     ]
     operands = [positions, block_tables, q, pool_k, pool_v]
     if quantized:
@@ -217,8 +234,8 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
         # trailing block dims that EQUAL the array's are always legal —
         # and the column broadcasts across the page's lanes as it is
         in_specs += [
-            pl.BlockSpec((1, BS, 1), sc_map),
-            pl.BlockSpec((1, BS, 1), sc_map),
+            pl.BlockSpec((None, 1, BS, 1), sc_map),
+            pl.BlockSpec((None, 1, BS, 1), sc_map),
         ]
         operands += [scale_k[..., None], scale_v[..., None]]
 
@@ -250,7 +267,7 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
 
 def paged_decode_attention(
     q, pool_k, pool_v, positions, block_tables, scale=None,
-    scale_k=None, scale_v=None,
+    scale_k=None, scale_v=None, layer=None,
 ):
     """Fused paged decode attention over one layer's K/V pool.
 
@@ -258,16 +275,21 @@ def paged_decode_attention(
       q: (B, G, H, D) query rows — ``G`` consecutive positions per
         lane (decode/draft G=1; speculative verify G=k+1).
       pool_k / pool_v: (num_blocks, H, BS, D) — the layer's paged pool
-        (physical block 0 is the allocator's trash block).
+        (physical block 0 is the allocator's trash block); or, with
+        ``layer`` given, the WHOLE (L, num_blocks, H, BS, D) pools, of
+        which the kernel reads layer ``layer`` (a static int) in place.
+        The serve programs pass the whole pools: a ``pool[i]`` slice in
+        front of the kernel is a copy of the layer, every call.
       positions: (B,) int32 — row 0's position per lane; row ``g``
         attends positions ``0 .. positions[b] + g`` inclusive (the
-        freshly scattered page rows included, matching the dense
+        freshly written page rows included, matching the dense
         path's ``k_pos <= pos`` mask).
       block_tables: (B, MB) int32 — logical page -> physical block.
       scale: score scale; default ``1/sqrt(D)``.
       scale_k / scale_v: optional (num_blocks, BS) float32 per-position
         dequant scales for an int8/fp8 pool (``PagedKVCache.scale_k[i]``
-        for layer ``i``); when given each DMA'd page is dequantized
+        for layer ``i``; the whole (L, num_blocks, BS) pools with
+        ``layer``); when given each DMA'd page is dequantized
         in-register via the shared ``int.astype(f32) * scale`` rule
         before the f32 online-softmax carry, so kernel and gather
         fallback stay bit-identical.  Pass both or neither.
@@ -282,25 +304,32 @@ def paged_decode_attention(
         scale = 1.0 / math.sqrt(q.shape[-1])
     positions = jnp.asarray(positions, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
+    if layer is None:
+        # one layer's pool is a pool of one layer (a reshape, no copy)
+        layer = 0
+        pool_k, pool_v = pool_k[None], pool_v[None]
+        if scale_k is not None:
+            scale_k, scale_v = scale_k[None], scale_v[None]
     return _paged_call(
         q, pool_k, pool_v, positions, block_tables, float(scale),
-        scale_k=scale_k, scale_v=scale_v,
+        scale_k=scale_k, scale_v=scale_v, layer=int(layer),
     )
 
 
 def paged_prefill_attention(
     q, pool_k, pool_v, start, block_tables, scale=None,
-    scale_k=None, scale_v=None,
+    scale_k=None, scale_v=None, layer=None,
 ):
     """Fused paged CHUNKED-PREFILL attention over one layer's K/V pool.
 
     The prefill-sized row group: ``q`` is (B, P, H, D) — P consecutive
     prompt positions per lane, row ``g`` of lane ``b`` at position
-    ``start[b] + g``.  The caller scatters the chunk's K/V into the
-    pool FIRST (padded rows to the trash block), then attends: row
+    ``start[b] + g``.  The caller writes the chunk's K/V into the
+    pool FIRST (:func:`paged_kv_write`; padded rows belong to the trash
+    block), then attends: row
     ``g``'s causal mask reaches positions ``0 .. start[b] + g``, which
     includes the chunk's own freshly written rows — the same
-    scatter-then-attend discipline as the speculative verify program,
+    write-then-attend discipline as the speculative verify program,
     at chunk width.
 
     What makes this the O(S^2) fix (docs/PERF.md): the kernel's
@@ -332,5 +361,136 @@ def paged_prefill_attention(
     # contract, no second code path to drift
     return paged_decode_attention(
         q, pool_k, pool_v, start, block_tables, scale=scale,
-        scale_k=scale_k, scale_v=scale_v,
+        scale_k=scale_k, scale_v=scale_v, layer=layer,
     )
+
+
+def _write_kernel(
+    phys_ref,  # SMEM (B, NP) int32 — physical block of lane b's page j
+    lo_ref,  # SMEM (B, NP) int32 — first new row of that page
+    hi_ref,  # SMEM (B, NP) int32 — one past its last new row
+    new_ref,  # VMEM (2, H, BS, D) — the lane's new K / V rows, page-shaped
+    k_ref,  # VMEM (H, BS, D) — page phys[b, j] of layer i
+    v_ref,
+    ko_ref,  # the same pages of the aliased pools
+    vo_ref,
+):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1)
+    fresh = (row >= lo_ref[b, j]) & (row < hi_ref[b, j])
+    ko_ref[...] = jnp.where(fresh, new_ref[0], k_ref[...])
+    vo_ref[...] = jnp.where(fresh, new_ref[1], v_ref[...])
+
+
+def _write_plan(start, block_tables, G, BS, n_valid=None):
+    """Which pages ``G`` consecutive rows a lane touch, and which rows of
+    each are new.  Returns (phys, lo, hi, page), all (B, NP) int32 with
+    ``NP = (G + BS - 2) // BS + 1``: lane b's j-th page is logical page
+    ``page[b, j]`` = physical block ``phys[b, j]``, and takes the rows
+    ``lo <= r < hi``.  A page that takes no row (past ``n_valid``, past
+    the table) names the trash block 0 with an empty range."""
+    MB = block_tables.shape[1]
+    NP = (G + BS - 2) // BS + 1
+    start = jnp.asarray(start, jnp.int32)[:, None]  # (B, 1)
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    end = start + (
+        G if n_valid is None else jnp.asarray(n_valid, jnp.int32)[:, None]
+    )
+    page = start // BS + jnp.arange(NP, dtype=jnp.int32)  # (B, NP)
+    lo = jnp.clip(start - page * BS, 0, BS)
+    hi = jnp.clip(end - page * BS, 0, BS)
+    live = (hi > lo) & (page < MB)
+    phys = jnp.where(
+        live,
+        jnp.take_along_axis(block_tables, jnp.clip(page, 0, MB - 1), axis=1),
+        0,
+    )
+    return phys, jnp.where(live, lo, 0), jnp.where(live, hi, 0), page
+
+
+def paged_kv_write(
+    pool_k, pool_v, layer, k, v, start, block_tables, n_valid=None
+):
+    """Write each lane's new K/V rows into layer ``layer`` of the paged
+    pools, in place: the device writer of the serve programs.
+
+    Args:
+      pool_k / pool_v: (L, num_blocks, H, BS, D) — the WHOLE pools.  They
+        go in and come out of one ``pallas_call`` aliased onto themselves
+        (``input_output_aliases``); ``layer`` is a static index inside
+        the ``index_map``, so no per-layer slice goes in or comes back
+        and XLA sees no operation that wants the pool in a layout other
+        than the attention kernel's.
+      k / v: (B, G, H, D) — row ``g`` of lane ``b`` belongs at position
+        ``start[b] + g`` (decode / draft G=1, verify G=k+1, prefill
+        G=P); cast to the pool's dtype like ``.at[...].set`` would.
+      start: (B,) int32.  block_tables: (B, MB) int32.
+      n_valid: (B,) int32 or None — only rows ``g < n_valid[b]`` are
+        written (a prefill chunk's padded tail); None writes all G.
+
+    G consecutive positions touch at most ``NP = (G + BS - 2) // BS + 1``
+    pages.  The grid is (B, NP): each step brings one (H, BS, D) page of
+    K and of V into VMEM, replaces the rows ``lo <= r < hi`` that are
+    new (a select against an iota over BS, which lowers for every pool
+    dtype) and writes the page back.  A page of the lane that takes no
+    row — past ``n_valid``, past the table, an idle lane — is steered to
+    the allocator's trash block 0 with an empty range.  Two grid steps
+    name the same page only there, so the pipeline's read-ahead can
+    never see a live page stale.  Every other byte of the pools is
+    untouched.
+
+    Returns the two pools.
+    """
+    _, _, H, BS, D = pool_k.shape
+    B, G = k.shape[:2]
+    phys, lo, hi, page = _write_plan(start, block_tables, G, BS, n_valid)
+    NP = phys.shape[1]
+    # the new rows in page shape: row r of page j is chunk row
+    # page * BS + r - start (clamped; rows outside [lo, hi) are not read)
+    kv = jnp.stack([k, v]).astype(pool_k.dtype)  # (2, B, G, H, D)
+    if G == 1:
+        # the decode step, every step: one row fills its page, no gather
+        new = jnp.broadcast_to(
+            kv[:, :, :, :, None, :], (2, B, NP, H, BS, D)
+        )
+    else:
+        first = jnp.asarray(start, jnp.int32)[:, None, None]
+        g = page[:, :, None] * BS + jnp.arange(BS, dtype=jnp.int32) - first
+        g = jnp.clip(g, 0, G - 1).reshape(1, B, NP * BS, 1, 1)
+        new = jnp.take_along_axis(kv, g, axis=2).reshape(
+            2, B, NP, BS, H, D
+        ).transpose(0, 1, 2, 4, 3, 5)
+
+    def new_map(b, j, phys_ref, lo_ref, hi_ref):
+        return (0, b, j, 0, 0, 0)
+
+    def page_map(b, j, phys_ref, lo_ref, hi_ref):
+        return (layer, phys_ref[b, j], 0, 0, 0)
+
+    page_spec = pl.BlockSpec((None, None, H, BS, D), page_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, NP),
+        in_specs=[
+            pl.BlockSpec((2, None, None, H, BS, D), new_map),
+            page_spec,
+            page_spec,
+        ],
+        out_specs=[page_spec, page_spec],
+    )
+    return pl.pallas_call(
+        _write_kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
+            jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype),
+        ],
+        # operands: phys, lo, hi, new, pool_k, pool_v
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=None if INTERPRET else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
+        interpret=INTERPRET,
+        name="kv_page_write",
+    )(phys, lo, hi, new, pool_k, pool_v)

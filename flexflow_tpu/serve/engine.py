@@ -3,11 +3,26 @@
 Execution model (docs/SERVING.md):
 
 * ONE jitted **decode step** serves every slot every step: inputs are
-  the paged K/V pools ``(L, num_blocks, H, block_size, D)`` (donated —
-  XLA scatters in place), per-slot tokens/positions, and the per-slot
+  the paged K/V pools ``(L, num_blocks, H, block_size, D)`` (donated,
+  and written in place), per-slot tokens/positions, and the per-slot
   block tables.  Inactive lanes carry an all-zero table row, so their
   writes land in the trash block (kvcache.py) — no masking, no
   recompile when the active set changes.
+* Every program writes its new K/V rows through ONE helper
+  (``write_kv``), which rides the engine's ``attn_kernel`` decision:
+  ``paged`` programs write through the Pallas page-write kernel
+  (``ops/pallas/paged_attention.py::paged_kv_write``) on the aliased
+  pools and hand both kernels the WHOLE pools (the layer is a static
+  index inside the kernels' index_maps), so that between a program's
+  boundary and its kernels nothing wants the pool in a third layout
+  and no layer is sliced out of it; ``gather`` programs keep the XLA
+  scatter ``ck.at[i, blk, :, off, :].set(k)``, which on the CPU is in
+  place.  ``ServeEngine.kv_write`` says which (``page_kernel`` /
+  ``xla_scatter``).  What is left on the TPU is the boundary itself:
+  at rest the backend keeps a (..., 16, 64) array with the block
+  dimension minor-most, the kernels take it row-major, so each call
+  re-lays both pools out once on the way in and once on the way out
+  (PERF.md, PR 27: why that takes another pool geometry to remove).
 * A **batched chunked prefill program** ingests ``P`` prompt positions
   per mid-prefill slot, ALL slots in ONE dispatch per window (static
   chunk size — ONE compile serves every prompt length and every
@@ -175,6 +190,7 @@ class ServeReport:
     # slot count (prefill_chunks keeps counting per-slot logical chunks)
     prefill_dispatches: int = 0
     prefill_attn_kernel: Optional[str] = None  # kernel prefill ran on
+    kv_write: Optional[str] = None  # "page_kernel" | "xla_scatter"
     # --- what it ran on (ServeEngine.device_info) ---
     attn_interpret: bool = False  # paged kernel ran in the Pallas interpreter
     device: Dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -266,6 +282,13 @@ class ServeEngine:
         # the flag is read when the programs trace — at the warmup below
         self.attn_interpret = (
             self.attn_kernel == "paged" and bool(_pattn.INTERPRET)
+        )
+        # how the programs write new K/V rows into the pool rides the
+        # same decision (``write_kv`` below): the Pallas page-write
+        # kernel wherever the paged attention kernel runs, the XLA
+        # scatter where Pallas cannot.  It engages on every call or none
+        self.kv_write = (
+            "page_kernel" if self.attn_kernel == "paged" else "xla_scatter"
         )
         dt = model.executor.compute_dtype
         # quantized serving arms (docs/SERVING.md "Quantized KV cache
@@ -405,8 +428,47 @@ class ServeEngine:
         if paged:
             from flexflow_tpu.ops.pallas.paged_attention import (
                 paged_decode_attention,
+                paged_kv_write,
                 paged_prefill_attention,
             )
+
+        def write_kv(ck, cv, sk, sv, i, k, v, start, bt, n_valid=None):
+            # THE write of a chunk's new K/V into layer i of the pools,
+            # for all four programs: k / v are (B, G, H, D), row g of
+            # lane b sits at position start[b] + g, and rows at or past
+            # n_valid[b] (the padded tail of a prefill chunk, a whole
+            # padded lane) belong to the trash block.  It rides the
+            # decision the engine already made (``self.kv_write``):
+            # paged programs write through the Pallas page-write kernel
+            # on the aliased pools, so that both users of the pool want
+            # it in ONE layout (an XLA scatter wants a third, and cost
+            # two more re-layouts of a layer, every layer); gather
+            # programs keep the XLA scatter, which is in place on the
+            # CPU.  A quantized pool stores ints plus a per-position
+            # scale; the (L, NB, BS) scale pools are small and scatter
+            # on adjacent index dimensions either way.
+            G = k.shape[1]
+            if quant or not paged:
+                pos = start[:, None] + jnp.arange(G)[None, :]
+                blk = bt[
+                    jnp.arange(B)[:, None], jnp.clip(pos // BS, 0, MB - 1)
+                ]
+                off = jnp.clip(pos % BS, 0, BS - 1)
+                if n_valid is not None:
+                    valid = jnp.arange(G)[None, :] < n_valid[:, None]
+                    blk = jnp.where(valid, blk, 0)
+                    off = jnp.where(valid, off, 0)
+            if quant:
+                k, ksc = quantize_kv(jnp, k, kvdt)  # scales (B, G)
+                v, vsc = quantize_kv(jnp, v, kvdt)
+                sk = sk.at[i, blk, off].set(ksc)
+                sv = sv.at[i, blk, off].set(vsc)
+            if paged:
+                ck, cv = paged_kv_write(ck, cv, i, k, v, start, bt, n_valid)
+            else:
+                ck = ck.at[i, blk, :, off, :].set(k)
+                cv = cv.at[i, blk, :, off, :].set(v)
+            return ck, cv, sk, sv
 
         def decode(params, ck, cv, *rest):
             # tok/pos (B,) int32; bt (B, MB) int32 block tables; a
@@ -422,9 +484,6 @@ class ServeEngine:
             x = x + params["pos_embed"]["value"][
                 jnp.clip(pos, 0, S_pos - 1)
             ]
-            lane = jnp.arange(B)
-            blk = bt[lane, jnp.clip(pos // BS, 0, MB - 1)]  # (B,)
-            off = jnp.clip(pos % BS, 0, BS - 1)
             mask = (jnp.arange(SV)[None, :] <= pos[:, None])[:, None, :]
             for i in range(L):
                 p_at = params[f"dec{i}_attn"]
@@ -435,24 +494,17 @@ class ServeEngine:
                 if has_bias:
                     q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
                 q = q.reshape(B, H, D)
-                k = k.reshape(B, H, D)
-                v = v.reshape(B, H, D)
-                # scatter this position's k/v into each lane's block
-                # (quantized pools store ints + a per-position scale)
-                if quant:
-                    k, ksc = quantize_kv(jnp, k, kvdt)
-                    v, vsc = quantize_kv(jnp, v, kvdt)
-                    sk = sk.at[i, blk, off].set(ksc)
-                    sv = sv.at[i, blk, off].set(vsc)
-                ck = ck.at[i, blk, :, off, :].set(k)
-                cv = cv.at[i, blk, :, off, :].set(v)
+                # write this position's k/v into each lane's block
+                ck, cv, sk, sv = write_kv(
+                    ck, cv, sk, sv, i,
+                    k.reshape(B, 1, H, D), v.reshape(B, 1, H, D), pos, bt,
+                )
                 if paged:
                     # block-table-native reads: no dense gather exists
                     # in the lowered program (ffcheck ``paged_attn``)
                     o = paged_decode_attention(
-                        q[:, None], ck[i], cv[i], pos, bt, scale=scale,
-                        scale_k=sk[i] if quant else None,
-                        scale_v=sv[i] if quant else None,
+                        q[:, None], ck, cv, pos, bt, scale=scale,
+                        scale_k=sk, scale_v=sv, layer=i,
                     )[:, 0]
                 else:
                     # gather each lane's pages: (B, MB, H, BS, D) ->
@@ -509,16 +561,8 @@ class ServeEngine:
             params = prep_params(params)
             lane = jnp.arange(B)
             pos = start[:, None] + jnp.arange(P)[None, :]  # (B, P)
-            valid = jnp.arange(P)[None, :] < n_valid[:, None]
             x = params["tok_embed"]["kernel"][toks]  # (B, P, hidden)
             x = x + params["pos_embed"]["value"][jnp.clip(pos, 0, S_pos - 1)]
-            # padded rows (and whole padded lanes) write the trash block
-            blk = jnp.where(
-                valid,
-                bt[lane[:, None], jnp.clip(pos // BS, 0, MB - 1)],
-                0,
-            )  # (B, P)
-            off = jnp.where(valid, pos % BS, 0)
             mask = (
                 jnp.arange(SV)[None, None, :] <= pos[..., None]
             )[:, :, None, :]  # (B, P, 1, SV)
@@ -537,18 +581,15 @@ class ServeEngine:
                 q = q.reshape(B, P, H, D)
                 k = k.reshape(B, P, H, D)
                 v = v.reshape(B, P, H, D)
-                # scatter the whole chunk, THEN attend: row g's mask
+                # write the whole chunk, THEN attend: row g's mask
                 # reaches rows 0..g of this same program (the verify
                 # discipline) — and under prefix sharing a chunk never
                 # writes a still-shared block (commit happens post-
-                # chunk, CoW-audited by serve_cow)
-                if quant:
-                    k, ksc = quantize_kv(jnp, k, kvdt)  # scale (B, P)
-                    v, vsc = quantize_kv(jnp, v, kvdt)
-                    sk = sk.at[i, blk, off].set(ksc)
-                    sv = sv.at[i, blk, off].set(vsc)
-                ck = ck.at[i, blk, :, off, :].set(k)
-                cv = cv.at[i, blk, :, off, :].set(v)
+                # chunk, CoW-audited by serve_cow).  Padded rows (and
+                # whole padded lanes) belong to the trash block
+                ck, cv, sk, sv = write_kv(
+                    ck, cv, sk, sv, i, k, v, start, bt, n_valid
+                )
                 if paged:
                     # block-table-native chunk attention: the kernel's
                     # visible-page clamp reads ceil((start + P) / BS)
@@ -556,9 +597,8 @@ class ServeEngine:
                     # O(S^2)-in-SV traffic (ffcheck ``paged_attn`` now
                     # audits prefill too)
                     o = paged_prefill_attention(
-                        q, ck[i], cv[i], start, bt, scale=scale,
-                        scale_k=sk[i] if quant else None,
-                        scale_v=sv[i] if quant else None,
+                        q, ck, cv, start, bt, scale=scale,
+                        scale_k=sk, scale_v=sv, layer=i,
                     )
                 else:
                     keys = ck[i][bt]
@@ -627,9 +667,6 @@ class ServeEngine:
             x = x + params["pos_embed"]["value"][
                 jnp.clip(pos, 0, S_pos - 1)
             ]
-            lane = jnp.arange(B)
-            blk = bt[lane, jnp.clip(pos // BS, 0, MB - 1)]
-            off = jnp.clip(pos % BS, 0, BS - 1)
             mask = (jnp.arange(SV)[None, :] <= pos[:, None])[:, None, :]
             for i in range(Ld):
                 p_at = params[f"dec{i}_attn"]
@@ -640,20 +677,14 @@ class ServeEngine:
                 if has_bias:
                     q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
                 q = q.reshape(B, H, D)
-                k = k.reshape(B, H, D)
-                v = v.reshape(B, H, D)
-                if quant:
-                    k, ksc = quantize_kv(jnp, k, kvdt)
-                    v, vsc = quantize_kv(jnp, v, kvdt)
-                    sk = sk.at[i, blk, off].set(ksc)
-                    sv = sv.at[i, blk, off].set(vsc)
-                ck = ck.at[i, blk, :, off, :].set(k)
-                cv = cv.at[i, blk, :, off, :].set(v)
+                ck, cv, sk, sv = write_kv(
+                    ck, cv, sk, sv, i,
+                    k.reshape(B, 1, H, D), v.reshape(B, 1, H, D), pos, bt,
+                )
                 if paged:
                     o = paged_decode_attention(
-                        q[:, None], ck[i], cv[i], pos, bt, scale=scale,
-                        scale_k=sk[i] if quant else None,
-                        scale_v=sv[i] if quant else None,
+                        q[:, None], ck, cv, pos, bt, scale=scale,
+                        scale_k=sk, scale_v=sv, layer=i,
                     )[:, 0]
                 else:
                     keys = ck[i][bt]
@@ -707,8 +738,6 @@ class ServeEngine:
             pos = pos0[:, None] + jnp.arange(W)[None, :]  # (B, W)
             x = params["tok_embed"]["kernel"][toks]  # (B, W, hidden)
             x = x + params["pos_embed"]["value"][jnp.clip(pos, 0, S_pos - 1)]
-            blk = bt[lane[:, None], jnp.clip(pos // BS, 0, MB - 1)]  # (B, W)
-            off = jnp.clip(pos % BS, 0, BS - 1)
             mask = (
                 jnp.arange(SV)[None, None, :] <= pos[..., None]
             )[:, :, None, :]  # (B, W, 1, SV)
@@ -724,23 +753,18 @@ class ServeEngine:
                 q = q.reshape(B, W, H, D)
                 k = k.reshape(B, W, H, D)
                 v = v.reshape(B, W, H, D)
-                # scatter all W rows, THEN attend: row j's mask reaches
+                # write all W rows, THEN attend: row j's mask reaches
                 # rows 0..j of this same program, freshly written (the
                 # prefill-chunk discipline, batched over slots)
-                if quant:
-                    k, ksc = quantize_kv(jnp, k, kvdt)  # scale (B, W)
-                    v, vsc = quantize_kv(jnp, v, kvdt)
-                    sk = sk.at[i, blk, off].set(ksc)
-                    sv = sv.at[i, blk, off].set(vsc)
-                ck = ck.at[i, blk, :, off, :].set(k)
-                cv = cv.at[i, blk, :, off, :].set(v)
+                ck, cv, sk, sv = write_kv(
+                    ck, cv, sk, sv, i, k, v, pos0, bt
+                )
                 if paged:
                     # one kernel call covers all W rows: row j's mask
                     # reaches position pos0 + j (G = W generalization)
                     o = paged_decode_attention(
-                        q, ck[i], cv[i], pos0, bt, scale=scale,
-                        scale_k=sk[i] if quant else None,
-                        scale_v=sv[i] if quant else None,
+                        q, ck, cv, pos0, bt, scale=scale,
+                        scale_k=sk, scale_v=sv, layer=i,
                     )
                 else:
                     keys = ck[i][bt]
@@ -1538,6 +1562,9 @@ class ServeEngine:
                         # tools/serve_report.py stays silent)
                         "prefill_attn_kernel": self.attn_kernel,
                         "prefill_dispatches": 1 if chunks else 0,
+                        # how the programs wrote this window's new K/V rows
+                        # into the pool (ADDITIVE, as the two above)
+                        "kv_write": self.kv_write,
                         # quantized-serving vocabulary (ADDITIVE — r19): the
                         # pool/weight formats and the per-position HBM cost
                         "kv_dtype": self.kv_dtype,
@@ -1613,6 +1640,7 @@ class ServeEngine:
             "drained": self.drained,
             "watchdog_fires": self.watchdog_fires,
             "attn_kernel": self.attn_kernel,
+            "kv_write": self.kv_write,
             "kv_dtype": self.kv_dtype,
             "weight_dtype": self.weight_dtype,
         }
@@ -1705,6 +1733,7 @@ class ServeEngine:
             watchdog_fires=self.watchdog_fires,
             prefill_dispatches=self.prefill_dispatches,
             prefill_attn_kernel=self.attn_kernel,
+            kv_write=self.kv_write,
             attn_interpret=self.attn_interpret,
             device=self.device_info(),
         )

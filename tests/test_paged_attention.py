@@ -10,7 +10,11 @@ mid-generation / the speculative verify program at k>=1, the ffcheck
 ``paged_attn`` audit (clean on the real paged programs, fires on a
 gather program claiming to be paged), the additive ffmetrics/1
 ``attn_kernel`` field + old/new stream interop, and the
-``FFTPU_PALLAS_INTERPRET`` env override.
+``FFTPU_PALLAS_INTERPRET`` env override.  ISSUE 27 adds the page-write
+kernel: bit for bit against ``pool.at[i, blk, :, off, :].set(rows)``
+at every row-group width and pool dtype, the pages no lane names
+untouched, and the paged engine (kernel writer) against the gather
+engine (XLA scatter) over prefill, decode and slot recycling.
 """
 
 from __future__ import annotations
@@ -167,6 +171,116 @@ def test_kernel_bf16_io_f32_accumulate(interpret):
     )
 
 
+# ----------------------------------------------------------- page write
+POOL_DTYPES = {
+    "fp32": jnp.float32, "bf16": jnp.bfloat16,
+    "int8": jnp.int8, "fp8": jnp.float8_e4m3fn,
+}
+L_W, B_W, H_W, D_W, BS_W, MB_W = 3, 4, 2, 8, 4, 5
+N_W = B_W * MB_W + 1  # + trash block 0
+
+
+def _write_case(case, rng):
+    """(G, start, n_valid, idle) of one row-group shape the programs
+    write: decode / draft, verify, a prefill chunk."""
+    if case == "decode":  # G = 1 anywhere in the lane's window
+        return 1, rng.integers(0, MB_W * BS_W, size=(B_W,)), None, ()
+    if case == "verify":  # G = k + 1, unaligned, crossing a page boundary
+        return 3, np.array([BS_W - 1, 2 * BS_W - 2, 5, 0]), None, ()
+    if case == "verify_wide":  # G > 2 pages' worth from an unaligned start
+        return 2 * BS_W + 1, np.array([BS_W - 1, 1, 2, BS_W + 3]), None, ()
+    # G = P: a full lane, a short tail, a one-row tail, a whole padded lane
+    return 2 * BS_W, np.array([BS_W, 3, 2 * BS_W + 1, 0]), \
+        np.array([2 * BS_W, 3, 1, 0]), (3,)
+
+
+@pytest.mark.parametrize("kv_dtype", list(POOL_DTYPES))
+@pytest.mark.parametrize(
+    "case", ["decode", "verify", "verify_wide", "prefill"]
+)
+def test_kv_page_write_matches_scatter(interpret, case, kv_dtype):
+    """The kernel against the XLA scatter it replaces, bit for bit: the
+    rows land where ``pool.at[i, blk, :, off, :].set(rows)`` puts them,
+    the other layers and every page no lane names are byte-identical
+    before and after (the alias writes nothing else), and where a chunk
+    has padded rows only the trash block 0 may differ."""
+    dt = POOL_DTYPES[kv_dtype]
+    rng = np.random.default_rng(len(case) * 7 + len(kv_dtype))
+    G, start, n_valid, idle = _write_case(case, rng)
+    start = np.asarray(start, np.int32)
+
+    def rand(shape):  # small integers: exact in every pool dtype
+        return jnp.asarray(
+            rng.integers(-8, 9, size=shape), jnp.float32
+        ).astype(dt)
+
+    pk, pv = rand((L_W, N_W, H_W, BS_W, D_W)), rand((L_W, N_W, H_W, BS_W, D_W))
+    k, v = rand((B_W, G, H_W, D_W)), rand((B_W, G, H_W, D_W))
+    bt = (rng.permutation(N_W - 1) + 1)[: B_W * MB_W].reshape(B_W, MB_W)
+    bt = bt.astype(np.int32)
+    for b in idle:
+        bt[b] = 0  # an idle lane rides with an all-zero table row
+    # the programs' scatter indices (serve/engine.py::write_kv)
+    pos = start[:, None] + np.arange(G)[None]
+    blk = bt[np.arange(B_W)[:, None], np.clip(pos // BS_W, 0, MB_W - 1)]
+    off = pos % BS_W
+    if n_valid is not None:
+        valid = np.arange(G)[None] < np.asarray(n_valid)[:, None]
+        blk, off = np.where(valid, blk, 0), np.where(valid, off, 0)
+    layer = 1
+    want_k = pk.at[layer, blk, :, off, :].set(k)
+    want_v = pv.at[layer, blk, :, off, :].set(v)
+    got_k, got_v = pa.paged_kv_write(
+        pk, pv, layer, k, v, jnp.asarray(start), jnp.asarray(bt),
+        None if n_valid is None else jnp.asarray(n_valid, jnp.int32),
+    )
+    assert got_k.dtype == dt and got_v.dtype == dt
+
+    def raw(x):  # compare bytes, not values (fp8 NaN payloads included)
+        return np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+
+    first = 1 if n_valid is not None else 0  # padded rows: block 0 is free
+    for got, want, before in ((got_k, want_k, pk), (got_v, want_v, pv)):
+        np.testing.assert_array_equal(
+            raw(got)[:, first:], raw(want)[:, first:]
+        )
+        named = np.zeros(N_W, bool)
+        named[blk.ravel()] = True
+        named[0] = True
+        np.testing.assert_array_equal(
+            raw(got)[:, ~named], raw(before)[:, ~named]
+        )
+        other = [i for i in range(L_W) if i != layer]
+        np.testing.assert_array_equal(
+            raw(got)[other][:, first:], raw(before)[other][:, first:]
+        )
+
+
+@pytest.mark.parametrize("G", [1, 3, BS_W, 2 * BS_W + 1])
+def test_kv_page_write_plan_shares_only_the_trash_block(G):
+    """Two grid steps name the same physical page only for block 0: the
+    kernel's read-ahead may then never see a live page stale.  Disjoint
+    tables, ragged starts, short and empty chunks."""
+    rng = np.random.default_rng(G)
+    bt = (rng.permutation(N_W - 1) + 1)[: B_W * MB_W].reshape(B_W, MB_W)
+    bt[2] = 0
+    start = rng.integers(0, MB_W * BS_W - G + 1, size=(B_W,))
+    start[2] = 0
+    n_valid = rng.integers(0, G + 1, size=(B_W,))
+    n_valid[0], n_valid[2] = G, 0
+    phys, lo, hi, _ = (
+        np.asarray(x) for x in pa._write_plan(
+            jnp.asarray(start, jnp.int32), jnp.asarray(bt, jnp.int32),
+            G, BS_W, jnp.asarray(n_valid, jnp.int32),
+        )
+    )
+    assert phys.shape == (B_W, (G + BS_W - 2) // BS_W + 1)
+    live = phys[phys > 0]
+    assert len(set(live.tolist())) == live.size
+    assert ((hi > lo) == (phys > 0)).all()  # block 0 takes an empty range
+    assert (hi - lo).sum(axis=1).tolist() == n_valid.tolist()
+
+
 # ----------------------------------------------------------------- knob
 def test_resolve_serve_attn_semantics():
     old = pa.INTERPRET
@@ -265,6 +379,43 @@ def test_paged_streams_bit_identical_across_block_sizes(
     assert rg.requests_finished == rp.requests_finished == 4
     assert _streams(reqs_g) == _streams(reqs_p)
     page.kv.check_invariants()
+
+
+def test_paged_write_kernel_streams_equal_scatter_engine(
+    model, gather_engine, interpret
+):
+    """The one choice the engine makes: ``paged`` programs write new
+    K/V rows through the page-write kernel, ``gather`` programs through
+    the XLA scatter.  More requests than slots, prompts longer than a
+    prefill chunk and than a page: batched chunked prefill (short
+    tails, idle lanes), decode, and every slot recycled — the greedy
+    streams are the same, and both engines say which writer they ran."""
+    def traffic():
+        return synthetic_requests(TrafficSpec(
+            n_requests=3 * SLOTS + 1, seed=27, rate_rps=0.0,
+            prompt_len=(3, 21), max_new=(2, 7), vocab=VOCAB,
+        ))
+
+    page = ServeEngine(model, slots=SLOTS, block_size=8, prefill_chunk=5,
+                       sync_every=3, attn="paged")
+    assert page.kv_write == "page_kernel"
+    assert gather_engine.kv_write == "xla_scatter"
+    reqs_p, reqs_g = traffic(), traffic()
+    rp = page.run(reqs_p)
+    rg = gather_engine.run(reqs_g)
+    assert rp.requests_finished == rg.requests_finished == 3 * SLOTS + 1
+    assert rp.prefill_dispatches < rp.prefill_chunks  # lanes co-prefilled
+    assert _streams(reqs_p) == _streams(reqs_g)
+    assert rp.kv_write == "page_kernel" and rg.kv_write == "xla_scatter"
+    assert rp.to_dict()["kv_write"] == "page_kernel"
+    page.kv.check_invariants()
+    # the audits keep passing on the new programs: the write path's
+    # gather of a chunk's rows into page shape (here 2 * 4 lanes * 2
+    # pages: more than one lane's 6 pages) is not a gather from the pool
+    from flexflow_tpu.analysis import analyze_serve_engine
+
+    rep = analyze_serve_engine(page, checks=["paged_attn", "serve_cow"])
+    assert rep.ok, rep.format_human()
 
 
 def test_paged_composes_with_prefix_sharing(model, interpret):

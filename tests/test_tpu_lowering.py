@@ -89,6 +89,85 @@ def test_paged_attention_lowers_for_tpu(G, kv_dtype):
     _lower_for_tpu(fn, *avals)
 
 
+L = 2  # layers of the pools below: enough to chain two writes
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8", "fp8"])
+@pytest.mark.parametrize("G", [1, 5, 32])
+def test_kv_page_write_lowers_for_tpu(G, kv_dtype):
+    """The page-write kernel at decode / verify / prefill width: the
+    select against the iota over BS has to lower for every pool dtype,
+    packed ones included."""
+    pool_dt = kv_pool_dtype(jnp, kv_dtype, fallback=jnp.float32)
+    sds = jax.ShapeDtypeStruct
+
+    def fn(ck, cv, k, v, start, bt, n_valid):
+        for i in range(L):
+            ck, cv = pa.paged_kv_write(ck, cv, i, k, v, start, bt, n_valid)
+        return ck, cv
+
+    _lower_for_tpu(
+        fn,
+        sds((L, N, H, BS, D), pool_dt), sds((L, N, H, BS, D), pool_dt),
+        sds((B, G, H, D), pool_dt), sds((B, G, H, D), pool_dt),
+        sds((B,), jnp.int32), sds((B, MB), jnp.int32), sds((B,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("G", [1, 32])
+def test_serve_layers_leave_the_pool_to_the_kernels(G):
+    """What the serve programs do with the pools, compiled for a v5e: a
+    layer writes its new rows through ``kv_page_write`` and attends
+    through the paged kernel, both on the WHOLE pools.  Between the
+    program's boundary and its kernels XLA then touches the pools four
+    times in all — one layout copy of each pool on the way in and one
+    on the way out (at rest the TPU keeps a (..., 16, 64) array with
+    the block dimension minor-most, the kernels take it row-major) —
+    however many layers there are: no scatter, no per-layer slice or
+    re-layout (ISSUE 27: each cost a copy of the pool or of a layer,
+    every layer of every call)."""
+    import re
+
+    sh = _v5e_sharding()
+    if sh is None:
+        pytest.skip("no v5e topology can be described here")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    def fn(ck, cv, q, k, v, start, bt):
+        o = q
+        for i in range(L):
+            ck, cv = pa.paged_kv_write(ck, cv, i, k, v, start, bt)
+            o = o + pa.paged_decode_attention(o, ck, cv, start, bt, layer=i)
+        return o, ck, cv
+
+    pool = sds((L, N, H, BS, D), jnp.bfloat16)
+    rows = sds((B, G, H, D), jnp.bfloat16)
+    txt = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        pool, pool, rows, rows, rows, sds((B,), jnp.int32), sds((B, MB), jnp.int32),
+    ).compile().as_text()
+    pool_sized = re.compile(
+        rf"^\s*(?:ROOT )?%(\S+) = \(?bf16\[(?:{L},)?{N},{H},{BS},{D}\]\S* "
+        r"(?:bf16\S+ )?([\w-]+)\(", re.M,
+    )
+    made = [
+        (name, op) for name, op in pool_sized.findall(txt)
+        if op not in ("parameter", "get-tuple-element", "tuple", "bitcast")
+    ]
+    kernels = [n for n, op in made if op == "custom-call"]
+    others = [(n, op) for n, op in made if op != "custom-call"]
+    assert len(kernels) == L and all(
+        n.startswith("kv_page_write") for n in kernels
+    ), made
+    # (a pool this small may also be staged through faster memory:
+    # copy-start / copy-done, which the cell-sized pools never are)
+    assert all(op.startswith("copy") for _, op in others), others
+    assert sum(op == "copy" for _, op in others) <= 4, others
+    # the attention kernel keeps the name the benchmark's regex reads
+    assert len(re.findall(r"^\s*%fn[.\d]* = \S+ custom-call\(", txt, re.M)) == L
+
+
 @pytest.mark.parametrize("dropout", [0.0, 0.1])
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_lowers_for_tpu(causal, dropout):

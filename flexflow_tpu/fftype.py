@@ -136,9 +136,12 @@ class OperatorType(enum.Enum):
     REDUCE_SUM = "reduce_sum"
     REDUCE_MEAN = "reduce_mean"
     MULTIHEAD_ATTENTION = "multihead_attention"
+    GATED_ATTENTION = "gated_attention"
+    GATED_DELTA_NET = "gated_delta_net"
     TOPK = "topk"
     GROUP_BY = "group_by"
     EXPERTS = "experts"
+    ROUTED_EXPERTS = "routed_experts"
     CAST = "cast"
     FUSED = "fused"
     # --- parallel ops (the resharding vocabulary, ffconst.h:152-158) ---

@@ -21,8 +21,12 @@ from flexflow_tpu.fftype import LossType
 
 
 def sparse_categorical_crossentropy(probs: jax.Array, labels: jax.Array) -> jax.Array:
-    """probs: (batch, classes) post-softmax; labels: int (batch,) or (batch,1)."""
-    labels = labels.reshape(labels.shape[0]).astype(jnp.int32)
+    """probs: (..., classes) post-softmax; labels: int, one per row of
+    ``probs`` in any shape that holds them -- (batch,), (batch, 1), or
+    next-token labels (batch, seq) against (batch, seq, classes) or
+    (batch*seq, classes)."""
+    probs = probs.reshape(-1, probs.shape[-1])
+    labels = labels.reshape(probs.shape[0]).astype(jnp.int32)
     p = jnp.take_along_axis(probs, labels[:, None], axis=-1)[:, 0]
     return -jnp.mean(jnp.log(jnp.maximum(p, 1e-12)))
 

@@ -34,6 +34,13 @@ import jax.numpy as jnp
 from flexflow_tpu.fftype import LossType, MetricsType
 
 
+# What ops count inside the step program (``OpDef.step_counters`` /
+# ``step_gauges``) rides the step's metrics under these prefixes: a
+# counter adds up over steps, a gauge is averaged over rows like a metric.
+COUNTER_PREFIX = "counter:"
+GAUGE_PREFIX = "gauge:"
+
+
 @dataclasses.dataclass
 class PerfMetrics:
     """Host-side accumulator (reference ``metrics_functions.h:19-42``)."""
@@ -46,9 +53,32 @@ class PerfMetrics:
     rmse_loss: float = 0.0
     mae_loss: float = 0.0
     start_time: float = dataclasses.field(default_factory=time.time)
+    # the ops' own counts (docs/OBSERVABILITY.md, "Step counters"):
+    # counters summed over the epoch's steps, gauges as row-weighted sums
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
+    gauge_sums: Dict[str, float] = dataclasses.field(default_factory=dict)
+
+    def gauge(self, name: str) -> Optional[float]:
+        """Row-weighted mean of a step gauge over the epoch so far."""
+        if name not in self.gauge_sums or not self.train_all:
+            return None
+        return self.gauge_sums[name] / self.train_all
+
+    def _merge_counted(self, sums: Dict[str, float]) -> None:
+        for k, v in sums.items():
+            if k.startswith(COUNTER_PREFIX):
+                n = k[len(COUNTER_PREFIX):]
+                self.counters[n] = self.counters.get(n, 0.0) + v
+            elif k.startswith(GAUGE_PREFIX):
+                n = k[len(GAUGE_PREFIX):]
+                self.gauge_sums[n] = self.gauge_sums.get(n, 0.0) + v
 
     def update(self, batch_metrics: Dict[str, float], batch_size: int) -> None:
         self.train_all += batch_size
+        self._merge_counted({
+            k: v * (batch_size if k.startswith(GAUGE_PREFIX) else 1)
+            for k, v in batch_metrics.items()
+        })
         if "accuracy" in batch_metrics:
             self.train_correct += int(batch_metrics["accuracy"] * batch_size + 0.5)
         self.cce_loss += batch_metrics.get("categorical_crossentropy", 0.0) * batch_size
@@ -69,6 +99,7 @@ class PerfMetrics:
         so one rounding at the flush recovers the same correct-count as
         per-step rounding)."""
         self.train_all += count
+        self._merge_counted(sums)
         if "accuracy" in sums:
             self.train_correct += int(sums["accuracy"] + 0.5)
         self.cce_loss += sums.get("categorical_crossentropy", 0.0)
@@ -109,16 +140,22 @@ class DeviceMetricAccumulator:
         if not metrics:
             return
         w = float(rows)
+
+        def weight(k, w):  # a counter adds up as it is
+            return 1.0 if k.startswith(COUNTER_PREFIX) else w
+
         if self._sums is None:
             # first window step: weighted copy (eager async dispatch)
             self._sums = {
-                k: jnp.asarray(v, jnp.float32) * w for k, v in metrics.items()
+                k: jnp.asarray(v, jnp.float32) * weight(k, w)
+                for k, v in metrics.items()
             }
             return
         if self._acc is None:
             self._acc = jax.jit(
                 lambda s, m, w: {
-                    k: s[k] + jnp.asarray(m[k], jnp.float32) * w for k in s
+                    k: s[k] + jnp.asarray(m[k], jnp.float32) * weight(k, w)
+                    for k in s
                 },
                 donate_argnums=(0,),
             )
@@ -153,8 +190,8 @@ class Metrics:
         for m in self.metrics:
             if m is MetricsType.ACCURACY:
                 if sparse:
-                    lab = labels.reshape(labels.shape[0]).astype(jnp.int32)
-                    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(-1)
+                    lab = labels.reshape(pred.shape[0]).astype(jnp.int32)
                 else:
                     lab = jnp.argmax(labels, axis=-1)
                     pred = jnp.argmax(logits, axis=-1)

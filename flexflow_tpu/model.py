@@ -50,7 +50,13 @@ from flexflow_tpu.fftype import (
     PoolType,
 )
 from flexflow_tpu.initializer import Initializer
-from flexflow_tpu.metrics import DeviceMetricAccumulator, Metrics, PerfMetrics
+from flexflow_tpu.metrics import (
+    COUNTER_PREFIX,
+    GAUGE_PREFIX,
+    DeviceMetricAccumulator,
+    Metrics,
+    PerfMetrics,
+)
 from flexflow_tpu.obs import (
     HealthError,
     configure_from_config,
@@ -427,9 +433,15 @@ class FFModel:
             dict(axes=tuple(a % input.ndim for a in axes), elementwise_affine=elementwise_affine, eps=eps),
         )[0]
 
-    def rms_norm(self, input: Tensor, eps: float = 1e-6, name: Optional[str] = None) -> Tensor:
+    def rms_norm(
+        self, input: Tensor, eps: float = 1e-6, zero_centered: bool = False,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """``zero_centered``: the weight starts at 0 and scales by
+        ``1 + w`` (``x / rms(x) * (1 + w)``, in float32)."""
+        attrs = dict(eps=eps, zero_centered=True) if zero_centered else dict(eps=eps)
         return self._add_layer(
-            OperatorType.RMS_NORM, self._name("rms_norm", name), [input], dict(eps=eps)
+            OperatorType.RMS_NORM, self._name("rms_norm", name), [input], attrs
         )[0]
 
     def embedding(
@@ -488,6 +500,56 @@ class FFModel:
                 use_flash=use_flash,
                 bias=bias,
                 kernel_initializer=kernel_initializer,
+            ),
+        )[0]
+
+    def gated_attention(
+        self,
+        input: Tensor,
+        num_heads: int,
+        num_kv_heads: int,
+        head_dim: int,
+        rotary_dim: int,
+        rope_theta: float = 10000.0,
+        eps: float = 1e-6,
+        use_flash: bool = True,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """Causal grouped-query self-attention with per-head q/k
+        RMS-norm, rotary positions on the first ``rotary_dim`` dims and a
+        sigmoid output gate (:class:`flexflow_tpu.ops.attention.GatedAttention`)."""
+        return self._add_layer(
+            OperatorType.GATED_ATTENTION,
+            self._name("gated_attention", name),
+            [input],
+            dict(
+                num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
+                rotary_dim=rotary_dim, rope_theta=rope_theta, eps=eps,
+                use_flash=use_flash,
+            ),
+        )[0]
+
+    def gated_delta_net(
+        self,
+        input: Tensor,
+        num_k_heads: int,
+        num_v_heads: int,
+        head_k_dim: int,
+        head_v_dim: int,
+        conv_kernel: int = 4,
+        eps: float = 1e-6,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """Linear attention with a gated delta-rule state
+        (:class:`flexflow_tpu.ops.linear_attention.GatedDeltaNet`)."""
+        return self._add_layer(
+            OperatorType.GATED_DELTA_NET,
+            self._name("gated_delta_net", name),
+            [input],
+            dict(
+                num_k_heads=num_k_heads, num_v_heads=num_v_heads,
+                head_k_dim=head_k_dim, head_v_dim=head_v_dim,
+                conv_kernel=conv_kernel, eps=eps,
             ),
         )[0]
 
@@ -641,6 +703,34 @@ class FFModel:
             self._name("experts", name),
             [input, assign, gate_preds, gate_full],
             dict(n_experts=num_experts, hidden=hidden, alpha=alpha, lambda_bal=lambda_bal),
+        )[0]
+
+    def routed_experts(
+        self,
+        input: Tensor,
+        n_experts: int,
+        top_k: int,
+        hidden: int,
+        first_expert: int = 0,
+        held: Optional[int] = None,
+        shared_hidden: int = 0,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """One share of a dropless sparse-MoE block with gated (SiLU)
+        experts: the router covers all ``n_experts``, this share holds
+        ``held`` of them from ``first_expert`` on and returns their part
+        (plus the shared expert's, when ``shared_hidden`` > 0).  See
+        :class:`flexflow_tpu.ops.moe.RoutedExperts`."""
+        held = n_experts if held is None else held
+        assert 0 <= first_expert and first_expert + held <= n_experts
+        return self._add_layer(
+            OperatorType.ROUTED_EXPERTS,
+            self._name("routed_experts", name),
+            [input],
+            dict(
+                n_experts=n_experts, first_expert=first_expert, held=held,
+                top_k=top_k, hidden=hidden, shared_hidden=shared_hidden,
+            ),
         )[0]
 
     def moe(
@@ -1389,6 +1479,13 @@ class FFModel:
             sums, count = acc.drain()
             self.executor.count_host_sync(1, stall_s=time.perf_counter() - t0)
             pm.merge_sums(sums, count)
+        if tracer.enabled:
+            # the ops' own counts of this window, under their names
+            for k, v in sums.items():
+                if k.startswith(COUNTER_PREFIX):
+                    tracer.counter(k[len(COUNTER_PREFIX):], v)
+                elif k.startswith(GAUGE_PREFIX):
+                    tracer.sample(k[len(GAUGE_PREFIX):], v / count, level="step")
         tracer.counter("fit.metric_flushes")
 
     def fit(

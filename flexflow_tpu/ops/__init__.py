@@ -6,6 +6,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     dense,
     elementwise,
     embedding,
+    linear_attention,
     moe,
     norm,
     parallel_ops,

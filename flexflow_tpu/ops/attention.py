@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from flexflow_tpu.fftype import OperatorType
 from flexflow_tpu.initializer import default_kernel_initializer
 from flexflow_tpu.ops.base import OpContext, OpDef, ShapeDtype, WeightSpec, register_op
+from flexflow_tpu.ops.norm import rms_norm_zero_centered
 from flexflow_tpu.tensor import Layer
 
 
@@ -227,6 +228,94 @@ class MultiHeadAttention(OpDef):
         return {0: "sample", 1: "seq", 2: "channel"}
 
 
+def rotate_half_rope(x, rotary_dim: int, theta: float):
+    """Rotary positions (rotate-half pairing: dim i with i + rotary_dim/2)
+    on the first ``rotary_dim`` dims of ``x`` (B, S, H, D), position =
+    index along dim 1; the remaining dims pass through.  Float32."""
+    s = x.shape[1]
+    half = rotary_dim // 2
+    inv_freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq  # (S, half)
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x = x.astype(jnp.float32)
+    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
+    )
+
+
+class GatedAttention(OpDef):
+    """Causal grouped-query self-attention with per-head q/k RMS-norm,
+    partial rotary positions and a sigmoid output gate (Qwen3-Next's
+    full-attention mixer).  Input (B, S, E) -> (B, S, E).  Attrs:
+    ``num_heads``, ``num_kv_heads``, ``head_dim``, ``rotary_dim``,
+    ``rope_theta``, ``eps``, ``use_flash``.  ``wq`` holds, per head,
+    the query's columns and then the gate's.  K/V heads are repeated to
+    ``num_heads`` in front of the core, so the core and its dispatch
+    (``sdpa`` or the flash kernel, by ``_flash_ok``) are
+    :class:`MultiHeadAttention`'s."""
+
+    op_type = OperatorType.GATED_ATTENTION
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        return [(t.shape, t.dtype)]
+
+    def weights(self, layer: Layer) -> List[WeightSpec]:
+        from flexflow_tpu.initializer import ZeroInitializer
+
+        t = layer.inputs[0]
+        a = layer.attrs
+        e, dt = t.shape[-1], t.dtype
+        h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
+        init = a.get("kernel_initializer") or default_kernel_initializer()
+        return [
+            WeightSpec("wq", (e, h * 2 * d), dt, init),
+            WeightSpec("wk", (e, kv * d), dt, init),
+            WeightSpec("wv", (e, kv * d), dt, init),
+            WeightSpec("wo", (h * d, e), dt, init),
+            WeightSpec("q_norm", (d,), dt, ZeroInitializer()),
+            WeightSpec("k_norm", (d,), dt, ZeroInitializer()),
+        ]
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        x = inputs[0]
+        a = layer.attrs
+        h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
+        eps = a.get("eps", 1e-6)
+        b, s, _ = x.shape
+        with jax.named_scope("ff.attn_gated"):
+            qg = (x @ params["wq"]).reshape(b, s, h, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+            k = (x @ params["wk"]).reshape(b, s, kv, d)
+            v = (x @ params["wv"]).reshape(b, s, kv, d)
+            q = rms_norm_zero_centered(q, params["q_norm"], eps)
+            k = rms_norm_zero_centered(k, params["k_norm"], eps)
+            q = rotate_half_rope(q, a["rotary_dim"], a["rope_theta"]).astype(x.dtype)
+            k = rotate_half_rope(k, a["rotary_dim"], a["rope_theta"]).astype(x.dtype)
+            k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            if a.get("use_flash", True) and _flash_ok(s, s, d, b * h):
+                from flexflow_tpu.ops.pallas.flash_attention import flash_attention
+
+                out = flash_attention(q, k, v, causal=True)
+            else:
+                out = sdpa(q, k, v, causal=True)
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+            out = out * jax.nn.sigmoid(gate.reshape(b, s, h * d))
+            return [out @ params["wo"]]
+
+    def flops(self, layer: Layer) -> float:
+        b, s, e = layer.inputs[0].shape
+        a = layer.attrs
+        h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
+        proj = 2.0 * b * s * e * (2 * h * d + 2 * kv * d + h * d)
+        return proj + 2.0 * b * h * s * s * d  # causal: half of 4 b h s s d
+
+    def partitionable_dims(self, layer):
+        return {0: "sample"}
+
+
 # Above this many bytes of materialized (b, h, sq, sk) score matrix the
 # O(S^2) sdpa path becomes memory-prohibitive and flash pays; below it,
 # XLA's fused attention measured consistently faster than the Pallas
@@ -263,3 +352,4 @@ def _flash_ok(sq: int, sk: int, d: int, bh_local: int = 1) -> bool:
 
 
 register_op(MultiHeadAttention())
+register_op(GatedAttention())

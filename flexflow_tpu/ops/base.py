@@ -120,6 +120,14 @@ class OpContext:
 
 class OpDef:
     op_type: OperatorType = OperatorType.NOOP
+    # weights the executor hands over in float32 under mixed precision
+    # (a router, a log-decay: what a bfloat16 rounding would change)
+    fp32_weights: frozenset = frozenset()
+    # names of the scalars ``forward`` returns after its outputs: the
+    # executor sums ``step_counters`` over layers and steps and averages
+    # ``step_gauges``; both reach the host with fit's metric flush
+    step_counters: Tuple[str, ...] = ()
+    step_gauges: Tuple[str, ...] = ()
 
     # --- graph side -------------------------------------------------------
     def infer(self, layer: Layer) -> List[ShapeDtype]:

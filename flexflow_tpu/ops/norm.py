@@ -72,6 +72,14 @@ class LayerNorm(OpDef):
         return d
 
 
+def rms_norm_zero_centered(x, w, eps):
+    """``x / rms(x) * (1 + w)`` over the last axis; statistics and
+    scaling in float32, returned in float32."""
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(ms + eps) * (1.0 + w.astype(jnp.float32))
+
+
 class RMSNorm(OpDef):
     op_type = OperatorType.RMS_NORM
 
@@ -81,11 +89,15 @@ class RMSNorm(OpDef):
 
     def weights(self, layer: Layer) -> List[WeightSpec]:
         t = layer.inputs[0]
+        if layer.attrs.get("zero_centered"):
+            return [WeightSpec("weight", (t.shape[-1],), t.dtype, ZeroInitializer())]
         return [WeightSpec("scale", (t.shape[-1],), t.dtype, OnesInitializer())]
 
     def forward(self, layer, params, inputs, ctx: OpContext):
         x = inputs[0]
         eps = layer.attrs.get("eps", 1e-6)
+        if layer.attrs.get("zero_centered"):
+            return [rms_norm_zero_centered(x, params["weight"], eps).astype(x.dtype)]
         ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
         return [x * jax.lax.rsqrt(ms + eps) * params["scale"]]
 

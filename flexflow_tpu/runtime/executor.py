@@ -42,7 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from flexflow_tpu.blocks import BlockChain, detect_block_chains
 from flexflow_tpu.fftype import LossType, OperatorType
 from flexflow_tpu.loss import get_loss_fn
-from flexflow_tpu.metrics import Metrics
+from flexflow_tpu.metrics import COUNTER_PREFIX, GAUGE_PREFIX, Metrics
 from flexflow_tpu.obs import get_monitor, get_tracer
 from flexflow_tpu.ops.base import OpContext, get_op_def
 from flexflow_tpu.ops.parallel_ops import resolve_parallel_sharding
@@ -338,6 +338,7 @@ class Executor:
             )
 
         aux_losses: List[jax.Array] = []
+        counters: Dict[str, List[jax.Array]] = {}
         new_state: Dict[str, Dict[str, jax.Array]] = {}
         for seg in self._segments:
             if isinstance(seg, BlockChain):
@@ -354,7 +355,7 @@ class Executor:
                 continue
             self._trace_layer(
                 seg, values, shardings, params, state, training, rng,
-                seq_length, new_state, aux_losses,
+                seq_length, new_state, aux_losses, counters=counters,
             )
         # carry over unchanged state
         for name, s in state.items():
@@ -363,7 +364,13 @@ class Executor:
         logits = values[self.logits.guid]
         if self._mixed and logits.dtype == self.compute_dtype:
             logits = logits.astype(jnp.float32)  # loss/metrics in fp32
-        return logits, new_state, aux_losses
+        # what the ops counted: counters summed, gauges averaged over the
+        # layers that reported them (they join the step's metrics)
+        counted = {
+            k: (sum(v) / len(v) if k.startswith(GAUGE_PREFIX) else sum(v))
+            for k, v in counters.items()
+        }
+        return logits, new_state, aux_losses, counted
 
     def _trace_layer(
         self,
@@ -378,6 +385,7 @@ class Executor:
         new_state: Dict[str, Dict[str, jax.Array]],
         aux_losses: List[jax.Array],
         rng_key: Optional[jax.Array] = None,
+        counters: Optional[Dict[str, List[jax.Array]]] = None,
     ) -> None:
         """Trace ONE layer into ``values``/``shardings`` — the loop body
         of the unrolled path, also reused per template position inside a
@@ -387,7 +395,10 @@ class Executor:
         ins = [values[t.guid] for t in layer.inputs]
         lp32 = dict(params.get(layer.name, {}))
         lp32.update(state.get(layer.name, {}))
-        lp = {k: self._cast_compute(v) for k, v in lp32.items()}
+        lp = {
+            k: v if k in opdef.fp32_weights else self._cast_compute(v)
+            for k, v in lp32.items()
+        }
         if rng_key is None and rng is not None:
             rng_key = jax.random.fold_in(
                 rng, zlib.crc32(layer.name.encode()) % (2**31)
@@ -429,6 +440,12 @@ class Executor:
             else:
                 shardings[t.guid] = TensorSharding.replicated(t.ndim)
             values[t.guid] = y
+        if counters is not None:
+            names = [COUNTER_PREFIX + n for n in opdef.step_counters] + [
+                GAUGE_PREFIX + n for n in opdef.step_gauges
+            ]
+            for n, v in zip(names, outs[len(layer.outputs):]):
+                counters.setdefault(n, []).append(v.astype(jnp.float32))
         # stateful ops (BN running stats) — accumulated in float32 even
         # under bf16 compute, like the reference's fp32 cudnn stats
         if training and hasattr(opdef, "state_update") and state.get(layer.name):
@@ -469,6 +486,8 @@ class Executor:
                 opdef = get_op_def(l.op_type)
                 if hasattr(opdef, "state_update"):
                     return False
+                if opdef.step_counters or opdef.step_gauges:
+                    return False  # the scan body has no way out for them
                 if any(not w.trainable for w in self._wspecs[int(l.layer_guid)]):
                     return False
                 if (
@@ -1422,13 +1441,15 @@ class Executor:
             rng = jax.random.fold_in(jax.random.PRNGKey(self.seed), cnt)
 
             def objective(p):
-                logits, new_state, aux = self._forward(p, state, inputs, True, rng)
+                logits, new_state, aux, counted = self._forward(
+                    p, state, inputs, True, rng
+                )
                 loss = loss_fn(logits, labels)
                 for a in aux:
                     loss = loss + a
-                return loss, (logits, new_state)
+                return loss, (logits, new_state, counted)
 
-            (loss, (logits, new_state)), grads = jax.value_and_grad(
+            (loss, (logits, new_state, counted)), grads = jax.value_and_grad(
                 objective, has_aux=True
             )(params)
             new_params, new_opt = self.optimizer.update(params, grads, opt_state)
@@ -1444,6 +1465,8 @@ class Executor:
                     # buckets' optimizer updates (math identity)
                     new_params = self._zero1_ring_gather(new_params)
             m = metrics.compute(logits, labels) if metrics else {}
+            if counted:
+                m = {**m, **counted}
             if diagnostics:
                 m = dict(m)
                 m["grad_norm"] = global_norm(grads)
@@ -1455,7 +1478,7 @@ class Executor:
 
     def _build_fwd(self):
         def fwd(params, state, inputs, seq_length):
-            logits, _, _ = self._forward(
+            logits, *_ = self._forward(
                 params, state, inputs, False, None, seq_length
             )
             return logits
@@ -1841,7 +1864,11 @@ class Executor:
         return jax.device_put(arr, ns)
 
 
-_REMAT_OPS = frozenset({OperatorType.MULTIHEAD_ATTENTION})
+_REMAT_OPS = frozenset({
+    OperatorType.MULTIHEAD_ATTENTION,
+    OperatorType.GATED_ATTENTION,
+    OperatorType.GATED_DELTA_NET,
+})
 
 
 def _compile_cache_entries() -> Optional[frozenset]:

@@ -185,3 +185,40 @@ def test_flash_attention_lowers_for_tpu(causal, dropout):
 
     _lower_for_tpu(fwd, q, q, q)
     _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_flash_attention_lowers_at_the_gated_attention_shape():
+    # b1 h16 s8192 d256, causal: the full-attention layer of the
+    # qwen3_next_80b_a3b cell (exactly 4 GiB of f32 scores, the
+    # dispatcher's threshold), forward and backward
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 256), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True).astype(jnp.float32))
+
+    _lower_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_grouped_expert_matmul_and_chunk_solve_lower_for_tpu():
+    """The two XLA pieces of the same cell whose TPU lowering is not a
+    plain fusion: ``lax.ragged_dot`` over 32 held experts (10,240 rows)
+    with its backward, and the unit-triangular solve of the delta rule
+    over 4,096 chunks of 64."""
+    from flexflow_tpu.ops import linear_attention as la
+
+    x = jax.ShapeDtypeStruct((10240, 2048), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((32, 2048, 512), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((32,), jnp.int32)
+
+    def experts(x, w, sizes):
+        return jnp.sum(jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32))
+
+    _lower_for_tpu(jax.grad(experts, argnums=(0, 1)), x, w, sizes)
+
+    qk = jax.ShapeDtypeStruct((1, 512, 32, 128), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, 512, 32), jnp.float32)
+
+    def rule(q, k, v, g, beta):
+        return jnp.sum(la.gated_delta_rule_chunked(q, k, v, g, beta)[0])
+
+    _lower_for_tpu(jax.grad(rule, argnums=(0, 1, 2, 3, 4)), qk, qk, qk, g, g)

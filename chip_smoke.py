@@ -11,7 +11,11 @@ what comes out:
                 (searched) and ``FFModel.fit`` on seeded synthetic data;
   3. server   — GPT-2-small s1024 bf16 through ``flexflow_tpu.serve.
                 driver.main`` (what ``python -m flexflow_tpu --serve``
-                runs), 8 slots, the default ``--serve-attn auto``.
+                runs), 8 slots, the default ``--serve-attn auto``; then
+                once more with an int8 pool (pages of 32) and
+                speculation (k = 3).  Both engines' compiled decode and
+                prefill programs must hold no whole-pool copy
+                (``ServeEngine.pool_relayouts() == 0``).
 
 On a host with several chips the trainer also runs under the default
 all-devices mesh and under the searched strategy, asserts where every
@@ -34,6 +38,7 @@ nothing.
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import io
 import json
@@ -89,20 +94,21 @@ def info(msg: str) -> None:
 _HIGHEST = "highest"  # jax.lax.Precision for every reference contraction
 
 
-def _paged_reference(q, pool_k, pool_v, positions, tables, scale_k, scale_v):
-    """Plain float32 attention over the gathered pages of each lane."""
+def _paged_reference(q, pages_k, pages_v, positions, tables, scale_k, scale_v):
+    """Plain float32 attention over the gathered pages of each lane;
+    ``pages_k`` / ``pages_v`` are (N, BS, H, D)."""
     import jax.numpy as jnp
 
     B, G, H, D = q.shape
-    BS = pool_k.shape[2]
+    BS = pages_k.shape[1]
     MB = tables.shape[1]
-    keys = pool_k[tables].astype(jnp.float32)  # (B, MB, H, BS, D)
-    vals = pool_v[tables].astype(jnp.float32)
+    keys = pages_k[tables].astype(jnp.float32)  # (B, MB, BS, H, D)
+    vals = pages_v[tables].astype(jnp.float32)
     if scale_k is not None:
-        keys = keys * scale_k[tables][:, :, None, :, None]
-        vals = vals * scale_v[tables][:, :, None, :, None]
-    keys = keys.transpose(0, 2, 1, 3, 4).reshape(B, H, MB * BS, D)
-    vals = vals.transpose(0, 2, 1, 3, 4).reshape(B, H, MB * BS, D)
+        keys = keys * scale_k[tables][..., None, None]
+        vals = vals * scale_v[tables][..., None, None]
+    keys = keys.transpose(0, 3, 1, 2, 4).reshape(B, H, MB * BS, D)
+    vals = vals.transpose(0, 3, 1, 2, 4).reshape(B, H, MB * BS, D)
     # HIGHEST: a TPU's default float32 matmul is one bfloat16 pass
     s = jnp.einsum(
         "bghd,bhkd->bghk", q.astype(jnp.float32), keys, precision=_HIGHEST
@@ -115,13 +121,22 @@ def _paged_reference(q, pool_k, pool_v, positions, tables, scale_k, scale_v):
     return jnp.einsum("bghk,bhkd->bghd", p, vals, precision=_HIGHEST)
 
 
+def _page_geometry(pa, pool_dtype, BS, MB):
+    """(BS, MB) for a pool of ``pool_dtype``: a page is whole sublane
+    tiles of it on the chip (32 rows for a one-byte pool), the virtual
+    length stays ``BS * MB``."""
+    bs = max(BS, 1 if pa.INTERPRET else pa.page_rows_tile(pool_dtype))
+    return bs, BS * MB // bs
+
+
 def check_paged_attention(
     *, B=8, H=12, D=64, BS=16, MB=64, groups=(1, 32),
     kv_dtypes=("fp32", "bf16", "int8", "fp8"), seed=0,
 ) -> dict:
     """``paged_decode_attention`` at GPT-2-small serving geometry (the
-    server phase's: 8 slots, 12 heads of 64, 16-position pages, 1024
-    positions) for G=1 (decode) and G=prefill_chunk, every ``kv_dtype``
+    server phase's: 8 slots, 12 heads of 64, 1024 positions in pages of
+    16, or of 32 for a one-byte pool; the pool position-major, (N * BS,
+    H * D)) for G=1 (decode) and G=prefill_chunk, every ``kv_dtype``
     the engine offers, scrambled block tables, lanes at different
     depths, garbage in the pages past each lane's write head."""
     import jax
@@ -131,9 +146,12 @@ def check_paged_attention(
     from flexflow_tpu.ops.pallas import paged_attention as pa
     from flexflow_tpu.serve.kvcache import kv_pool_dtype, quantize_kv
 
-    N = B * MB + 1
+    cell = (BS, MB)
     out = {}
     for kv_dtype in kv_dtypes:
+        dt = kv_pool_dtype(jnp, kv_dtype, fallback=jnp.float32)
+        BS, MB = _page_geometry(pa, dt, *cell)
+        N = B * MB + 1
         for G in groups:
             rng = np.random.default_rng(seed)
             q = jnp.asarray(rng.normal(size=(B, G, H, D)), jnp.bfloat16)
@@ -144,10 +162,7 @@ def check_paged_attention(
                 kq, sk = quantize_kv(jnp, kf, kv_dtype)  # scales (N, BS)
                 vq, sv = quantize_kv(jnp, vf, kv_dtype)
             else:
-                dt = kv_pool_dtype(jnp, kv_dtype, fallback=jnp.float32)
                 kq, vq = kf.astype(dt), vf.astype(dt)
-            pool_k = kq.transpose(0, 2, 1, 3)  # (N, H, BS, D)
-            pool_v = vq.transpose(0, 2, 1, 3)
             tables = jnp.asarray(
                 rng.permutation(np.arange(1, N)).reshape(B, MB), jnp.int32
             )
@@ -156,9 +171,15 @@ def check_paged_attention(
             positions = jnp.asarray(
                 np.linspace(0, MB * BS - G, B).astype(np.int32)
             )
-            args = (q, pool_k, pool_v, positions, tables)
-            got = jax.jit(pa.paged_decode_attention)(*args, None, sk, sv)
-            ref = jax.jit(_paged_reference)(*args, sk, sv)
+            got = jax.jit(
+                functools.partial(pa.paged_decode_attention, block_size=BS)
+            )(
+                q, kq.reshape(N * BS, H * D), vq.reshape(N * BS, H * D),
+                positions, tables, None, sk, sv,
+            )
+            ref = jax.jit(_paged_reference)(
+                q, kq, vq, positions, tables, sk, sv
+            )
             got = np.asarray(got, np.float32)
             ref = np.asarray(ref, np.float32)
             check(got.shape == (B, G, H, D), f"paged {kv_dtype} G={G}: shape {got.shape}")
@@ -188,17 +209,19 @@ def check_kv_page_write(
     from flexflow_tpu.ops.pallas import paged_attention as pa
     from flexflow_tpu.serve.kvcache import kv_pool_dtype
 
-    N = B * MB + 1
+    cell = (BS, MB)
     out = {}
     for kv_dtype in kv_dtypes:
         dt = kv_pool_dtype(jnp, kv_dtype, fallback=jnp.float32)
+        BS, MB = _page_geometry(pa, dt, *cell)
+        N = B * MB + 1
         for G in groups:
             rng = np.random.default_rng(seed)
 
             def rand(shape):  # small integers: exact in every pool dtype
                 return jnp.asarray(rng.integers(-8, 9, size=shape), jnp.float32).astype(dt)
 
-            pk, pv = rand((L, N, H, BS, D)), rand((L, N, H, BS, D))
+            pk, pv = rand((L, N * BS, H * D)), rand((L, N * BS, H * D))
             k, v = rand((B, G, H, D)), rand((B, G, H, D))
             bt = rng.permutation(np.arange(1, N)).reshape(B, MB).astype(np.int32)
             bt[-1] = 0  # an idle lane
@@ -209,18 +232,22 @@ def check_kv_page_write(
             pos = start[:, None] + np.arange(G)[None]
             valid = np.arange(G)[None] < n_valid[:, None]
             blk = np.where(valid, bt[np.arange(B)[:, None], pos // BS], 0)
-            off = np.where(valid, pos % BS, 0)
+            row = blk * BS + np.where(valid, pos % BS, 0)
             want = jax.jit(
                 lambda a, b, k, v: (
-                    a.at[1, blk, :, off, :].set(k), b.at[1, blk, :, off, :].set(v)
+                    a.at[1, row].set(k.reshape(B, G, H * D)),
+                    b.at[1, row].set(v.reshape(B, G, H * D)),
                 )
             )(pk, pv, k, v)
             got = jax.jit(
-                lambda a, b, k, v: pa.paged_kv_write(a, b, 1, k, v, start, bt, n_valid)
+                lambda a, b, k, v: pa.paged_kv_write(
+                    a, b, 1, k, v, start, bt, n_valid, block_size=BS
+                )
             )(pk, pv, k, v)
 
-            def raw(x):  # bytes; the device's layout need not be C order
-                return np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+            def raw(x):  # bytes by block; the device's layout need not be C order
+                x = np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+                return x.reshape(L, N, -1)
 
             # block 0 is the trash block: padded rows may land anywhere in it
             diff = sum(
@@ -546,15 +573,26 @@ def train_phase(n_devices: int, **size) -> dict:
 # ================================================================ server
 def serve_gpt2(
     *, slots=8, seq=1024, width=None, vocab=50257, requests=24,
-    prompt_len=(64, 512), gen_len=(32, 128), dtype="bfloat16",
+    prompt_len=(64, 512), gen_len=(32, 128), dtype="bfloat16", extra=(),
 ) -> dict:
     """GPT-2-small through the serve driver's ``main`` — the function
-    ``python -m flexflow_tpu --serve`` calls — and its JSON summary."""
+    ``python -m flexflow_tpu --serve`` calls — and its JSON summary;
+    ``extra`` is more of its command line."""
+    from unittest import mock
+
     import jax
 
+    import flexflow_tpu.serve as serve_pkg
     from flexflow_tpu.models.transformer import GPT2_SMALL
     from flexflow_tpu.serve import TrafficSpec, synthetic_requests
     from flexflow_tpu.serve.driver import main as serve_main
+
+    built = []  # the engine the driver builds, to ask it afterwards
+
+    class Recorded(serve_pkg.ServeEngine):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            built.append(self)
 
     width = dict(width or GPT2_SMALL)
     argv = [
@@ -564,10 +602,13 @@ def serve_gpt2(
         "--vocab", str(vocab), "--seq", str(seq),
         "--requests", str(requests), "--traffic-seed", "0",
         "--prompt-len", "%d:%d" % prompt_len, "--gen-len", "%d:%d" % gen_len,
+        *extra,
     ]
     buf = io.StringIO()
     t0 = time.perf_counter()
-    with contextlib.redirect_stdout(buf):
+    with contextlib.redirect_stdout(buf), mock.patch.object(
+        serve_pkg, "ServeEngine", Recorded
+    ):
         rc = serve_main(argv)
     total_s = time.perf_counter() - t0
     check(rc == 0, f"serve driver returned {rc}")
@@ -599,6 +640,14 @@ def serve_gpt2(
     check(s["prefill_attn_kernel"] == "paged", "prefill did not run the paged kernel")
     check(not s["attn_interpret"], "the paged kernel ran in the Pallas interpreter")
     check(s["kv_write"] == "page_kernel", f"kv_write {s['kv_write']!r}")
+    check(len(built) == 1, f"the driver built {len(built)} engines")
+    relayouts = built[0].pool_relayouts()
+    check(
+        relayouts == 0,
+        f"{relayouts} whole-pool copies or transposes in the compiled decode "
+        f"and prefill programs (pool {built[0].kv.cache_k.shape} "
+        f"{built[0].kv.cache_k.dtype})",
+    )
     check(
         s["prefill_chunks"] > s["prefill_dispatches"] > 0,
         "prefill chunks were not batched over slots",
@@ -622,11 +671,14 @@ def serve_gpt2(
         f"{s['prefill_dispatches']} dispatches"
     )
     return {
-        k: s[k] for k in (
+        **{k: s[k] for k in (
             "model", "requests_finished", "new_tokens", "windows", "host_syncs",
             "decode_steps", "prefill_chunks", "prefill_dispatches",
             "attn_kernel", "attn_interpret", "kv_write", "kv_dtype", "device",
-        )
+            "block_size", "spec_k", "spec_accepted",
+        )},
+        "pool_shape": list(built[0].kv.cache_k.shape),
+        "pool_relayouts": relayouts,
     }
 
 
@@ -667,6 +719,16 @@ def run_phases(n_devices: int) -> dict:
              f"included): {json.dumps(phases['kernels'][name])}")
     phases["train"] = train_phase(n_devices)
     phases["serve"] = serve_gpt2()
+    # the other users of the pool and its kernels: a one-byte pool (a
+    # page is 32 rows of it) with its scale pools, draft and verify
+    phases["serve_int8_spec"] = serve_gpt2(extra=(
+        "--serve-kv-dtype", "int8", "--serve-block-size", "32",
+        "--serve-spec-k", "3",
+    ))
+    check(
+        phases["serve_int8_spec"]["spec_accepted"] > 0,
+        "speculation accepted no draft token",
+    )
     new = cached_programs() - cached_before
     info(f"compile cache: {len(new)} programs added")
     return {

@@ -222,12 +222,43 @@ def check_serve_cow(artifact: ProgramArtifact) -> List[Violation]:
     return []
 
 
+def _pool_inputs(artifact: ProgramArtifact):
+    """The K/V pool inputs of a serve program, ``(label, shape, dtype)``:
+    recognised by their label and by the pool's rank, ``(L, num_blocks *
+    block_size, H * D)`` (serve/kvcache.py)."""
+    return [
+        (label, tuple(shape), dtype)
+        for label, shape, dtype, _ in artifact.inputs
+        if label in ("cache_k", "cache_v") and len(shape) == 3
+    ]
+
+
+def _no_pool_violation(check: str, artifact: ProgramArtifact, claim: str):
+    return Violation(
+        check=check,
+        severity="error",
+        program=artifact.name,
+        message=(
+            f"program claims {claim} but none of its inputs is "
+            f"recognised as a K/V pool (label cache_k / cache_v, shape "
+            f"(L, num_blocks * block_size, H * D)) — the audit has "
+            f"nothing to hold the claim against"
+        ),
+        where="inputs",
+        details={
+            "inputs": [
+                [label, list(shape)] for label, shape, _, _ in artifact.inputs
+            ],
+        },
+    )
+
+
 @register_check("paged_attn")
 def check_paged_attn(artifact: ProgramArtifact) -> List[Violation]:
     """Structural proof the paged-attention fusion happened: a serve
     program that CLAIMS the fused Pallas kernel (docs/PERF.md "Paged
     decode attention") must lower no pool-sized gather — the dense
-    fallback's per-layer ``pool[tables]`` materializes a (B, MB, H, BS,
+    fallback's per-layer ``pool[tables]`` materializes a (B, MB, BS, H,
     D) buffer, so any gather/take whose output is at least ONE lane's
     virtual-length K/V bytes (``MB * BS * H * D * itemsize``) means the
     gather is still in the program.
@@ -238,20 +269,21 @@ def check_paged_attn(artifact: ProgramArtifact) -> List[Violation]:
     ``slots`` lanes of virtual-length K/V, the exact O(S^2) hazard the
     prefill kernel extension deletes.
 
-    Only a gather FROM THE POOL counts: its operand is a layer of the
-    pool, ``(N, H, BS, D)``, or the whole of it.  A paged program has
-    other gathers that outgrow one lane's K/V bytes at some scale and
-    are none of the fallback's: the batched token-embedding lookup
-    (a 2-D table), and the page-write path's gather of each lane's new
-    rows into page shape (``paged_kv_write``: its operand is the
-    chunk's rows, (2, slots, chunk, H, D), and its size follows the
-    chunk, not the virtual length).
+    Only a gather FROM THE POOL counts: its operand holds a layer of
+    the pool, ``num_blocks * BS * H * D`` elements under any shape, or
+    the whole of it.  A paged program has other gathers that outgrow one
+    lane's K/V bytes at some scale and are none of the fallback's: the
+    batched token-embedding lookup (a 2-D table), and the page-write
+    path's gather of each lane's new rows into page shape
+    (``paged_kv_write``: its operand is the chunk's rows, (2, slots,
+    chunk, H * D), and its size follows the chunk, not the virtual
+    length).
 
     Total: artifacts without a ``serve_attn: "paged"`` detail (gather
-    engines, non-serve programs), without a jaxpr, or without a K/V
-    pool input all skip.  Small gathers from the pool (per-page
-    dynamic slices from the kernel's own lowering) sit far below the
-    threshold and pass."""
+    engines, non-serve programs) or without a jaxpr skip.  One that
+    claims ``paged`` and shows no K/V pool input is a violation, not a
+    skip.  Small gathers from the pool (per-page dynamic slices from
+    the kernel's own lowering) sit far below the threshold and pass."""
     det = artifact.details or {}
     if det.get("serve_attn") != "paged":
         return []
@@ -260,27 +292,26 @@ def check_paged_attn(artifact: ProgramArtifact) -> List[Violation]:
     if artifact.jaxpr is None:
         return []
     # one lane's virtual-length K/V bytes from the pool operand's
-    # (L, N, H, BS, D) shape + the table geometry
-    mb = det.get("max_blocks_per_seq")
+    # (L, N * BS, H * D) shape + the table geometry
+    mb, bs = det.get("max_blocks_per_seq"), det.get("block_size")
     pool = next(
-        (
-            (shape, dtype)
-            for label, shape, dtype, _ in artifact.inputs
-            if label == "cache_k" and len(shape) == 5
-        ),
-        None,
+        (p for p in _pool_inputs(artifact) if p[0] == "cache_k"), None
     )
-    if not mb or pool is None:
-        return []
-    (_, n, h, bs, d), pool_dtype = pool
-    lane_bytes = int(mb) * h * bs * d * _dtype_bytes(pool_dtype)
+    if not mb or not bs or pool is None:
+        return [_no_pool_violation(
+            "paged_attn", artifact, 'serve_attn "paged"'
+        )]
+    _, (layers, rows, hd), pool_dtype = pool
+    lane_bytes = int(mb) * int(bs) * hd * _dtype_bytes(pool_dtype)
     out: List[Violation] = []
     for eqn in walk_jaxpr_eqns(artifact.jaxpr):
         if eqn.primitive.name not in ("gather", "take"):
             continue
         # a gather from the pool only (see docstring)
         aval0 = getattr(eqn.invars[0] if eqn.invars else None, "aval", None)
-        if tuple(getattr(aval0, "shape", ()))[-4:] != (n, h, bs, d):
+        if math.prod(getattr(aval0, "shape", (0,))) not in (
+            rows * hd, layers * rows * hd
+        ):
             continue
         for var in eqn.outvars:
             aval = getattr(var, "aval", None)
@@ -317,24 +348,28 @@ def check_kv_quant(artifact: ProgramArtifact) -> List[Violation]:
     """Structural proof the quantized KV pool actually shrank: a serve
     program whose details CLAIM ``kv_dtype: "int8"|"fp8"`` (docs/
     SERVING.md "Quantized KV cache and weight-only decode") must lower
-    its 5-D ``cache_k`` pool input with a 1-byte element type.  A
-    config that claims int8 while the traced pool aval is still
+    its ``cache_k`` / ``cache_v`` pool inputs with a 1-byte element
+    type.  A config that claims int8 while the traced pool aval is still
     float32/bfloat16 prices and reports an HBM footprint it does not
     have — the exact graft this check exists to catch.
 
     Total: artifacts without a quantized ``kv_dtype`` claim (fp32/bf16
-    engines, non-serve programs) or without a 5-D ``cache_k`` input all
-    skip.  Prefill is included — it writes the same pool the decode
-    programs read, so a full-precision prefill pool is the same lie."""
+    engines, non-serve programs) skip; one with the claim and no K/V
+    pool input recognised is a violation.  Prefill is included — it
+    writes the same pool the decode programs read, so a full-precision
+    prefill pool is the same lie."""
     det = artifact.details or {}
     if det.get("kv_dtype") not in ("int8", "fp8"):
         return []
     if artifact.role not in ("decode", "draft", "verify", "prefill"):
         return []
+    pools = _pool_inputs(artifact)
+    if not pools:
+        return [_no_pool_violation(
+            "kv_quant", artifact, f"kv_dtype {det.get('kv_dtype')!r}"
+        )]
     out: List[Violation] = []
-    for label, shape, dtype, _ in artifact.inputs:
-        if label not in ("cache_k", "cache_v") or len(shape) != 5:
-            continue
+    for label, shape, dtype in pools:
         ds = str(dtype)
         # ml_dtypes float8 names don't round-trip through np.dtype —
         # size the aval by name for the 1-byte families

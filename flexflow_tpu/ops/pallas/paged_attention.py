@@ -3,12 +3,14 @@ the page-write kernel that lands new K/V rows in the pool in place.
 
 The serving hot path (``serve/engine.py``) keeps each slot's K/V in a
 :class:`~flexflow_tpu.serve.kvcache.PagedKVCache` pool of fixed-size
-blocks named by a per-slot block table.  The dense decode step
+blocks named by a per-slot block table.  The pool is position-major,
+``(L, num_blocks * BS, H * D)``: a page is ``BS`` consecutive rows, a
+row holds every head of one position.  The dense decode step
 materializes a gather every layer, every step::
 
-    keys = ck[i][bt].transpose(0, 2, 1, 3, 4).reshape(B, H, SV, D)
+    keys = ck[i].reshape(NB, BS, H, D)[bt].transpose(0, 3, 1, 2, 4)
 
-— a (B, MB, H, BS, D) buffer at the FULL virtual length ``SV = MB *
+— a (B, MB, BS, H, D) buffer at the FULL virtual length ``SV = MB *
 BS`` per lane, even for a request three tokens in.  That is pure HBM
 traffic and peak-memory overhead: the pages are then read *again* by
 the attention contraction.
@@ -48,11 +50,26 @@ The write side is :func:`paged_kv_write`: the serve programs hand it
 the WHOLE aliased pools and each lane's new rows, and it rewrites only
 the pages those rows fall in (decode G=1, verify G=k+1, prefill G=P
 with a padded tail).  Write, then attend — row ``g`` sees rows
-``0..g`` of its own chunk.  Both kernels take the pools row-major and
-whole, with the layer a static index in their index_maps, so nothing
-between a serve program's boundary and its kernels wants the pool in
-another layout or copies a layer out of it (an XLA scatter and a
-``pool[i]`` slice each did; PERF.md PR 27).
+``0..g`` of its own chunk.  Both kernels take the pools whole, with
+the layer a scalar their index_maps read from SMEM (so a program's
+layers share one trace and one lowering of each), and in the layout the
+TPU keeps them in at rest: the minor dimension is the whole ``H * D``
+row (768 at GPT-2-small width, six vregs of lanes) and a page's ``BS``
+rows fill whole sublane tiles (:func:`page_rows_tile`), so the default
+tiled layout IS the row-major one Mosaic reads.  Nothing between a
+serve program's boundary and its kernels copies, slices or re-lays a
+pool out (a (..., BS, D) page with D = 64 cost four whole-pool layout
+copies a call, an XLA scatter and a ``pool[i]`` slice more; PERF.md
+PR 27 and PR 29).
+
+Heads are 64-lane groups of one row here, not a leading dimension, so
+the attention kernel contracts over the whole row against a
+block-diagonal query: row ``(g, h)`` of ``q_bd`` holds ``q[g, h, :]``
+in head ``h``'s lanes and zeros elsewhere; ``q_bd @ k.T`` is the
+(G*H, BS) scores, ``p @ v`` a (G*H, H*D) accumulator of which only the
+row's own head's lanes mean anything, picked once when the lane ends.
+Two MXU products a page on an otherwise idle MXU; float32 accumulation,
+softmax and carry.
 
 Off-TPU the kernels run in interpreter mode only (``INTERPRET``,
 default from ``FFTPU_PALLAS_INTERPRET`` — see ``__init__.py``);
@@ -74,6 +91,7 @@ from flexflow_tpu.ops.pallas import env_interpret
 
 __all__ = [
     "INTERPRET",
+    "page_rows_tile",
     "paged_decode_attention",
     "paged_kv_write",
     "paged_prefill_attention",
@@ -86,23 +104,38 @@ __all__ = [
 INTERPRET = env_interpret()
 
 
-def supported() -> bool:
-    """Can the paged kernel run here?  TPU backends lower natively;
-    anything else needs interpreter mode."""
-    return INTERPRET or jax.default_backend() == "tpu"
+def page_rows_tile(dtype) -> int:
+    """Rows of ``dtype``'s sublane tile on the TPU: 8 rows of 32-bit
+    words, so 8 for float32, 16 for bfloat16, 32 for a one-byte pool.
+    A page block ``(BS, H * D)`` of the ``(L, num_blocks * BS, H * D)``
+    pool is whole tiles exactly when ``BS`` is a multiple of it."""
+    return 32 // jnp.dtype(dtype).itemsize
 
 
-def resolve_serve_attn(mode: str) -> str:
+def supported(block_size=None, pool_dtype=None) -> bool:
+    """Can the paged kernels run here?  Interpreter mode takes any page;
+    a TPU backend lowers natively, for pages of whole sublane tiles
+    (``block_size`` a multiple of :func:`page_rows_tile`; not asked
+    when ``block_size`` is None)."""
+    if INTERPRET:
+        return True
+    if jax.default_backend() != "tpu":
+        return False
+    return block_size is None or block_size % page_rows_tile(pool_dtype) == 0
+
+
+def resolve_serve_attn(mode: str, block_size=None, pool_dtype=None) -> str:
     """Resolve the ``--serve-attn`` knob to a concrete kernel.
 
     ``auto`` picks ``paged`` whenever :func:`supported` says the kernel
-    can run (TPU, or interpreter mode forced) and declines to
-    ``gather`` otherwise — so a plain CPU run is byte-identical to the
-    pre-paged engine.  An explicit ``paged`` on an unsupported backend
-    raises truthfully instead of silently falling back."""
+    can run (TPU with pages of whole tiles, or interpreter mode forced)
+    and declines to ``gather`` otherwise — so a plain CPU run is
+    byte-identical to the pre-paged engine.  An explicit ``paged``
+    where it cannot run raises truthfully instead of silently falling
+    back."""
     m = (mode or "auto").strip().lower()
     if m == "auto":
-        return "paged" if supported() else "gather"
+        return "paged" if supported(block_size, pool_dtype) else "gather"
     if m == "gather":
         return "gather"
     if m == "paged":
@@ -113,6 +146,13 @@ def resolve_serve_attn(mode: str) -> str:
                 "FFTPU_PALLAS_INTERPRET=1 to force interpret on "
                 f"{jax.default_backend()!r})"
             )
+        if not supported(block_size, pool_dtype):
+            raise ValueError(
+                f"--serve-attn paged: a page of {block_size} rows is not "
+                f"whole sublane tiles of a {jnp.dtype(pool_dtype).name} "
+                f"pool on the TPU — block_size must be a multiple of "
+                f"{page_rows_tile(pool_dtype)}"
+            )
         return "paged"
     raise ValueError(
         f"--serve-attn {mode!r}: expected auto | gather | paged"
@@ -120,29 +160,61 @@ def resolve_serve_attn(mode: str) -> str:
 
 
 def _kernel(
+    layer_ref,  # SMEM (1,) int32 — the layer the index_maps read
     pos_ref,  # SMEM (B,) int32 — row-0 position per lane
     bt_ref,  # SMEM (B, MB) int32 — block tables
-    q_ref,  # VMEM (1, G, H, D)
-    k_ref,  # VMEM (1, H, BS, D) — page table[b, min(i, last)]
-    v_ref,  # VMEM (1, H, BS, D)
-    *rest,  # [sk_ref, sv_ref (VMEM (1, BS, 1) f32)], o_ref, 3 scratch refs
+    q_ref,  # VMEM (1, G, H*D)
+    k_ref,  # VMEM (BS, H*D) — page table[b, min(i, last)] of the layer
+    v_ref,  # VMEM (BS, H*D)
+    *rest,  # [sk_ref, sv_ref (VMEM (BS, 1) f32)], o_ref, 4 scratch refs
     G: int,
+    H: int,
     BS: int,
     MB: int,
     scale: float,
     quantized: bool,
 ):
     if quantized:
-        sk_ref, sv_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        sk_ref, sv_ref, o_ref, qbd_ref, acc_ref, m_ref, l_ref = rest
     else:
         sk_ref = sv_ref = None
-        o_ref, acc_ref, m_ref, l_ref = rest
-    H = q_ref.shape[2]
+        o_ref, qbd_ref, acc_ref, m_ref, l_ref = rest
+    GH, HD = acc_ref.shape
+    D = HD // H
     b = pl.program_id(0)
     i = pl.program_id(1)
+    # both products accumulate in float32.  bfloat16 rows against a
+    # bfloat16 page multiply exactly in one MXU pass; anything wider (a
+    # float32 pool, a dequantized page, the float32 probabilities) takes
+    # the full-precision passes
+    exact = jax.lax.Precision.HIGHEST
+    qk_exact = None if qbd_ref.dtype == jnp.bfloat16 else exact
+
+    def own_head():
+        # (G*H, H*D): lane c lies in the head of row (g, h) = g * H + h
+        r = jax.lax.broadcasted_iota(jnp.int32, (GH, HD), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (GH, HD), 1)
+        return c // D == r % H
+
+    def own_row():
+        # (G*H, G) 0/1: row (g, h) belongs to query row g
+        r = jax.lax.broadcasted_iota(jnp.int32, (GH, G), 0)
+        g = jax.lax.broadcasted_iota(jnp.int32, (GH, G), 1)
+        return (r // H == g).astype(jnp.float32)
 
     @pl.when(i == 0)
     def _init():
+        # the block-diagonal query, once a lane: every query row H
+        # times over, each copy keeping one head's lanes
+        q = q_ref[0].astype(jnp.float32)  # (G, H*D)
+        if G == 1:
+            rows = jnp.broadcast_to(q, (GH, HD))
+        else:
+            rows = jax.lax.dot_general(
+                own_row(), q, (((1,), (0,)), ((), ())),
+                precision=exact, preferred_element_type=jnp.float32,
+            )
+        qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -152,134 +224,173 @@ def _kernel(
 
     @pl.when(i <= last)
     def _step():
-        q = q_ref[0].astype(jnp.float32)  # (G, H, D)
-        k = k_ref[0].astype(jnp.float32)  # (H, BS, D)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[...]  # (BS, H*D)
+        v = v_ref[...].astype(jnp.float32)
+        if qk_exact is not None:
+            k = k.astype(jnp.float32)
         if quantized:
             # in-register dequant of the DMA'd page: the SAME
             # ``int.astype(f32) * scale`` rule as the gather fallback
             # (kvcache.dequantize_kv), applied before the f32 online-
-            # softmax carry — elementwise, so the two paths agree
-            # bit-for-bit
-            k = k * sk_ref[0][None]  # scales (BS, 1) per position
-            v = v * sv_ref[0][None]
-        # the dense path's mul+reduce contraction, one page at a time
-        s = (q[:, :, None, :] * k[None]).sum(-1) * scale  # (G, H, BS)
-        k_pos = i * BS + jax.lax.broadcasted_iota(
-            jnp.int32, (G, H, BS), 2
-        )
+            # softmax carry
+            k = k * sk_ref[...]  # scales (BS, 1) per position
+            v = v * sv_ref[...]
+        s = jax.lax.dot_general(
+            qbd_ref[...], k, (((1,), (1,)), ((), ())),
+            precision=qk_exact, preferred_element_type=jnp.float32,
+        ) * scale  # (G*H, BS)
+        k_pos = i * BS + jax.lax.broadcasted_iota(jnp.int32, (GH, BS), 1)
         row_pos = pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (G, H, BS), 0
-        )
-        s = jnp.where(
-            k_pos <= row_pos, s, jnp.finfo(jnp.float32).min
-        )
-        sf = s.reshape(G * H, BS)
+            jnp.int32, (GH, BS), 0
+        ) // H
+        s = jnp.where(k_pos <= row_pos, s, jnp.finfo(jnp.float32).min)
         m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, sf.max(axis=-1))
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sf - m_new[:, None])  # (G*H, BS)
+        p = jnp.exp(s - m_new[:, None])  # (G*H, BS) float32
         l_ref[:, 0] = l_ref[:, 0] * alpha + p.sum(axis=-1)
-        pv = (p.reshape(G, H, BS)[..., None] * v[None]).sum(axis=2)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv.reshape(
-            G * H, -1
-        )
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32,
+        )  # (G*H, H*D); row (g, h) means something in head h's lanes
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
         m_ref[:, 0] = m_new
 
     @pl.when(i == MB - 1)
     def _finalize():
-        out = acc_ref[...] / l_ref[:, 0][:, None]
-        o_ref[0] = out.reshape(G, *o_ref.shape[2:]).astype(o_ref.dtype)
+        out = jnp.where(
+            own_head(), acc_ref[...] / l_ref[:, 0][:, None], 0.0
+        )
+        # each query row's H copies back into one row: head h's lanes
+        # come from copy h, every other copy holds zeros there
+        if G == 1:
+            out = out.sum(axis=0, keepdims=True)
+        else:
+            out = jax.lax.dot_general(
+                own_row(), out, (((0,), (0,)), ((), ())),
+                precision=exact, preferred_element_type=jnp.float32,
+            )
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _paged_call(q, pool_k, pool_v, positions, block_tables, scale,
-                scale_k=None, scale_v=None, layer=0):
-    # NOT jitted here: the callers (the serve programs) are jitted
-    # closures, and an own-cache jit would pin the INTERPRET flag at
-    # first trace — tests flip it per engine build.
+def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
+                scale_k, scale_v, *, BS, scale, interpret):
+    # Called through ``_JITTED``: jitted on its own, with the
+    # interpreter flag among the static arguments (tests flip it per
+    # engine build).  A serve program calls this once a layer with the
+    # same shapes, so the kernel is traced, lowered and compiled once a
+    # program and not once a layer.
     #
-    # The pools come in WHOLE, (L, N, H, BS, D), and ``layer`` is a
-    # static index inside the index_maps: a ``pool[layer]`` slice in
+    # The pools come in WHOLE, (L, N * BS, H * D), and ``layer`` is a
+    # scalar the index_maps read from SMEM: a ``pool[layer]`` slice in
     # front of a custom call is a copy of the layer (76 MB a layer at
     # GPT-2-small width and 24 slots), not a view.
     B, G, H, D = q.shape
-    _, N, _, BS, _ = pool_k.shape
+    HD = H * D
     MB = block_tables.shape[1]
     quantized = scale_k is not None
+    # the block-diagonal query multiplies a bfloat16 page as bfloat16
+    # (the kernel reads the choice off the scratch's dtype)
+    qbd_dtype = (
+        jnp.bfloat16 if q.dtype == pool_k.dtype == jnp.bfloat16
+        else jnp.float32
+    )
 
-    def q_map(b, i, pos_ref, bt_ref):
-        return (b, 0, 0, 0)
+    def q_map(b, i, layer_ref, pos_ref, bt_ref):
+        return (b, 0, 0)
 
-    def kv_map(b, i, pos_ref, bt_ref):
+    def kv_map(b, i, layer_ref, pos_ref, bt_ref):
         # clamp to the lane's last live page: a repeated block index is
         # an unchanged DMA (Mosaic skips it) and the i > last compute
         # is pl.when-gated off, so masked pages are never fetched
         last = jnp.minimum((pos_ref[b] + G - 1) // BS, MB - 1)
-        return (layer, bt_ref[b, jnp.minimum(i, last)], 0, 0, 0)
+        return (layer_ref[0], bt_ref[b, jnp.minimum(i, last)], 0)
 
-    def sc_map(b, i, pos_ref, bt_ref):
+    def sc_map(b, i, layer_ref, pos_ref, bt_ref):
         # the scale row rides the same physical-block index as its page
-        last = jnp.minimum((pos_ref[b] + G - 1) // BS, MB - 1)
-        return (layer, bt_ref[b, jnp.minimum(i, last)], 0, 0)
+        return kv_map(b, i, layer_ref, pos_ref, bt_ref) + (0,)
 
     in_specs = [
-        pl.BlockSpec((1, G, H, D), q_map),
-        pl.BlockSpec((None, 1, H, BS, D), kv_map),
-        pl.BlockSpec((None, 1, H, BS, D), kv_map),
+        pl.BlockSpec((1, G, HD), q_map),
+        pl.BlockSpec((None, BS, HD), kv_map),
+        pl.BlockSpec((None, BS, HD), kv_map),
     ]
-    operands = [positions, block_tables, q, pool_k, pool_v]
+    operands = [
+        layer.reshape(1), positions, block_tables, q.reshape(B, G, HD),
+        pool_k, pool_v,
+    ]
     if quantized:
         # one scale row per page, as a (BS, 1) column: a (1, BS) block of
         # the (N, BS) array breaks Mosaic's (8, 128) block rule, while
         # trailing block dims that EQUAL the array's are always legal —
         # and the column broadcasts across the page's lanes as it is
         in_specs += [
-            pl.BlockSpec((None, 1, BS, 1), sc_map),
-            pl.BlockSpec((None, 1, BS, 1), sc_map),
+            pl.BlockSpec((None, None, BS, 1), sc_map),
+            pl.BlockSpec((None, None, BS, 1), sc_map),
         ]
         operands += [scale_k[..., None], scale_v[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, MB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, H, D), q_map),
+        out_specs=pl.BlockSpec((1, G, HD), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G * H, D), jnp.float32),
+            pltpu.VMEM((G * H, HD), qbd_dtype),
+            pltpu.VMEM((G * H, HD), jnp.float32),
             pltpu.VMEM((G * H, 128), jnp.float32),
             pltpu.VMEM((G * H, 128), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _kernel, G=G, BS=BS, MB=MB, scale=scale, quantized=quantized
+        _kernel, G=G, H=H, BS=BS, MB=MB, scale=scale, quantized=quantized
     )
+    # no ``name=``: a compiled program and the profiler's trace name the
+    # call after the jitted function around it (``_jitted_as`` below)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, HD), q.dtype),
         # pages chain a carry per lane: both grid dims are sequential
-        compiler_params=None if INTERPRET else pltpu.CompilerParams(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
-        interpret=INTERPRET,
-    )(*operands)
+        interpret=interpret,
+    )(*operands).reshape(B, G, H, D)
+
+
+def _jitted_as(name):
+    """``_paged_call`` jitted under ``name``: the kernel's custom call
+    is ``%<name>.N`` in a compiled program and in the profiler's trace,
+    which is how a reader of either tells a decode-width call from a
+    prefill chunk's (the benchmark's ``paged_attention_roofline.*``
+    reads ``%decode.N`` and ``%prefill.N``)."""
+    def call(*args, **kwargs):
+        return _paged_call(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, static_argnames=("BS", "scale", "interpret"))
+
+
+_JITTED = {name: _jitted_as(name) for name in ("decode", "prefill")}
 
 
 def paged_decode_attention(
     q, pool_k, pool_v, positions, block_tables, scale=None,
-    scale_k=None, scale_v=None, layer=None,
+    scale_k=None, scale_v=None, layer=None, *, block_size,
 ):
     """Fused paged decode attention over one layer's K/V pool.
 
     Args:
       q: (B, G, H, D) query rows — ``G`` consecutive positions per
         lane (decode/draft G=1; speculative verify G=k+1).
-      pool_k / pool_v: (num_blocks, H, BS, D) — the layer's paged pool
-        (physical block 0 is the allocator's trash block); or, with
-        ``layer`` given, the WHOLE (L, num_blocks, H, BS, D) pools, of
-        which the kernel reads layer ``layer`` (a static int) in place.
-        The serve programs pass the whole pools: a ``pool[i]`` slice in
-        front of the kernel is a copy of the layer, every call.
+      pool_k / pool_v: (num_blocks * BS, H * D) — the layer's paged pool,
+        position-major (physical block ``n`` is rows ``n * BS ..``; block
+        0 is the allocator's trash block); or, with ``layer`` given, the
+        WHOLE (L, num_blocks * BS, H * D) pools, of which the kernel
+        reads layer ``layer`` in place.  The serve programs pass the
+        whole pools: a ``pool[i]`` slice in front of the kernel is a
+        copy of the layer, every call.
       positions: (B,) int32 — row 0's position per lane; row ``g``
         attends positions ``0 .. positions[b] + g`` inclusive (the
         freshly written page rows included, matching the dense
@@ -291,13 +402,24 @@ def paged_decode_attention(
         for layer ``i``; the whole (L, num_blocks, BS) pools with
         ``layer``); when given each DMA'd page is dequantized
         in-register via the shared ``int.astype(f32) * scale`` rule
-        before the f32 online-softmax carry, so kernel and gather
-        fallback stay bit-identical.  Pass both or neither.
+        before the f32 online-softmax carry.  Pass both or neither.
+      block_size: ``BS``, the rows of a page (the pool's shape does not
+        say).
 
-    Returns (B, G, H, D) in ``q.dtype``.  Numerics: online softmax in
-    float32 — agrees with the dense gather path to reordering ulp
-    (the greedy argmax streams are bit-identical; tests pin both).
+    Returns (B, G, H, D) in ``q.dtype``.  Numerics: float32 scores,
+    online softmax and accumulation; the two contractions run on the
+    MXU at full precision, so the result agrees with the dense gather
+    path to a float32 tolerance, not to the bit (the greedy argmax
+    streams are identical; tests pin both).
     """
+    return _attention(
+        "decode", q, pool_k, pool_v, positions, block_tables, scale,
+        scale_k, scale_v, layer, block_size,
+    )
+
+
+def _attention(name, q, pool_k, pool_v, positions, block_tables, scale,
+               scale_k, scale_v, layer, block_size):
     if (scale_k is None) != (scale_v is None):
         raise ValueError("pass both scale_k and scale_v, or neither")
     if scale is None:
@@ -310,15 +432,16 @@ def paged_decode_attention(
         pool_k, pool_v = pool_k[None], pool_v[None]
         if scale_k is not None:
             scale_k, scale_v = scale_k[None], scale_v[None]
-    return _paged_call(
-        q, pool_k, pool_v, positions, block_tables, float(scale),
-        scale_k=scale_k, scale_v=scale_v, layer=int(layer),
+    return _JITTED[name](
+        q, pool_k, pool_v, positions, block_tables,
+        jnp.asarray(layer, jnp.int32), scale_k, scale_v,
+        BS=int(block_size), scale=float(scale), interpret=bool(INTERPRET),
     )
 
 
 def paged_prefill_attention(
     q, pool_k, pool_v, start, block_tables, scale=None,
-    scale_k=None, scale_v=None, layer=None,
+    scale_k=None, scale_v=None, layer=None, *, block_size,
 ):
     """Fused paged CHUNKED-PREFILL attention over one layer's K/V pool.
 
@@ -351,33 +474,33 @@ def paged_prefill_attention(
     ``scale_k``/``scale_v`` are the quantized pool's per-position
     dequant scale rows ((num_blocks, BS) float32), riding the same
     block-table scalar-prefetch as the pages with in-register dequant
-    — paged and gather prefill stay bit-identical per kv_dtype, the
-    decode contract at chunk width (tests pin fp32/int8/fp8).
+    — the decode contract at chunk width (tests pin fp32/int8/fp8).
 
     Returns (B, P, H, D) in ``q.dtype``.
     """
     # the decode entry point already generalizes to G consecutive rows;
     # prefill IS that kernel at G = P — one shared lowering, one parity
     # contract, no second code path to drift
-    return paged_decode_attention(
-        q, pool_k, pool_v, start, block_tables, scale=scale,
-        scale_k=scale_k, scale_v=scale_v, layer=layer,
+    return _attention(
+        "prefill", q, pool_k, pool_v, start, block_tables, scale,
+        scale_k, scale_v, layer, block_size,
     )
 
 
 def _write_kernel(
+    layer_ref,  # SMEM (1,) int32 — the layer the index_maps write
     phys_ref,  # SMEM (B, NP) int32 — physical block of lane b's page j
     lo_ref,  # SMEM (B, NP) int32 — first new row of that page
     hi_ref,  # SMEM (B, NP) int32 — one past its last new row
-    new_ref,  # VMEM (2, H, BS, D) — the lane's new K / V rows, page-shaped
-    k_ref,  # VMEM (H, BS, D) — page phys[b, j] of layer i
+    new_ref,  # VMEM (2, BS, H*D) — the lane's new K / V rows, page-shaped
+    k_ref,  # VMEM (BS, H*D) — page phys[b, j] of layer i
     v_ref,
     ko_ref,  # the same pages of the aliased pools
     vo_ref,
 ):
     b = pl.program_id(0)
     j = pl.program_id(1)
-    row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 0)
     fresh = (row >= lo_ref[b, j]) & (row < hi_ref[b, j])
     ko_ref[...] = jnp.where(fresh, new_ref[0], k_ref[...])
     vo_ref[...] = jnp.where(fresh, new_ref[1], v_ref[...])
@@ -410,28 +533,30 @@ def _write_plan(start, block_tables, G, BS, n_valid=None):
 
 
 def paged_kv_write(
-    pool_k, pool_v, layer, k, v, start, block_tables, n_valid=None
+    pool_k, pool_v, layer, k, v, start, block_tables, n_valid=None,
+    *, block_size,
 ):
     """Write each lane's new K/V rows into layer ``layer`` of the paged
     pools, in place: the device writer of the serve programs.
 
     Args:
-      pool_k / pool_v: (L, num_blocks, H, BS, D) — the WHOLE pools.  They
-        go in and come out of one ``pallas_call`` aliased onto themselves
-        (``input_output_aliases``); ``layer`` is a static index inside
-        the ``index_map``, so no per-layer slice goes in or comes back
-        and XLA sees no operation that wants the pool in a layout other
-        than the attention kernel's.
+      pool_k / pool_v: (L, num_blocks * BS, H * D) — the WHOLE pools.
+        They go in and come out of one ``pallas_call`` aliased onto
+        themselves (``input_output_aliases``); ``layer`` is a scalar
+        the ``index_map`` reads from SMEM, so no per-layer slice goes in or
+        comes back and XLA sees no operation that wants the pool in a
+        layout other than the attention kernel's.
       k / v: (B, G, H, D) — row ``g`` of lane ``b`` belongs at position
         ``start[b] + g`` (decode / draft G=1, verify G=k+1, prefill
         G=P); cast to the pool's dtype like ``.at[...].set`` would.
       start: (B,) int32.  block_tables: (B, MB) int32.
       n_valid: (B,) int32 or None — only rows ``g < n_valid[b]`` are
         written (a prefill chunk's padded tail); None writes all G.
+      block_size: ``BS``, the rows of a page.
 
     G consecutive positions touch at most ``NP = (G + BS - 2) // BS + 1``
-    pages.  The grid is (B, NP): each step brings one (H, BS, D) page of
-    K and of V into VMEM, replaces the rows ``lo <= r < hi`` that are
+    pages.  The grid is (B, NP): each step brings one (BS, H * D) page
+    of K and of V into VMEM, replaces the rows ``lo <= r < hi`` that are
     new (a select against an iota over BS, which lowers for every pool
     dtype) and writes the page back.  A page of the lane that takes no
     row — past ``n_valid``, past the table, an idle lane — is steered to
@@ -442,38 +567,45 @@ def paged_kv_write(
 
     Returns the two pools.
     """
-    _, _, H, BS, D = pool_k.shape
+    return _kv_write(
+        pool_k, pool_v, jnp.asarray(layer, jnp.int32), k, v, start,
+        block_tables, n_valid, BS=int(block_size), interpret=bool(INTERPRET),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("BS", "interpret"))
+def _kv_write(pool_k, pool_v, layer, k, v, start, block_tables, n_valid,
+              *, BS, interpret):
+    # jitted on its own like ``_paged_call``: one trace and one lowering
+    # a program, whatever its depth
+    HD = pool_k.shape[-1]
     B, G = k.shape[:2]
     phys, lo, hi, page = _write_plan(start, block_tables, G, BS, n_valid)
     NP = phys.shape[1]
     # the new rows in page shape: row r of page j is chunk row
     # page * BS + r - start (clamped; rows outside [lo, hi) are not read)
-    kv = jnp.stack([k, v]).astype(pool_k.dtype)  # (2, B, G, H, D)
+    kv = jnp.stack([k, v]).astype(pool_k.dtype).reshape(2, B, G, HD)
     if G == 1:
         # the decode step, every step: one row fills its page, no gather
-        new = jnp.broadcast_to(
-            kv[:, :, :, :, None, :], (2, B, NP, H, BS, D)
-        )
+        new = jnp.broadcast_to(kv[:, :, :, None, :], (2, B, NP, BS, HD))
     else:
         first = jnp.asarray(start, jnp.int32)[:, None, None]
         g = page[:, :, None] * BS + jnp.arange(BS, dtype=jnp.int32) - first
-        g = jnp.clip(g, 0, G - 1).reshape(1, B, NP * BS, 1, 1)
-        new = jnp.take_along_axis(kv, g, axis=2).reshape(
-            2, B, NP, BS, H, D
-        ).transpose(0, 1, 2, 4, 3, 5)
+        g = jnp.clip(g, 0, G - 1).reshape(1, B, NP * BS, 1)
+        new = jnp.take_along_axis(kv, g, axis=2).reshape(2, B, NP, BS, HD)
 
-    def new_map(b, j, phys_ref, lo_ref, hi_ref):
-        return (0, b, j, 0, 0, 0)
+    def new_map(b, j, layer_ref, phys_ref, lo_ref, hi_ref):
+        return (0, b, j, 0, 0)
 
-    def page_map(b, j, phys_ref, lo_ref, hi_ref):
-        return (layer, phys_ref[b, j], 0, 0, 0)
+    def page_map(b, j, layer_ref, phys_ref, lo_ref, hi_ref):
+        return (layer_ref[0], phys_ref[b, j], 0)
 
-    page_spec = pl.BlockSpec((None, None, H, BS, D), page_map)
+    page_spec = pl.BlockSpec((None, BS, HD), page_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, NP),
         in_specs=[
-            pl.BlockSpec((2, None, None, H, BS, D), new_map),
+            pl.BlockSpec((2, None, None, BS, HD), new_map),
             page_spec,
             page_spec,
         ],
@@ -486,11 +618,11 @@ def paged_kv_write(
             jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
             jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype),
         ],
-        # operands: phys, lo, hi, new, pool_k, pool_v
-        input_output_aliases={4: 0, 5: 1},
-        compiler_params=None if INTERPRET else pltpu.CompilerParams(
+        # operands: layer, phys, lo, hi, new, pool_k, pool_v
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
-        interpret=INTERPRET,
+        interpret=interpret,
         name="kv_page_write",
-    )(phys, lo, hi, new, pool_k, pool_v)
+    )(layer.reshape(1), phys, lo, hi, new, pool_k, pool_v)

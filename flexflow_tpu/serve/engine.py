@@ -3,7 +3,7 @@
 Execution model (docs/SERVING.md):
 
 * ONE jitted **decode step** serves every slot every step: inputs are
-  the paged K/V pools ``(L, num_blocks, H, block_size, D)`` (donated,
+  the paged K/V pools ``(L, num_blocks * block_size, H * D)`` (donated,
   and written in place), per-slot tokens/positions, and the per-slot
   block tables.  Inactive lanes carry an all-zero table row, so their
   writes land in the trash block (kvcache.py) — no masking, no
@@ -14,15 +14,16 @@ Execution model (docs/SERVING.md):
   (``ops/pallas/paged_attention.py::paged_kv_write``) on the aliased
   pools and hand both kernels the WHOLE pools (the layer is a static
   index inside the kernels' index_maps), so that between a program's
-  boundary and its kernels nothing wants the pool in a third layout
+  boundary and its kernels nothing wants the pool in another layout
   and no layer is sliced out of it; ``gather`` programs keep the XLA
-  scatter ``ck.at[i, blk, :, off, :].set(k)``, which on the CPU is in
+  scatter ``ck.at[i, blk * BS + off].set(k)``, which on the CPU is in
   place.  ``ServeEngine.kv_write`` says which (``page_kernel`` /
-  ``xla_scatter``).  What is left on the TPU is the boundary itself:
-  at rest the backend keeps a (..., 16, 64) array with the block
-  dimension minor-most, the kernels take it row-major, so each call
-  re-lays both pools out once on the way in and once on the way out
-  (PERF.md, PR 27: why that takes another pool geometry to remove).
+  ``xla_scatter``).  The boundary itself costs nothing either: the
+  pool's minor dimension is the whole ``H * D`` row, so the layout the
+  TPU keeps it in at rest is the one the kernels read (kvcache.py;
+  PERF.md, PR 29).  ``ServeEngine.pool_relayouts()`` counts, in the
+  compiled decode and prefill programs, the operations that copy or
+  transpose a pool-sized array all the same: 0 says the geometry held.
 * A **batched chunked prefill program** ingests ``P`` prompt positions
   per mid-prefill slot, ALL slots in ONE dispatch per window (static
   chunk size — ONE compile serves every prompt length and every
@@ -53,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -73,7 +75,11 @@ from flexflow_tpu.obs import (
     step_record,
 )
 from flexflow_tpu.runtime.faults import get_fault_plan
-from flexflow_tpu.serve.kvcache import PagedKVCache, quantize_kv
+from flexflow_tpu.serve.kvcache import (
+    PagedKVCache,
+    kv_pool_dtype,
+    quantize_kv,
+)
 from flexflow_tpu.serve.scheduler import (
     ContinuousBatchingScheduler,
     Request,
@@ -83,6 +89,7 @@ from flexflow_tpu.serve.scheduler import (
 __all__ = [
     "ServeEngine",
     "ServeReport",
+    "count_pool_relayouts",
     "load_drain",
     "save_drain",
 ]
@@ -136,6 +143,33 @@ def load_drain(path: str) -> Dict[str, Any]:
         raise CheckpointError(str(e)) from e
     requests = unflatten_requests(flat, manifest["requests"])
     return {"schema": manifest["schema"], "requests": requests}
+
+
+# one instruction of a compiled module's text: result type, then the
+# first `` word(`` after it, which is the operation
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(")
+_HLO_ARRAY = re.compile(r"[a-z]+(\d+)\w*\[([\d,]*)\]")
+
+
+def count_pool_relayouts(hlo_text: str, pool_nbytes: int) -> int:
+    """How many operations of a compiled program's text (fused ones
+    included) ``copy`` or ``transpose`` into an array of ``pool_nbytes``
+    bytes, under any shape: a whole K/V pool re-laid out, which is what
+    a pool geometry the kernels cannot read at rest costs at every call
+    (PERF.md, PR 27 and PR 29).  Not counted: ``copy-start`` /
+    ``copy-done``, the compiler staging a small array in faster
+    memory."""
+    n = 0
+    for line in hlo_text.splitlines():
+        m = _HLO_OP.match(line)
+        if not m or m.group(2) not in ("copy", "transpose"):
+            continue
+        made = _HLO_ARRAY.search(m.group(1))
+        if made is None:
+            continue
+        dims = [int(d) for d in made.group(2).split(",") if d]
+        n += math.prod(dims) * int(made.group(1)) // 8 == pool_nbytes
+    return n
 
 
 def _pct(vals: Sequence[float], q: float) -> Optional[float]:
@@ -278,7 +312,13 @@ class ServeEngine:
         # stays byte-identical to the pre-paged engine
         from flexflow_tpu.ops.pallas import paged_attention as _pattn
 
-        self.attn_kernel = _pattn.resolve_serve_attn(attn)
+        dt = model.executor.compute_dtype
+        # a page has to be whole sublane tiles of the pool's dtype for
+        # the kernels to lower on a TPU: ``auto`` declines to the gather
+        # arm where it is not, an explicit ``paged`` says so and raises
+        self.attn_kernel = _pattn.resolve_serve_attn(
+            attn, block_size, kv_pool_dtype(jnp, str(kv_dtype), fallback=dt)
+        )
         # the flag is read when the programs trace — at the warmup below
         self.attn_interpret = (
             self.attn_kernel == "paged" and bool(_pattn.INTERPRET)
@@ -290,7 +330,6 @@ class ServeEngine:
         self.kv_write = (
             "page_kernel" if self.attn_kernel == "paged" else "xla_scatter"
         )
-        dt = model.executor.compute_dtype
         # quantized serving arms (docs/SERVING.md "Quantized KV cache
         # and weight-only decode"): kv_dtype picks the pool element
         # format (fp32 = the engine's compute dtype — the legacy pool),
@@ -420,10 +459,11 @@ class ServeEngine:
 
         # fused paged decode attention (docs/PERF.md): the kernel walks
         # each lane's block table in SMEM instead of materializing the
-        # (B, MB, H, BS, D) gather every layer, every step.  Same score
-        # contraction and mask rule as ``attend``; online softmax in
-        # f32 — the greedy argmax streams are bit-identical (pinned by
-        # tests/test_paged_attention.py)
+        # (B, MB, BS, H, D) gather every layer, every step.  Same mask
+        # rule as ``attend``; scores and values contracted on the MXU
+        # over whole H * D rows, online softmax in f32 — the result
+        # agrees to a float32 tolerance and the greedy argmax streams
+        # are identical (pinned by tests/test_paged_attention.py)
         paged = self.attn_kernel == "paged"
         if paged:
             from flexflow_tpu.ops.pallas.paged_attention import (
@@ -446,7 +486,9 @@ class ServeEngine:
             # programs keep the XLA scatter, which is in place on the
             # CPU.  A quantized pool stores ints plus a per-position
             # scale; the (L, NB, BS) scale pools are small and scatter
-            # on adjacent index dimensions either way.
+            # on adjacent index dimensions either way.  A pool row is
+            # one position, all heads: block ``blk`` row ``off`` is pool
+            # row ``blk * BS + off``.
             G = k.shape[1]
             if quant or not paged:
                 pos = start[:, None] + jnp.arange(G)[None, :]
@@ -464,11 +506,27 @@ class ServeEngine:
                 sk = sk.at[i, blk, off].set(ksc)
                 sv = sv.at[i, blk, off].set(vsc)
             if paged:
-                ck, cv = paged_kv_write(ck, cv, i, k, v, start, bt, n_valid)
+                ck, cv = paged_kv_write(
+                    ck, cv, i, k, v, start, bt, n_valid, block_size=BS
+                )
             else:
-                ck = ck.at[i, blk, :, off, :].set(k)
-                cv = cv.at[i, blk, :, off, :].set(v)
+                ck = ck.at[i, blk * BS + off].set(k.reshape(B, G, H * D))
+                cv = cv.at[i, blk * BS + off].set(v.reshape(B, G, H * D))
             return ck, cv, sk, sv
+
+        def gather_kv(ck, cv, sk, sv, i, bt):
+            # the dense arm's read of layer i: each lane's pages,
+            # (B, MB, BS, H, D), as (B, H, SV, D) keys and values in
+            # logical position order — a buffer at the full virtual
+            # length, which is what the paged kernel exists to delete
+            def lanes(pool, sc):
+                x = pool[i].reshape(-1, BS, H, D)[bt]
+                if quant:
+                    # the kernel's exact dequant rule, pre-gather
+                    x = x.astype(jnp.float32) * sc[i][bt][..., None, None]
+                return x.transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
+
+            return lanes(ck, sk), lanes(cv, sv)
 
         def decode(params, ck, cv, *rest):
             # tok/pos (B,) int32; bt (B, MB) int32 block tables; a
@@ -504,27 +562,10 @@ class ServeEngine:
                     # in the lowered program (ffcheck ``paged_attn``)
                     o = paged_decode_attention(
                         q[:, None], ck, cv, pos, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i,
+                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
                     )[:, 0]
                 else:
-                    # gather each lane's pages: (B, MB, H, BS, D) ->
-                    # (B, H, SV, D) in logical position order
-                    keys = ck[i][bt]
-                    vals = cv[i][bt]
-                    if quant:
-                        # the kernel's exact dequant rule, pre-gather
-                        keys = keys.astype(jnp.float32) * (
-                            sk[i][bt][:, :, None, :, None]
-                        )
-                        vals = vals.astype(jnp.float32) * (
-                            sv[i][bt][:, :, None, :, None]
-                        )
-                    keys = keys.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
-                    vals = vals.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
+                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
                     o = attend(q, keys, vals, mask)
                 o = o.reshape(B, H * D) @ p_at["wo"]
                 if has_bias:
@@ -598,24 +639,10 @@ class ServeEngine:
                     # audits prefill too)
                     o = paged_prefill_attention(
                         q, ck, cv, start, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i,
+                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
                     )
                 else:
-                    keys = ck[i][bt]
-                    vals = cv[i][bt]
-                    if quant:
-                        keys = keys.astype(jnp.float32) * (
-                            sk[i][bt][:, :, None, :, None]
-                        )
-                        vals = vals.astype(jnp.float32) * (
-                            sv[i][bt][:, :, None, :, None]
-                        )
-                    keys = keys.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
-                    vals = vals.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
+                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
                     o = attend(q, keys[:, None], vals[:, None], mask)
                 o = o.reshape(B * P, H * D) @ p_at["wo"]
                 if has_bias:
@@ -684,24 +711,10 @@ class ServeEngine:
                 if paged:
                     o = paged_decode_attention(
                         q[:, None], ck, cv, pos, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i,
+                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
                     )[:, 0]
                 else:
-                    keys = ck[i][bt]
-                    vals = cv[i][bt]
-                    if quant:
-                        keys = keys.astype(jnp.float32) * (
-                            sk[i][bt][:, :, None, :, None]
-                        )
-                        vals = vals.astype(jnp.float32) * (
-                            sv[i][bt][:, :, None, :, None]
-                        )
-                    keys = keys.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
-                    vals = vals.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
+                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
                     o = attend(q, keys, vals, mask)
                 o = o.reshape(B, H * D) @ p_at["wo"]
                 if has_bias:
@@ -764,24 +777,10 @@ class ServeEngine:
                     # reaches position pos0 + j (G = W generalization)
                     o = paged_decode_attention(
                         q, ck, cv, pos0, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i,
+                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
                     )
                 else:
-                    keys = ck[i][bt]
-                    vals = cv[i][bt]
-                    if quant:
-                        keys = keys.astype(jnp.float32) * (
-                            sk[i][bt][:, :, None, :, None]
-                        )
-                        vals = vals.astype(jnp.float32) * (
-                            sv[i][bt][:, :, None, :, None]
-                        )
-                    keys = keys.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
-                    vals = vals.transpose(
-                        0, 2, 1, 3, 4
-                    ).reshape(B, H, SV, D)
+                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
                     o = attend(q, keys[:, None], vals[:, None], mask)
                 o = o.reshape(B * W, H * D) @ p_at["wo"]
                 if has_bias:
@@ -819,17 +818,11 @@ class ServeEngine:
         # warmup both programs once so the cache layout/sharding
         # stabilizes (same rationale as GPTDecodeSession) and steady
         # state replays compiled code only
-        z = jnp.zeros((B,), jnp.int32)
-        bt0 = jnp.zeros((B, MB), jnp.int32)
-        res = self._decode(
-            self._params_arg, *self._kvs(), z, z, bt0,
-        )
+        idle_decode, idle_prefill = self._idle_args()
+        z, _, bt0 = idle_decode
+        res = self._decode(self._params_arg, *self._kvs(), *idle_decode)
         bufs = res[2:]
-        res = self._prefill(
-            self._params_arg, *bufs,
-            jnp.zeros((B, P), jnp.int32), z,
-            jnp.ones((B,), jnp.int32), bt0,
-        )
+        res = self._prefill(self._params_arg, *bufs, *idle_prefill)
         bufs = res[2:]
         # chain one more decode on the prefill's outputs so BOTH
         # programs have seen the other's cache layout — steady state
@@ -849,7 +842,6 @@ class ServeEngine:
             bufs = res[4:]
             res = self._decode(self._params_arg, *bufs, z, z, bt0)
             bufs = res[2:]
-        self._cache_sharding = (bufs[0].sharding, bufs[1].sharding)
         # keep the CHAINED warmup buffers as the live pool: the warmup
         # only ever wrote the trash block (all tables were zero), so
         # every real block still holds zeros — and replacing them with
@@ -961,6 +953,43 @@ class ServeEngine:
         }
 
     # --- pool-buffer threading ---------------------------------------------
+    def _idle_args(self):
+        """What the decode and the prefill program take after the pools
+        when no lane is live (all-zero tables: every write lands in the
+        trash block): ``(tok, pos, bt)`` and ``(toks, start, n_valid,
+        bt)``."""
+        jnp = self._jnp
+        B, MB = self.slots, self.kv.max_blocks_per_seq
+        z = jnp.zeros((B,), jnp.int32)
+        bt0 = jnp.zeros((B, MB), jnp.int32)
+        toks = jnp.zeros((B, self.prefill_chunk), jnp.int32)
+        return (z, z, bt0), (toks, z, jnp.ones((B,), jnp.int32), bt0)
+
+    def pool_relayouts(self) -> int:
+        """Operations in the compiled decode and prefill programs that
+        copy or transpose an array of a pool's byte size
+        (:func:`count_pool_relayouts`).  The pool's geometry exists to
+        make this 0 on a TPU; ``H * D`` off the 128-lane grid or a page
+        that is not whole sublane tiles can bring a copy back, correct
+        and slow, and this is where it shows.  Read on demand
+        (``chip_smoke.py``, the status server's ``/poolz``), never at
+        build: it lowers and compiles both programs a second time."""
+        # shapes, not the live buffers: a running window donates those
+        pools = [
+            self._jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+            for x in self._kvs()
+        ]
+        return sum(
+            count_pool_relayouts(
+                prog.lower(self._params_arg, *pools, *args)
+                .compile().as_text(),
+                pools[0].size * pools[0].dtype.itemsize,
+            )
+            for prog, args in zip(
+                (self._decode, self._prefill), self._idle_args()
+            )
+        )
+
     def _kvs(self):
         """The live pool buffers in program-argument order: (ck, cv)
         for a full-precision pool, (ck, cv, sk, sv) for a quantized one

@@ -1,7 +1,7 @@
 """Live serve introspection: a read-only ops plane on ``--serve-status-port``.
 
 A stdlib :class:`~http.server.ThreadingHTTPServer` (no new deps) bound
-on localhost, serving four endpoints while an engine or cluster runs:
+on localhost, serving five endpoints while an engine or cluster runs:
 
   ===========  =========================================================
   endpoint     body
@@ -13,6 +13,10 @@ on localhost, serving four endpoints while an engine or cluster runs:
                identities (JSON)
   /spanz?n=    the last ``n`` ffspan/1 records (JSON; default 64)
   /metricz     Prometheus text exposition (obs/export.py)
+  /poolz       the K/V pool's geometry and ``pool_relayouts``: whole-pool
+               copies in the compiled decode and prefill programs (JSON;
+               compiles both once more on first use, on this server's
+               own thread, then answers from memory)
   ===========  =========================================================
 
 The zero-sync contract, stated once: the serve hot path NEVER talks to
@@ -85,6 +89,8 @@ class _Handler(BaseHTTPRequestHandler):
                 q = parse_qs(url.query)
                 n = int(q.get("n", ["64"])[0])
                 self._send_json(st.spanz(n))
+            elif url.path == "/poolz":
+                self._send_json(st.poolz())
             elif url.path == "/metricz":
                 self._send(
                     200, st.metricz().encode(),
@@ -95,6 +101,7 @@ class _Handler(BaseHTTPRequestHandler):
                     {"error": f"no such endpoint {url.path!r}",
                      "endpoints": [
                          "/healthz", "/statusz", "/spanz", "/metricz",
+                         "/poolz",
                      ]},
                     code=404,
                 )
@@ -147,6 +154,7 @@ class StatusServer:
         self._spans: deque = deque(maxlen=self.SPAN_RING)
         self._closing = False
         self._threads: list = []
+        self._poolz: Optional[Dict[str, Any]] = None  # fixed at build
 
     # --- wiring -------------------------------------------------------
     def attach(
@@ -295,6 +303,30 @@ class StatusServer:
                 report, self._slo.policy,
             )
         return doc
+
+    @staticmethod
+    def _engine_pool(eng: Any) -> Dict[str, Any]:
+        return {
+            "pool_shape": list(eng.kv.cache_k.shape),
+            "kv_dtype": eng.kv.kv_dtype,
+            "block_size": eng.kv.block_size,
+            "attn_kernel": eng.attn_kernel,
+            "pool_relayouts": eng.pool_relayouts(),
+        }
+
+    def poolz(self) -> Dict[str, Any]:
+        t = self._target
+        if t is None:
+            return {}
+        if self._poolz is None:
+            if hasattr(t, "prefill") and hasattr(t, "decode"):
+                self._poolz = {"pools": {
+                    "prefill": self._engine_pool(t.prefill),
+                    "decode": self._engine_pool(t.decode),
+                }}
+            else:
+                self._poolz = self._engine_pool(t)
+        return self._poolz
 
     def spanz(self, n: int = 64) -> Dict[str, Any]:
         with self._lock:

@@ -6,9 +6,16 @@ cache — every slot pays max-S HBM whether its conversation is 8 tokens
 or 8000.  This module carves the same capacity into fixed-size blocks
 (``block_size`` positions each, all layers and heads of one slot's
 position range together) with a free list and per-request block tables:
-physically the cache is ``(L, num_blocks, H, block_size, D)``, and a
-request's logical position ``p`` lives in physical block
-``table[p // block_size]`` at offset ``p % block_size``.  Short and
+physically the cache is position-major, ``(L, num_blocks * block_size,
+H * D)`` — a row holds every head of one position, physical block ``n``
+is the ``block_size`` rows from ``n * block_size`` — and a request's
+logical position ``p`` lives in physical block ``table[p //
+block_size]`` at offset ``p % block_size``.  The minor dimension is the
+whole ``H * D`` row (768 at GPT-2-small width), so the layout the TPU
+keeps the pool in at rest is the plain row-major tiled one the Pallas
+kernels read: no serve program re-lays a pool out (PERF.md, PR 29; a
+(..., block_size, D) page with D = 64 is kept in a compact layout of
+the runtime's own and cost four whole-pool copies a call).  Short and
 long requests then share HBM — the pool only needs to cover the sum of
 *actual* reserved lengths, not slots x max-S (the admission test in
 tests/test_serve.py pins a workload whose summed max-lengths exceed the
@@ -164,9 +171,10 @@ class PagedKVCache:
     with per-block refcounts, and the invariant checks (a block's
     refcount equals the number of tables mapping it, double-free
     rejected).  Device side: ``cache_k``/``cache_v`` of shape
-    ``(L, num_blocks, H, block_size, D)``, written/read by the serving
-    programs in :mod:`flexflow_tpu.serve.engine` through gather/scatter
-    indices derived from the block tables.
+    ``(L, num_blocks * block_size, H * D)``, written/read by the serving
+    programs in :mod:`flexflow_tpu.serve.engine` through the Pallas
+    kernels' block-table index_maps or gather/scatter indices derived
+    from the block tables.
     """
 
     def __init__(
@@ -240,8 +248,10 @@ class PagedKVCache:
         self.tables = np.zeros(
             (slots, self.max_blocks_per_seq), np.int32
         )
+        # position-major: block n is rows n * block_size .., a row is all
+        # heads of one position (the module docstring says why)
         shape = (
-            num_layers, self.num_blocks, heads, block_size, head_dim,
+            num_layers, self.num_blocks * block_size, heads * head_dim,
         )
         self.cache_k = jnp.zeros(shape, self.dtype)
         self.cache_v = jnp.zeros(shape, self.dtype)
@@ -497,8 +507,9 @@ class PagedKVCache:
             )
             return blk
         new = self._acquire(1, protect=blocks)[0]
-        self.cache_k = self.cache_k.at[:, new].set(self.cache_k[:, blk])
-        self.cache_v = self.cache_v.at[:, new].set(self.cache_v[:, blk])
+        src, dst = self._rows([blk]), self._rows([new])
+        self.cache_k = self.cache_k.at[:, dst].set(self.cache_k[:, src])
+        self.cache_v = self.cache_v.at[:, dst].set(self.cache_v[:, src])
         if self.quantized:  # the scale row travels with its block
             self.scale_k = self.scale_k.at[:, new].set(self.scale_k[:, blk])
             self.scale_v = self.scale_v.at[:, new].set(self.scale_v[:, blk])
@@ -628,20 +639,21 @@ class PagedKVCache:
             zeros = np.zeros((L, H, pad, D), k.dtype)
             k = np.concatenate([k, zeros], axis=2)
             v = np.concatenate([v, zeros], axis=2)
-        # (L, H, hi*BS, D) -> blocks (L, nb, H, BS, D) for the private span
-        k = k[:, :, lo_blk * BS:].reshape(L, H, nb, BS, D).transpose(
-            0, 2, 1, 3, 4
+        # (L, H, hi*BS, D) -> the private span's rows (L, nb*BS, H*D)
+        k = k[:, :, lo_blk * BS:].transpose(0, 2, 1, 3).reshape(
+            L, nb * BS, H * D
         )
-        v = v[:, :, lo_blk * BS:].reshape(L, H, nb, BS, D).transpose(
-            0, 2, 1, 3, 4
+        v = v[:, :, lo_blk * BS:].transpose(0, 2, 1, 3).reshape(
+            L, nb * BS, H * D
         )
         ids = np.asarray(self._owned[slot][lo_blk:hi_blk], np.int32)
         assert not any(
             self._refcount.get(int(b), 0) > 1 or int(b) in self._block_key
             for b in ids
         ), "restore would write a shared block (CoW discipline breached)"
-        self.cache_k = self.cache_k.at[:, ids].set(jnp.asarray(k, self.dtype))
-        self.cache_v = self.cache_v.at[:, ids].set(jnp.asarray(v, self.dtype))
+        rows = self._rows(ids)
+        self.cache_k = self.cache_k.at[:, rows].set(jnp.asarray(k, self.dtype))
+        self.cache_v = self.cache_v.at[:, rows].set(jnp.asarray(v, self.dtype))
         if self.quantized:
             sk = np.stack([
                 np.asarray(payload["layers"][f"layer{i}"]["sk"],
@@ -693,6 +705,12 @@ class PagedKVCache:
             assert blk in self._block_key, "retained block lost its key"
 
     # --- device-side views -------------------------------------------------
+    def _rows(self, blocks) -> np.ndarray:
+        """The pool rows of physical ``blocks``, in order: block ``n`` is
+        rows ``n * block_size .. (n + 1) * block_size - 1``."""
+        ids = np.asarray(blocks, np.int32)[:, None] * self.block_size
+        return (ids + np.arange(self.block_size, dtype=np.int32)).ravel()
+
     def table_row(self, slot: int):
         """One slot's (max_blocks_per_seq,) block table, for prefill."""
         return self.tables[slot].copy()
@@ -704,16 +722,13 @@ class PagedKVCache:
         against the dense session's cache (dtype preserved)."""
         ck = np.asarray(self.cache_k)
         cv = np.asarray(self.cache_v)
-        row = self.tables[slot]
-        L, H, BS, D = (
-            self.num_layers, self.heads, self.block_size, self.head_dim,
-        )
+        L, H, D = self.num_layers, self.heads, self.head_dim
         n = self.blocks_for(seq_len)
-        k = ck[:, row[:n]]  # (L, n, H, BS, D)
-        v = cv[:, row[:n]]
-        k = k.transpose(0, 2, 1, 3, 4).reshape(L, H, n * BS, D)[:, :, :seq_len]
-        v = v.transpose(0, 2, 1, 3, 4).reshape(L, H, n * BS, D)[:, :, :seq_len]
-        return k, v
+        rows = self._rows(self.tables[slot][:n])[:seq_len]
+        # (L, seq_len, H * D) rows -> heads in front of positions
+        k = ck[:, rows].reshape(L, -1, H, D).transpose(0, 2, 1, 3)
+        v = cv[:, rows].reshape(L, -1, H, D).transpose(0, 2, 1, 3)
+        return np.ascontiguousarray(k), np.ascontiguousarray(v)
 
     def gather_scales(self, slot: int, seq_len: int) -> Tuple[np.ndarray, np.ndarray]:
         """Host-side re-assembly of ``slot``'s per-position scales into

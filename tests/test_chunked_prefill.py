@@ -77,15 +77,22 @@ def _streams(reqs):
 
 
 # --------------------------------------------------------------- kernel
+def _pool(x):
+    """Pages ``(N, BS, H, D)`` as the kernels take them: position-major
+    rows ``(N * BS, H * D)``."""
+    return jnp.asarray(x).reshape(-1, x.shape[-2] * x.shape[-1])
+
+
 def _dense_ref(q, pk, pv, pos, bt, scale):
     """The engine's gather + mul/reduce contraction, in numpy — same
-    reference as test_paged_attention.py, here driven at G = chunk."""
+    reference as test_paged_attention.py, here driven at G = chunk;
+    ``pk`` / ``pv`` are pages ``(N, BS, H, D)``."""
     B, G, H, D = q.shape
-    _, _, BS, _ = pk.shape
+    BS = pk.shape[1]
     MB = bt.shape[1]
     SV = MB * BS
-    keys = pk[bt].transpose(0, 2, 1, 3, 4).reshape(B, H, SV, D)
-    vals = pv[bt].transpose(0, 2, 1, 3, 4).reshape(B, H, SV, D)
+    keys = pk[bt].transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
+    vals = pv[bt].transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
     s = np.einsum("bghd,bhsd->bghs", q, keys).astype(np.float32) * scale
     k_pos = np.arange(SV, dtype=np.int64)
     row = pos[:, None].astype(np.int64) + np.arange(G)[None]
@@ -127,15 +134,15 @@ def test_prefill_kernel_matches_dense_reference(
     rng = np.random.default_rng(101 * B + P)
     N = B * MB + 1
     q = rng.standard_normal((B, P, H, D)).astype(np.float32)
-    pk = rng.standard_normal((N, H, BS, D)).astype(np.float32)
-    pv = rng.standard_normal((N, H, BS, D)).astype(np.float32)
+    pk = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    pv = rng.standard_normal((N, BS, H, D)).astype(np.float32)
     perm = rng.permutation(N - 1) + 1
     bt = perm[: B * MB].reshape(B, MB).astype(np.int32)
     pos = rng.integers(0, MB * BS - P + 1, size=(B,)).astype(np.int32)
     _poison_dead(pk, pv, bt, pos, P, BS)
     got = np.asarray(pa.paged_prefill_attention(
-        jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
-        jnp.asarray(pos), jnp.asarray(bt),
+        jnp.asarray(q), _pool(pk), _pool(pv),
+        jnp.asarray(pos), jnp.asarray(bt), block_size=BS,
     ))
     want = _dense_ref(q, pk, pv, pos, bt, 1.0 / np.sqrt(D))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
@@ -151,8 +158,8 @@ def test_prefill_kernel_chunk_boundary_starts(interpret, start_kind):
     rng = np.random.default_rng(7)
     N = B * MB + 1
     q = rng.standard_normal((B, P, H, D)).astype(np.float32)
-    pk = rng.standard_normal((N, H, BS, D)).astype(np.float32)
-    pv = rng.standard_normal((N, H, BS, D)).astype(np.float32)
+    pk = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    pv = rng.standard_normal((N, BS, H, D)).astype(np.float32)
     bt = (rng.permutation(N - 1) + 1)[: B * MB].reshape(B, MB)
     bt = bt.astype(np.int32)
     pos = {
@@ -164,8 +171,8 @@ def test_prefill_kernel_chunk_boundary_starts(interpret, start_kind):
     }[start_kind]
     _poison_dead(pk, pv, bt, pos, P, BS)
     got = np.asarray(pa.paged_prefill_attention(
-        jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
-        jnp.asarray(pos), jnp.asarray(bt),
+        jnp.asarray(q), _pool(pk), _pool(pv),
+        jnp.asarray(pos), jnp.asarray(bt), block_size=BS,
     ))
     want = _dense_ref(q, pk, pv, pos, bt, 1.0 / np.sqrt(D))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
@@ -183,25 +190,21 @@ def test_prefill_kernel_quantized_pool_parity(interpret, kv_dtype):
     rng = np.random.default_rng(23)
     N = B * MB + 1
     q = rng.standard_normal((B, P, H, D)).astype(np.float32)
-    fk = rng.standard_normal((N, H, BS, D)).astype(np.float32)
-    fv = rng.standard_normal((N, H, BS, D)).astype(np.float32)
+    fk = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    fv = rng.standard_normal((N, BS, H, D)).astype(np.float32)
     # quantize per POSITION: (N, BS, H, D) -> q, scale (N, BS)
-    qk, sk = quantize_kv(jnp, jnp.asarray(fk).transpose(0, 2, 1, 3),
-                         kv_dtype)
-    qv, sv = quantize_kv(jnp, jnp.asarray(fv).transpose(0, 2, 1, 3),
-                         kv_dtype)
-    pk = jnp.transpose(qk, (0, 2, 1, 3))  # back to (N, H, BS, D)
-    pv = jnp.transpose(qv, (0, 2, 1, 3))
+    pk, sk = quantize_kv(jnp, jnp.asarray(fk), kv_dtype)
+    pv, sv = quantize_kv(jnp, jnp.asarray(fv), kv_dtype)
     bt = (rng.permutation(N - 1) + 1)[: B * MB].reshape(B, MB)
     bt = bt.astype(np.int32)
     pos = np.array([3, BS * 2], np.int32)
     got = np.asarray(pa.paged_prefill_attention(
-        jnp.asarray(q), pk, pv, jnp.asarray(pos), jnp.asarray(bt),
-        scale_k=sk, scale_v=sv,
+        jnp.asarray(q), _pool(pk), _pool(pv), jnp.asarray(pos),
+        jnp.asarray(bt), scale_k=sk, scale_v=sv, block_size=BS,
     ))
     # host-side dequant, then the exact fp32 dense reference
-    dk = np.asarray(pk, np.float32) * np.asarray(sk)[:, None, :, None]
-    dv = np.asarray(pv, np.float32) * np.asarray(sv)[:, None, :, None]
+    dk = np.asarray(pk, np.float32) * np.asarray(sk)[:, :, None, None]
+    dv = np.asarray(pv, np.float32) * np.asarray(sv)[:, :, None, None]
     want = _dense_ref(q, dk, dv, pos, bt, 1.0 / np.sqrt(D))
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-4)
 

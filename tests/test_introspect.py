@@ -456,6 +456,30 @@ def test_cluster_health_covers_both_pools():
         assert json.loads(body)["snapshot"]["split"] == "p4+d4"
 
 
+def test_poolz_reports_the_pool_and_its_relayouts(model):
+    """/poolz: the K/V pool's geometry and ``pool_relayouts`` of the
+    attached engine, computed on the first request and answered from
+    memory afterwards (the programs are fixed at build)."""
+    eng = ServeEngine(model, slots=SLOTS, block_size=8, sync_every=4,
+                      attn="gather")
+    with StatusServer(0) as srv:
+        base = f"http://127.0.0.1:{srv.port}"
+        srv.start()
+        code, _, body = _get(base, "/poolz", timeout=60.0)
+        assert code == 200 and json.loads(body) == {}  # nothing attached
+        srv.attach(eng)
+        code, _, body = _get(base, "/poolz", timeout=60.0)
+        doc = json.loads(body)
+        assert code == 200
+        assert doc["pool_shape"] == list(eng.kv.cache_k.shape)
+        assert doc["pool_shape"][-1] == eng.kv.heads * eng.kv.head_dim
+        assert doc["block_size"] == 8 and doc["attn_kernel"] == "gather"
+        assert doc["pool_relayouts"] == 0  # the CPU's scatter is in place
+        first = srv._poolz
+        _get(base, "/poolz", timeout=60.0)
+        assert srv._poolz is first
+
+
 # ------------------------------------------------- driver truthful startup
 def _free_port():
     s = socket.socket()

@@ -11,10 +11,15 @@ mid-generation / the speculative verify program at k>=1, the ffcheck
 gather program claiming to be paged), the additive ffmetrics/1
 ``attn_kernel`` field + old/new stream interop, and the
 ``FFTPU_PALLAS_INTERPRET`` env override.  ISSUE 27 adds the page-write
-kernel: bit for bit against ``pool.at[i, blk, :, off, :].set(rows)``
+kernel: bit for bit against ``pool.at[i, blk * BS + off].set(rows)``
 at every row-group width and pool dtype, the pages no lane names
 untouched, and the paged engine (kernel writer) against the gather
-engine (XLA scatter) over prefill, decode and slot recycling.
+engine (XLA scatter) over prefill, decode and slot recycling.  ISSUE 29
+makes the pool position-major, ``(L, num_blocks * BS, H * D)``: the
+kernels' contractions run on the MXU, so kernel against gather is a
+float32 tolerance plus identical greedy streams, and the pool's
+geometry is pinned (minor dimension ``heads * head_dim``, no layout
+API anywhere in ``serve/`` or ``ops/pallas/``).
 """
 
 from __future__ import annotations
@@ -81,14 +86,21 @@ def _streams(reqs):
 
 
 # --------------------------------------------------------------- kernel
+def _pool(x):
+    """A test's pages ``(N, BS, H, D)`` (or ``(L, N, BS, H, D)``) as the
+    kernels take them: position-major rows ``(N * BS, H * D)``."""
+    return x.reshape(*x.shape[:-4], -1, x.shape[-2] * x.shape[-1])
+
+
 def _dense_ref(q, pk, pv, pos, bt, scale):
-    """The engine's gather + mul/reduce contraction, in numpy."""
+    """The engine's gather + mul/reduce contraction, in numpy; ``pk`` /
+    ``pv`` are pages ``(N, BS, H, D)``."""
     B, G, H, D = q.shape
-    _, _, BS, _ = pk.shape
+    BS = pk.shape[1]
     MB = bt.shape[1]
     SV = MB * BS
-    keys = pk[bt].transpose(0, 2, 1, 3, 4).reshape(B, H, SV, D)
-    vals = pv[bt].transpose(0, 2, 1, 3, 4).reshape(B, H, SV, D)
+    keys = pk[bt].transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
+    vals = pv[bt].transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
     s = np.einsum("bghd,bhsd->bghs", q, keys).astype(np.float32) * scale
     k_pos = np.arange(SV, dtype=np.int64)
     row = pos[:, None].astype(np.int64) + np.arange(G)[None]
@@ -117,8 +129,8 @@ def test_kernel_matches_dense_reference(interpret, B, G, H, D, BS, MB):
     rng = np.random.default_rng(17 * B + G)
     N = B * MB + 1  # + trash block 0
     q = rng.standard_normal((B, G, H, D)).astype(np.float32)
-    pk = rng.standard_normal((N, H, BS, D)).astype(np.float32)
-    pv = rng.standard_normal((N, H, BS, D)).astype(np.float32)
+    pk = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    pv = rng.standard_normal((N, BS, H, D)).astype(np.float32)
     # each lane gets a scrambled disjoint set of physical blocks (> 0)
     perm = rng.permutation(N - 1) + 1
     bt = perm[: B * MB].reshape(B, MB).astype(np.int32)
@@ -135,8 +147,8 @@ def test_kernel_matches_dense_reference(interpret, B, G, H, D, BS, MB):
     scale = 1.0 / np.sqrt(D)
     got = np.asarray(
         pa.paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
-            jnp.asarray(pos), jnp.asarray(bt),
+            jnp.asarray(q), jnp.asarray(_pool(pk)), jnp.asarray(_pool(pv)),
+            jnp.asarray(pos), jnp.asarray(bt), block_size=BS,
         )
     )
     want = _dense_ref(q, pk, pv, pos, bt, scale)
@@ -150,14 +162,14 @@ def test_kernel_bf16_io_f32_accumulate(interpret):
     B, G, H, D, BS, MB = 2, 1, 2, 8, 4, 3
     N = B * MB + 1
     q = rng.standard_normal((B, G, H, D)).astype(np.float32)
-    pk = rng.standard_normal((N, H, BS, D)).astype(np.float32)
-    pv = rng.standard_normal((N, H, BS, D)).astype(np.float32)
+    pk = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    pv = rng.standard_normal((N, BS, H, D)).astype(np.float32)
     bt = (rng.permutation(N - 1) + 1)[: B * MB].reshape(B, MB)
     pos = np.array([5, 11], np.int32)
     out = pa.paged_decode_attention(
-        jnp.asarray(q, jnp.bfloat16), jnp.asarray(pk, jnp.bfloat16),
-        jnp.asarray(pv, jnp.bfloat16), jnp.asarray(pos),
-        jnp.asarray(bt, np.int32),
+        jnp.asarray(q, jnp.bfloat16), jnp.asarray(_pool(pk), jnp.bfloat16),
+        jnp.asarray(_pool(pv), jnp.bfloat16), jnp.asarray(pos),
+        jnp.asarray(bt, np.int32), block_size=BS,
     )
     assert out.dtype == jnp.bfloat16
     want = _dense_ref(
@@ -171,11 +183,56 @@ def test_kernel_bf16_io_f32_accumulate(interpret):
     )
 
 
-# ----------------------------------------------------------- page write
 POOL_DTYPES = {
     "fp32": jnp.float32, "bf16": jnp.bfloat16,
     "int8": jnp.int8, "fp8": jnp.float8_e4m3fn,
 }
+
+
+@pytest.mark.parametrize("kv_dtype", list(POOL_DTYPES))
+@pytest.mark.parametrize("G", [1, 3, 8], ids=["decode", "verify", "chunk"])
+def test_kernel_at_page_boundaries_every_pool_dtype(interpret, G, kv_dtype):
+    """The block-diagonal contraction against the dense float32
+    reference where a row group meets a page: it starts one (row 0 on a
+    page's first position), ends one (the last row on a page's last
+    position) and crosses one (rows on both sides; for G = 1, the row
+    just past the boundary, whose history does) — at G = 1, G = k + 1
+    and G = P, for every pool dtype.  A quantized pool is compared
+    against the reference over the host-dequantized pages (the shared
+    ``int * scale`` rule), a bfloat16 one over the rounded pages."""
+    from flexflow_tpu.serve.kvcache import quantize_kv
+
+    B, H, D, BS, MB = 3, 2, 8, 4, 4
+    rng = np.random.default_rng(29 + G)
+    N = B * MB + 1
+    q = rng.standard_normal((B, G, H, D)).astype(np.float32)
+    fk = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    fv = rng.standard_normal((N, BS, H, D)).astype(np.float32)
+    bt = (rng.permutation(N - 1) + 1)[: B * MB].reshape(B, MB)
+    bt = bt.astype(np.int32)
+    crosses = 2 * BS - 1 if G > 1 else 2 * BS
+    pos = np.array([BS, 3 * BS - G, crosses], np.int32)
+    assert pos[0] % BS == 0 and (pos[1] + G) % BS == 0
+    assert G == 1 or pos[2] // BS != (pos[2] + G - 1) // BS
+    sk = sv = None
+    if kv_dtype in ("int8", "fp8"):
+        pk, sk = quantize_kv(jnp, jnp.asarray(fk), kv_dtype)  # (N, BS)
+        pv, sv = quantize_kv(jnp, jnp.asarray(fv), kv_dtype)
+        dk = np.asarray(pk, np.float32) * np.asarray(sk)[:, :, None, None]
+        dv = np.asarray(pv, np.float32) * np.asarray(sv)[:, :, None, None]
+    else:
+        pk = jnp.asarray(fk, POOL_DTYPES[kv_dtype])
+        pv = jnp.asarray(fv, POOL_DTYPES[kv_dtype])
+        dk, dv = np.asarray(pk, np.float32), np.asarray(pv, np.float32)
+    got = np.asarray(pa.paged_decode_attention(
+        jnp.asarray(q), _pool(pk), _pool(pv), jnp.asarray(pos),
+        jnp.asarray(bt), scale_k=sk, scale_v=sv, block_size=BS,
+    ))
+    want = _dense_ref(q, dk, dv, pos, bt, 1.0 / np.sqrt(D))
+    np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-4)
+
+
+# ----------------------------------------------------------- page write
 L_W, B_W, H_W, D_W, BS_W, MB_W = 3, 4, 2, 8, 4, 5
 N_W = B_W * MB_W + 1  # + trash block 0
 
@@ -200,7 +257,7 @@ def _write_case(case, rng):
 )
 def test_kv_page_write_matches_scatter(interpret, case, kv_dtype):
     """The kernel against the XLA scatter it replaces, bit for bit: the
-    rows land where ``pool.at[i, blk, :, off, :].set(rows)`` puts them,
+    rows land where ``pool.at[i, blk * BS + off].set(rows)`` puts them,
     the other layers and every page no lane names are byte-identical
     before and after (the alias writes nothing else), and where a chunk
     has padded rows only the trash block 0 may differ."""
@@ -214,7 +271,8 @@ def test_kv_page_write_matches_scatter(interpret, case, kv_dtype):
             rng.integers(-8, 9, size=shape), jnp.float32
         ).astype(dt)
 
-    pk, pv = rand((L_W, N_W, H_W, BS_W, D_W)), rand((L_W, N_W, H_W, BS_W, D_W))
+    pool_shape = (L_W, N_W * BS_W, H_W * D_W)
+    pk, pv = rand(pool_shape), rand(pool_shape)
     k, v = rand((B_W, G, H_W, D_W)), rand((B_W, G, H_W, D_W))
     bt = (rng.permutation(N_W - 1) + 1)[: B_W * MB_W].reshape(B_W, MB_W)
     bt = bt.astype(np.int32)
@@ -228,16 +286,20 @@ def test_kv_page_write_matches_scatter(interpret, case, kv_dtype):
         valid = np.arange(G)[None] < np.asarray(n_valid)[:, None]
         blk, off = np.where(valid, blk, 0), np.where(valid, off, 0)
     layer = 1
-    want_k = pk.at[layer, blk, :, off, :].set(k)
-    want_v = pv.at[layer, blk, :, off, :].set(v)
+    row = blk * BS_W + off
+    want_k = pk.at[layer, row].set(k.reshape(B_W, G, -1))
+    want_v = pv.at[layer, row].set(v.reshape(B_W, G, -1))
     got_k, got_v = pa.paged_kv_write(
         pk, pv, layer, k, v, jnp.asarray(start), jnp.asarray(bt),
         None if n_valid is None else jnp.asarray(n_valid, jnp.int32),
+        block_size=BS_W,
     )
     assert got_k.dtype == dt and got_v.dtype == dt
+    assert got_k.shape == got_v.shape == pool_shape
 
-    def raw(x):  # compare bytes, not values (fp8 NaN payloads included)
-        return np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+    def raw(x):  # bytes, not values (fp8 NaN payloads included), by block
+        x = np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+        return x.reshape(L_W, N_W, -1)
 
     first = 1 if n_valid is not None else 0  # padded rows: block 0 is free
     for got, want, before in ((got_k, want_k, pk), (got_v, want_v, pv)):
@@ -279,6 +341,94 @@ def test_kv_page_write_plan_shares_only_the_trash_block(G):
     assert len(set(live.tolist())) == live.size
     assert ((hi > lo) == (phys > 0)).all()  # block 0 takes an empty range
     assert (hi - lo).sum(axis=1).tolist() == n_valid.tolist()
+
+
+# ------------------------------------------------------------- geometry
+def test_pool_geometry_is_one_and_needs_no_layout_api():
+    """What the whole change rests on, cheap to break later: the pool's
+    minor dimension is the whole ``heads * head_dim`` row (so the layout
+    the TPU keeps it in at rest is the one the kernels read), for every
+    pool dtype, and nothing under ``serve/`` or ``ops/pallas/`` reaches
+    for ``jax.experimental.layout`` to say otherwise."""
+    import pathlib
+    import re
+
+    from flexflow_tpu.serve.kvcache import KV_DTYPES, PagedKVCache
+
+    for kv_dtype in KV_DTYPES:
+        kv = PagedKVCache(3, 4, 16, slots=2, block_size=8, num_blocks=7,
+                          max_seq_len=40, kv_dtype=kv_dtype)
+        assert kv.cache_k.shape == kv.cache_v.shape == (3, 7 * 8, 4 * 16)
+        assert kv.cache_k.shape[-1] == kv.heads * kv.head_dim
+        if kv.quantized:
+            assert kv.scale_k.shape == kv.scale_v.shape == (3, 7, 8)
+        assert kv.hbm_bytes() == 2 * kv.cache_k.size * (
+            kv.cache_k.dtype.itemsize
+        ) + (2 * kv.scale_k.size * 4 if kv.quantized else 0)
+    root = pathlib.Path(pa.__file__).resolve().parents[2]
+    layout_api = re.compile(
+        r"jax\.experimental\.layout|from jax\.experimental import[^\n]*\blayout\b"
+        r"|\bFormat\(|\bLayout\(|DeviceLocalLayout"
+    )
+    for sub in ("serve", "ops/pallas"):
+        for path in sorted((root / sub).glob("*.py")):
+            assert not layout_api.search(path.read_text()), path
+
+
+def test_page_rows_tile_and_the_tpu_rule(monkeypatch):
+    """A page is whole sublane tiles of the pool's dtype on a TPU: 8
+    rows of float32, 16 of bfloat16, 32 of a one-byte pool.  Where
+    ``block_size`` is not a multiple, ``auto`` declines to the gather
+    arm and an explicit ``paged`` says what it needs; the interpreter
+    takes any page."""
+    import jax
+
+    assert pa.page_rows_tile(jnp.float32) == 8
+    assert pa.page_rows_tile(jnp.bfloat16) == 16
+    assert pa.page_rows_tile(jnp.int8) == 32
+    assert pa.page_rows_tile(jnp.float8_e4m3fn) == 32
+    monkeypatch.setattr(pa, "INTERPRET", True)
+    assert pa.resolve_serve_attn("paged", 4, jnp.int8) == "paged"
+    monkeypatch.setattr(pa, "INTERPRET", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.resolve_serve_attn("auto", 16, jnp.bfloat16) == "paged"
+    assert pa.resolve_serve_attn("auto", 32, jnp.int8) == "paged"
+    assert pa.resolve_serve_attn("auto", 16, jnp.int8) == "gather"
+    assert pa.resolve_serve_attn("auto", 8, jnp.bfloat16) == "gather"
+    assert pa.resolve_serve_attn("paged", 8, jnp.float32) == "paged"
+    with pytest.raises(ValueError, match="multiple of 32"):
+        pa.resolve_serve_attn("paged", 16, jnp.int8)
+
+
+def test_pool_relayouts_counts_whole_pool_copies(model, gather_engine):
+    """``count_pool_relayouts`` reads a compiled module's text: a
+    ``copy`` or ``transpose`` into an array of the pool's byte size
+    counts under any shape (fused ones too), smaller arrays, other
+    operations and the compiler's own staging (``copy-start``) do not.
+    The engine's counter is an int read on demand; on the CPU the
+    gather arm's scatter is in place, so it reads 0."""
+    from flexflow_tpu.serve.engine import count_pool_relayouts
+
+    text = """
+HloModule jit_decode
+%fused_computation (p: bf16[12,24592,768]) -> bf16[12,1537,12,16,64] {
+  %p = bf16[12,24592,768]{2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %transpose.3 = bf16[12,1537,12,16,64]{1,4,3,2,0:T(8,128)(2,1)} transpose(%p), dimensions={0,1,3,2,4}
+}
+ENTRY %main {
+  %ck = bf16[12,24592,768]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %copy.184 = bf16[12,24592,768]{2,1,0:T(8,128)(2,1)} copy(%ck)
+  %copy.9 = bf16[1,24592,768]{2,1,0} copy(%slice.1)
+  %copy-start.1 = (bf16[12,24592,768]{2,1,0:S(1)}, bf16[12,24592,768]{2,1,0}, u32[]{:S(2)}) copy-start(%ck)
+  %fusion.2 = bf16[12,1537,12,16,64]{1,4,3,2,0} fusion(%ck), kind=kLoop, calls=%fused_computation
+  %decode.18 = bf16[24,1,768]{2,1,0} custom-call(%ck), custom_call_target="tpu_custom_call", metadata={op_name="jit(decode)/copy(x)"}
+  ROOT %kv_page_write.2 = (bf16[12,24592,768]{2,1,0}, bf16[12,24592,768]{2,1,0}) custom-call(%ck, %ck), custom_call_target="tpu_custom_call"
+}
+"""
+    assert count_pool_relayouts(text, 12 * 24592 * 768 * 2) == 2
+    assert count_pool_relayouts(text, 24592 * 768 * 2) == 1
+    assert count_pool_relayouts(text, 12 * 24592 * 768) == 0
+    assert gather_engine.pool_relayouts() == 0
 
 
 # ----------------------------------------------------------------- knob
@@ -542,5 +692,44 @@ def test_ffcheck_paged_attn_fires_on_gather_program(gather_engine):
     assert hits[0].severity == "error"
     assert "gather" in hits[0].message
     assert hits[0].details["nbytes"] >= hits[0].details["lane_kv_bytes"]
+
+
+@pytest.mark.parametrize("check", ["paged_attn", "kv_quant"])
+def test_ffcheck_pool_audits_refuse_to_skip_an_unrecognised_pool(check):
+    """The two pool audits find the pool by its label and its rank,
+    ``(L, num_blocks * BS, H * D)``.  A program that makes the claim
+    (``serve_attn: paged`` / ``kv_dtype: int8``) and shows no such input
+    — a pool in another geometry, another label — is a violation: the
+    audit had nothing to hold the claim against, and says so instead of
+    passing in silence."""
+    import jax
+
+    from flexflow_tpu.analysis import analyze_program, capture_jit
+
+    details = {
+        "serve_attn": "paged", "kv_dtype": "int8", "block_size": 4,
+        "max_blocks_per_seq": 3, "slots": 2,
+    }
+
+    def program(ck, cv, tok):
+        return tok, ck, cv
+
+    def audit(pool_shape, names=("cache_k", "cache_v", "tok")):
+        pool = jnp.zeros(pool_shape, jnp.int8)
+        art = capture_jit(
+            "serve.decode", "decode", jax.jit(program),
+            (pool, pool, jnp.zeros((2,), jnp.int32)),
+            arg_names=names, details=details, expects_donation=False,
+        )
+        return [v for v in analyze_program(art, checks=[check])
+                if v.check == check]
+
+    assert audit((2, 7 * 4, 2 * 8)) == []  # the pool as it is
+    for hits in (
+        audit((2, 7, 2, 4, 8)),  # the geometry before ISSUE 29
+        audit((2, 7 * 4, 2 * 8), names=("pool_k", "pool_v", "tok")),
+    ):
+        assert len(hits) == 1 and hits[0].severity == "error"
+        assert "K/V pool" in hits[0].message
 
 

@@ -136,12 +136,26 @@ def test_scheduler_fifo_admission_under_full_batch():
 # --------------------------------------------------- batched prefill parity
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_prefill_bit_identical_to_token_loop(dtype):
-    """Satellite pin: the one-call prefill produces bit-identical cache
-    contents AND next-token probs vs the per-token warmup loop, for
-    fp32 and compute_dtype=bf16."""
+    """Satellite pin: the one-call prefill produces the cache contents
+    AND next-token probs of the per-token warmup loop — to the bit under
+    compute_dtype=bf16, where every matmul's output is rounded to
+    bfloat16; under fp32 to a float32 tolerance plus the same greedy
+    token (ROADMAP: bit-identity is no longer the universal contract —
+    the one-call prefill multiplies (B * P, hidden) rows where the loop
+    multiplies (B, hidden), and this XLA:CPU sums a float32 dot in an
+    order that follows the shape: the two differ in the last ulp, 1.3e-7
+    on a probability)."""
     model = _build_model(dtype) if dtype != "float32" else _build_model()
     sess = GPTDecodeSession(model)
     rng = np.random.default_rng(7)
+
+    def same(a, b):
+        a, b = np.asarray(a), np.asarray(b)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(a, b)
+
     for plen in (1, 6, 13):
         prompt = rng.integers(0, VOCAB, size=(SLOTS, plen)).astype(np.int32)
         sess.reset()
@@ -151,11 +165,13 @@ def test_prefill_bit_identical_to_token_loop(dtype):
         cv = np.asarray(sess.cache_v)
         sess.reset()
         probs_pre = sess.prefill(prompt, 0)
+        same(probs_loop, probs_pre)
         np.testing.assert_array_equal(
-            np.asarray(probs_loop), np.asarray(probs_pre)
+            np.argmax(np.asarray(probs_loop), -1),
+            np.argmax(np.asarray(probs_pre), -1),
         )
-        np.testing.assert_array_equal(ck, np.asarray(sess.cache_k))
-        np.testing.assert_array_equal(cv, np.asarray(sess.cache_v))
+        same(ck, sess.cache_k)
+        same(cv, sess.cache_v)
 
 
 def test_generate_cached_same_tokens_either_prefill(model):

@@ -210,6 +210,9 @@ def test_kv_spill_restore_cross_geometry_property(kv_dtype):
             # dense bytes back out, restore THAT into the destination
             kv_src.restore(0, payload, length)
             hop = kv_src.spill(0, length)
+            # the frame is dense (H, length, D) a layer, whatever the
+            # pools' own geometry (position-major rows, ISSUE 29)
+            assert hop["layers"]["layer0"]["k"].shape == (H, length, D)
             kv_dst.restore(1, hop, length)
             back = kv_dst.spill(1, length)
             for i in range(L):

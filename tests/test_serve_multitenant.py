@@ -111,22 +111,33 @@ def test_ensure_private_cow_and_deregistration():
     kv.reserve(0, 24, prompt=p)
     ids = np.asarray(kv.owned(0), np.int32)
     rng = np.random.default_rng(0)
-    k_vals = rng.standard_normal((2, 3, 2, 8, 4)).astype(np.float32)
-    kv.cache_k = kv.cache_k.at[:, ids].set(jnp.asarray(k_vals))
+    # three pages of the position-major pool: (L, 3 * BS, H * D)
+    k_vals = rng.standard_normal((2, 3 * 8, 2 * 4)).astype(np.float32)
+    kv.cache_k = kv.cache_k.at[:, kv._rows(ids)].set(jnp.asarray(k_vals))
+    assert kv.cache_k.shape == (2, 8 * 8, 2 * 4)
     kv.commit_prefix(0, p, 17)
     kv.reserve(1, 24, prompt=p)
     shared_blk = kv.owned(1)[1]
     assert kv.refcount(shared_blk) == 2
     # CoW on a genuinely shared block: fresh id, contents bit-equal
+    before_k, before_v = np.asarray(kv.cache_k), np.asarray(kv.cache_v)
     new_blk = kv.ensure_private(1, 1)
     assert new_blk != shared_blk
     assert kv.refcount(shared_blk) == 1 and kv.refcount(new_blk) == 1
     assert kv.cow_copies == 1
     assert kv.tables[1, 1] == new_blk
+    pages_k = np.asarray(kv.cache_k).reshape(2, 8, 8, 2 * 4)
     np.testing.assert_array_equal(
-        np.asarray(kv.cache_k[:, new_blk]),
-        np.asarray(kv.cache_k[:, shared_blk]),
+        pages_k[:, new_blk], pages_k[:, shared_blk]
     )
+    assert np.any(pages_k[:, new_blk] != 0)
+    # one page copied and nothing else: every other row is as it was
+    other = np.ones(8 * 8, bool)
+    other[kv._rows([new_blk])] = False
+    np.testing.assert_array_equal(
+        np.asarray(kv.cache_k)[:, other], before_k[:, other]
+    )
+    np.testing.assert_array_equal(np.asarray(kv.cache_v), before_v)
     assert kv.shared_write_hazards() == []
     # sole-owner-but-indexed path: de-register in place, no copy
     before = kv.cow_copies
@@ -158,13 +169,20 @@ def test_spill_restore_round_trip_bit_exact():
     kv.reserve(0, 12, prompt=p)
     ids = np.asarray(kv.owned(0), np.int32)
     rng = np.random.default_rng(1)
-    k_vals = rng.standard_normal((2, 3, 2, 4, 4)).astype(np.float32)
-    v_vals = rng.standard_normal((2, 3, 2, 4, 4)).astype(np.float32)
-    kv.cache_k = kv.cache_k.at[:, ids].set(jnp.asarray(k_vals))
-    kv.cache_v = kv.cache_v.at[:, ids].set(jnp.asarray(v_vals))
+    k_vals = rng.standard_normal((2, 3 * 4, 2 * 4)).astype(np.float32)
+    v_vals = rng.standard_normal((2, 3 * 4, 2 * 4)).astype(np.float32)
+    rows = kv._rows(ids)
+    kv.cache_k = kv.cache_k.at[:, rows].set(jnp.asarray(k_vals))
+    kv.cache_v = kv.cache_v.at[:, rows].set(jnp.asarray(v_vals))
     kv.commit_prefix(0, p, 9)
     k0, v0 = kv.gather_dense(0, 11)
+    # the dense payload is (L, H, length, D) whatever the pool's geometry
+    assert k0.shape == v0.shape == (2, 2, 11, 4)
+    np.testing.assert_array_equal(
+        k0, k_vals[:, :11].reshape(2, 11, 2, 4).transpose(0, 2, 1, 3)
+    )
     payload = kv.spill(0, 11)
+    assert payload["layers"]["layer0"]["k"].shape == (2, 11, 4)
     kv.check_invariants()
     # restore to a DIFFERENT slot: shared prefix re-attaches from the
     # index, the private span scatters back — bytes identical

@@ -48,7 +48,7 @@ __all__ = ["GPTSpec", "GPTDecodeSession", "gpt_generate_cached"]
 class GPTSpec:
     """Shapes + attrs a compiled :func:`gpt_decoder` model implies —
     the ONE extraction rule, shared by the dense session here and the
-    paged serving programs (:mod:`flexflow_tpu.serve.engine`)."""
+    paged serving programs (:mod:`flexflow_tpu.serve.programs`)."""
 
     num_layers: int
     heads: int

@@ -1,7 +1,7 @@
 """Pallas paged decode attention — block-table-native K/V reads, and
 the page-write kernel that lands new K/V rows in the pool in place.
 
-The serving hot path (``serve/engine.py``) keeps each slot's K/V in a
+The serving hot path (``serve/programs.py``) keeps each slot's K/V in a
 :class:`~flexflow_tpu.serve.kvcache.PagedKVCache` pool of fixed-size
 blocks named by a per-slot block table.  The pool is position-major,
 ``(L, num_blocks * BS, H * D)``: a page is ``BS`` consecutive rows, a

@@ -13,10 +13,12 @@ compiled decode path (:mod:`flexflow_tpu.models.gpt_decode`):
   variable-length requests admitted FIFO into a shared fixed-slot
   decode step; finished sequences free their slot mid-flight and a
   queued request takes it without recompiling.
-* :mod:`flexflow_tpu.serve.engine` — the compiled paged decode step +
-  chunked prefill programs and the zero-per-step-sync serve loop
-  (device-chained tokens, one host sync per flush window — the
-  async-fit machinery applied to serving).
+* :mod:`flexflow_tpu.serve.programs` — the four jitted serve programs
+  (decode, chunked prefill, speculative draft and verify): one decoder
+  trunk over the paged pools, built outside the engine.
+* :mod:`flexflow_tpu.serve.engine` — the zero-per-step-sync serve loop
+  over them (device-chained tokens, one host sync per flush window —
+  the async-fit machinery applied to serving).
 * :mod:`flexflow_tpu.serve.traffic` — synthetic open-loop traffic
   generator for CPU-smoke A/Bs (`bench.py serve_continuous_ab`).
 * :mod:`flexflow_tpu.serve.objective` — ``ServeObjective``: prices

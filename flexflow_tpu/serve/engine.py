@@ -1,37 +1,15 @@
-"""Paged decode/prefill programs + the zero-per-step-sync serve loop.
+"""The zero-per-step-sync serve loop over the paged serve programs.
 
 Execution model (docs/SERVING.md):
 
-* ONE jitted **decode step** serves every slot every step: inputs are
-  the paged K/V pools ``(L, num_blocks * block_size, H * D)`` (donated,
-  and written in place), per-slot tokens/positions, and the per-slot
-  block tables.  Inactive lanes carry an all-zero table row, so their
-  writes land in the trash block (kvcache.py) — no masking, no
-  recompile when the active set changes.
-* Every program writes its new K/V rows through ONE helper
-  (``write_kv``), which rides the engine's ``attn_kernel`` decision:
-  ``paged`` programs write through the Pallas page-write kernel
-  (``ops/pallas/paged_attention.py::paged_kv_write``) on the aliased
-  pools and hand both kernels the WHOLE pools (the layer is a static
-  index inside the kernels' index_maps), so that between a program's
-  boundary and its kernels nothing wants the pool in another layout
-  and no layer is sliced out of it; ``gather`` programs keep the XLA
-  scatter ``ck.at[i, blk * BS + off].set(k)``, which on the CPU is in
-  place.  ``ServeEngine.kv_write`` says which (``page_kernel`` /
-  ``xla_scatter``).  The boundary itself costs nothing either: the
-  pool's minor dimension is the whole ``H * D`` row, so the layout the
-  TPU keeps it in at rest is the one the kernels read (kvcache.py;
-  PERF.md, PR 29).  ``ServeEngine.pool_relayouts()`` counts, in the
-  compiled decode and prefill programs, the operations that copy or
-  transpose a pool-sized array all the same: 0 says the geometry held.
-* A **batched chunked prefill program** ingests ``P`` prompt positions
-  per mid-prefill slot, ALL slots in ONE dispatch per window (static
-  chunk size — ONE compile serves every prompt length and every
-  mid-prefill slot count; padded rows and idle lanes write to the
-  trash block).  The weights stream once per chunk-batch instead of
-  once per slot, and on a paged engine the chunk attends through the
-  block-table-native Pallas kernel (visible pages only — no
-  virtual-length gather; docs/PERF.md).  Chunks are scheduled between
+* The four jitted programs (``decode``, ``prefill``, ``draft``,
+  ``verify``) are one decoder trunk over the donated paged K/V pools,
+  built by :func:`flexflow_tpu.serve.programs.build_serve_programs`; the
+  engine warms them up in one chain, so that all agree on ONE buffer
+  layout.  ONE decode step serves every slot every step, and ONE batched
+  chunked prefill ingests ``prefill_chunk`` prompt positions of ALL
+  mid-prefill slots per window (static shapes: one compile serves every
+  prompt length and every active set).  Chunks are scheduled between
   decode windows so a long prompt never stalls running decodes for its
   whole length.
 * The loop runs in **flush windows** (the async-fit discipline of
@@ -61,13 +39,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from flexflow_tpu.dataloader import DevicePrefetcher
-from flexflow_tpu.models.gpt_decode import (
-    GPTSpec,
-    dequantize_weights_int8,
-    layer_norm,
-    make_cast,
-    quantize_weights_int8,
-)
+from flexflow_tpu.models.gpt_decode import GPTSpec
 from flexflow_tpu.obs import (
     MetricsStream,
     SpanRecorder,
@@ -75,11 +47,8 @@ from flexflow_tpu.obs import (
     step_record,
 )
 from flexflow_tpu.runtime.faults import get_fault_plan
-from flexflow_tpu.serve.kvcache import (
-    PagedKVCache,
-    kv_pool_dtype,
-    quantize_kv,
-)
+from flexflow_tpu.serve.kvcache import PagedKVCache, kv_pool_dtype
+from flexflow_tpu.serve.programs import build_serve_programs
 from flexflow_tpu.serve.scheduler import (
     ContinuousBatchingScheduler,
     Request,
@@ -324,7 +293,7 @@ class ServeEngine:
             self.attn_kernel == "paged" and bool(_pattn.INTERPRET)
         )
         # how the programs write new K/V rows into the pool rides the
-        # same decision (``write_kv`` below): the Pallas page-write
+        # same decision (``write_kv``, programs.py): the Pallas page-write
         # kernel wherever the paged attention kernel runs, the XLA
         # scatter where Pallas cannot.  It engages on every call or none
         self.kv_write = (
@@ -399,421 +368,16 @@ class ServeEngine:
         self.predicted_step_s = sp.get("step_s")
         self.predicted_tok_s = sp.get("tok_s")
 
-        # --- build the two compiled programs -----------------------------
-        spec = self.spec
-        L, H, D = spec.num_layers, spec.heads, spec.head_dim
-        B, MB, BS = self.slots, self.kv.max_blocks_per_seq, block_size
-        SV = MB * BS  # virtual (paged) sequence length
-        S_pos = spec.seq  # pos_embed table height
-        has_bias, eps = spec.has_bias, spec.eps
-        scale = 1.0 / math.sqrt(D)
-        cast = make_cast(jnp, dt)
-        P = self.prefill_chunk
-        # quantized-pool trace-time switches: with ``quant`` the four
-        # programs take/donate/return the two scale pools beside the
-        # K/V pools (``*rest`` unpack below) and every scatter runs the
-        # shared quantize_kv rule; with fp32 arms the traced graphs are
-        # the pre-r19 programs bit for bit
-        quant = self.kv.quantized
-        kvdt = self.kv_dtype
-        # weight-only int8: the params ARGUMENT becomes the (qparams,
-        # scales) pair and every program folds the scales back first
-        # thing — the jitted signature changes, the math after the
-        # dequant edge does not
-        wq = self.weight_dtype == "int8"
-        # the programs below index params by LAYER name; a model whose
-        # blocks the executor scan-stacked (--stack-blocks, any chain of
-        # depth >= 4 under "auto") stores one (depth, ...) array per
-        # template layer.  The per-layer view is taken INSIDE the
-        # programs (static slices XLA reads in place, no second copy of
-        # the weights at rest); int8 quantizes that view on the host, so
-        # scales stay per layer
-        unstack = model.executor.unstack_tree
-        if wq:
-            self._params_arg = quantize_weights_int8(
-                jnp, unstack(model.executor.params)
-            )
-        else:
-            self._params_arg = model.executor.params
-
-        def prep_params(params):
-            if wq:
-                qp, qs = params
-                params = dequantize_weights_int8(jax, jnp, qp, qs)
-            else:
-                params = unstack(params)
-            return jax.tree.map(cast, params)
-
-        def ln(p, x):
-            return layer_norm(jax, jnp, p, x, eps)
-
-        def attend(q, keys, vals, mask):
-            # q (..., H, D) vs keys/vals (..., H, SV, D); mul+reduce
-            # scores — the same contraction form as the dense session
-            # (models/gpt_decode.py), so paged and dense decode agree
-            # to the ulp the shared formulation allows
-            scores = (q[..., None, :] * keys).sum(-1) * scale
-            scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-            w = jax.nn.softmax(scores, axis=-1)
-            return (w[..., None] * vals).sum(-2)
-
-        # fused paged decode attention (docs/PERF.md): the kernel walks
-        # each lane's block table in SMEM instead of materializing the
-        # (B, MB, BS, H, D) gather every layer, every step.  Same mask
-        # rule as ``attend``; scores and values contracted on the MXU
-        # over whole H * D rows, online softmax in f32 — the result
-        # agrees to a float32 tolerance and the greedy argmax streams
-        # are identical (pinned by tests/test_paged_attention.py)
-        paged = self.attn_kernel == "paged"
-        if paged:
-            from flexflow_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention,
-                paged_kv_write,
-                paged_prefill_attention,
-            )
-
-        def write_kv(ck, cv, sk, sv, i, k, v, start, bt, n_valid=None):
-            # THE write of a chunk's new K/V into layer i of the pools,
-            # for all four programs: k / v are (B, G, H, D), row g of
-            # lane b sits at position start[b] + g, and rows at or past
-            # n_valid[b] (the padded tail of a prefill chunk, a whole
-            # padded lane) belong to the trash block.  It rides the
-            # decision the engine already made (``self.kv_write``):
-            # paged programs write through the Pallas page-write kernel
-            # on the aliased pools, so that both users of the pool want
-            # it in ONE layout (an XLA scatter wants a third, and cost
-            # two more re-layouts of a layer, every layer); gather
-            # programs keep the XLA scatter, which is in place on the
-            # CPU.  A quantized pool stores ints plus a per-position
-            # scale; the (L, NB, BS) scale pools are small and scatter
-            # on adjacent index dimensions either way.  A pool row is
-            # one position, all heads: block ``blk`` row ``off`` is pool
-            # row ``blk * BS + off``.
-            G = k.shape[1]
-            if quant or not paged:
-                pos = start[:, None] + jnp.arange(G)[None, :]
-                blk = bt[
-                    jnp.arange(B)[:, None], jnp.clip(pos // BS, 0, MB - 1)
-                ]
-                off = jnp.clip(pos % BS, 0, BS - 1)
-                if n_valid is not None:
-                    valid = jnp.arange(G)[None, :] < n_valid[:, None]
-                    blk = jnp.where(valid, blk, 0)
-                    off = jnp.where(valid, off, 0)
-            if quant:
-                k, ksc = quantize_kv(jnp, k, kvdt)  # scales (B, G)
-                v, vsc = quantize_kv(jnp, v, kvdt)
-                sk = sk.at[i, blk, off].set(ksc)
-                sv = sv.at[i, blk, off].set(vsc)
-            if paged:
-                ck, cv = paged_kv_write(
-                    ck, cv, i, k, v, start, bt, n_valid, block_size=BS
-                )
-            else:
-                ck = ck.at[i, blk * BS + off].set(k.reshape(B, G, H * D))
-                cv = cv.at[i, blk * BS + off].set(v.reshape(B, G, H * D))
-            return ck, cv, sk, sv
-
-        def gather_kv(ck, cv, sk, sv, i, bt):
-            # the dense arm's read of layer i: each lane's pages,
-            # (B, MB, BS, H, D), as (B, H, SV, D) keys and values in
-            # logical position order — a buffer at the full virtual
-            # length, which is what the paged kernel exists to delete
-            def lanes(pool, sc):
-                x = pool[i].reshape(-1, BS, H, D)[bt]
-                if quant:
-                    # the kernel's exact dequant rule, pre-gather
-                    x = x.astype(jnp.float32) * sc[i][bt][..., None, None]
-                return x.transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
-
-            return lanes(ck, sk), lanes(cv, sv)
-
-        def decode(params, ck, cv, *rest):
-            # tok/pos (B,) int32; bt (B, MB) int32 block tables; a
-            # quantized pool threads its two scale pools right after
-            # the K/V pools (same donation discipline)
-            if quant:
-                sk, sv, tok, pos, bt = rest
-            else:
-                sk = sv = None
-                tok, pos, bt = rest
-            params = prep_params(params)
-            x = params["tok_embed"]["kernel"][tok]  # (B, hidden)
-            x = x + params["pos_embed"]["value"][
-                jnp.clip(pos, 0, S_pos - 1)
-            ]
-            mask = (jnp.arange(SV)[None, :] <= pos[:, None])[:, None, :]
-            for i in range(L):
-                p_at = params[f"dec{i}_attn"]
-                h = ln(params[f"dec{i}_ln0"], x)
-                q = h @ p_at["wq"]
-                k = h @ p_at["wk"]
-                v = h @ p_at["wv"]
-                if has_bias:
-                    q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
-                q = q.reshape(B, H, D)
-                # write this position's k/v into each lane's block
-                ck, cv, sk, sv = write_kv(
-                    ck, cv, sk, sv, i,
-                    k.reshape(B, 1, H, D), v.reshape(B, 1, H, D), pos, bt,
-                )
-                if paged:
-                    # block-table-native reads: no dense gather exists
-                    # in the lowered program (ffcheck ``paged_attn``)
-                    o = paged_decode_attention(
-                        q[:, None], ck, cv, pos, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
-                    )[:, 0]
-                else:
-                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
-                    o = attend(q, keys, vals, mask)
-                o = o.reshape(B, H * D) @ p_at["wo"]
-                if has_bias:
-                    o = o + p_at["bo"]
-                x = x + o
-                h = ln(params[f"dec{i}_ln1"], x)
-                p0, p1 = params[f"dec{i}_ff0"], params[f"dec{i}_ff1"]
-                f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
-                f = f @ p1["kernel"] + p1["bias"]
-                x = x + f
-            x = jax.lax.optimization_barrier(x)  # same boundary as dense
-            x = ln(params["final_ln"], x)
-            logits = x @ params["lm_head"]["kernel"]
-            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-            nxt = jnp.argmax(probs, axis=-1).astype(jnp.int32)
-            if quant:
-                return nxt, probs, ck, cv, sk, sv
-            return nxt, probs, ck, cv
-
-        def prefill(params, ck, cv, *rest):
-            # ALL mid-prefill slots' chunks in ONE dispatch (r20): toks
-            # (B, P), start/n_valid (B,), bt (B, MB).  Row g of lane b
-            # sits at position start[b] + g; lanes with n_valid == 0
-            # (no mid-prefill request in that slot) ride with an
-            # all-zero table row and write the trash block — the
-            # decode/verify idle-lane discipline at chunk width.  The
-            # weight-streaming win: the window streams the decode
-            # weights ONCE per chunk-batch instead of once per slot.
-            if quant:
-                sk, sv, toks, start, n_valid, bt = rest
-            else:
-                sk = sv = None
-                toks, start, n_valid, bt = rest
-            params = prep_params(params)
-            lane = jnp.arange(B)
-            pos = start[:, None] + jnp.arange(P)[None, :]  # (B, P)
-            x = params["tok_embed"]["kernel"][toks]  # (B, P, hidden)
-            x = x + params["pos_embed"]["value"][jnp.clip(pos, 0, S_pos - 1)]
-            mask = (
-                jnp.arange(SV)[None, None, :] <= pos[..., None]
-            )[:, :, None, :]  # (B, P, 1, SV)
-            hid = x.shape[-1]
-            for i in range(L):
-                p_at = params[f"dec{i}_attn"]
-                # every matmul flattens to (B*P, ...) 2-D — each row's
-                # arithmetic is the per-slot prefill's, bit for bit
-                # (the verify-program contract at chunk width)
-                h = ln(params[f"dec{i}_ln0"], x).reshape(B * P, hid)
-                q = h @ p_at["wq"]
-                k = h @ p_at["wk"]
-                v = h @ p_at["wv"]
-                if has_bias:
-                    q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
-                q = q.reshape(B, P, H, D)
-                k = k.reshape(B, P, H, D)
-                v = v.reshape(B, P, H, D)
-                # write the whole chunk, THEN attend: row g's mask
-                # reaches rows 0..g of this same program (the verify
-                # discipline) — and under prefix sharing a chunk never
-                # writes a still-shared block (commit happens post-
-                # chunk, CoW-audited by serve_cow).  Padded rows (and
-                # whole padded lanes) belong to the trash block
-                ck, cv, sk, sv = write_kv(
-                    ck, cv, sk, sv, i, k, v, start, bt, n_valid
-                )
-                if paged:
-                    # block-table-native chunk attention: the kernel's
-                    # visible-page clamp reads ceil((start + P) / BS)
-                    # pages per lane — no (H, SV, D) buffer, no
-                    # O(S^2)-in-SV traffic (ffcheck ``paged_attn`` now
-                    # audits prefill too)
-                    o = paged_prefill_attention(
-                        q, ck, cv, start, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
-                    )
-                else:
-                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
-                    o = attend(q, keys[:, None], vals[:, None], mask)
-                o = o.reshape(B * P, H * D) @ p_at["wo"]
-                if has_bias:
-                    o = o + p_at["bo"]
-                x = x + o.reshape(B, P, hid)
-                h = ln(params[f"dec{i}_ln1"], x).reshape(B * P, hid)
-                p0, p1 = params[f"dec{i}_ff0"], params[f"dec{i}_ff1"]
-                f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
-                f = f @ p1["kernel"] + p1["bias"]
-                x = x + f.reshape(B, P, hid)
-            x = jax.lax.optimization_barrier(x)
-            # distribution after each lane's LAST VALID row (layer norm
-            # is per-row, so select-then-ln == ln-then-select)
-            row = x[lane, jnp.clip(n_valid - 1, 0, P - 1)]  # (B, hid)
-            row = ln(params["final_ln"], row)
-            logits = row @ params["lm_head"]["kernel"]
-            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-            nxt = jnp.argmax(probs, axis=-1).astype(jnp.int32)  # (B,)
-            if quant:
-                return nxt, probs, ck, cv, sk, sv
-            return nxt, probs, ck, cv
-
-        # --- speculative decoding programs (docs/SERVING.md) --------------
-        # The chain layout makes a depth-Ld draft model a SLICE of the
-        # stacked params: the draft trunk is layers 0..Ld-1 plus the
-        # shared final_ln/lm_head — no second set of weights.  The
-        # draft program is the decode step truncated to Ld layers
-        # (writing only those layers' K/V); the verify program is one
-        # batched paged-decode step over W = k+1 consecutive positions
-        # per slot that rewrites ALL layers and computes, ON DEVICE, the
-        # longest draft prefix the full model agrees with.  Both return
-        # their successors (next token, next position) as device arrays,
-        # so macro steps chain device-to-device exactly like plain
-        # decode — the zero-per-step-sync ledger is unchanged.
-        Ld, W = self.spec_draft_layers, self.spec_k + 1
-
-        def draft(params, ck, cv, *rest):
-            # identical to decode through the first Ld layers; the
-            # rejected-position K/V this writes is rewritten by whichever
-            # program next processes those positions before any row's
-            # causal mask can expose it (see SERVING.md)
-            if quant:
-                sk, sv, tok, pos, bt = rest
-            else:
-                sk = sv = None
-                tok, pos, bt = rest
-            params = prep_params(params)
-            x = params["tok_embed"]["kernel"][tok]
-            x = x + params["pos_embed"]["value"][
-                jnp.clip(pos, 0, S_pos - 1)
-            ]
-            mask = (jnp.arange(SV)[None, :] <= pos[:, None])[:, None, :]
-            for i in range(Ld):
-                p_at = params[f"dec{i}_attn"]
-                h = ln(params[f"dec{i}_ln0"], x)
-                q = h @ p_at["wq"]
-                k = h @ p_at["wk"]
-                v = h @ p_at["wv"]
-                if has_bias:
-                    q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
-                q = q.reshape(B, H, D)
-                ck, cv, sk, sv = write_kv(
-                    ck, cv, sk, sv, i,
-                    k.reshape(B, 1, H, D), v.reshape(B, 1, H, D), pos, bt,
-                )
-                if paged:
-                    o = paged_decode_attention(
-                        q[:, None], ck, cv, pos, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
-                    )[:, 0]
-                else:
-                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
-                    o = attend(q, keys, vals, mask)
-                o = o.reshape(B, H * D) @ p_at["wo"]
-                if has_bias:
-                    o = o + p_at["bo"]
-                x = x + o
-                h = ln(params[f"dec{i}_ln1"], x)
-                p0, p1 = params[f"dec{i}_ff0"], params[f"dec{i}_ff1"]
-                f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
-                f = f @ p1["kernel"] + p1["bias"]
-                x = x + f
-            x = jax.lax.optimization_barrier(x)
-            x = ln(params["final_ln"], x)
-            logits = x @ params["lm_head"]["kernel"]
-            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-            nxt = jnp.argmax(probs, axis=-1).astype(jnp.int32)
-            if quant:
-                return nxt, ck, cv, sk, sv
-            return nxt, ck, cv
-
-        def verify(params, ck, cv, *rest):
-            # toks (B, W): [current, draft_1..draft_k]; row j of slot b
-            # sits at position pos0[b] + j.  Every matmul flattens to
-            # (B*W, ...) 2-D and attention keeps the shared mul+reduce
-            # contraction, so each row's arithmetic is the decode
-            # step's — the full model's argmax, bit for bit (the
-            # bit-identity tests pin this)
-            if quant:
-                sk, sv, toks, pos0, bt = rest
-            else:
-                sk = sv = None
-                toks, pos0, bt = rest
-            params = prep_params(params)
-            lane = jnp.arange(B)
-            pos = pos0[:, None] + jnp.arange(W)[None, :]  # (B, W)
-            x = params["tok_embed"]["kernel"][toks]  # (B, W, hidden)
-            x = x + params["pos_embed"]["value"][jnp.clip(pos, 0, S_pos - 1)]
-            mask = (
-                jnp.arange(SV)[None, None, :] <= pos[..., None]
-            )[:, :, None, :]  # (B, W, 1, SV)
-            hid = x.shape[-1]
-            for i in range(L):
-                p_at = params[f"dec{i}_attn"]
-                h = ln(params[f"dec{i}_ln0"], x).reshape(B * W, hid)
-                q = h @ p_at["wq"]
-                k = h @ p_at["wk"]
-                v = h @ p_at["wv"]
-                if has_bias:
-                    q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
-                q = q.reshape(B, W, H, D)
-                k = k.reshape(B, W, H, D)
-                v = v.reshape(B, W, H, D)
-                # write all W rows, THEN attend: row j's mask reaches
-                # rows 0..j of this same program, freshly written (the
-                # prefill-chunk discipline, batched over slots)
-                ck, cv, sk, sv = write_kv(
-                    ck, cv, sk, sv, i, k, v, pos0, bt
-                )
-                if paged:
-                    # one kernel call covers all W rows: row j's mask
-                    # reaches position pos0 + j (G = W generalization)
-                    o = paged_decode_attention(
-                        q, ck, cv, pos0, bt, scale=scale,
-                        scale_k=sk, scale_v=sv, layer=i, block_size=BS,
-                    )
-                else:
-                    keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
-                    o = attend(q, keys[:, None], vals[:, None], mask)
-                o = o.reshape(B * W, H * D) @ p_at["wo"]
-                if has_bias:
-                    o = o + p_at["bo"]
-                x = x + o.reshape(B, W, hid)
-                h = ln(params[f"dec{i}_ln1"], x).reshape(B * W, hid)
-                p0, p1 = params[f"dec{i}_ff0"], params[f"dec{i}_ff1"]
-                f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
-                f = f @ p1["kernel"] + p1["bias"]
-                x = x + f.reshape(B, W, hid)
-            x = jax.lax.optimization_barrier(x)
-            x = ln(params["final_ln"], x)
-            logits = x.reshape(B * W, hid) @ params["lm_head"]["kernel"]
-            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-            n = jnp.argmax(probs, axis=-1).astype(jnp.int32).reshape(B, W)
-            # accept the longest agreeing prefix: draft j survives iff
-            # every draft before it did AND the full model's argmax at
-            # its predecessor row reproduces it
-            agree = (toks[:, 1:] == n[:, :-1]).astype(jnp.int32)  # (B, k)
-            acc = jnp.cumprod(agree, axis=1).sum(axis=1)  # (B,) in [0, k]
-            next_cur = n[lane, acc]  # the first token NOT yet fed
-            next_pos = pos0 + acc + 1
-            if quant:
-                return n, acc, next_cur, next_pos, ck, cv, sk, sv
-            return n, acc, next_cur, next_pos, ck, cv
-
-        donate = (1, 2, 3, 4) if quant else (1, 2)
-        self._decode = jax.jit(decode, donate_argnums=donate)
-        self._prefill = jax.jit(prefill, donate_argnums=donate)
-        self._draft = self._verify = None
-        if self.spec_k:
-            self._draft = jax.jit(draft, donate_argnums=donate)
-            self._verify = jax.jit(verify, donate_argnums=donate)
+        # --- the compiled programs: one trunk, four programs (programs.py)
+        progs = build_serve_programs(
+            model, self.kv, attn_kernel=self.attn_kernel,
+            weight_dtype=self.weight_dtype, spec_k=self.spec_k,
+            spec_draft_layers=self.spec_draft_layers,
+        )
+        self._params_arg = progs.params_arg
+        self._decode, self._prefill = progs.decode, progs.prefill
+        self._draft, self._verify = progs.draft, progs.verify
+        B, MB, P = self.slots, self.kv.max_blocks_per_seq, self.prefill_chunk
 
         # warmup both programs once so the cache layout/sharding
         # stabilizes (same rationale as GPTDecodeSession) and steady
@@ -837,7 +401,7 @@ class ServeEngine:
             bufs = res[1:]
             res = self._verify(
                 self._params_arg, *bufs,
-                jnp.zeros((B, W), jnp.int32), z, bt0,
+                jnp.zeros((B, self.spec_k + 1), jnp.int32), z, bt0,
             )
             bufs = res[4:]
             res = self._decode(self._params_arg, *bufs, z, z, bt0)

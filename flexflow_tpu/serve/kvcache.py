@@ -172,7 +172,7 @@ class PagedKVCache:
     refcount equals the number of tables mapping it, double-free
     rejected).  Device side: ``cache_k``/``cache_v`` of shape
     ``(L, num_blocks * block_size, H * D)``, written/read by the serving
-    programs in :mod:`flexflow_tpu.serve.engine` through the Pallas
+    programs in :mod:`flexflow_tpu.serve.programs` through the Pallas
     kernels' block-table index_maps or gather/scatter indices derived
     from the block tables.
     """

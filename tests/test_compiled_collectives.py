@@ -119,7 +119,14 @@ def test_grad_overlap_off_is_byte_identical():
         txt = step.lower(
             ex.params, ex.state, ex.opt_state, xs, ys, 0
         ).compile().as_text()
-        return re.sub(r", metadata=\{[^}]*\}", "", txt)
+        txt = re.sub(r", metadata=\{[^}]*\}", "", txt)
+        # the tables of source locations that jax 0.9 prints at the head
+        # of a module hold the line each ``_hlo(...)`` below sits on
+        return re.sub(
+            r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+            r"(?:\d+ .*\n)*",
+            "", txt, flags=re.M,
+        )
 
     default = _hlo()
     off = _hlo(grad_overlap="off")

@@ -232,6 +232,10 @@ def test_gpt_generate_continues_learned_cycle():
         loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
         metrics=[],
         seed=0,
+        # the subject is generation, not parallelism: 150 steps of
+        # eight-device collectives beside five other workers aborted the
+        # worker when the rendezvous gave up (ROADMAP D0)
+        mesh=MachineMesh((1, 1), ("data", "model")),
     )
     rng = np.random.default_rng(0)
     ex = model.executor

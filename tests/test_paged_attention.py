@@ -278,7 +278,7 @@ def test_kv_page_write_matches_scatter(interpret, case, kv_dtype):
     bt = bt.astype(np.int32)
     for b in idle:
         bt[b] = 0  # an idle lane rides with an all-zero table row
-    # the programs' scatter indices (serve/engine.py::write_kv)
+    # the programs' scatter indices (serve/programs.py::write_kv)
     pos = start[:, None] + np.arange(G)[None]
     blk = bt[np.arange(B_W)[:, None], np.clip(pos // BS_W, 0, MB_W - 1)]
     off = pos % BS_W

@@ -15,7 +15,8 @@ what comes out:
                 once more with an int8 pool (pages of 32) and
                 speculation (k = 3).  Both engines' compiled decode and
                 prefill programs must hold no whole-pool copy
-                (``ServeEngine.pool_relayouts() == 0``).
+                (``ServeEngine.pool_relayouts() == 0``); the paged
+                kernel's walk (``attn_walk()``) is printed beside it.
 
 On a host with several chips the trainer also runs under the default
 all-devices mesh and under the searched strategy, asserts where every
@@ -648,6 +649,8 @@ def serve_gpt2(
         f"and prefill programs (pool {built[0].kv.cache_k.shape} "
         f"{built[0].kv.cache_k.dtype})",
     )
+    walk = built[0].attn_walk()
+    info(f"serve: pool_relayouts {relayouts}, attn_walk {walk}")
     check(
         s["prefill_chunks"] > s["prefill_dispatches"] > 0,
         "prefill chunks were not batched over slots",
@@ -679,6 +682,7 @@ def serve_gpt2(
         )},
         "pool_shape": list(built[0].kv.cache_k.shape),
         "pool_relayouts": relayouts,
+        "attn_walk": walk,
     }
 
 

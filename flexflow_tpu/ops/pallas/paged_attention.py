@@ -15,22 +15,32 @@ BS`` per lane, even for a request three tokens in.  That is pure HBM
 traffic and peak-memory overhead: the pages are then read *again* by
 the attention contraction.
 
-This kernel deletes the gather.  The grid walks the block table
-directly: block indices and per-lane positions ride as scalar-prefetch
-operands (SMEM), the K/V BlockSpec index_map resolves ``table[b, i]``
-per grid step, and Mosaic's DMA pipeline fetches each page straight
-from the pool — an online-softmax (running max/sum) carry accumulates
-the attention output page by page, so no virtual-length buffer ever
+This kernel deletes the gather.  Its grid is one step a lane.  The
+pools come in whole and stay in HBM (``memory_space=pl.ANY``); positions,
+block tables and the layer ride as scalar-prefetch operands (SMEM), and
+the body walks the lane's block table itself: a ``fori_loop`` whose trip
+count is the lane's own — ``n_pages = (pos + G - 1) // BS + 1`` live
+pages, ``pages_per_block`` of them a compute block
+(:func:`attention_walk`: the pages that make about 128 key positions, 8
+at 16 rows a page, read off the page size).  A block's pages are copied
+from the pool into one of two VMEM buffers by ``make_async_copy``, page
+by page as the table names them, and block ``j + 1``'s copies are
+started before block ``j``'s are waited on, so a block is contracted
+while the next one arrives.  An online-softmax (running max/sum) carry
+accumulates the output block by block, so no virtual-length buffer ever
 exists.  Three structural guarantees:
 
-* **per-slot virtual length** — the page index is clamped to the
-  lane's last live page (``min(i, last)``); a clamped (repeated) index
-  means Mosaic skips the DMA and ``pl.when`` skips the compute, so a
-  short request reads only its own pages;
+* **per-slot virtual length** — the loop ends at the lane's last live
+  page: a request three tokens in takes one block, an idle lane
+  (position 0, an all-zero table row) one block of the trash block, and
+  table entries past the last live page are never read at all.  A call's
+  time follows the tokens its lanes hold, not the table's length times
+  the slot count;
 * **trash-block-0 never contributes** — inactive table rows are zero
   (the allocator's trash block); the per-position causal mask
   ``k_pos <= row_pos`` zeroes every position past the lane's write
-  head, which is exactly the set of rows that could alias block 0;
+  head, which is exactly the set of rows that could alias block 0, and
+  the rows of a last block's buffer that no live page filled;
 * **read-only on shared pages** — the kernel only loads K/V; CoW
   prefix sharing needs no new ``serve_cow`` hazard class.
 
@@ -38,20 +48,21 @@ Query rows generalize to ``G`` consecutive positions per lane (``q``
 is (B, G, H, D), row ``g`` of lane ``b`` sits at ``positions[b] + g``)
 so ONE kernel serves plain decode / draft (G=1), the speculative
 verify program (G = k+1), and prefill-sized chunks
-(:func:`paged_prefill_attention`, G = the prefill chunk P).  The
-clamp is what makes the prefill case cheap: a chunk starting at
-position ``s`` visits only ``ceil((s + G) / BS)`` live pages — the
-grid still spans MB steps, but every step past ``last`` repeats the
-clamped index (no DMA) and skips the compute, so per-layer traffic is
-O(chunk x visible) instead of the dense gather's O(chunk x SV), and
-the O(S^2)-in-SV prefill materialization never exists.
+(:func:`paged_prefill_attention`, G = the prefill chunk P).  A chunk
+starting at position ``s`` walks only ``ceil((s + G) / BS)`` live pages,
+so per-layer traffic is O(chunk x visible) instead of the dense
+gather's O(chunk x SV), and the O(S^2)-in-SV prefill materialization
+never exists.  A quantized pool's scale rows are a sub-tile of a page:
+they are gathered by the block tables in front of the call (a few
+hundred KB) and the kernel applies a key's scale to its score and a
+value's to its probability.
 
 The write side is :func:`paged_kv_write`: the serve programs hand it
 the WHOLE aliased pools and each lane's new rows, and it rewrites only
 the pages those rows fall in (decode G=1, verify G=k+1, prefill G=P
 with a padded tail).  Write, then attend — row ``g`` sees rows
 ``0..g`` of its own chunk.  Both kernels take the pools whole, with
-the layer a scalar their index_maps read from SMEM (so a program's
+the layer a scalar read from SMEM (so a program's
 layers share one trace and one lowering of each), and in the layout the
 TPU keeps them in at rest: the minor dimension is the whole ``H * D``
 row (768 at GPT-2-small width, six vregs of lanes) and a page's ``BS``
@@ -66,10 +77,10 @@ Heads are 64-lane groups of one row here, not a leading dimension, so
 the attention kernel contracts over the whole row against a
 block-diagonal query: row ``(g, h)`` of ``q_bd`` holds ``q[g, h, :]``
 in head ``h``'s lanes and zeros elsewhere; ``q_bd @ k.T`` is the
-(G*H, BS) scores, ``p @ v`` a (G*H, H*D) accumulator of which only the
-row's own head's lanes mean anything, picked once when the lane ends.
-Two MXU products a page on an otherwise idle MXU; float32 accumulation,
-softmax and carry.
+(G*H, PPB*BS) scores of a block, ``p @ v`` a (G*H, H*D) accumulator of
+which only the row's own head's lanes mean anything, picked once when
+the lane ends.  Two MXU products a block, ``p @ v`` contracting over the
+block's ~128 key positions; float32 accumulation, softmax and carry.
 
 Off-TPU the kernels run in interpreter mode only (``INTERPRET``,
 default from ``FFTPU_PALLAS_INTERPRET`` — see ``__init__.py``);
@@ -84,6 +95,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,6 +103,8 @@ from flexflow_tpu.ops.pallas import env_interpret
 
 __all__ = [
     "INTERPRET",
+    "attention_walk",
+    "lane_blocks",
     "page_rows_tile",
     "paged_decode_attention",
     "paged_kv_write",
@@ -159,30 +173,74 @@ def resolve_serve_attn(mode: str, block_size=None, pool_dtype=None) -> str:
     )
 
 
+# key positions a compute block aims at: the depth of the MXU that
+# contracts over them in ``p @ v``
+_BLOCK_KEYS = 128
+
+
+def attention_walk(slots, block_size, max_blocks_per_seq):
+    """How the attention kernel walks a ``(slots, max_blocks_per_seq)``
+    block table of ``block_size``-row pages: one grid step a lane, whose
+    loop takes ``pages_per_block`` pages a compute block — the pages that
+    make about ``_BLOCK_KEYS`` key positions, read off the page size
+    (8 at 16 rows, 4 at 32, 1 at 128 and above), never more than the
+    table holds — over at most ``max_blocks`` blocks, and over a lane's
+    live pages only.  ``_paged_call`` builds its grid and its buffers
+    from this; ``ServeEngine.attn_walk`` reports it."""
+    ppb = max(1, min(_BLOCK_KEYS // block_size, max_blocks_per_seq))
+    return {
+        "grid": [slots],
+        "pages_per_block": ppb,
+        "max_blocks": -(-max_blocks_per_seq // ppb),
+    }
+
+
+def _live(xp, pos, G, BS, MB, PPB):
+    """(pages, compute blocks) a lane whose ``G`` rows start at ``pos``
+    has to read: up to its last row's page, within the table.  ``xp`` is
+    ``jnp`` inside the kernel and ``np`` on the host."""
+    n_pages = xp.minimum((pos + G - 1) // BS, MB - 1) + 1
+    return n_pages, (n_pages + PPB - 1) // PPB
+
+
+def lane_blocks(positions, G, *, block_size, max_blocks_per_seq):
+    """Compute blocks the kernel's loop takes for lanes whose ``G`` rows
+    start at ``positions`` (numpy, any shape), for the engine's report."""
+    ppb = attention_walk(1, block_size, max_blocks_per_seq)["pages_per_block"]
+    return _live(
+        np, np.asarray(positions), G, block_size, max_blocks_per_seq, ppb
+    )[1]
+
+
 def _kernel(
-    layer_ref,  # SMEM (1,) int32 — the layer the index_maps read
+    layer_ref,  # SMEM (1,) int32 — the layer of the pools to read
     pos_ref,  # SMEM (B,) int32 — row-0 position per lane
     bt_ref,  # SMEM (B, MB) int32 — block tables
     q_ref,  # VMEM (1, G, H*D)
-    k_ref,  # VMEM (BS, H*D) — page table[b, min(i, last)] of the layer
-    v_ref,  # VMEM (BS, H*D)
-    *rest,  # [sk_ref, sv_ref (VMEM (BS, 1) f32)], o_ref, 4 scratch refs
+    k_hbm,  # the WHOLE pool (L, N * BS, H*D), where it lies (HBM)
+    v_hbm,
+    *rest,  # [sk_ref, sv_ref (VMEM (NB, PPB*BS) f32)], o_ref, scratch refs
     G: int,
     H: int,
     BS: int,
     MB: int,
+    PPB: int,
     scale: float,
     quantized: bool,
 ):
     if quantized:
-        sk_ref, sv_ref, o_ref, qbd_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        sk_ref = sv_ref = None
-        o_ref, qbd_ref, acc_ref, m_ref, l_ref = rest
+        sk_ref, sv_ref, *rest = rest
+    o_ref, kbuf, vbuf, sem, qbd_ref, acc_ref, m_ref, l_ref = rest
     GH, HD = acc_ref.shape
     D = HD // H
+    W = PPB * BS  # key positions a compute block
     b = pl.program_id(0)
-    i = pl.program_id(1)
+    layer = layer_ref[0]
+    pos0 = pos_ref[b]
+    # the walk ends at the lane's last live page: a lane three tokens in
+    # takes one block, and so does an idle lane (position 0, the trash
+    # block); table entries past ``n_pages`` are never read
+    n_pages, n_blocks = _live(jnp, pos0, G, BS, MB, PPB)
     # both products accumulate in float32.  bfloat16 rows against a
     # bfloat16 page multiply exactly in one MXU pass; anything wider (a
     # float32 pool, a dequantized page, the float32 probabilities) takes
@@ -202,75 +260,122 @@ def _kernel(
         g = jax.lax.broadcasted_iota(jnp.int32, (GH, G), 1)
         return (r // H == g).astype(jnp.float32)
 
-    @pl.when(i == 0)
-    def _init():
-        # the block-diagonal query, once a lane: every query row H
-        # times over, each copy keeping one head's lanes
-        q = q_ref[0].astype(jnp.float32)  # (G, H*D)
-        if G == 1:
-            rows = jnp.broadcast_to(q, (GH, HD))
-        else:
-            rows = jax.lax.dot_general(
-                own_row(), q, (((1,), (0,)), ((), ())),
-                precision=exact, preferred_element_type=jnp.float32,
-            )
-        qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def block_copies(j, slot, go):
+        # block j's live pages, K and V, pool -> buffer ``slot``; ``go``
+        # starts them or waits for them.  A page past the lane's last
+        # live one is not copied: its rows keep what the buffer held (an
+        # earlier page, or the zeros below) and the causal mask drops
+        # them
+        def page(i, carry):
+            blk = bt_ref[b, j * PPB + i]
+            rows = pl.ds(pl.multiple_of(blk * BS, BS), BS)
+            into = pl.ds(pl.multiple_of(i * BS, BS), BS)
+            go(pltpu.make_async_copy(
+                k_hbm.at[layer, rows], kbuf.at[slot, into], sem.at[0, slot]
+            ))
+            go(pltpu.make_async_copy(
+                v_hbm.at[layer, rows], vbuf.at[slot, into], sem.at[1, slot]
+            ))
+            return carry
 
-    pos0 = pos_ref[b]
-    last = jnp.minimum((pos0 + G - 1) // BS, MB - 1)
+        jax.lax.fori_loop(0, jnp.minimum(PPB, n_pages - j * PPB), page, None)
 
-    @pl.when(i <= last)
-    def _step():
-        k = k_ref[...]  # (BS, H*D)
-        v = v_ref[...].astype(jnp.float32)
+    @pl.when(b == 0)
+    def _():
+        # ``p @ v`` multiplies the masked positions' zeros by whatever
+        # the value buffer holds there: make that finite once a call
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    block_copies(0, 0, lambda c: c.start())
+
+    # while the first block is in flight — the block-diagonal query,
+    # once a lane: every query row H times over, each copy keeping one
+    # head's lanes
+    q = q_ref[0].astype(jnp.float32)  # (G, H*D)
+    if G == 1:
+        rows = jnp.broadcast_to(q, (GH, HD))
+    else:
+        rows = jax.lax.dot_general(
+            own_row(), q, (((1,), (0,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32,
+        )
+    qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def block(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            block_copies(j + 1, 1 - slot, lambda c: c.start())
+
+        block_copies(j, slot, lambda c: c.wait())
+        k = kbuf[slot]  # (PPB*BS, H*D)
+        v = vbuf[slot].astype(jnp.float32)
         if qk_exact is not None:
             k = k.astype(jnp.float32)
-        if quantized:
-            # in-register dequant of the DMA'd page: the SAME
-            # ``int.astype(f32) * scale`` rule as the gather fallback
-            # (kvcache.dequantize_kv), applied before the f32 online-
-            # softmax carry
-            k = k * sk_ref[...]  # scales (BS, 1) per position
-            v = v * sv_ref[...]
         s = jax.lax.dot_general(
             qbd_ref[...], k, (((1,), (1,)), ((), ())),
             precision=qk_exact, preferred_element_type=jnp.float32,
-        ) * scale  # (G*H, BS)
-        k_pos = i * BS + jax.lax.broadcasted_iota(jnp.int32, (GH, BS), 1)
-        row_pos = pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (GH, BS), 0
-        ) // H
-        s = jnp.where(k_pos <= row_pos, s, jnp.finfo(jnp.float32).min)
+        ) * scale  # (G*H, PPB*BS)
+        if quantized:
+            # the pool's ``int.astype(f32) * scale`` rule
+            # (kvcache.dequantize_kv) with the positions' scales applied
+            # to the products instead of the pages: a key's scale to its
+            # score, a value's to its probability
+            s = s * sk_ref[pl.ds(j, 1), :]
+        k_pos = j * W + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 1)
+        # (a chunk's padded rows may lie past the table's end: they see
+        # no further than its last page, as when the grid ended there)
+        row_pos = jnp.minimum(
+            pos0 + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 0) // H,
+            n_pages * BS - 1,
+        )
+        seen = k_pos <= row_pos
+        s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
         m_prev = m_ref[:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])  # (G*H, BS) float32
+        p = jnp.exp(s - m_new[:, None])  # (G*H, PPB*BS) float32
         l_ref[:, 0] = l_ref[:, 0] * alpha + p.sum(axis=-1)
+        if quantized:
+            p = jnp.where(seen, p * sv_ref[pl.ds(j, 1), :], 0.0)
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             precision=exact, preferred_element_type=jnp.float32,
         )  # (G*H, H*D); row (g, h) means something in head h's lanes
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
         m_ref[:, 0] = m_new
+        return carry
 
-    @pl.when(i == MB - 1)
-    def _finalize():
-        out = jnp.where(
-            own_head(), acc_ref[...] / l_ref[:, 0][:, None], 0.0
+    jax.lax.fori_loop(0, n_blocks, block, None)
+
+    out = jnp.where(own_head(), acc_ref[...] / l_ref[:, 0][:, None], 0.0)
+    # each query row's H copies back into one row: head h's lanes come
+    # from copy h, every other copy holds zeros there
+    if G == 1:
+        out = out.sum(axis=0, keepdims=True)
+    else:
+        out = jax.lax.dot_general(
+            own_row(), out, (((0,), (0,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32,
         )
-        # each query row's H copies back into one row: head h's lanes
-        # come from copy h, every other copy holds zeros there
-        if G == 1:
-            out = out.sum(axis=0, keepdims=True)
-        else:
-            out = jax.lax.dot_general(
-                own_row(), out, (((0,), (0,)), ((), ())),
-                precision=exact, preferred_element_type=jnp.float32,
-            )
-        o_ref[0] = out.astype(o_ref.dtype)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _lane_scales(scales, layer, block_tables, ppb):
+    """A quantized pool's ``(L, N, BS)`` scale rows of ``layer``, gathered
+    by the block tables into each lane's key order and cut into the
+    kernel's compute blocks: ``(B, max_blocks, ppb * BS)`` float32, a few
+    hundred KB, which the kernel takes a lane at a time in VMEM (a scale
+    row is a sub-tile of a page: not worth a DMA of its own)."""
+    B, MB = block_tables.shape
+    rows = scales[layer][block_tables]  # (B, MB, BS)
+    nb = -(-MB // ppb)
+    rows = jnp.pad(rows, ((0, 0), (0, nb * ppb - MB), (0, 0)))
+    return rows.reshape(B, nb, -1)
 
 
 def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
@@ -281,13 +386,16 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
     # same shapes, so the kernel is traced, lowered and compiled once a
     # program and not once a layer.
     #
-    # The pools come in WHOLE, (L, N * BS, H * D), and ``layer`` is a
-    # scalar the index_maps read from SMEM: a ``pool[layer]`` slice in
-    # front of a custom call is a copy of the layer (76 MB a layer at
-    # GPT-2-small width and 24 slots), not a view.
+    # The pools come in WHOLE, (L, N * BS, H * D), and stay where they
+    # lie: the kernel copies the pages it wants itself, and ``layer`` is
+    # a scalar it reads from SMEM.  A ``pool[layer]`` slice in front of
+    # a custom call is a copy of the layer (76 MB a layer at GPT-2-small
+    # width and 24 slots), not a view.
     B, G, H, D = q.shape
     HD = H * D
     MB = block_tables.shape[1]
+    walk = attention_walk(B, BS, MB)
+    PPB = walk["pages_per_block"]
     quantized = scale_k is not None
     # the block-diagonal query multiplies a bfloat16 page as bfloat16
     # (the kernel reads the choice off the scratch's dtype)
@@ -296,46 +404,39 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
         else jnp.float32
     )
 
-    def q_map(b, i, layer_ref, pos_ref, bt_ref):
+    def lane_map(b, layer_ref, pos_ref, bt_ref):
         return (b, 0, 0)
 
-    def kv_map(b, i, layer_ref, pos_ref, bt_ref):
-        # clamp to the lane's last live page: a repeated block index is
-        # an unchanged DMA (Mosaic skips it) and the i > last compute
-        # is pl.when-gated off, so masked pages are never fetched
-        last = jnp.minimum((pos_ref[b] + G - 1) // BS, MB - 1)
-        return (layer_ref[0], bt_ref[b, jnp.minimum(i, last)], 0)
-
-    def sc_map(b, i, layer_ref, pos_ref, bt_ref):
-        # the scale row rides the same physical-block index as its page
-        return kv_map(b, i, layer_ref, pos_ref, bt_ref) + (0,)
-
     in_specs = [
-        pl.BlockSpec((1, G, HD), q_map),
-        pl.BlockSpec((None, BS, HD), kv_map),
-        pl.BlockSpec((None, BS, HD), kv_map),
+        pl.BlockSpec((1, G, HD), lane_map),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [
         layer.reshape(1), positions, block_tables, q.reshape(B, G, HD),
         pool_k, pool_v,
     ]
     if quantized:
-        # one scale row per page, as a (BS, 1) column: a (1, BS) block of
-        # the (N, BS) array breaks Mosaic's (8, 128) block rule, while
-        # trailing block dims that EQUAL the array's are always legal —
-        # and the column broadcasts across the page's lanes as it is
-        in_specs += [
-            pl.BlockSpec((None, None, BS, 1), sc_map),
-            pl.BlockSpec((None, None, BS, 1), sc_map),
+        lane_scales = pl.BlockSpec(
+            (None, walk["max_blocks"], PPB * BS), lane_map
+        )
+        in_specs += [lane_scales, lane_scales]
+        operands += [
+            _lane_scales(s, layer, block_tables, PPB)
+            for s in (scale_k, scale_v)
         ]
-        operands += [scale_k[..., None], scale_v[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, MB),
+        grid=tuple(walk["grid"]),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, HD), q_map),
+        out_specs=pl.BlockSpec((1, G, HD), lane_map),
         scratch_shapes=[
+            # two compute blocks of K and of V: one contracted while the
+            # next one's pages arrive
+            pltpu.VMEM((2, PPB * BS, HD), pool_k.dtype),
+            pltpu.VMEM((2, PPB * BS, HD), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
             pltpu.VMEM((G * H, HD), qbd_dtype),
             pltpu.VMEM((G * H, HD), jnp.float32),
             pltpu.VMEM((G * H, 128), jnp.float32),
@@ -343,7 +444,8 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
         ],
     )
     kernel = functools.partial(
-        _kernel, G=G, H=H, BS=BS, MB=MB, scale=scale, quantized=quantized
+        _kernel, G=G, H=H, BS=BS, MB=MB, PPB=PPB, scale=scale,
+        quantized=quantized,
     )
     # no ``name=``: a compiled program and the profiler's trace name the
     # call after the jitted function around it (``_jitted_as`` below)
@@ -351,9 +453,9 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, HD), q.dtype),
-        # pages chain a carry per lane: both grid dims are sequential
+        # lanes run in turn: the value buffer is cleared by the first
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
     )(*operands).reshape(B, G, H, D)
@@ -400,9 +502,10 @@ def paged_decode_attention(
       scale_k / scale_v: optional (num_blocks, BS) float32 per-position
         dequant scales for an int8/fp8 pool (``PagedKVCache.scale_k[i]``
         for layer ``i``; the whole (L, num_blocks, BS) pools with
-        ``layer``); when given each DMA'd page is dequantized
-        in-register via the shared ``int.astype(f32) * scale`` rule
-        before the f32 online-softmax carry.  Pass both or neither.
+        ``layer``); when given, the shared ``int.astype(f32) * scale``
+        rule is applied in the kernel (a key's scale to its score, a
+        value's to its probability) inside the f32 online-softmax
+        carry.  Pass both or neither.
       block_size: ``BS``, the rows of a page (the pool's shape does not
         say).
 
@@ -455,26 +558,22 @@ def paged_prefill_attention(
     write-then-attend discipline as the speculative verify program,
     at chunk width.
 
-    What makes this the O(S^2) fix (docs/PERF.md): the kernel's
-    visible-page DMA clamp.  The grid walks MB logical pages but the
-    page index is clamped to ``last = (start[b] + P - 1) // BS``, so a
-    chunk at start ``s`` fetches only ``ceil((s + P) / BS)`` pages —
-    a repeated (clamped) index is a skipped DMA and ``pl.when`` skips
-    the compute.  The dense gather fallback materializes (H, SV, D) at
-    the FULL virtual length for every chunk of every slot; here no
-    virtual-length buffer ever exists and traffic is proportional to
-    the visible prefix only.
+    What makes this the O(S^2) fix (docs/PERF.md): the walk ends at the
+    lane's last live page, ``(start[b] + P - 1) // BS``, so a chunk at
+    start ``s`` copies only ``ceil((s + P) / BS)`` pages.  The dense
+    gather fallback materializes (H, SV, D) at the FULL virtual length
+    for every chunk of every slot; here no virtual-length buffer ever
+    exists and traffic is proportional to the visible prefix only.
 
     Padded lanes (an idle slot in the batched prefill dispatch) ride
-    with ``start = 0`` and an all-zero table row: every page index
-    clamps/maps to the allocator's trash block 0, the per-lane DMAs
-    degenerate to one repeated page, and the garbage output rows are
+    with ``start = 0`` and an all-zero table row: they walk one block,
+    of the allocator's trash block 0, and the garbage output rows are
     discarded by the caller.
 
     ``scale_k``/``scale_v`` are the quantized pool's per-position
-    dequant scale rows ((num_blocks, BS) float32), riding the same
-    block-table scalar-prefetch as the pages with in-register dequant
-    — the decode contract at chunk width (tests pin fp32/int8/fp8).
+    dequant scale rows ((num_blocks, BS) float32), gathered by the same
+    block tables and applied in the kernel — the decode contract at
+    chunk width (tests pin fp32/int8/fp8).
 
     Returns (B, P, H, D) in ``q.dtype``.
     """

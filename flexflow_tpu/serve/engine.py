@@ -34,7 +34,7 @@ import dataclasses
 import math
 import re
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -194,6 +194,11 @@ class ServeReport:
     prefill_dispatches: int = 0
     prefill_attn_kernel: Optional[str] = None  # kernel prefill ran on
     kv_write: Optional[str] = None  # "page_kernel" | "xla_scatter"
+    # compute blocks the paged kernel's lanes walked over the run's decode
+    # and prefill calls, and what walking every lane's whole table would
+    # have taken (ServeEngine._attn_blocks; None where it does not apply)
+    attn_blocks_walked: Optional[int] = None
+    attn_blocks_full_table: Optional[int] = None
     # --- what it ran on (ServeEngine.device_info) ---
     attn_interpret: bool = False  # paged kernel ran in the Pallas interpreter
     device: Dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -553,6 +558,49 @@ class ServeEngine:
                 (self._decode, self._prefill), self._idle_args()
             )
         )
+
+    def attn_walk(self) -> Optional[Dict[str, Any]]:
+        """How the paged kernel of this engine's programs walks a block
+        table (``paged_attention.attention_walk``, which the kernel
+        builds its grid and buffers from): ``grid`` (one step a lane),
+        ``pages_per_block``, ``max_blocks``.  None on the gather arm."""
+        if self.attn_kernel != "paged":
+            return None
+        from flexflow_tpu.ops.pallas.paged_attention import attention_walk
+
+        return attention_walk(
+            self.slots, self.kv.block_size, self.kv.max_blocks_per_seq
+        )
+
+    def _attn_blocks(self, fin) -> Tuple[Optional[int], Optional[int]]:
+        """(walked, full table): compute blocks the kernel's lanes took
+        over the run's decode and prefill calls, a layer, against
+        ``max_blocks`` a lane a call.  Reckoned once, at the end of a
+        run, from the lengths of the requests that finished — a live lane
+        walks the blocks up to its position, every other lane of a call
+        one (the trash block) — so the window pays nothing for it.  Not
+        reckoned under speculation (a request's lengths do not say what
+        its draft and verify calls read) or on the gather arm."""
+        walk = self.attn_walk()
+        if walk is None or self.spec_k:
+            return None, None
+        from flexflow_tpu.ops.pallas.paged_attention import lane_blocks
+
+        geom = dict(
+            block_size=self.kv.block_size,
+            max_blocks_per_seq=self.kv.max_blocks_per_seq,
+        )
+        P = self.prefill_chunk
+        live = walked = 0
+        for r in fin:
+            chunks = np.arange(r.shared_prefix_pos, r.prompt_len, P)
+            steps = r.prompt_len + np.arange(max(0, r.done_tokens - 1))
+            live += len(chunks) + len(steps)
+            walked += int(lane_blocks(chunks, P, **geom).sum())
+            walked += int(lane_blocks(steps, 1, **geom).sum())
+        lanes = (self.decode_steps + self.prefill_dispatches) * self.slots
+        walked += max(0, lanes - live)
+        return walked, lanes * walk["max_blocks"]
 
     def _kvs(self):
         """The live pool buffers in program-argument order: (ck, cv)
@@ -1272,6 +1320,7 @@ class ServeEngine:
             d["ttft_p50_ms"] = _pct(ttfts, 50)
             d["ttft_p99_ms"] = _pct(ttfts, 99)
             per_tenant[tenant] = d
+        blocks_walked, blocks_full_table = self._attn_blocks(fin)
         rep = ServeReport(
             wall_s=wall,
             new_tokens=new_tokens,
@@ -1327,6 +1376,8 @@ class ServeEngine:
             prefill_dispatches=self.prefill_dispatches,
             prefill_attn_kernel=self.attn_kernel,
             kv_write=self.kv_write,
+            attn_blocks_walked=blocks_walked,
+            attn_blocks_full_table=blocks_full_table,
             attn_interpret=self.attn_interpret,
             device=self.device_info(),
         )

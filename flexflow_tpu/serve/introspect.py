@@ -13,10 +13,12 @@ on localhost, serving five endpoints while an engine or cluster runs:
                identities (JSON)
   /spanz?n=    the last ``n`` ffspan/1 records (JSON; default 64)
   /metricz     Prometheus text exposition (obs/export.py)
-  /poolz       the K/V pool's geometry and ``pool_relayouts``: whole-pool
-               copies in the compiled decode and prefill programs (JSON;
-               compiles both once more on first use, on this server's
-               own thread, then answers from memory)
+  /poolz       the K/V pool's geometry, ``pool_relayouts`` (whole-pool
+               copies in the compiled decode and prefill programs) and
+               ``attn_walk`` (the paged kernel's grid and pages a compute
+               block) (JSON; compiles both programs once more on first
+               use, on this server's own thread, then answers from
+               memory)
   ===========  =========================================================
 
 The zero-sync contract, stated once: the serve hot path NEVER talks to
@@ -312,6 +314,7 @@ class StatusServer:
             "block_size": eng.kv.block_size,
             "attn_kernel": eng.attn_kernel,
             "pool_relayouts": eng.pool_relayouts(),
+            "attn_walk": eng.attn_walk(),
         }
 
     def poolz(self) -> Dict[str, Any]:

@@ -19,7 +19,12 @@ makes the pool position-major, ``(L, num_blocks * BS, H * D)``: the
 kernels' contractions run on the MXU, so kernel against gather is a
 float32 tolerance plus identical greedy streams, and the pool's
 geometry is pinned (minor dimension ``heads * head_dim``, no layout
-API anywhere in ``serve/`` or ``ops/pallas/``).
+API anywhere in ``serve/`` or ``ops/pallas/``).  ISSUE 31 makes the
+kernel's grid one step a lane, whose loop walks the lane's live pages a
+compute block at a time: parity where the trip count changes (an idle
+lane, exactly one block, one page more, a full table), a NaN block past
+the last live page never read, the built call's grid, and the engine's
+``attn_walk`` / ``attn_blocks_walked``.
 """
 
 from __future__ import annotations
@@ -124,8 +129,8 @@ def _dense_ref(q, pk, pv, pos, bt, scale):
 def test_kernel_matches_dense_reference(interpret, B, G, H, D, BS, MB):
     """Parity vs the gather reference with scrambled block tables,
     ragged per-lane positions, and GARBAGE (huge values) in every page
-    past each lane's last live one — any DMA-clamp or mask leak would
-    blow the comparison up by orders of magnitude."""
+    past each lane's last live one — a walk past the last live page or
+    a mask leak would blow the comparison up by orders of magnitude."""
     rng = np.random.default_rng(17 * B + G)
     N = B * MB + 1  # + trash block 0
     q = rng.standard_normal((B, G, H, D)).astype(np.float32)
@@ -137,7 +142,7 @@ def test_kernel_matches_dense_reference(interpret, B, G, H, D, BS, MB):
     # ragged positions: lane b's row 0 sits anywhere in its window
     pos = rng.integers(0, MB * BS - G + 1, size=(B,)).astype(np.int32)
     # poison all pages past each lane's last live page AND the trash
-    # block: correct clamping/masking means they never contribute
+    # block: the walk ends before them and the mask covers the rest
     pk[0] = pv[0] = 1e4
     for b in range(B):
         last = (int(pos[b]) + G - 1) // BS
@@ -189,6 +194,25 @@ POOL_DTYPES = {
 }
 
 
+def _pool_of(kv_dtype, fk, fv):
+    """(pool_k, pool_v, sk, sv, dense_k, dense_v) of float32 pages
+    ``(N, BS, H, D)``: the pages in ``kv_dtype``, a quantized pool's
+    scale rows ``(N, BS)`` (else None), and the float32 pages the dense
+    reference reads — the host-dequantized ones (the shared
+    ``int * scale`` rule), or the rounded ones."""
+    from flexflow_tpu.serve.kvcache import quantize_kv
+
+    if kv_dtype in ("int8", "fp8"):
+        pk, sk = quantize_kv(jnp, jnp.asarray(fk), kv_dtype)
+        pv, sv = quantize_kv(jnp, jnp.asarray(fv), kv_dtype)
+        dk = np.asarray(pk, np.float32) * np.asarray(sk)[:, :, None, None]
+        dv = np.asarray(pv, np.float32) * np.asarray(sv)[:, :, None, None]
+        return pk, pv, sk, sv, dk, dv
+    pk = jnp.asarray(fk, POOL_DTYPES[kv_dtype])
+    pv = jnp.asarray(fv, POOL_DTYPES[kv_dtype])
+    return pk, pv, None, None, np.asarray(pk, np.float32), np.asarray(pv, np.float32)
+
+
 @pytest.mark.parametrize("kv_dtype", list(POOL_DTYPES))
 @pytest.mark.parametrize("G", [1, 3, 8], ids=["decode", "verify", "chunk"])
 def test_kernel_at_page_boundaries_every_pool_dtype(interpret, G, kv_dtype):
@@ -200,8 +224,6 @@ def test_kernel_at_page_boundaries_every_pool_dtype(interpret, G, kv_dtype):
     and G = P, for every pool dtype.  A quantized pool is compared
     against the reference over the host-dequantized pages (the shared
     ``int * scale`` rule), a bfloat16 one over the rounded pages."""
-    from flexflow_tpu.serve.kvcache import quantize_kv
-
     B, H, D, BS, MB = 3, 2, 8, 4, 4
     rng = np.random.default_rng(29 + G)
     N = B * MB + 1
@@ -214,22 +236,214 @@ def test_kernel_at_page_boundaries_every_pool_dtype(interpret, G, kv_dtype):
     pos = np.array([BS, 3 * BS - G, crosses], np.int32)
     assert pos[0] % BS == 0 and (pos[1] + G) % BS == 0
     assert G == 1 or pos[2] // BS != (pos[2] + G - 1) // BS
-    sk = sv = None
-    if kv_dtype in ("int8", "fp8"):
-        pk, sk = quantize_kv(jnp, jnp.asarray(fk), kv_dtype)  # (N, BS)
-        pv, sv = quantize_kv(jnp, jnp.asarray(fv), kv_dtype)
-        dk = np.asarray(pk, np.float32) * np.asarray(sk)[:, :, None, None]
-        dv = np.asarray(pv, np.float32) * np.asarray(sv)[:, :, None, None]
-    else:
-        pk = jnp.asarray(fk, POOL_DTYPES[kv_dtype])
-        pv = jnp.asarray(fv, POOL_DTYPES[kv_dtype])
-        dk, dv = np.asarray(pk, np.float32), np.asarray(pv, np.float32)
+    pk, pv, sk, sv, dk, dv = _pool_of(kv_dtype, fk, fv)
     got = np.asarray(pa.paged_decode_attention(
         jnp.asarray(q), _pool(pk), _pool(pv), jnp.asarray(pos),
         jnp.asarray(bt), scale_k=sk, scale_v=sv, block_size=BS,
     ))
     want = _dense_ref(q, dk, dv, pos, bt, 1.0 / np.sqrt(D))
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-4)
+
+
+# ------------------------------------------------------------- the walk
+# pages of 16 -> 8 pages a compute block; 20 pages a lane -> 3 blocks,
+# the last one half past the table
+H_K, D_K, BS_K, MB_K = 2, 8, 16, 20
+PPB_K = 8
+
+
+def _walk_pool(kv_dtype, rng, n_blocks):
+    shape = (n_blocks, BS_K, H_K, D_K)
+    return _pool_of(
+        kv_dtype, rng.standard_normal(shape).astype(np.float32),
+        rng.standard_normal(shape).astype(np.float32),
+    )
+
+
+@pytest.mark.parametrize("kv_dtype", list(POOL_DTYPES))
+@pytest.mark.parametrize("G", [1, 3, 32], ids=["decode", "verify", "chunk"])
+def test_kernel_walks_live_pages_only_every_depth(interpret, G, kv_dtype):
+    """Against the dense float32 reference where the loop's trip count
+    changes: an idle lane (position 0, an all-zero table row: one block
+    of the trash block), a lane that ends exactly one compute block
+    (``PPB`` pages), one whose last row opens the next block (``PPB + 1``
+    pages), and a full table (``pos + G`` reaching ``MB * BS``, the last
+    block half past the table's end) — at G = 1, k + 1 and P, for every
+    pool dtype."""
+    assert pa.attention_walk(4, BS_K, MB_K) == {
+        "grid": [4], "pages_per_block": PPB_K, "max_blocks": 3,
+    }
+    rng = np.random.default_rng(31 + G)
+    B = 4
+    N = B * MB_K + 1
+    pk, pv, sk, sv, dk, dv = _walk_pool(kv_dtype, rng, N)
+    q = rng.standard_normal((B, G, H_K, D_K)).astype(np.float32)
+    bt = (rng.permutation(N - 1) + 1)[: B * MB_K].reshape(B, MB_K)
+    bt = bt.astype(np.int32)
+    bt[0] = 0
+    pos = np.array(
+        [0, PPB_K * BS_K - G, PPB_K * BS_K - G + 1, MB_K * BS_K - G], np.int32
+    )
+    pages = (pos + G - 1) // BS_K + 1
+    assert list(pages[1:]) == [PPB_K, PPB_K + 1, MB_K]
+    assert list(pa.lane_blocks(
+        pos, G, block_size=BS_K, max_blocks_per_seq=MB_K
+    )) == [1, 1, 2, 3]
+    got = np.asarray(pa.paged_decode_attention(
+        jnp.asarray(q), _pool(pk), _pool(pv), jnp.asarray(pos),
+        jnp.asarray(bt), scale_k=sk, scale_v=sv, block_size=BS_K,
+    ))
+    want = _dense_ref(q, dk, dv, pos, bt, 1.0 / np.sqrt(D_K))
+    np.testing.assert_allclose(got, want, atol=5e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("kv_dtype", list(POOL_DTYPES))
+@pytest.mark.parametrize("G", [1, 3], ids=["decode", "verify"])
+def test_kernel_never_reads_past_the_last_live_page(interpret, G, kv_dtype):
+    """The walk ends, it does not mask: table entries past a lane's last
+    live page name a block that is NaN all over (its pages where the
+    dtype has a NaN, its scale rows in a quantized pool), and the result
+    is finite and equal to the one with those entries naming the trash
+    block.  Lanes end inside the first block, at its end and inside the
+    second."""
+    rng = np.random.default_rng(37 + G)
+    B = 3
+    N = B * MB_K + 2
+    bad = N - 1
+    pk, pv, sk, sv, _, _ = _walk_pool(kv_dtype, rng, N)
+    nan_rows = slice(bad * BS_K, (bad + 1) * BS_K)
+    pk, pv = _pool(pk), _pool(pv)
+    if kv_dtype != "int8":
+        pk = pk.at[nan_rows].set(jnp.nan)
+        pv = pv.at[nan_rows].set(jnp.nan)
+    if sk is not None:
+        sk, sv = sk.at[bad].set(jnp.nan), sv.at[bad].set(jnp.nan)
+    q = jnp.asarray(rng.standard_normal((B, G, H_K, D_K)), jnp.float32)
+    bt = (rng.permutation(N - 2) + 1)[: B * MB_K].reshape(B, MB_K)
+    bt = bt.astype(np.int32)
+    pos = np.array([5, PPB_K * BS_K - G, (PPB_K + 2) * BS_K + 3], np.int32)
+    clean, dirty = bt.copy(), bt.copy()
+    for b in range(B):
+        past = (int(pos[b]) + G - 1) // BS_K + 1
+        clean[b, past:] = 0
+        dirty[b, past:] = bad
+
+    def run(tables):
+        return np.asarray(pa.paged_decode_attention(
+            q, pk, pv, jnp.asarray(pos), jnp.asarray(tables),
+            scale_k=sk, scale_v=sv, block_size=BS_K,
+        ))
+
+    got = run(dirty)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, run(clean))
+
+
+@pytest.mark.parametrize(
+    "BS,MB,ppb,blocks",
+    [(16, 64, 8, 8), (32, 32, 4, 8), (8, 128, 16, 8), (128, 8, 1, 8),
+     (256, 4, 1, 4), (16, 20, 8, 3), (4, 3, 3, 1)],
+)
+def test_the_built_call_has_one_grid_step_a_lane(BS, MB, ppb, blocks):
+    """``pages_per_block`` is read off the page size — the pages that
+    make 128 key positions, never more than the table holds — and the
+    ``pallas_call`` that is built has one grid step a lane: no page axis,
+    whatever the table's length."""
+    import jax
+
+    B, G, H, D = 5, 1, 2, 8
+    walk = pa.attention_walk(B, BS, MB)
+    assert walk == {"grid": [B], "pages_per_block": ppb, "max_blocks": blocks}
+    sds = jax.ShapeDtypeStruct
+    n = 2 * MB + 1
+    jaxpr = jax.make_jaxpr(
+        lambda *a: pa.paged_decode_attention(*a, block_size=BS)
+    )(
+        sds((B, G, H, D), jnp.float32), sds((n * BS, H * D), jnp.float32),
+        sds((n * BS, H * D), jnp.float32), sds((B,), jnp.int32),
+        sds((B, MB), jnp.int32),
+    )
+
+    def calls(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (call,) = calls(jaxpr.jaxpr)
+    assert tuple(call.params["grid_mapping"].grid) == (B,)
+    kbuf = [
+        v.aval.shape for v in call.params["jaxpr"].invars
+        if len(v.aval.shape) == 3 and v.aval.shape[0] == 2
+    ]
+    assert kbuf == [(2, ppb * BS, H * D)] * 2  # K and V, double-buffered
+
+
+def test_engine_reports_the_walk_and_the_blocks_walked(model, interpret):
+    """``ServeEngine.attn_walk`` is the geometry the engine's kernels were
+    built with, and the run's report carries the compute blocks its
+    lanes walked beside what the whole table would have taken: every
+    live lane here stays inside its first block, so each lane of each
+    call walked exactly one."""
+    eng = ServeEngine(model, slots=SLOTS, block_size=4, prefill_chunk=5,
+                      sync_every=3, attn="paged")
+    walk = eng.attn_walk()
+    MB = eng.kv.max_blocks_per_seq
+    assert walk == pa.attention_walk(SLOTS, 4, MB)
+    assert walk["grid"] == [SLOTS] and walk["pages_per_block"] == MB
+    rep = eng.run(synthetic_requests(TrafficSpec(
+        n_requests=SLOTS + 2, seed=5, rate_rps=0.0, prompt_len=(3, 12),
+        max_new=(2, 5), vocab=VOCAB,
+    )))
+    lanes = (rep.decode_steps + rep.prefill_dispatches) * SLOTS
+    assert rep.attn_blocks_walked == lanes
+    assert rep.attn_blocks_full_table == lanes * walk["max_blocks"]
+    assert rep.to_dict()["attn_blocks_walked"] == lanes
+
+
+@pytest.fixture()
+def blocks_of_eight_keys(monkeypatch):
+    """Compute blocks of 8 key positions instead of 128, so that the
+    48-position tables of this file's model hold several: the kernels'
+    jitted wrappers are traced anew on both sides of the change."""
+    def retrace():
+        for fn in pa._JITTED.values():
+            fn.clear_cache()
+
+    retrace()
+    monkeypatch.setattr(pa, "_BLOCK_KEYS", 8)
+    yield
+    retrace()
+
+
+def test_blocks_walked_follow_the_requests_lengths(
+    model, gather_engine, interpret, blocks_of_eight_keys
+):
+    """Pages of 4 rows, two a block, six blocks a table.  One request of
+    6 prompt positions and 4 new tokens: two chunks of 4 (pages 1 and 2:
+    a block each) and decode steps at positions 6, 7, 8 (pages 2, 2, 3:
+    blocks 1, 1, 2) walk 6 blocks, the 15 other lanes of the 5 calls one
+    each; the stream is the gather engine's."""
+    from flexflow_tpu.serve import Request
+
+    eng = ServeEngine(model, slots=SLOTS, block_size=4, prefill_chunk=4,
+                      sync_every=2, attn="paged")
+    assert eng.attn_walk() == {
+        "grid": [SLOTS], "pages_per_block": 2, "max_blocks": 6,
+    }
+
+    def one():
+        return [Request(prompt=np.arange(1, 7), max_new_tokens=4)]
+
+    mine, theirs = one(), one()
+    rep = eng.run(mine)
+    assert (rep.decode_steps, rep.prefill_dispatches) == (3, 2)
+    assert rep.attn_blocks_walked == 6 + 15
+    assert rep.attn_blocks_full_table == 5 * SLOTS * 6
+    rg = gather_engine.run(theirs)
+    assert rg.attn_blocks_walked is None and gather_engine.attn_walk() is None
+    assert _streams(mine) == _streams(theirs)
 
 
 # ----------------------------------------------------------- page write

@@ -141,6 +141,13 @@ class FFConfig:
     node_id: Optional[int] = None  # this process's index
     dcn_axis: str = "data"  # mesh axis that spans hosts
     compute_dtype: str = "float32"  # params/compute dtype; "bfloat16" for perf
+    # dtype the executor HOLDS floating-point parameters in.  "float32"
+    # (default): master weights, cast to compute_dtype at use.
+    # "bfloat16": weights at rest in bfloat16 -- half the bytes held and
+    # no cast inside a serve program; for serving a model whose float32
+    # weights pass the chip (an op's fp32_weights stay float32).  Not for
+    # training: the optimizer would update bfloat16 masters.
+    param_dtype: str = "float32"
     # ZeRO-1: shard optimizer moments over the data axis (memory /dp at the
     # cost of an all-gathered param delta per step).  Beyond the reference,
     # whose optimizer state is replicated per device (optimizer_kernel.cu).

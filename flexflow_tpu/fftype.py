@@ -142,6 +142,7 @@ class OperatorType(enum.Enum):
     GROUP_BY = "group_by"
     EXPERTS = "experts"
     ROUTED_EXPERTS = "routed_experts"
+    GATED_FFN = "gated_ffn"
     CAST = "cast"
     FUSED = "fused"
     # --- parallel ops (the resharding vocabulary, ffconst.h:152-158) ---

@@ -513,20 +513,37 @@ class FFModel:
         rope_theta: float = 10000.0,
         eps: float = 1e-6,
         use_flash: bool = True,
+        window: int = 0,
+        zero_centered: bool = True,
         name: Optional[str] = None,
     ) -> Tensor:
         """Causal grouped-query self-attention with per-head q/k
-        RMS-norm, rotary positions on the first ``rotary_dim`` dims and a
-        sigmoid output gate (:class:`flexflow_tpu.ops.attention.GatedAttention`)."""
+        RMS-norm, rotary positions on the first ``rotary_dim`` dims (0:
+        none), a sigmoid output gate and, with ``window``, sight of the
+        last ``window`` keys only
+        (:class:`flexflow_tpu.ops.attention.GatedAttention`)."""
+        attrs = dict(
+            num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
+            rotary_dim=rotary_dim, rope_theta=rope_theta, eps=eps,
+            use_flash=use_flash,
+        )
+        if window:
+            attrs["window"] = int(window)
+        if not zero_centered:
+            attrs["zero_centered"] = False
         return self._add_layer(
             OperatorType.GATED_ATTENTION,
             self._name("gated_attention", name),
             [input],
-            dict(
-                num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
-                rotary_dim=rotary_dim, rope_theta=rope_theta, eps=eps,
-                use_flash=use_flash,
-            ),
+            attrs,
+        )[0]
+
+    def gated_ffn(self, input: Tensor, hidden: int, name: Optional[str] = None) -> Tensor:
+        """Dense gated FFN ``W_d (silu(W_g x) * W_u x)``
+        (:class:`flexflow_tpu.ops.moe.GatedFFN`)."""
+        return self._add_layer(
+            OperatorType.GATED_FFN, self._name("gated_ffn", name), [input],
+            dict(hidden=hidden),
         )[0]
 
     def gated_delta_net(
@@ -714,23 +731,43 @@ class FFModel:
         first_expert: int = 0,
         held: Optional[int] = None,
         shared_hidden: int = 0,
+        score: str = "softmax",
+        route_norm: bool = True,
+        route_scale: float = 1.0,
+        router_bias: bool = False,
+        shared_gated: bool = True,
         name: Optional[str] = None,
     ) -> Tensor:
         """One share of a dropless sparse-MoE block with gated (SiLU)
         experts: the router covers all ``n_experts``, this share holds
         ``held`` of them from ``first_expert`` on and returns their part
-        (plus the shared expert's, when ``shared_hidden`` > 0).  See
-        :class:`flexflow_tpu.ops.moe.RoutedExperts`."""
+        (plus the shared expert's, when ``shared_hidden`` > 0).  The
+        router's rule (``score``, ``route_norm``, ``route_scale``,
+        ``router_bias``) and ``shared_gated`` are
+        :class:`flexflow_tpu.ops.moe.RoutedExperts`'s."""
         held = n_experts if held is None else held
         assert 0 <= first_expert and first_expert + held <= n_experts
+        attrs = dict(
+            n_experts=n_experts, first_expert=first_expert, held=held,
+            top_k=top_k, hidden=hidden, shared_hidden=shared_hidden,
+        )
+        # the defaults stay out of the attrs: a layer built before they
+        # existed keeps its params_key
+        if score != "softmax":
+            attrs["score"] = score
+        if not route_norm:
+            attrs["route_norm"] = False
+        if route_scale != 1.0:
+            attrs["route_scale"] = float(route_scale)
+        if router_bias:
+            attrs["router_bias"] = True
+        if not shared_gated:
+            attrs["shared_gated"] = False
         return self._add_layer(
             OperatorType.ROUTED_EXPERTS,
             self._name("routed_experts", name),
             [input],
-            dict(
-                n_experts=n_experts, first_expert=first_expert, held=held,
-                top_k=top_k, hidden=hidden, shared_hidden=shared_hidden,
-            ),
+            attrs,
         )[0]
 
     def moe(
@@ -1254,6 +1291,7 @@ class FFModel:
             metrics=Metrics(loss_type, metrics),
             seed=seed if seed is not None else cfg.rng_seed,
             compute_dtype=cfg.compute_dtype,
+            param_dtype=cfg.param_dtype,
             remat_policy=cfg.remat_policy,
             dcn_axis=cfg.dcn_axis,
             zero1=cfg.enable_zero1,

@@ -1,7 +1,9 @@
 """Built-in model builders (reference ``examples/cpp/*`` apps as library
 functions): Transformer/BERT, MLP, AlexNet, ResNet, ResNeXt-50,
-InceptionV3, DLRM, XDL, CANDLE-Uno, MoE, and the Qwen3-Next hybrid decoder."""
+InceptionV3, DLRM, XDL, CANDLE-Uno, MoE, the Qwen3-Next hybrid decoder
+and the afmoe (Trinity) decoder."""
 
+from flexflow_tpu.models.afmoe import afmoe_decoder
 from flexflow_tpu.models.candle_uno import candle_uno
 from flexflow_tpu.models.cnn import alexnet, inception_v3, resnet, resnext50
 from flexflow_tpu.models.dlrm import dlrm, dlrm_strategy, xdl
@@ -11,6 +13,7 @@ from flexflow_tpu.models.qwen3_next import qwen3_next_decoder
 from flexflow_tpu.models.transformer import transformer_encoder
 
 __all__ = [
+    "afmoe_decoder",
     "alexnet",
     "candle_uno",
     "dlrm",

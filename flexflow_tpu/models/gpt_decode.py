@@ -37,18 +37,52 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["GPTSpec", "GPTDecodeSession", "gpt_generate_cached"]
+__all__ = ["GPTSpec", "LayerSpec", "GPTDecodeSession", "gpt_generate_cached"]
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One decoder layer as the serve programs run it: the names of the
+    layers whose parameters it reads, and what each of them is."""
+
+    norm_in: str
+    attn: str
+    norm_post_attn: Optional[str]  # sandwich norm on the mixer's output
+    norm_pre_ffn: str
+    ffn: Tuple[str, ...]  # (ff0, ff1) for "gelu", one layer otherwise
+    norm_post_ffn: Optional[str]
+    attn_kind: str  # "mha" (q/k/v/o, biases optional) | "gated"
+    heads: int
+    kv_heads: int
+    head_dim: int
+    has_bias: bool
+    qk_norm_zero_centered: bool  # gated: the per-head norm's weight from 0
+    qk_eps: float  # gated: the per-head norm's eps
+    rotary_dim: int  # 0: the layer carries no positions of its own
+    rope_theta: float
+    window: int  # 0: every earlier key; else the last ``window`` keys
+    ffn_kind: str  # "gelu" | "gated" | "moe"
+    moe: Optional[Dict[str, Any]]  # the RoutedExperts layer's attrs
 
 
 @dataclasses.dataclass(frozen=True)
 class GPTSpec:
-    """Shapes + attrs a compiled :func:`gpt_decoder` model implies —
-    the ONE extraction rule, shared by the dense session here and the
-    paged serving programs (:mod:`flexflow_tpu.serve.programs`)."""
+    """What a compiled decoder implies for decoding -- the ONE
+    extraction rule, shared by the dense session here (``gpt_decoder``
+    models) and the paged serving programs
+    (:mod:`flexflow_tpu.serve.programs`).  Read from the model's layers
+    and their attrs, whatever builder named them: an embedding (plus a
+    learned position table, or times a constant), then per layer a norm,
+    an attention op (:class:`MultiHeadAttention` or
+    :class:`GatedAttention`), a residual add, a norm, an FFN (two dense
+    layers around a GELU, a :class:`GatedFFN`, or
+    :class:`RoutedExperts` holding every expert), a residual add --
+    each mixer optionally followed by a norm of its own -- and a final
+    norm and a bias-free head."""
 
     num_layers: int
     heads: int
@@ -58,31 +92,172 @@ class GPTSpec:
     eps: float
     batch: int
     seq: int
+    kv_heads: int = 0
+    vocab: int = 0
+    embed: str = "tok_embed"
+    pos_embed: Optional[str] = "pos_embed"  # learned position table
+    embed_scale: float = 1.0
+    norm: str = "layer"  # "layer" | "rms" | "rms_zero_centered"
+    final_norm: str = "final_ln"
+    head: str = "lm_head"
+    layers: Tuple[LayerSpec, ...] = ()
+
+    @property
+    def is_gpt(self) -> bool:
+        """The shape the dense session and the speculative, int8 and
+        quantized-pool serve arms are written for: learned positions,
+        LayerNorm, one head count, GELU FFN, no window."""
+        return self.pos_embed is not None and self.norm == "layer" and all(
+            l.attn_kind == "mha" and l.ffn_kind == "gelu" and not l.window
+            and l.kv_heads == l.heads for l in self.layers
+        )
+
+    @property
+    def window(self) -> int:
+        """The window of the layers that have one (0: none has)."""
+        ws = {l.window for l in self.layers if l.window}
+        if len(ws) > 1:
+            raise ValueError(f"layers with different windows {sorted(ws)} are not served")
+        return ws.pop() if ws else 0
+
+    @property
+    def has_moe(self) -> bool:
+        return any(l.ffn_kind == "moe" for l in self.layers)
 
     @classmethod
     def from_model(cls, model) -> "GPTSpec":
         assert model.executor is not None, "call compile() first"
-        names = {l.name: l for l in model.layers}
-        assert "tok_embed" in names and "lm_head" in names, (
-            "requires a gpt_decoder-built model "
-            "(tok_embed/dec{i}_*/final_ln/lm_head layer names)"
-        )
-        num_layers = sum(
-            1 for n in names if n.startswith("dec") and n.endswith("_attn")
-        )
-        attn = names["dec0_attn"].attrs
-        heads = attn["num_heads"]
-        e = attn["embed_dim"]
+        from flexflow_tpu.fftype import ActiMode, OperatorType as T
+
+        layers = model.layers
+        producer = {t.guid: l for l in layers for t in l.outputs}
+        consumers: Dict[int, list] = {}
+        for l in layers:
+            for t in l.inputs:
+                consumers.setdefault(t.guid, []).append(l)
+        NORMS = (T.LAYERNORM, T.RMS_NORM)
+
+        def refuse(why):
+            raise ValueError(
+                "not a decoder the serve programs know (an embedding, then "
+                "per layer norm -> MultiHeadAttention | GatedAttention -> "
+                "[norm] -> add -> norm -> dense+GELU+dense | GatedFFN | "
+                f"RoutedExperts -> [norm] -> add, a final norm, a head): {why}"
+            )
+
+        def after(layer, *kinds):
+            """The one consumer of ``layer``'s output among ``kinds``
+            (a residual add also reads a block's input: not asked for
+            unless named)."""
+            hits = [c for c in consumers.get(layer.outputs[0].guid, ())
+                    if c.op_type in kinds]
+            return hits[0] if len(hits) == 1 else None
+
+        def norm_kind(l):
+            if l.op_type == T.LAYERNORM:
+                return "layer"
+            return "rms_zero_centered" if l.attrs.get("zero_centered") else "rms"
+
+        embeds = [l for l in layers if l.op_type == T.EMBEDDING]
+        heads_ = [l for l in layers if l.op_type == T.LINEAR]
+        if len(embeds) != 1 or not heads_:
+            refuse("it needs one token embedding and an output head")
+        embed, head = embeds[0], heads_[-1]
+        if head.attrs.get("use_bias", True):
+            refuse(f"the head {head.name!r} has a bias")
+        final = producer.get(head.inputs[0].guid)
+        if final is None or final.op_type not in NORMS:
+            refuse(f"the head {head.name!r} does not read a norm")
+        pos_embed, embed_scale = None, 1.0
+        nxt = after(embed, T.EW_ADD, T.SCALAR_MULTIPLY)
+        if nxt is not None and nxt.op_type == T.EW_ADD:
+            other = [producer.get(t.guid) for t in nxt.inputs
+                     if producer.get(t.guid) is not embed]
+            if len(other) != 1 or other[0] is None or other[0].op_type != T.WEIGHT:
+                refuse("what is added to the embedding is not a position table")
+            pos_embed = other[0].name
+        elif nxt is not None:
+            embed_scale = float(nxt.attrs["scalar"])
+
+        specs = []
+        for at in layers:
+            if at.op_type not in (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION):
+                continue
+            a = at.attrs
+            n_in = producer.get(at.inputs[0].guid)
+            if n_in is None or n_in.op_type not in NORMS:
+                refuse(f"{at.name!r} does not read a norm")
+            if any(t.guid != at.inputs[0].guid for t in at.inputs):
+                refuse(f"{at.name!r} is not self-attention")
+            post_attn = after(at, *NORMS)
+            res0 = after(post_attn or at, T.EW_ADD)
+            n_ffn = after(res0, *NORMS) if res0 is not None else None
+            f0 = after(n_ffn, T.LINEAR, T.GATED_FFN, T.ROUTED_EXPERTS) if n_ffn else None
+            if f0 is None:
+                refuse(f"no residual add, norm and FFN after {at.name!r}")
+            ffn, moe = (f0,), None
+            if f0.op_type == T.LINEAR:
+                f1 = after(f0, T.LINEAR)
+                if f1 is None or f0.attrs.get("activation") != ActiMode.GELU:
+                    refuse(f"{f0.name!r} is not dense + GELU + dense")
+                ffn, ffn_kind = (f0, f1), "gelu"
+            elif f0.op_type == T.GATED_FFN:
+                ffn_kind = "gated"
+            else:
+                ffn_kind, moe = "moe", dict(f0.attrs)
+                if moe["held"] != moe["n_experts"]:
+                    refuse(f"{f0.name!r} holds {moe['held']} of "
+                           f"{moe['n_experts']} experts: serving needs all")
+            post_ffn = after(ffn[-1], *NORMS)
+            if at.op_type == T.MULTIHEAD_ATTENTION:
+                if not a.get("causal"):
+                    refuse(f"{at.name!r} is not causal")
+                h = a["num_heads"]
+                kind = dict(attn_kind="mha", heads=h, kv_heads=h,
+                            head_dim=a.get("kdim") or a["embed_dim"] // h,
+                            has_bias=bool(a.get("bias")),
+                            qk_norm_zero_centered=False, qk_eps=0.0,
+                            rotary_dim=0, rope_theta=0.0, window=0)
+            else:
+                kind = dict(attn_kind="gated", heads=a["num_heads"],
+                            kv_heads=a["num_kv_heads"], head_dim=a["head_dim"],
+                            has_bias=False,
+                            qk_norm_zero_centered=bool(a.get("zero_centered", True)),
+                            qk_eps=float(a.get("eps", 1e-6)),
+                            rotary_dim=int(a["rotary_dim"]),
+                            rope_theta=float(a["rope_theta"]),
+                            window=int(a.get("window", 0)))
+            specs.append(LayerSpec(
+                norm_in=n_in.name, attn=at.name,
+                norm_post_attn=post_attn.name if post_attn else None,
+                norm_pre_ffn=n_ffn.name, ffn=tuple(f.name for f in ffn),
+                norm_post_ffn=post_ffn.name if post_ffn else None,
+                ffn_kind=ffn_kind, moe=moe, **kind,
+            ))
+        if not specs:
+            refuse("no attention layer")
+        l0 = specs[0]
+        if len({(l.heads, l.kv_heads, l.head_dim) for l in specs}) != 1:
+            refuse("layers differ in their heads (one K/V pool geometry is served)")
         batch, seq = model.graph_inputs[0].shape
         return cls(
-            num_layers=num_layers,
-            heads=heads,
-            head_dim=attn.get("kdim") or e // heads,
-            hidden=e,
-            has_bias=bool(attn.get("bias")),
-            eps=names["final_ln"].attrs.get("eps", 1e-5),
+            num_layers=len(specs),
+            heads=l0.heads,
+            head_dim=l0.head_dim,
+            hidden=embed.attrs["out_dim"],
+            has_bias=l0.has_bias,
+            eps=final.attrs.get("eps", 1e-5),
             batch=batch,
             seq=seq,
+            kv_heads=l0.kv_heads,
+            vocab=embed.attrs["num_entries"],
+            embed=embed.name,
+            pos_embed=pos_embed,
+            embed_scale=embed_scale,
+            norm=norm_kind(final),
+            final_norm=final.name,
+            head=head.name,
+            layers=tuple(specs),
         )
 
 
@@ -158,6 +333,12 @@ class GPTDecodeSession:
 
         self.model = model
         spec = GPTSpec.from_model(model)
+        if not spec.is_gpt:
+            raise ValueError(
+                "GPTDecodeSession decodes gpt_decoder-shaped models (learned "
+                "positions, LayerNorm, GELU FFN); this decoder is served by "
+                "flexflow_tpu.serve.ServeEngine"
+            )
         self.spec = spec
         self.num_layers = spec.num_layers
         self.heads = spec.heads

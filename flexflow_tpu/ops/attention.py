@@ -26,17 +26,22 @@ import jax.numpy as jnp
 from flexflow_tpu.fftype import OperatorType
 from flexflow_tpu.initializer import default_kernel_initializer
 from flexflow_tpu.ops.base import OpContext, OpDef, ShapeDtype, WeightSpec, register_op
-from flexflow_tpu.ops.norm import rms_norm_zero_centered
+from flexflow_tpu.ops.norm import rms_norm_f32, rms_norm_zero_centered
 from flexflow_tpu.tensor import Layer
 
 
-def sdpa(q, k, v, *, causal: bool = False, dropout_rate: float = 0.0, rng=None):
-    """Scaled dot-product attention over (B, H, S, D) tensors."""
+def sdpa(q, k, v, *, causal: bool = False, dropout_rate: float = 0.0, rng=None,
+         window: int = 0):
+    """Scaled dot-product attention over (B, H, S, D) tensors.  With
+    ``window`` (causal only) a query sees its last ``window`` keys, its
+    own among them."""
     d = q.shape[-1]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
     if causal:
         sq, sk = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq - window)
         scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_rate > 0.0 and rng is not None:
@@ -228,15 +233,15 @@ class MultiHeadAttention(OpDef):
         return {0: "sample", 1: "seq", 2: "channel"}
 
 
-def rotate_half_rope(x, rotary_dim: int, theta: float):
+def rotate_half_rope_at(x, pos, rotary_dim: int, theta: float):
     """Rotary positions (rotate-half pairing: dim i with i + rotary_dim/2)
-    on the first ``rotary_dim`` dims of ``x`` (B, S, H, D), position =
-    index along dim 1; the remaining dims pass through.  Float32."""
-    s = x.shape[1]
+    on the first ``rotary_dim`` dims of ``x`` (B, S, H, D), row ``s`` of
+    batch ``b`` at position ``pos[b, s]`` (``pos`` (B | 1, S) int); the
+    remaining dims pass through.  Float32."""
     half = rotary_dim // 2
     inv_freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq  # (S, half)
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    ang = pos.astype(jnp.float32)[:, :, None] * inv_freq  # (B | 1, S, half)
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     x = x.astype(jnp.float32)
     x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
     return jnp.concatenate(
@@ -244,12 +249,23 @@ def rotate_half_rope(x, rotary_dim: int, theta: float):
     )
 
 
+def rotate_half_rope(x, rotary_dim: int, theta: float):
+    """:func:`rotate_half_rope_at` with position = index along dim 1."""
+    return rotate_half_rope_at(x, jnp.arange(x.shape[1])[None, :], rotary_dim, theta)
+
+
 class GatedAttention(OpDef):
     """Causal grouped-query self-attention with per-head q/k RMS-norm,
     partial rotary positions and a sigmoid output gate (Qwen3-Next's
-    full-attention mixer).  Input (B, S, E) -> (B, S, E).  Attrs:
-    ``num_heads``, ``num_kv_heads``, ``head_dim``, ``rotary_dim``,
-    ``rope_theta``, ``eps``, ``use_flash``.  ``wq`` holds, per head,
+    full-attention mixer; the ``afmoe`` family's window and full
+    layers).  Input (B, S, E) -> (B, S, E).  Attrs:
+    ``num_heads``, ``num_kv_heads``, ``head_dim``, ``rotary_dim`` (0:
+    the layer carries no positions), ``rope_theta``, ``eps``,
+    ``use_flash``, ``window`` (0: every earlier key; else a query sees
+    its last ``window`` keys, through the ``sdpa`` arm's mask -- the
+    flash kernel declines a window), ``zero_centered`` (the q/k norm's
+    weight scales by ``1 + w`` from 0, the default, or by ``w`` from
+    1).  ``wq`` holds, per head,
     the query's columns and then the gate's.  K/V heads are repeated to
     ``num_heads`` in front of the core, so the core and its dispatch
     (``sdpa`` or the flash kernel, by ``_flash_ok``) are
@@ -262,20 +278,21 @@ class GatedAttention(OpDef):
         return [(t.shape, t.dtype)]
 
     def weights(self, layer: Layer) -> List[WeightSpec]:
-        from flexflow_tpu.initializer import ZeroInitializer
+        from flexflow_tpu.initializer import OnesInitializer, ZeroInitializer
 
         t = layer.inputs[0]
         a = layer.attrs
         e, dt = t.shape[-1], t.dtype
         h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
         init = a.get("kernel_initializer") or default_kernel_initializer()
+        norm_init = ZeroInitializer() if a.get("zero_centered", True) else OnesInitializer()
         return [
             WeightSpec("wq", (e, h * 2 * d), dt, init),
             WeightSpec("wk", (e, kv * d), dt, init),
             WeightSpec("wv", (e, kv * d), dt, init),
             WeightSpec("wo", (h * d, e), dt, init),
-            WeightSpec("q_norm", (d,), dt, ZeroInitializer()),
-            WeightSpec("k_norm", (d,), dt, ZeroInitializer()),
+            WeightSpec("q_norm", (d,), dt, norm_init),
+            WeightSpec("k_norm", (d,), dt, norm_init),
         ]
 
     def forward(self, layer, params, inputs, ctx: OpContext):
@@ -283,24 +300,28 @@ class GatedAttention(OpDef):
         a = layer.attrs
         h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
         eps = a.get("eps", 1e-6)
+        window = a.get("window", 0)
+        norm = rms_norm_zero_centered if a.get("zero_centered", True) else rms_norm_f32
         b, s, _ = x.shape
         with jax.named_scope("ff.attn_gated"):
             qg = (x @ params["wq"]).reshape(b, s, h, 2 * d)
             q, gate = qg[..., :d], qg[..., d:]
             k = (x @ params["wk"]).reshape(b, s, kv, d)
             v = (x @ params["wv"]).reshape(b, s, kv, d)
-            q = rms_norm_zero_centered(q, params["q_norm"], eps)
-            k = rms_norm_zero_centered(k, params["k_norm"], eps)
-            q = rotate_half_rope(q, a["rotary_dim"], a["rope_theta"]).astype(x.dtype)
-            k = rotate_half_rope(k, a["rotary_dim"], a["rope_theta"]).astype(x.dtype)
+            q = norm(q, params["q_norm"], eps)
+            k = norm(k, params["k_norm"], eps)
+            if a["rotary_dim"]:
+                q = rotate_half_rope(q, a["rotary_dim"], a["rope_theta"])
+                k = rotate_half_rope(k, a["rotary_dim"], a["rope_theta"])
+            q, k = q.astype(x.dtype), k.astype(x.dtype)
             k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-            if a.get("use_flash", True) and _flash_ok(s, s, d, b * h):
+            if not window and a.get("use_flash", True) and _flash_ok(s, s, d, b * h):
                 from flexflow_tpu.ops.pallas.flash_attention import flash_attention
 
                 out = flash_attention(q, k, v, causal=True)
             else:
-                out = sdpa(q, k, v, causal=True)
+                out = sdpa(q, k, v, causal=True, window=window)
             out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
             out = out * jax.nn.sigmoid(gate.reshape(b, s, h * d))
             return [out @ params["wo"]]
@@ -310,7 +331,8 @@ class GatedAttention(OpDef):
         a = layer.attrs
         h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
         proj = 2.0 * b * s * e * (2 * h * d + 2 * kv * d + h * d)
-        return proj + 2.0 * b * h * s * s * d  # causal: half of 4 b h s s d
+        seen = min(s, a.get("window", 0) or s)  # keys a late query sees
+        return proj + 2.0 * b * h * s * seen * d  # causal: half of 4 b h s s d
 
     def partitionable_dims(self, layer):
         return {0: "sample"}

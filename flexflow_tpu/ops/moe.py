@@ -364,18 +364,38 @@ class Experts(OpDef):
         return base
 
 
-def route_top_k(x, router, k: int):
-    """Softmax routing over ALL of the router's outputs, in float32 (the
+def route_top_k(x, router, k: int, *, score: str = "softmax", bias=None,
+                route_norm: bool = True, route_scale: float = 1.0):
+    """Routing over ALL of the router's outputs, in float32 (the
     matmul too: an expert choice that flips against the reference moves
-    a whole token's output), the chosen weights renormalised to sum 1.
+    a whole token's output).  ``score`` turns logits into scores
+    (``softmax`` over the experts, or ``sigmoid`` of each); the top
+    ``k`` of ``score + bias`` are chosen (the bias chooses only: the
+    weights are the scores themselves), renormalised to sum 1
+    (``route_norm``) and multiplied by ``route_scale``.
     ``x`` (t, d), ``router`` (d, n).  Returns ``(weights (t, k) float32,
     expert ids (t, k) int32)``."""
     logits = jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    return w / jnp.sum(w, axis=-1, keepdims=True), idx.astype(jnp.int32)
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router score {score!r}: softmax | sigmoid")
+    if bias is None:
+        w, idx = jax.lax.top_k(s, k)
+    else:
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+    if route_norm:
+        # softmax scores cannot sum to nought; sigmoid ones can underflow
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + (1e-20 if score == "sigmoid" else 0.0))
+    if route_scale != 1.0:
+        w = w * route_scale
+    return w, idx.astype(jnp.int32)
 
 
 # Rows of one pass over a share's sorted assignments, as a multiple of
@@ -492,23 +512,86 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def shared_expert_part(attrs, params, x):
+    """What a ``RoutedExperts`` layer's shared expert adds for rows ``x``
+    (t, d), float32: its gated FFN, behind a sigmoid gate unless
+    ``shared_gated`` is false."""
+    out = gated_ffn(
+        x, params["shared_gate_proj"], params["shared_up_proj"],
+        params["shared_down_proj"],
+    ).astype(jnp.float32)
+    if attrs.get("shared_gated", True):
+        out = out * jax.nn.sigmoid((x @ params["shared_gate"]).astype(jnp.float32))
+    return out
+
+
+def router_rule(attrs, params) -> dict:
+    """:func:`route_top_k`'s keyword arguments from a ``RoutedExperts``
+    layer's attrs and weights (the op's forward and the serve programs
+    route by the same rule)."""
+    return dict(
+        score=attrs.get("score", "softmax"), bias=params.get("router_bias"),
+        route_norm=attrs.get("route_norm", True),
+        route_scale=float(attrs.get("route_scale", 1.0)),
+    )
+
+
+class GatedFFN(OpDef):
+    """Dense gated FFN ``W_d (silu(W_g x) * W_u x)``, no biases.  Input
+    (..., d) -> (..., d).  Attr: ``hidden``."""
+
+    op_type = OperatorType.GATED_FFN
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        return [(t.shape, t.dtype)]
+
+    def weights(self, layer: Layer):
+        from flexflow_tpu.initializer import default_kernel_initializer
+        from flexflow_tpu.ops.base import WeightSpec
+
+        t = layer.inputs[0]
+        d, f, dt = t.shape[-1], layer.attrs["hidden"], t.dtype
+        init = layer.attrs.get("kernel_initializer") or default_kernel_initializer()
+        return [
+            WeightSpec("w_gate", (d, f), dt, init, tp_dim=1),
+            WeightSpec("w_up", (d, f), dt, init, tp_dim=1),
+            WeightSpec("w_down", (f, d), dt, init, tp_dim=0),
+        ]
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        with jax.named_scope("ff.ffn_dense"):
+            return [gated_ffn(inputs[0], params["w_gate"], params["w_up"], params["w_down"])]
+
+    def flops(self, layer: Layer) -> float:
+        t = layer.inputs[0]
+        return 6.0 * math.prod(t.shape) * layer.attrs["hidden"]
+
+    def partitionable_dims(self, layer: Layer):
+        return {0: "sample"}
+
+
 class RoutedExperts(OpDef):
     """One share of a sparse-MoE block: router over all ``n_experts``,
     the ``held`` experts from ``first_expert`` on, and (``shared_hidden``
-    > 0) a shared expert behind a sigmoid gate, which every share
-    computes alike.  Input (..., d) -> output (..., d)::
+    > 0) a shared expert, which every share computes alike, behind a
+    sigmoid gate unless ``shared_gated`` is false.  Input (..., d) ->
+    output (..., d)::
 
         sum_{k: e_k held} w_k E_{e_k}(x) + sigmoid(x . shared_gate) E_shared(x)
 
     What absent experts would add is left out; no row routed to a held
     expert is (``held_experts_part``).  Attrs: ``n_experts``,
-    ``first_expert``, ``held``, ``top_k``, ``hidden``, ``shared_hidden``.
+    ``first_expert``, ``held``, ``top_k``, ``hidden``, ``shared_hidden``;
+    the router's ``score`` (``softmax`` | ``sigmoid``), ``route_norm``,
+    ``route_scale`` and ``router_bias`` (a weight of ``n_experts`` added
+    to the scores for choosing only) -- :func:`route_top_k`.
     After its output the forward returns the values of ``step_counters``
     (``moe.rows_over_budget``: held rows less those the passes covered)
     and ``step_gauges``."""
 
     op_type = OperatorType.ROUTED_EXPERTS
-    fp32_weights = frozenset({"router"})
+    fp32_weights = frozenset({"router", "router_bias"})
     step_counters = ("moe.held_rows", "moe.passes", "moe.rows_over_budget")
     step_gauges = ("moe.load_max_over_mean",)
 
@@ -531,13 +614,18 @@ class RoutedExperts(OpDef):
             WeightSpec("w_up", (held, d, f), dt, init),
             WeightSpec("w_down", (held, f, d), dt, init),
         ]
+        if a.get("router_bias"):
+            from flexflow_tpu.initializer import ZeroInitializer
+
+            ws.append(WeightSpec("router_bias", (n,), dt, ZeroInitializer()))
         if fs:
             ws += [
                 WeightSpec("shared_gate_proj", (d, fs), dt, init),
                 WeightSpec("shared_up_proj", (d, fs), dt, init),
                 WeightSpec("shared_down_proj", (fs, d), dt, init),
-                WeightSpec("shared_gate", (d, 1), dt, init),
             ]
+            if a.get("shared_gated", True):
+                ws.append(WeightSpec("shared_gate", (d, 1), dt, init))
         return ws
 
     def forward(self, layer, params, inputs, ctx: OpContext):
@@ -545,7 +633,7 @@ class RoutedExperts(OpDef):
         x = inputs[0].reshape(-1, inputs[0].shape[-1])
         budget = pass_rows(x.shape[0], a["top_k"], a["held"], a["n_experts"])
         with jax.named_scope("ff.moe.route"):
-            w, idx = route_top_k(x, params["router"], a["top_k"])
+            w, idx = route_top_k(x, params["router"], a["top_k"], **router_rule(a, params))
         with jax.named_scope("ff.moe.experts"):
             out, counts, passes, covered = held_experts_part(
                 x, w, idx, a["first_expert"], budget,
@@ -553,12 +641,7 @@ class RoutedExperts(OpDef):
             )
         if a["shared_hidden"]:
             with jax.named_scope("ff.moe.shared"):
-                shared = gated_ffn(
-                    x, params["shared_gate_proj"], params["shared_up_proj"],
-                    params["shared_down_proj"],
-                )
-                gate = jax.nn.sigmoid((x @ params["shared_gate"]).astype(jnp.float32))
-                out = out + gate * shared.astype(jnp.float32)
+                out = out + shared_expert_part(a, params, x)
         load = counts.astype(jnp.float32)
         return [
             out.astype(x.dtype).reshape(inputs[0].shape),
@@ -587,4 +670,5 @@ register_op(GroupBy())
 register_op(Aggregate())
 register_op(AggregateSpec())
 register_op(Experts())
+register_op(GatedFFN())
 register_op(RoutedExperts())

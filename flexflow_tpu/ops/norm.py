@@ -80,6 +80,14 @@ def rms_norm_zero_centered(x, w, eps):
     return x * jax.lax.rsqrt(ms + eps) * (1.0 + w.astype(jnp.float32))
 
 
+def rms_norm_f32(x, w, eps):
+    """``x / rms(x) * w`` over the last axis; statistics and scaling in
+    float32, returned in float32."""
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(ms + eps) * w.astype(jnp.float32)
+
+
 class RMSNorm(OpDef):
     op_type = OperatorType.RMS_NORM
 

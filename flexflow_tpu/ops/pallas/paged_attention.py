@@ -104,6 +104,7 @@ from flexflow_tpu.ops.pallas import env_interpret
 __all__ = [
     "INTERPRET",
     "attention_walk",
+    "rows_tile",
     "lane_blocks",
     "page_rows_tile",
     "paged_decode_attention",
@@ -178,9 +179,30 @@ def resolve_serve_attn(mode: str, block_size=None, pool_dtype=None) -> str:
 _BLOCK_KEYS = 128
 
 
-def attention_walk(slots, block_size, max_blocks_per_seq):
+# rows of the block-diagonal query and of the accumulator one grid step
+# holds in VMEM when query heads share K/V heads: 512 rows of 512 lanes
+# are 0.5 MB (bfloat16) and 1 MB (float32)
+_TILE_ROWS = 512
+
+
+def rows_tile(G, q_heads, kv_heads, tile_rows=None):
+    """Query positions of a lane one grid step takes.  With one head
+    count (``q_heads == kv_heads``) all ``G``: the geometry the kernel
+    has always had.  With grouped heads the ``(G * q_heads, kv_heads *
+    D)`` query and accumulator of a whole chunk pass VMEM (64 MB at 256
+    positions of 32 / 4 heads of 128), so a lane's rows are cut into
+    tiles of at most ``tile_rows`` rows (the largest divisor of ``G``
+    that fits), each a grid step with its own walk."""
+    if q_heads == kv_heads:
+        return G
+    limit = max(1, (tile_rows or _TILE_ROWS) // q_heads)
+    return max(g for g in range(1, min(G, limit) + 1) if G % g == 0)
+
+
+def attention_walk(slots, block_size, max_blocks_per_seq, tiles=1):
     """How the attention kernel walks a ``(slots, max_blocks_per_seq)``
-    block table of ``block_size``-row pages: one grid step a lane, whose
+    block table of ``block_size``-row pages: one grid step a lane (and a
+    tile of its rows, :func:`rows_tile`), whose
     loop takes ``pages_per_block`` pages a compute block — the pages that
     make about ``_BLOCK_KEYS`` key positions, read off the page size
     (8 at 16 rows, 4 at 32, 1 at 128 and above), never more than the
@@ -189,58 +211,78 @@ def attention_walk(slots, block_size, max_blocks_per_seq):
     from this; ``ServeEngine.attn_walk`` reports it."""
     ppb = max(1, min(_BLOCK_KEYS // block_size, max_blocks_per_seq))
     return {
-        "grid": [slots],
+        "grid": [slots] if tiles == 1 else [slots, tiles],
         "pages_per_block": ppb,
         "max_blocks": -(-max_blocks_per_seq // ppb),
     }
 
 
-def _live(xp, pos, G, BS, MB, PPB):
-    """(pages, compute blocks) a lane whose ``G`` rows start at ``pos``
-    has to read: up to its last row's page, within the table.  ``xp`` is
+def _live(xp, pos, G, BS, MB, PPB, window=0):
+    """(first page, pages, compute blocks) a lane whose ``G`` rows start
+    at ``pos`` has to read: up to its last row's page, within the table;
+    from page 0, or with ``window`` from the page of the first position
+    its first row still sees, ``pos - window + 1`` (the table is then a
+    ring of ``MB`` pages: never more than that many).  ``xp`` is
     ``jnp`` inside the kernel and ``np`` on the host."""
-    n_pages = xp.minimum((pos + G - 1) // BS, MB - 1) + 1
-    return n_pages, (n_pages + PPB - 1) // PPB
+    if window:
+        first = xp.maximum(pos - (window - 1), 0) // BS
+        n_pages = xp.minimum((pos + G - 1) // BS - first + 1, MB)
+    else:
+        first = 0 * pos
+        n_pages = xp.minimum((pos + G - 1) // BS, MB - 1) + 1
+    return first, n_pages, (n_pages + PPB - 1) // PPB
 
 
-def lane_blocks(positions, G, *, block_size, max_blocks_per_seq):
+def lane_blocks(positions, G, *, block_size, max_blocks_per_seq, window=0):
     """Compute blocks the kernel's loop takes for lanes whose ``G`` rows
     start at ``positions`` (numpy, any shape), for the engine's report."""
     ppb = attention_walk(1, block_size, max_blocks_per_seq)["pages_per_block"]
     return _live(
-        np, np.asarray(positions), G, block_size, max_blocks_per_seq, ppb
-    )[1]
+        np, np.asarray(positions), G, block_size, max_blocks_per_seq, ppb,
+        window,
+    )[2]
 
 
 def _kernel(
     layer_ref,  # SMEM (1,) int32 — the layer of the pools to read
     pos_ref,  # SMEM (B,) int32 — row-0 position per lane
     bt_ref,  # SMEM (B, MB) int32 — block tables
-    q_ref,  # VMEM (1, G, H*D)
+    q_ref,  # VMEM (1, G, H*D); grouped heads: (1, G*QH, D)
     k_hbm,  # the WHOLE pool (L, N * BS, H*D), where it lies (HBM)
     v_hbm,
     *rest,  # [sk_ref, sv_ref (VMEM (NB, PPB*BS) f32)], o_ref, scratch refs
-    G: int,
-    H: int,
+    G: int,  # query positions this grid step takes (a tile of the lane's)
+    QH: int,
+    H: int,  # K/V heads: the pool's row is H * D
     BS: int,
     MB: int,
     PPB: int,
     scale: float,
     quantized: bool,
+    window: int,
+    tiled: bool,
 ):
     if quantized:
         sk_ref, sv_ref, *rest = rest
     o_ref, kbuf, vbuf, sem, qbd_ref, acc_ref, m_ref, l_ref = rest
-    GH, HD = acc_ref.shape
+    GH, HD = acc_ref.shape  # G * QH rows of H * D lanes
     D = HD // H
+    rep = QH // H  # query heads a K/V head serves
     W = PPB * BS  # key positions a compute block
     b = pl.program_id(0)
     layer = layer_ref[0]
     pos0 = pos_ref[b]
+    first_call = b == 0
+    if tiled:
+        # this step's rows are positions pos0 + t * G .. of the lane: to
+        # the walk, a lane of G rows that starts there
+        pos0 = pos0 + pl.program_id(1) * G
+        first_call &= pl.program_id(1) == 0
     # the walk ends at the lane's last live page: a lane three tokens in
     # takes one block, and so does an idle lane (position 0, the trash
-    # block); table entries past ``n_pages`` are never read
-    n_pages, n_blocks = _live(jnp, pos0, G, BS, MB, PPB)
+    # block); table entries past ``n_pages`` are never read.  With a
+    # window it also STARTS at the first page a row still sees
+    first_page, n_pages, n_blocks = _live(jnp, pos0, G, BS, MB, PPB, window)
     # both products accumulate in float32.  bfloat16 rows against a
     # bfloat16 page multiply exactly in one MXU pass; anything wider (a
     # float32 pool, a dequantized page, the float32 probabilities) takes
@@ -249,10 +291,10 @@ def _kernel(
     qk_exact = None if qbd_ref.dtype == jnp.bfloat16 else exact
 
     def own_head():
-        # (G*H, H*D): lane c lies in the head of row (g, h) = g * H + h
+        # (G*QH, H*D): lane c lies in the K/V head of row (g, h) = g * QH + h
         r = jax.lax.broadcasted_iota(jnp.int32, (GH, HD), 0)
         c = jax.lax.broadcasted_iota(jnp.int32, (GH, HD), 1)
-        return c // D == r % H
+        return c // D == (r % QH) // rep
 
     def own_row():
         # (G*H, G) 0/1: row (g, h) belongs to query row g
@@ -267,7 +309,10 @@ def _kernel(
         # earlier page, or the zeros below) and the causal mask drops
         # them
         def page(i, carry):
-            blk = bt_ref[b, j * PPB + i]
+            at = first_page + j * PPB + i
+            if window:
+                at = at % MB  # the window group's table is a ring
+            blk = bt_ref[b, at]
             rows = pl.ds(pl.multiple_of(blk * BS, BS), BS)
             into = pl.ds(pl.multiple_of(i * BS, BS), BS)
             go(pltpu.make_async_copy(
@@ -280,7 +325,7 @@ def _kernel(
 
         jax.lax.fori_loop(0, jnp.minimum(PPB, n_pages - j * PPB), page, None)
 
-    @pl.when(b == 0)
+    @pl.when(first_call)
     def _():
         # ``p @ v`` multiplies the masked positions' zeros by whatever
         # the value buffer holds there: make that finite once a call
@@ -291,15 +336,24 @@ def _kernel(
     # while the first block is in flight — the block-diagonal query,
     # once a lane: every query row H times over, each copy keeping one
     # head's lanes
-    q = q_ref[0].astype(jnp.float32)  # (G, H*D)
-    if G == 1:
-        rows = jnp.broadcast_to(q, (GH, HD))
+    q = q_ref[0].astype(jnp.float32)
+    if QH != H:
+        # grouped heads: the rows come in as (G*QH, D), a query head a
+        # row; each goes into its K/V head's D lanes
+        r = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
+        for kvh in range(H):
+            qbd_ref[:, kvh * D:(kvh + 1) * D] = jnp.where(
+                (r % QH) // rep == kvh, q, 0.0
+            ).astype(qbd_ref.dtype)
     else:
-        rows = jax.lax.dot_general(
-            own_row(), q, (((1,), (0,)), ((), ())),
-            precision=exact, preferred_element_type=jnp.float32,
-        )
-    qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
+        if G == 1:
+            rows = jnp.broadcast_to(q, (GH, HD))  # q is (G, H*D)
+        else:
+            rows = jax.lax.dot_general(
+                own_row(), q, (((1,), (0,)), ((), ())),
+                precision=exact, preferred_element_type=jnp.float32,
+            )
+        qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -319,33 +373,41 @@ def _kernel(
         s = jax.lax.dot_general(
             qbd_ref[...], k, (((1,), (1,)), ((), ())),
             precision=qk_exact, preferred_element_type=jnp.float32,
-        ) * scale  # (G*H, PPB*BS)
+        ) * scale  # (G*QH, PPB*BS)
         if quantized:
             # the pool's ``int.astype(f32) * scale`` rule
             # (kvcache.dequantize_kv) with the positions' scales applied
             # to the products instead of the pages: a key's scale to its
             # score, a value's to its probability
             s = s * sk_ref[pl.ds(j, 1), :]
-        k_pos = j * W + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 1)
+        k_pos = first_page * BS + j * W + jax.lax.broadcasted_iota(
+            jnp.int32, (GH, W), 1
+        )
         # (a chunk's padded rows may lie past the table's end: they see
         # no further than its last page, as when the grid ended there)
         row_pos = jnp.minimum(
-            pos0 + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 0) // H,
-            n_pages * BS - 1,
+            pos0 + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 0) // QH,
+            (first_page + n_pages) * BS - 1,
         )
         seen = k_pos <= row_pos
+        if window:
+            # a row sees its last ``window`` keys, its own among them.
+            # (A block may hold none of a late row's keys: its running
+            # max is then the mask's floor, and the first block that
+            # holds one scales what came before to exactly nothing.)
+            seen &= k_pos > row_pos - window
         s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
         m_prev = m_ref[:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])  # (G*H, PPB*BS) float32
+        p = jnp.exp(s - m_new[:, None])  # (G*QH, PPB*BS) float32
         l_ref[:, 0] = l_ref[:, 0] * alpha + p.sum(axis=-1)
         if quantized:
             p = jnp.where(seen, p * sv_ref[pl.ds(j, 1), :], 0.0)
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             precision=exact, preferred_element_type=jnp.float32,
-        )  # (G*H, H*D); row (g, h) means something in head h's lanes
+        )  # (G*QH, H*D); row (g, h) means something in its K/V head's lanes
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
         m_ref[:, 0] = m_new
         return carry
@@ -353,6 +415,12 @@ def _kernel(
     jax.lax.fori_loop(0, n_blocks, block, None)
 
     out = jnp.where(own_head(), acc_ref[...] / l_ref[:, 0][:, None], 0.0)
+    if QH != H:
+        # a row keeps its own K/V head's D lanes (the others hold zeros)
+        o_ref[0] = sum(
+            out[:, kvh * D:(kvh + 1) * D] for kvh in range(H)
+        ).astype(o_ref.dtype)
+        return
     # each query row's H copies back into one row: head h's lanes come
     # from copy h, every other copy holds zeros there
     if G == 1:
@@ -379,7 +447,8 @@ def _lane_scales(scales, layer, block_tables, ppb):
 
 
 def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
-                scale_k, scale_v, *, BS, scale, interpret):
+                scale_k, scale_v, *, BS, scale, interpret, window=0,
+                tile_rows=None):
     # Called through ``_JITTED``: jitted on its own, with the
     # interpreter flag among the static arguments (tests flip it per
     # engine build).  A serve program calls this once a layer with the
@@ -391,29 +460,42 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
     # a scalar it reads from SMEM.  A ``pool[layer]`` slice in front of
     # a custom call is a copy of the layer (76 MB a layer at GPT-2-small
     # width and 24 slots), not a view.
-    B, G, H, D = q.shape
-    HD = H * D
+    B, G, QH, D = q.shape
+    HD = pool_k.shape[-1]
+    H = HD // D  # K/V heads
+    assert H * D == HD and QH % H == 0, (q.shape, pool_k.shape)
     MB = block_tables.shape[1]
-    walk = attention_walk(B, BS, MB)
+    GT = rows_tile(G, QH, H, tile_rows)
+    NT = G // GT
+    walk = attention_walk(B, BS, MB, NT)
     PPB = walk["pages_per_block"]
     quantized = scale_k is not None
+    assert not (quantized and window), "no quantized pool behind a window"
     # the block-diagonal query multiplies a bfloat16 page as bfloat16
     # (the kernel reads the choice off the scratch's dtype)
     qbd_dtype = (
         jnp.bfloat16 if q.dtype == pool_k.dtype == jnp.bfloat16
         else jnp.float32
     )
+    grouped = QH != H
+    # grouped heads go in and come out a query head a row, (G*QH, D):
+    # both are the caller's (B, G, QH, D) seen otherwise, no copy
+    width, rows = (D, G * QH) if grouped else (QH * D, G)
+    tile = GT * QH if grouped else G
 
-    def lane_map(b, layer_ref, pos_ref, bt_ref):
+    def lane_map(b, *rest):
         return (b, 0, 0)
 
+    def tile_map(b, *rest):
+        return (b, rest[0], 0) if NT > 1 else (b, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, G, HD), lane_map),
+        pl.BlockSpec((1, tile, width), tile_map),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [
-        layer.reshape(1), positions, block_tables, q.reshape(B, G, HD),
+        layer.reshape(1), positions, block_tables, q.reshape(B, rows, width),
         pool_k, pool_v,
     ]
     if quantized:
@@ -430,35 +512,35 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
         num_scalar_prefetch=3,
         grid=tuple(walk["grid"]),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, HD), lane_map),
+        out_specs=pl.BlockSpec((1, tile, width), tile_map),
         scratch_shapes=[
             # two compute blocks of K and of V: one contracted while the
             # next one's pages arrive
             pltpu.VMEM((2, PPB * BS, HD), pool_k.dtype),
             pltpu.VMEM((2, PPB * BS, HD), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
-            pltpu.VMEM((G * H, HD), qbd_dtype),
-            pltpu.VMEM((G * H, HD), jnp.float32),
-            pltpu.VMEM((G * H, 128), jnp.float32),
-            pltpu.VMEM((G * H, 128), jnp.float32),
+            pltpu.VMEM((GT * QH, HD), qbd_dtype),
+            pltpu.VMEM((GT * QH, HD), jnp.float32),
+            pltpu.VMEM((GT * QH, 128), jnp.float32),
+            pltpu.VMEM((GT * QH, 128), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _kernel, G=G, H=H, BS=BS, MB=MB, PPB=PPB, scale=scale,
-        quantized=quantized,
+        _kernel, G=GT, QH=QH, H=H, BS=BS, MB=MB, PPB=PPB, scale=scale,
+        quantized=quantized, window=window, tiled=NT > 1,
     )
     # no ``name=``: a compiled program and the profiler's trace name the
     # call after the jitted function around it (``_jitted_as`` below)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, HD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rows, width), q.dtype),
         # lanes run in turn: the value buffer is cleared by the first
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",) * len(walk["grid"])
         ),
         interpret=interpret,
-    )(*operands).reshape(B, G, H, D)
+    )(*operands).reshape(B, G, QH, D)
 
 
 def _jitted_as(name):
@@ -471,7 +553,9 @@ def _jitted_as(name):
         return _paged_call(*args, **kwargs)
 
     call.__name__ = call.__qualname__ = name
-    return jax.jit(call, static_argnames=("BS", "scale", "interpret"))
+    return jax.jit(call, static_argnames=(
+        "BS", "scale", "interpret", "window", "tile_rows",
+    ))
 
 
 _JITTED = {name: _jitted_as(name) for name in ("decode", "prefill")}
@@ -479,13 +563,18 @@ _JITTED = {name: _jitted_as(name) for name in ("decode", "prefill")}
 
 def paged_decode_attention(
     q, pool_k, pool_v, positions, block_tables, scale=None,
-    scale_k=None, scale_v=None, layer=None, *, block_size,
+    scale_k=None, scale_v=None, layer=None, *, block_size, window=0,
+    tile_rows=None,
 ):
     """Fused paged decode attention over one layer's K/V pool.
 
     Args:
-      q: (B, G, H, D) query rows — ``G`` consecutive positions per
-        lane (decode/draft G=1; speculative verify G=k+1).
+      q: (B, G, QH, D) query rows — ``G`` consecutive positions per
+        lane (decode/draft G=1; speculative verify G=k+1).  ``QH`` may
+        be a multiple of the pool's ``H`` K/V heads (grouped-query
+        attention: ``QH // H`` query heads read one K/V head, and a
+        lane's rows are then taken :func:`rows_tile` positions a grid
+        step).
       pool_k / pool_v: (num_blocks * BS, H * D) — the layer's paged pool,
         position-major (physical block ``n`` is rows ``n * BS ..``; block
         0 is the allocator's trash block); or, with ``layer`` given, the
@@ -508,8 +597,15 @@ def paged_decode_attention(
         carry.  Pass both or neither.
       block_size: ``BS``, the rows of a page (the pool's shape does not
         say).
+      window: 0, or the positions a row sees back, its own among them
+        (row at ``p`` attends ``p - window + 1 .. p``).  The walk then
+        starts at the page of the first row's first visible position,
+        and the block table is read as a RING of its ``MB`` pages
+        (logical page ``j`` at entry ``j % MB``: the window group of
+        ``PagedKVCache``), which has to hold ``window + G`` positions
+        and a page.
 
-    Returns (B, G, H, D) in ``q.dtype``.  Numerics: float32 scores,
+    Returns (B, G, QH, D) in ``q.dtype``.  Numerics: float32 scores,
     online softmax and accumulation; the two contractions run on the
     MXU at full precision, so the result agrees with the dense gather
     path to a float32 tolerance, not to the bit (the greedy argmax
@@ -517,12 +613,12 @@ def paged_decode_attention(
     """
     return _attention(
         "decode", q, pool_k, pool_v, positions, block_tables, scale,
-        scale_k, scale_v, layer, block_size,
+        scale_k, scale_v, layer, block_size, window, tile_rows,
     )
 
 
 def _attention(name, q, pool_k, pool_v, positions, block_tables, scale,
-               scale_k, scale_v, layer, block_size):
+               scale_k, scale_v, layer, block_size, window=0, tile_rows=None):
     if (scale_k is None) != (scale_v is None):
         raise ValueError("pass both scale_k and scale_v, or neither")
     if scale is None:
@@ -539,12 +635,14 @@ def _attention(name, q, pool_k, pool_v, positions, block_tables, scale,
         q, pool_k, pool_v, positions, block_tables,
         jnp.asarray(layer, jnp.int32), scale_k, scale_v,
         BS=int(block_size), scale=float(scale), interpret=bool(INTERPRET),
+        window=int(window), tile_rows=tile_rows,
     )
 
 
 def paged_prefill_attention(
     q, pool_k, pool_v, start, block_tables, scale=None,
-    scale_k=None, scale_v=None, layer=None, *, block_size,
+    scale_k=None, scale_v=None, layer=None, *, block_size, window=0,
+    tile_rows=None,
 ):
     """Fused paged CHUNKED-PREFILL attention over one layer's K/V pool.
 
@@ -582,7 +680,7 @@ def paged_prefill_attention(
     # contract, no second code path to drift
     return _attention(
         "prefill", q, pool_k, pool_v, start, block_tables, scale,
-        scale_k, scale_v, layer, block_size,
+        scale_k, scale_v, layer, block_size, window, tile_rows,
     )
 
 
@@ -605,13 +703,15 @@ def _write_kernel(
     vo_ref[...] = jnp.where(fresh, new_ref[1], v_ref[...])
 
 
-def _write_plan(start, block_tables, G, BS, n_valid=None):
+def _write_plan(start, block_tables, G, BS, n_valid=None, ring=False):
     """Which pages ``G`` consecutive rows a lane touch, and which rows of
     each are new.  Returns (phys, lo, hi, page), all (B, NP) int32 with
     ``NP = (G + BS - 2) // BS + 1``: lane b's j-th page is logical page
     ``page[b, j]`` = physical block ``phys[b, j]``, and takes the rows
     ``lo <= r < hi``.  A page that takes no row (past ``n_valid``, past
-    the table) names the trash block 0 with an empty range."""
+    the table) names the trash block 0 with an empty range.  With
+    ``ring`` the table is the window group's ring: logical page ``j`` is
+    its entry ``j % MB``, and no page lies past it."""
     MB = block_tables.shape[1]
     NP = (G + BS - 2) // BS + 1
     start = jnp.asarray(start, jnp.int32)[:, None]  # (B, 1)
@@ -622,18 +722,17 @@ def _write_plan(start, block_tables, G, BS, n_valid=None):
     page = start // BS + jnp.arange(NP, dtype=jnp.int32)  # (B, NP)
     lo = jnp.clip(start - page * BS, 0, BS)
     hi = jnp.clip(end - page * BS, 0, BS)
-    live = (hi > lo) & (page < MB)
-    phys = jnp.where(
-        live,
-        jnp.take_along_axis(block_tables, jnp.clip(page, 0, MB - 1), axis=1),
-        0,
-    )
+    if ring:
+        live, at = hi > lo, page % MB
+    else:
+        live, at = (hi > lo) & (page < MB), jnp.clip(page, 0, MB - 1)
+    phys = jnp.where(live, jnp.take_along_axis(block_tables, at, axis=1), 0)
     return phys, jnp.where(live, lo, 0), jnp.where(live, hi, 0), page
 
 
 def paged_kv_write(
     pool_k, pool_v, layer, k, v, start, block_tables, n_valid=None,
-    *, block_size,
+    *, block_size, ring=False,
 ):
     """Write each lane's new K/V rows into layer ``layer`` of the paged
     pools, in place: the device writer of the serve programs.
@@ -652,6 +751,7 @@ def paged_kv_write(
       n_valid: (B,) int32 or None — only rows ``g < n_valid[b]`` are
         written (a prefill chunk's padded tail); None writes all G.
       block_size: ``BS``, the rows of a page.
+      ring: the table is the window group's ring (``_write_plan``).
 
     G consecutive positions touch at most ``NP = (G + BS - 2) // BS + 1``
     pages.  The grid is (B, NP): each step brings one (BS, H * D) page
@@ -669,17 +769,18 @@ def paged_kv_write(
     return _kv_write(
         pool_k, pool_v, jnp.asarray(layer, jnp.int32), k, v, start,
         block_tables, n_valid, BS=int(block_size), interpret=bool(INTERPRET),
+        ring=bool(ring),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("BS", "interpret"))
+@functools.partial(jax.jit, static_argnames=("BS", "interpret", "ring"))
 def _kv_write(pool_k, pool_v, layer, k, v, start, block_tables, n_valid,
-              *, BS, interpret):
+              *, BS, interpret, ring=False):
     # jitted on its own like ``_paged_call``: one trace and one lowering
     # a program, whatever its depth
     HD = pool_k.shape[-1]
     B, G = k.shape[:2]
-    phys, lo, hi, page = _write_plan(start, block_tables, G, BS, n_valid)
+    phys, lo, hi, page = _write_plan(start, block_tables, G, BS, n_valid, ring)
     NP = phys.shape[1]
     # the new rows in page shape: row r of page j is chunk row
     # page * BS + r - start (clamped; rows outside [lo, hi) are not read)

@@ -68,6 +68,7 @@ class Executor:
         seed: int = 0,
         remat_policy: str = "none",
         compute_dtype: str = "float32",
+        param_dtype: str = "float32",
         dcn_axis: str = "data",
         zero1: bool = False,
         profiling: bool = False,
@@ -90,6 +91,9 @@ class Executor:
         self.remat_policy = remat_policy
         self.compute_dtype = jnp.dtype(compute_dtype)
         self._mixed = self.compute_dtype != jnp.float32
+        # what init_params makes float32-declared weights in
+        # (FFConfig.param_dtype: weights at rest)
+        self.param_dtype = jnp.dtype(param_dtype)
         # ZeRO-1: optimizer moments sharded over the data axis (memory /dp);
         # GSPMD turns the update into slice-update + all-gather of the
         # param delta — a capability the reference lacks entirely (its
@@ -1086,9 +1090,15 @@ class Executor:
 
         def make_init(layer, w):
             pspec = self.strategy.weight_pspec(layer, w.name, len(w.shape))
+            dtype = w.dtype.to_jnp()
+            if (
+                dtype == jnp.float32
+                and w.name not in get_op_def(layer.op_type).fp32_weights
+            ):
+                dtype = self.param_dtype
 
             def init_fn(k):
-                return w.initializer(k, w.shape, w.dtype.to_jnp())
+                return w.initializer(k, w.shape, dtype)
 
             if self.mesh is not None:
                 return jax.jit(

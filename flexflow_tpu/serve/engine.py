@@ -58,6 +58,7 @@ from flexflow_tpu.serve.scheduler import (
 __all__ = [
     "ServeEngine",
     "ServeReport",
+    "UnsupportedServeConfig",
     "count_pool_relayouts",
     "load_drain",
     "save_drain",
@@ -141,6 +142,12 @@ def count_pool_relayouts(hlo_text: str, pool_nbytes: int) -> int:
     return n
 
 
+class UnsupportedServeConfig(ValueError):
+    """An engine option the decoder at hand is not served with: raised by
+    ``ServeEngine.__init__`` before anything is built, never by an
+    assertion deep in a program."""
+
+
 def _pct(vals: Sequence[float], q: float) -> Optional[float]:
     vals = [v for v in vals if v is not None]
     if not vals:
@@ -199,6 +206,24 @@ class ServeReport:
     # have taken (ServeEngine._attn_blocks; None where it does not apply)
     attn_blocks_walked: Optional[int] = None
     attn_blocks_full_table: Optional[int] = None
+    # --- window and full layer groups, routed experts (PR 32) ---
+    # K/V rows the attention calls of the finished requests had to read,
+    # every layer counted (a window layer reads its window), and what
+    # they would read were every layer full
+    kv_rows_visible: Optional[int] = None
+    kv_rows_context: Optional[int] = None
+    # most pages mapped into slots' tables at a window's start, a group
+    kv_pages_held_full: int = 0
+    kv_pages_held_window: int = 0
+    # device-side counters of the expert layers, summed over the run's
+    # program calls (they ride the window's one sync): valid rows routed
+    # (positions x top-k), distinct experts with >= 1 row a (layer, call),
+    # and the mean over (layer, call) of the fullest expert's load over
+    # the mean load.  None for a model without routed experts
+    moe_rows: Optional[int] = None
+    moe_experts_touched: Optional[int] = None
+    moe_load_max_over_mean: Optional[float] = None
+    moe_layers: int = 0
     # --- what it ran on (ServeEngine.device_info) ---
     attn_interpret: bool = False  # paged kernel ran in the Pallas interpreter
     device: Dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -210,7 +235,11 @@ class ServeReport:
 
 
 class ServeEngine:
-    """Continuous-batching serving over one compiled gpt_decoder model.
+    """Continuous-batching serving over one compiled decoder
+    (``models/gpt_decode.py::GPTSpec`` says which: ``gpt_decoder``,
+    ``afmoe_decoder``).  Speculation, int8 weights, a quantized pool and
+    disaggregated or fleet serving are built for ``gpt_decoder``-shaped
+    models and refused for the rest (:class:`UnsupportedServeConfig`).
 
     ``slots`` defaults to the model's compiled batch; the KV pool
     defaults to full provisioning (``num_blocks`` =
@@ -255,6 +284,23 @@ class ServeEngine:
         self._jax, self._jnp = jax, jnp
         self.model = model
         self.spec = GPTSpec.from_model(model)
+        if not self.spec.is_gpt:
+            for what, on in (
+                (f"speculation (spec_k={spec_k})", int(spec_k) > 0),
+                (f"weight_dtype {weight_dtype!r}", str(weight_dtype) != "fp32"),
+                (f"a quantized pool (kv_dtype {kv_dtype!r})",
+                 str(kv_dtype) in ("int8", "fp8")),
+                (f"a disaggregated or fleet pool (phase {phase!r})",
+                 phase is not None),
+                ("--verify-compiled (its serve audit knows one pool group)",
+                 getattr(model.config, "verify_compiled", "off") != "off"),
+            ):
+                if on:
+                    raise UnsupportedServeConfig(
+                        f"{what} is built for gpt_decoder-shaped models "
+                        "(learned positions, LayerNorm, one head count, GELU "
+                        "FFN); this decoder is served without it"
+                    )
         self.slots = int(slots or self.spec.batch)
         self.prefill_chunk = max(1, int(prefill_chunk))
         self.sync_every = max(1, int(sync_every))
@@ -315,11 +361,17 @@ class ServeEngine:
             raise ValueError(
                 f"weight_dtype {self.weight_dtype!r}: expected fp32 | int8"
             )
+        # the pool's row is the K/V heads'; window layers live in a
+        # group of their own (a ring a slot, kvcache.py)
+        n_win = sum(1 for l in self.spec.layers if l.window)
         self.kv = PagedKVCache(
-            self.spec.num_layers, self.spec.heads, self.spec.head_dim,
+            self.spec.num_layers - n_win, self.spec.kv_heads,
+            self.spec.head_dim,
             slots=self.slots, block_size=block_size,
             num_blocks=num_blocks, max_seq_len=self.spec.seq, dtype=dt,
             kv_dtype=self.kv_dtype, prefix_sharing=prefix_sharing,
+            window_layers=n_win, window=self.spec.window,
+            chunk=self.prefill_chunk,
         )
         self.sched = ContinuousBatchingScheduler(self.slots, self.kv)
         self.metrics = MetricsStream(metrics_out, max_mb=metrics_max_mb)
@@ -378,7 +430,15 @@ class ServeEngine:
             model, self.kv, attn_kernel=self.attn_kernel,
             weight_dtype=self.weight_dtype, spec_k=self.spec_k,
             spec_draft_layers=self.spec_draft_layers,
+            # greedy decoding needs the argmax alone: the distribution
+            # stays on the device (and out of the program)
+            return_probs=self.temperature > 0.0,
         )
+        self._n_head = progs.n_head
+        self._moe_layers = sum(1 for l in self.spec.layers if l.ffn_kind == "moe")
+        # the expert layers' counters, summed on the device call by call
+        # and read with the window's tokens
+        self._moe_acc = None
         self._params_arg = progs.params_arg
         self._decode, self._prefill = progs.decode, progs.prefill
         self._draft, self._verify = progs.draft, progs.verify
@@ -389,15 +449,16 @@ class ServeEngine:
         # state replays compiled code only
         idle_decode, idle_prefill = self._idle_args()
         z, _, bt0 = idle_decode
+        nh = self._n_head
         res = self._decode(self._params_arg, *self._kvs(), *idle_decode)
-        bufs = res[2:]
+        bufs = res[nh:]
         res = self._prefill(self._params_arg, *bufs, *idle_prefill)
-        bufs = res[2:]
+        bufs = res[nh:]
         # chain one more decode on the prefill's outputs so BOTH
         # programs have seen the other's cache layout — steady state
         # then replays compiled code regardless of phase interleaving
         res = self._decode(self._params_arg, *bufs, z, z, bt0)
-        bufs = res[2:]
+        bufs = res[nh:]
         if self.spec_k:
             # the speculative programs join the same warmup chain so
             # all four agree on ONE buffer layout (a second layout
@@ -410,7 +471,7 @@ class ServeEngine:
             )
             bufs = res[4:]
             res = self._decode(self._params_arg, *bufs, z, z, bt0)
-            bufs = res[2:]
+            bufs = res[nh:]
         # keep the CHAINED warmup buffers as the live pool: the warmup
         # only ever wrote the trash block (all tables were zero), so
         # every real block still holds zeros — and replacing them with
@@ -457,6 +518,12 @@ class ServeEngine:
         self._pf_start = np.zeros((B,), np.int32)
         self._pf_n = np.zeros((B,), np.int32)
         self._pf_bt = np.zeros((B, MB), np.int32)
+        self._pf_wbt = (
+            np.zeros((B, self.kv.ring_blocks), np.int32)
+            if self.kv.window_layers else None
+        )
+        self._moe_tot = np.zeros((4,), np.float64)
+        self._pages_peak = {"full": 0, "window": 0}
         self.spec_drafted = 0  # draft tokens proposed (spec mode)
         self.spec_accepted = 0  # draft tokens the full model confirmed
         self.peak_active = 0
@@ -531,6 +598,9 @@ class ServeEngine:
         B, MB = self.slots, self.kv.max_blocks_per_seq
         z = jnp.zeros((B,), jnp.int32)
         bt0 = jnp.zeros((B, MB), jnp.int32)
+        if self.kv.window_layers:
+            # both groups' tables, as the programs take them
+            bt0 = (bt0, jnp.zeros((B, self.kv.ring_blocks), jnp.int32))
         toks = jnp.zeros((B, self.prefill_chunk), jnp.int32)
         return (z, z, bt0), (toks, z, jnp.ones((B,), jnp.int32), bt0)
 
@@ -568,9 +638,43 @@ class ServeEngine:
             return None
         from flexflow_tpu.ops.pallas.paged_attention import attention_walk
 
-        return attention_walk(
+        walk = attention_walk(
             self.slots, self.kv.block_size, self.kv.max_blocks_per_seq
         )
+        if self.kv.window_layers:
+            # the window group's walk: over a ring, from the first page a
+            # row still sees; a chunk's rows in tiles where heads are grouped
+            from flexflow_tpu.ops.pallas.paged_attention import rows_tile
+
+            P, sp = self.prefill_chunk, self.spec
+            walk["window"] = dict(
+                attention_walk(
+                    self.slots, self.kv.block_size, self.kv.ring_blocks,
+                    P // rows_tile(P, sp.heads, sp.kv_heads),
+                ),
+                window=self.kv.window, ring_blocks=self.kv.ring_blocks,
+            )
+        return walk
+
+    def _kv_rows(self, fin) -> Tuple[int, int]:
+        """(visible, context): K/V rows the attention calls of the
+        finished requests had to read, summed over the layers -- a full
+        layer reads a lane's positions up to its last row, a window
+        layer those its rows still see -- and the same were every layer
+        full.  Reckoned at the end of a run from the requests' lengths,
+        like :meth:`_attn_blocks`."""
+        W, P = self.kv.window, self.prefill_chunk
+        n_win, n_full = self.kv.window_layers, self.kv.num_layers
+        full = win = 0
+        for r in fin:
+            lo = np.arange(r.shared_prefix_pos, r.prompt_len, P)
+            hi = np.minimum(lo + P, r.prompt_len)
+            at = r.prompt_len + np.arange(max(0, r.done_tokens - 1))
+            full += int(hi.sum() + (at + 1).sum())
+            if n_win:
+                win += int((hi - np.maximum(0, lo - W + 1)).sum()
+                           + np.minimum(at + 1, W).sum())
+        return n_full * full + n_win * win, (n_full + n_win) * full
 
     def _attn_blocks(self, fin) -> Tuple[Optional[int], Optional[int]]:
         """(walked, full table): compute blocks the kernel's lanes took
@@ -604,21 +708,34 @@ class ServeEngine:
 
     def _kvs(self):
         """The live pool buffers in program-argument order: (ck, cv)
-        for a full-precision pool, (ck, cv, sk, sv) for a quantized one
-        — every program donates and returns exactly this tuple."""
+        for a full-precision pool, (ck, cv, sk, sv) for a quantized one,
+        (ck, cv, wk, wv) with a window group — every program donates and
+        returns exactly this tuple."""
+        return tuple(getattr(self.kv, name) for name in self._pool_names())
+
+    def _pool_names(self) -> Tuple[str, ...]:
         kv = self.kv
+        names = ("cache_k", "cache_v")
         if kv.quantized:
-            return (kv.cache_k, kv.cache_v, kv.scale_k, kv.scale_v)
-        return (kv.cache_k, kv.cache_v)
+            names += ("scale_k", "scale_v")
+        if kv.window_layers:  # the window group's pools ride last
+            names += ("win_k", "win_v")
+        return names
 
     def _store_kvs(self, bufs) -> None:
         """Write a program's returned pool buffers back as the live
         pool (the counterpart of :meth:`_kvs`)."""
-        kv = self.kv
-        if kv.quantized:
-            kv.cache_k, kv.cache_v, kv.scale_k, kv.scale_v = bufs
-        else:
-            kv.cache_k, kv.cache_v = bufs
+        for name, buf in zip(self._pool_names(), bufs, strict=True):
+            setattr(self.kv, name, buf)
+
+    def _take(self, res):
+        """A decode or prefill call's results: keep its pools (and add
+        its expert counters to the device-side sum), return ``(nxt,
+        probs)``."""
+        self._store_kvs(res[self._n_head:])
+        if self._n_head == 3:
+            self._moe_acc = res[2] if self._moe_acc is None else self._moe_acc + res[2]
+        return res[0], res[1]
 
     # --- the serve loop ----------------------------------------------------
     def run(self, requests: Optional[Sequence[Request]] = None) -> ServeReport:
@@ -643,6 +760,8 @@ class ServeEngine:
         self._occ_sum = 0.0
         self.watchdog_fires = 0
         self._slo_breach_windows = 0
+        self._moe_tot[:] = 0.0
+        self._pages_peak = {"full": 0, "window": 0}
         fin0 = len(self.sched.finished)
         rej0 = len(self.sched.rejected)
         pre0 = self.sched.preemptions
@@ -857,6 +976,9 @@ class ServeEngine:
             # admission happened just before this window — sample the high-
             # water mark now, before any in-window finishes release slots
             self.peak_active = max(self.peak_active, len(self.sched.active))
+            for g, n in self.kv.pages_held().items():
+                self._pages_peak[g] = max(self._pages_peak[g], n)
+            wbt_pf = self._pf_wbt
 
             # 1) prefill: ONE batched dispatch covers every mid-prefill
             #    slot (r20) — per-lane block tables/start/n_valid, idle
@@ -883,12 +1005,16 @@ class ServeEngine:
                     start.fill(0)
                     n_valid.fill(0)
                     bt_pf.fill(0)
+                    if wbt_pf is not None:
+                        wbt_pf.fill(0)
                     for slot, lo, hi in chunks:
                         req = self.sched.active[slot]
                         toks[slot, : hi - lo] = req.prompt[lo:hi]
                         start[slot] = lo
                         n_valid[slot] = hi - lo
                         bt_pf[slot] = self.kv.table_row(slot)
+                        if wbt_pf is not None:
+                            wbt_pf[slot] = self.kv.win_tables[slot]
 
                     def place(arrs):
                         # the dispatch gets its OWN copy of each staging buffer:
@@ -899,14 +1025,18 @@ class ServeEngine:
                             self._jax.device_put(jnp.asarray(a.copy())) for a in arrs
                         )
 
+                    host = (toks, start, n_valid, bt_pf)
+                    if wbt_pf is not None:
+                        host += (wbt_pf,)
                     (staged,) = list(DevicePrefetcher(
-                        [(toks, start, n_valid, bt_pf)], place,
-                        depth=self.prefetch_depth,
+                        [host], place, depth=self.prefetch_depth,
                     ))
+                    if wbt_pf is not None:  # both groups' tables, one argument
+                        staged = staged[:3] + (staged[3:],)
                     t_c0 = spans.now() if spans is not None else 0.0
-                    res = self._prefill(self._params_arg, *self._kvs(), *staged)
-                    pf_nxt, pf_probs = res[0], res[1]
-                    self._store_kvs(res[2:])
+                    pf_nxt, pf_probs = self._take(
+                        self._prefill(self._params_arg, *self._kvs(), *staged)
+                    )
                     self.prefill_chunks += len(chunks)
                     self.prefill_dispatches += 1
                     t_c1 = spans.now() if spans is not None else 0.0
@@ -958,12 +1088,19 @@ class ServeEngine:
                     cur = np.zeros((B,), np.int32)
                     pos = np.zeros((B,), np.int32)
                     bt = np.zeros((B, MB), np.int32)
+                    wbt = None
+                    if self.kv.window_layers:
+                        wbt = np.zeros_like(self.kv.win_tables)
                     for s in dec_slots:
                         r = self.sched.active[s]
                         cur[s] = r.tokens[-1]
                         pos[s] = r.prompt_len + r.done_tokens - 1
                         bt[s] = self.kv.tables[s]
+                        if wbt is not None:
+                            wbt[s] = self.kv.win_tables[s]
                     bt_d = self._jax.device_put(jnp.asarray(bt))
+                    if wbt is not None:
+                        bt_d = (bt_d, self._jax.device_put(jnp.asarray(wbt)))
                     cur_d = self._jax.device_put(jnp.asarray(cur))
                     if self.spec_k:
                         # speculative macro steps: k chained draft calls on the
@@ -1003,12 +1140,10 @@ class ServeEngine:
                         for _ in range(steps):
                             # a copy of pos: it is advanced in place below while
                             # this step may still be queued (see place() above)
-                            res = self._decode(
+                            nxt, probs_last = self._take(self._decode(
                                 self._params_arg, *self._kvs(),
                                 cur_d, jnp.asarray(pos.copy()), bt_d,
-                            )
-                            nxt, probs_last = res[0], res[1]
-                            self._store_kvs(res[2:])
+                            ))
                             buffered.append(nxt)
                             cur_d = nxt  # device-to-device chain: NO host fetch
                             for s in dec_slots:
@@ -1024,15 +1159,25 @@ class ServeEngine:
                 ]
                 if prefill_done:
                     # ONE fetch of the batched dispatch's lanes, inside the
-                    # window's single sync — indexed per finishing slot
+                    # window's single sync — indexed per finishing slot.
+                    # The distribution comes along only where sampling asks
+                    # (greedy programs do not return one: (slots, vocab)
+                    # float32 is 0.8 MB a slot at 200 k entries)
                     pf_nxt_h = np.asarray(pf_nxt)
-                    pf_probs_h = np.asarray(pf_probs)
+                    pf_probs_h = None if pf_probs is None else np.asarray(pf_probs)
                     host_pre = [
-                        (req, int(pf_nxt_h[slot]), pf_probs_h[slot])
+                        (req, int(pf_nxt_h[slot]),
+                         None if pf_probs_h is None else pf_probs_h[slot])
                         for req, slot in prefill_done
                     ]
                 else:
                     host_pre = []
+                if self._moe_acc is not None and (buffered or prefill_done):
+                    # the expert layers' counters ride this same sync (a
+                    # window that fetches nothing leaves them on the device
+                    # for the next: it does not wait for its programs)
+                    self._moe_tot += np.asarray(self._moe_acc, np.float64)
+                    self._moe_acc = None
                 stall = self._now() - t_sync
             with tracer.span("flush", cat="serve"):
                 ex.count_host_sync(1, stall)
@@ -1284,6 +1429,21 @@ class ServeEngine:
             "kv_write": self.kv_write,
             "kv_dtype": self.kv_dtype,
             "weight_dtype": self.weight_dtype,
+            "kv_pages_held": self.kv.pages_held(),
+            "moe": self._moe_report(),
+        }
+
+    def _moe_report(self) -> Optional[Dict[str, Any]]:
+        """The expert layers' counters so far this run (``moe.rows``,
+        ``moe.experts_touched``, ``moe.load_max_over_mean``), from what
+        the windows' syncs brought; None without routed experts."""
+        if not self._moe_layers:
+            return None
+        rows, touched, lmm, calls = self._moe_tot
+        return {
+            "rows": int(rows), "experts_touched": int(touched),
+            "load_max_over_mean": float(lmm / calls) if calls else None,
+            "layers": self._moe_layers,
         }
 
     def _finish_if_done(self, req: Request, tok: int) -> None:
@@ -1321,6 +1481,20 @@ class ServeEngine:
             d["ttft_p99_ms"] = _pct(ttfts, 99)
             per_tenant[tenant] = d
         blocks_walked, blocks_full_table = self._attn_blocks(fin)
+        rows_visible, rows_context = self._kv_rows(fin)
+        if self._moe_acc is not None:  # what the last windows left behind
+            self._moe_tot += np.asarray(self._moe_acc, np.float64)
+            self._moe_acc = None
+        moe = self._moe_report() or {}
+        tracer = get_tracer()
+        if tracer.enabled:
+            tracer.counter("kv.rows_visible", float(rows_visible))
+            tracer.counter("kv.rows_context", float(rows_context))
+            for g, n in self._pages_peak.items():
+                tracer.counter(f"kv.pages_held.{g}", float(n))
+            for k in ("rows", "experts_touched", "load_max_over_mean"):
+                if moe.get(k) is not None:
+                    tracer.counter(f"moe.{k}", float(moe[k]))
         rep = ServeReport(
             wall_s=wall,
             new_tokens=new_tokens,
@@ -1380,6 +1554,14 @@ class ServeEngine:
             attn_blocks_full_table=blocks_full_table,
             attn_interpret=self.attn_interpret,
             device=self.device_info(),
+            kv_rows_visible=rows_visible,
+            kv_rows_context=rows_context,
+            kv_pages_held_full=self._pages_peak["full"],
+            kv_pages_held_window=self._pages_peak["window"],
+            moe_rows=moe.get("rows"),
+            moe_experts_touched=moe.get("experts_touched"),
+            moe_load_max_over_mean=moe.get("load_max_over_mean"),
+            moe_layers=self._moe_layers,
         )
         self.metrics.close()
         if self.spans is not None and self._owns_spans:

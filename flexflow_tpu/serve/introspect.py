@@ -308,14 +308,21 @@ class StatusServer:
 
     @staticmethod
     def _engine_pool(eng: Any) -> Dict[str, Any]:
-        return {
+        out = {
             "pool_shape": list(eng.kv.cache_k.shape),
             "kv_dtype": eng.kv.kv_dtype,
             "block_size": eng.kv.block_size,
             "attn_kernel": eng.attn_kernel,
             "pool_relayouts": eng.pool_relayouts(),
             "attn_walk": eng.attn_walk(),
+            "hbm_bytes": eng.kv.hbm_bytes(),
         }
+        if eng.kv.window_layers:
+            # the window group: its own pool, a ring a slot
+            out["window_pool_shape"] = list(eng.kv.win_k.shape)
+            out["window"] = eng.kv.window
+            out["ring_blocks"] = eng.kv.ring_blocks
+        return out
 
     def poolz(self) -> Dict[str, Any]:
         t = self._target
@@ -329,6 +336,12 @@ class StatusServer:
                 }}
             else:
                 self._poolz = self._engine_pool(t)
+        # geometry is fixed at build; what the groups hold and the expert
+        # layers' counters are the newest window's (the /statusz snapshot)
+        snap = getattr(t, "status_snapshot", None)
+        if isinstance(snap, dict) and "kv_pages_held" in snap:
+            return dict(self._poolz, kv_pages_held=snap["kv_pages_held"],
+                        moe=snap.get("moe"))
         return self._poolz
 
     def spanz(self, n: int = 64) -> Dict[str, Any]:

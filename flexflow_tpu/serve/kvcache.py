@@ -62,6 +62,26 @@ Physical block 0 is the TRASH block: never allocated, never registered,
 it absorbs the writes of inactive decode lanes and padded prefill rows
 (their block tables are all-zero), so the jitted step needs no masking.
 
+**Two layer groups (PR 32, docs/SERVING.md "Window and full layers").**
+A model whose layers mix full and sliding-window attention keeps the two
+kinds apart.  Everything above is the FULL group: its layers keep every
+page of a request.  A WINDOW layer never looks further back than
+``window`` positions, so the window group (``window_layers`` > 0) is a
+RING a slot: ``ring_blocks = ceil((window + chunk) / block_size) + 1``
+pages in a pool of its own (``win_k`` / ``win_v``, ``(window_layers,
+(slots * ring_blocks + 1) * block_size, H * D)``), logical page ``j``
+of the slot at ring page ``j % ring_blocks``, mapped into ``win_tables``
+while the slot holds a reservation and zeroed (the group's own trash
+block 0) while it does not.  A chunk of up to ``chunk`` rows is written
+and then attended: the oldest position any of its rows can see and the
+newest it writes are less than ``ring_blocks`` pages apart, so a write
+never lands on a page still visible.  The ring cannot run out, so
+admission is decided by the full group alone; however long a request,
+a window layer holds ``ring_blocks`` pages of it.  Prefix sharing is
+declined for such a model (both groups): a prefix re-attached in the
+full group would leave the window layers without the keys their first
+rows look back to.
+
 **Quantized pools (PR 19, docs/SERVING.md "Quantized KV cache").**
 ``kv_dtype="int8" | "fp8"`` stores the pools in 1-byte elements with
 per-block symmetric scale arrays ``scale_k``/``scale_v`` of shape
@@ -191,7 +211,13 @@ class PagedKVCache:
         dtype=None,
         kv_dtype: str = "fp32",
         prefix_sharing: bool = True,
+        window_layers: int = 0,
+        window: int = 0,
+        chunk: int = 1,
     ) -> None:
+        """``num_layers`` full layers of ``heads`` K/V heads; with
+        ``window_layers`` > 0 also that many layers that see ``window``
+        positions back and are written at most ``chunk`` rows a call."""
         import jax.numpy as jnp
 
         assert block_size >= 1 and slots >= 1
@@ -200,7 +226,11 @@ class PagedKVCache:
         self.head_dim = head_dim
         self.slots = slots
         self.block_size = block_size
-        self.prefix_sharing = bool(prefix_sharing)
+        self.window_layers = int(window_layers)
+        self.window = int(window) if self.window_layers else 0
+        assert not self.window_layers or self.window >= 1
+        # declined for a model with window layers (module docstring)
+        self.prefix_sharing = bool(prefix_sharing) and not self.window_layers
         if max_blocks_per_seq is None:
             assert max_seq_len is not None, (
                 "need max_blocks_per_seq or max_seq_len"
@@ -265,6 +295,24 @@ class PagedKVCache:
         else:
             self.scale_k = None
             self.scale_v = None
+        # the window group: a ring of ``ring_blocks`` pages a slot, never
+        # more than a table (then it never wraps)
+        self.ring_blocks = 0
+        self.win_tables = self.win_k = self.win_v = None
+        if self.window_layers:
+            if self.quantized:
+                raise ValueError("a quantized pool is not built for window layers")
+            self.ring_blocks = min(
+                -(-(self.window + max(1, int(chunk))) // block_size) + 1,
+                self.max_blocks_per_seq,
+            )
+            self.win_tables = np.zeros((slots, self.ring_blocks), np.int32)
+            wshape = (
+                self.window_layers,
+                (slots * self.ring_blocks + 1) * block_size, heads * head_dim,
+            )
+            self.win_k = jnp.zeros(wshape, self.dtype)
+            self.win_v = jnp.zeros(wshape, self.dtype)
 
     # --- capacity queries --------------------------------------------------
     @property
@@ -424,7 +472,15 @@ class PagedKVCache:
         self._protected[slot] = len(shared)
         self.tables[slot, :] = 0
         self.tables[slot, :n] = blocks
+        if self.window_layers:
+            self.win_tables[slot] = self._ring(slot)
         return blocks
+
+    def _ring(self, slot: int) -> np.ndarray:
+        """The window group's pages of ``slot``: its ring, after the
+        group's trash block 0."""
+        R = self.ring_blocks
+        return 1 + slot * R + np.arange(R, dtype=np.int32)
 
     def shared_len(self, slot: int) -> int:
         """Positions of ``slot`` served by re-attached prefix blocks —
@@ -453,6 +509,8 @@ class PagedKVCache:
                 else:
                     self._free.append(b)
         self.tables[slot, :] = 0
+        if self.window_layers:
+            self.win_tables[slot] = 0
 
     def refcount(self, blk: int) -> int:
         return self._refcount.get(blk, 0)
@@ -566,8 +624,44 @@ class PagedKVCache:
             for i in range(self.num_layers):
                 payload["layers"][f"layer{i}"]["sk"] = np.asarray(sk[i])
                 payload["layers"][f"layer{i}"]["sv"] = np.asarray(sv[i])
+        if self.window_layers:
+            # a window layer's payload starts at the page of the oldest
+            # position the next row (at ``length``) can still see
+            lo = self.window_first_held(length)
+            wk, wv = self.gather_window(slot, lo, length)
+            payload["window"] = {
+                "lo": lo,
+                "layers": {
+                    f"layer{i}": {"k": wk[i], "v": wv[i]}
+                    for i in range(self.window_layers)
+                },
+            }
         self.release(slot)
         return payload
+
+    def window_first_held(self, length: int) -> int:
+        """The first position, on a page boundary, that a window layer
+        still has to hold for a request ``length`` positions in."""
+        lo = max(0, int(length) - self.window + 1)
+        return lo // self.block_size * self.block_size
+
+    def _window_rows(self, slot: int, lo: int, hi: int) -> np.ndarray:
+        """Rows of the window pool that hold ``slot``'s positions
+        ``lo .. hi - 1`` (the ring's mapping, by the slot's table)."""
+        pos = np.arange(int(lo), int(hi))
+        page = self.win_tables[slot][(pos // self.block_size) % self.ring_blocks]
+        return page * self.block_size + pos % self.block_size
+
+    def gather_window(self, slot: int, lo: int, hi: int):
+        """Host-side copy of ``slot``'s positions ``lo .. hi - 1`` in the
+        window group: ``(window_layers, H, hi - lo, D)`` keys and values
+        (at most a ring's worth are there to gather)."""
+        assert hi - lo <= self.ring_blocks * self.block_size
+        rows = self._window_rows(slot, lo, hi)
+        L, H, D = self.window_layers, self.heads, self.head_dim
+        k = np.asarray(self.win_k[:, rows]).reshape(L, -1, H, D).transpose(0, 2, 1, 3)
+        v = np.asarray(self.win_v[:, rows]).reshape(L, -1, H, D).transpose(0, 2, 1, 3)
+        return np.ascontiguousarray(k), np.ascontiguousarray(v)
 
     def restore(self, slot: int, payload: Dict[str, Any], seq_len: int,
                 prompt=None) -> int:
@@ -612,6 +706,14 @@ class PagedKVCache:
             )
         shared_pos = self.shared_len(slot)
         length = int(payload["length"])
+        if ("window" in payload) != bool(self.window_layers):
+            self.release(slot)
+            raise ValueError(
+                "KV payload and pool disagree on window layers: a payload "
+                "restores into a pool of the same layer groups"
+            )
+        if self.window_layers:
+            self._restore_window(slot, payload["window"], length)
         if length <= shared_pos:
             return shared_pos
         L, H, BS, D = (
@@ -675,6 +777,34 @@ class PagedKVCache:
             self.scale_v = self.scale_v.at[:, ids].set(jnp.asarray(sv))
         return shared_pos
 
+    def _restore_window(self, slot: int, win: Dict[str, Any], length: int) -> None:
+        import jax.numpy as jnp
+
+        L, H, D = self.window_layers, self.heads, self.head_dim
+        lo = int(win["lo"])
+        k = np.stack([np.asarray(win["layers"][f"layer{i}"]["k"]) for i in range(L)])
+        v = np.stack([np.asarray(win["layers"][f"layer{i}"]["v"]) for i in range(L)])
+        if k.shape != (L, H, length - lo, D) or v.shape != k.shape:
+            self.release(slot)
+            raise ValueError(
+                f"window payload shape {k.shape} does not match this pool "
+                f"(layers={L}, heads={H}, positions={length - lo}, head_dim={D})"
+            )
+        rows = self._window_rows(slot, lo, length)
+        k = k.transpose(0, 2, 1, 3).reshape(L, -1, H * D)
+        v = v.transpose(0, 2, 1, 3).reshape(L, -1, H * D)
+        self.win_k = self.win_k.at[:, rows].set(jnp.asarray(k, self.dtype))
+        self.win_v = self.win_v.at[:, rows].set(jnp.asarray(v, self.dtype))
+
+    def pages_held(self) -> Dict[str, int]:
+        """Pages mapped into slots' tables right now, a layer group: the
+        full group's follow the requests' budgets, the window group's are
+        ``ring_blocks`` a slot that holds a reservation."""
+        return {
+            "full": sum(len(b) for b in self._owned.values()),
+            "window": self.ring_blocks * len(self._owned) if self.window_layers else 0,
+        }
+
     # --- invariants ---------------------------------------------------------
     def check_invariants(self) -> None:
         """Every block is free, retained (refcount 0 + indexed), or
@@ -703,6 +833,13 @@ class PagedKVCache:
             assert self._block_key.get(blk) == key, "index/reverse mismatch"
         for blk in cached:
             assert blk in self._block_key, "retained block lost its key"
+        if self.window_layers:
+            for slot in range(self.slots):
+                want = self._ring(slot) if slot in self._owned else 0
+                assert (self.win_tables[slot] == want).all(), (
+                    f"slot {slot}: the window group's table is not "
+                    f"{'its ring' if slot in self._owned else 'the trash block'}"
+                )
 
     # --- device-side views -------------------------------------------------
     def _rows(self, blocks) -> np.ndarray:
@@ -765,18 +902,23 @@ class PagedKVCache:
 
     @property
     def bytes_per_token(self) -> int:
-        """HBM bytes one cached position costs across all layers (k+v
-        elements, plus the 2 float32 scales per layer when quantized) —
+        """HBM bytes one cached position costs across all layers of both
+        groups while every layer holds it (k+v elements over the K/V
+        heads, plus the 2 float32 scales per layer when quantized) —
         the ffmetrics/1 ``kv_bytes_per_token`` field."""
-        elems = 2 * self.num_layers * self.heads * self.head_dim
+        layers = self.num_layers + self.window_layers
+        elems = 2 * layers * self.heads * self.head_dim
         n = elems * self.cache_k.dtype.itemsize
         if self.quantized:
             n += 2 * self.num_layers * 4
         return n
 
     def hbm_bytes(self) -> int:
-        """Physical pool footprint (both caches + scales)."""
+        """Physical pool footprint (both caches + scales, both layer
+        groups)."""
         n = 2 * self.cache_k.size * self.cache_k.dtype.itemsize
         if self.quantized:
             n += 2 * self.scale_k.size * 4
+        if self.window_layers:
+            n += 2 * self.win_k.size * self.win_k.dtype.itemsize
         return n

@@ -5,8 +5,17 @@ All four are one forward over the paged K/V pools
 ``(L, num_blocks * block_size, H * D)``: ``G`` consecutive positions a
 lane (row ``g`` of lane ``b`` at ``start[b] + g``) are embedded, run
 through ``layers`` unrolled blocks that write the rows' K/V into the
-pools and attend over each lane's pages, and a head turns rows into a
-float32 distribution and its argmax.  They differ in rows a lane
+pools and attend over each lane's pages, and a head turns rows into
+their argmax (and, where sampling asks, a float32 distribution).  What a
+block IS comes from the decoder spec (``models/gpt_decode.py::GPTSpec``,
+read off the compiled model's layers): LayerNorm or RMSNorm, with or
+without a norm after each mixer; one head count or grouped K/V heads
+with per-head q/k norm, rotary positions by absolute position and a
+sigmoid output gate; every earlier key or a window (then the layer
+lives in the pool's WINDOW group and its walk starts at the first
+position a row still sees); GELU, gated dense or routed experts
+(``ops/moe.py``'s sorted rows and grouped matmuls, dropless, every
+expert held; rows of idle lanes and past ``n_valid`` routed nowhere).  They differ in rows a lane
 (``G`` = 1, ``P``, 1, ``k + 1``), layers run (all, or the first
 ``spec_draft_layers``), whether ``n_valid`` masks padded rows, and
 which rows reach the head.  Inactive lanes carry an all-zero table row,
@@ -19,8 +28,9 @@ program with another compile time (ROADMAP S4).
 
 What reads the programs from outside, and so may not move: the jitted
 functions' names (``jit_decode`` ... in a trace and in the compile
-cache), their arguments ``(params, ck, cv[, sk, sv], ...)`` and outputs
-``(..., ck, cv[, sk, sv])`` (analysis/capture.py, the benchmark's tests),
+cache), their arguments ``(params, ck, cv[, sk, sv][, wk, wv], ...)``
+and outputs ``(nxt, probs | None, [moe stats,] ck, cv[, sk, sv][, wk,
+wv])`` (analysis/capture.py, the benchmark's tests),
 and the paged kernel's two names — ``prefill`` calls it as
 ``%prefill.N``, the other three as ``%decode.N``, which is how the
 benchmark's ``paged_attention_roofline.*`` tells a chunk's call from a
@@ -41,7 +51,11 @@ from flexflow_tpu.models.gpt_decode import (
 )
 from flexflow_tpu.serve.kvcache import PagedKVCache, quantize_kv
 
-__all__ = ["ServePrograms", "build_serve_programs"]
+__all__ = ["ServePrograms", "build_serve_programs", "MOE_STATS"]
+
+# what a program of a model with routed experts returns after its
+# tokens, one float32 each, summed over the call's expert layers
+MOE_STATS = ("rows", "experts_touched", "load_max_over_mean", "layer_calls")
 
 
 class ServePrograms(NamedTuple):
@@ -53,6 +67,17 @@ class ServePrograms(NamedTuple):
     verify: Optional[Callable]  # (.., toks, pos0, bt) -> n, acc, cur, pos, *pools
     params_arg: Any  # what every program takes as ``params``
     donate: Tuple[int, ...]  # the pools' (and scale pools') positions
+    n_head: int  # outputs of decode / prefill in front of the pools
+
+
+def serve_pass_rows(rows: int) -> int:
+    """Sorted expert rows one pass of a serve program takes (``rows`` =
+    positions x top-k): all of a decode step's, an eighth of a prefill
+    dispatch's -- the passes are a loop of the length the device finds
+    (``ops/moe.py::_held_passes``), so a dispatch in which one lane of
+    many is mid-prompt pays for the rows it routes, not for ``slots x
+    chunk``."""
+    return rows if rows <= 8192 else -(-rows // 64) * 8
 
 
 def build_serve_programs(
@@ -63,15 +88,28 @@ def build_serve_programs(
     weight_dtype: str = "fp32",
     spec_k: int = 0,
     spec_draft_layers: int = 0,
+    return_probs: bool = True,
 ) -> ServePrograms:
-    """Jit the serve programs of a compiled ``gpt_decoder`` model over
-    the pool geometry of ``kv``.  ``attn_kernel`` is the engine's
+    """Jit the serve programs of a compiled decoder (anything
+    ``GPTSpec.from_model`` reads: ``gpt_decoder``, ``afmoe_decoder``)
+    over the pool geometry of ``kv``.  ``attn_kernel`` is the engine's
     resolved decision (``paged`` | ``gather``); ``draft`` and ``verify``
-    are built only with ``spec_k``.  Nothing is compiled here: each
+    are built only with ``spec_k``; ``return_probs`` false (greedy
+    decoding) leaves the float32 distribution out of the outputs
+    (``None`` in its place).  Nothing is compiled here: each
     program traces at its first call."""
     import jax
     import jax.numpy as jnp
 
+    from flexflow_tpu.ops.attention import rotate_half_rope_at
+    from flexflow_tpu.ops.moe import (
+        gated_ffn,
+        held_experts_part,
+        route_top_k,
+        router_rule,
+        shared_expert_part,
+    )
+    from flexflow_tpu.ops.norm import rms_norm_f32, rms_norm_zero_centered
     from flexflow_tpu.ops.pallas.paged_attention import (
         paged_decode_attention,
         paged_kv_write,
@@ -80,12 +118,36 @@ def build_serve_programs(
 
     spec = GPTSpec.from_model(model)
     L, H, D = spec.num_layers, spec.heads, spec.head_dim
+    KVH = spec.kv_heads
+    rep = H // KVH
     B, MB, BS = kv.slots, kv.max_blocks_per_seq, kv.block_size
     SV = MB * BS  # virtual (paged) sequence length
     S_pos = spec.seq  # pos_embed table height
-    has_bias, eps = spec.has_bias, spec.eps
+    eps = spec.eps
     scale = 1.0 / math.sqrt(D)
-    cast = make_cast(jnp, model.executor.compute_dtype)
+    cdt = model.executor.compute_dtype  # matmul operands, K/V, q
+    cast = make_cast(jnp, cdt)
+    # the residual stream: the compute dtype for gpt_decoder models (their
+    # programs are pinned bit for bit), float32 for the rest -- a stream
+    # of |x| up to sqrt(hidden) loses three digits at every bfloat16 add,
+    # and a routed layer turns that into another choice of experts.  The
+    # norms read it in float32 and hand the matmuls the compute dtype
+    rdt = cdt if spec.is_gpt else jnp.float32
+    # which pool a layer's K/V live in, and where: a window layer in the
+    # window group's ring, every other in the full group
+    where, n_full, n_win = [], 0, 0
+    for ls in spec.layers:
+        if ls.window:
+            where.append(("window", n_win))
+            n_win += 1
+        else:
+            where.append(("full", n_full))
+            n_full += 1
+    assert (n_full, n_win) == (kv.num_layers, kv.window_layers), (
+        "the pool's layer groups are not the model's",
+        (n_full, n_win), (kv.num_layers, kv.window_layers),
+    )
+    R = kv.ring_blocks
     # quantized-pool trace-time switch: with ``quant`` the programs
     # take/donate/return the two scale pools beside the K/V pools and
     # every write runs the shared quantize_kv rule
@@ -95,6 +157,10 @@ def build_serve_programs(
     # pair and every program folds the scales back first thing — the
     # jitted signature changes, the math after the dequant edge does not
     wq = weight_dtype == "int8"
+    assert spec.is_gpt or not (wq or quant or spec_k), (
+        "int8 weights, a quantized pool and speculation are built for "
+        "gpt_decoder-shaped models (ServeEngine refuses the rest by name)"
+    )
     # the programs index params by LAYER name; a model whose blocks the
     # executor scan-stacked (--stack-blocks, any chain of depth >= 4
     # under "auto") stores one (depth, ...) array per template layer.
@@ -106,6 +172,11 @@ def build_serve_programs(
         params_arg = quantize_weights_int8(jnp, unstack(model.executor.params))
     else:
         params_arg = model.executor.params
+    # weights an op declares float32 (the router) stay so in a program
+    keep_f32 = [
+        (ls.ffn[0], w) for ls in spec.layers if ls.ffn_kind == "moe"
+        for w in ("router", "router_bias")
+    ]
 
     def prep_params(params):
         if wq:
@@ -113,10 +184,21 @@ def build_serve_programs(
             params = dequantize_weights_int8(jax, jnp, qp, qs)
         else:
             params = unstack(params)
-        return jax.tree.map(cast, params)
+        out = jax.tree.map(cast, params)
+        for lname, w in keep_f32:
+            if w in params[lname]:
+                out[lname][w] = params[lname][w]
+        return out
 
-    def ln(p, x):
-        return layer_norm(jax, jnp, p, x, eps)
+    if spec.norm == "layer":
+        def norm(p, x, dtype=cdt):
+            return layer_norm(jax, jnp, p, x, eps).astype(dtype)
+    elif spec.norm == "rms":
+        def norm(p, x, dtype=cdt):  # statistics in float32
+            return rms_norm_f32(x, p["scale"], eps).astype(dtype)
+    else:
+        def norm(p, x, dtype=cdt):
+            return rms_norm_zero_centered(x, p["weight"], eps).astype(dtype)
 
     def attend(q, keys, vals, mask):
         # q (..., H, D) vs keys/vals (..., H, SV, D); mul+reduce
@@ -128,7 +210,7 @@ def build_serve_programs(
         w = jax.nn.softmax(scores, axis=-1)
         return (w[..., None] * vals).sum(-2)
 
-    def write_kv(ck, cv, sk, sv, i, k, v, start, bt, n_valid):
+    def write_kv(ck, cv, sk, sv, i, k, v, start, bt, n_valid, ring=False):
         # THE write of a chunk's new K/V into layer i of the pools: k / v
         # are (B, G, H, D), row g of lane b sits at position
         # start[b] + g, and rows at or past n_valid[b] (the padded tail
@@ -143,13 +225,13 @@ def build_serve_programs(
         # per-position scale; the (L, NB, BS) scale pools are small and
         # scatter on adjacent index dimensions either way.  A pool row
         # is one position, all heads: block ``blk`` row ``off`` is pool
-        # row ``blk * BS + off``.
+        # row ``blk * BS + off``.  ``ring``: the window group's table,
+        # logical page j at entry j % R.
         G = k.shape[1]
         if quant or not paged:
             pos = start[:, None] + jnp.arange(G)[None, :]
-            blk = bt[
-                jnp.arange(B)[:, None], jnp.clip(pos // BS, 0, MB - 1)
-            ]
+            page = (pos // BS) % R if ring else jnp.clip(pos // BS, 0, MB - 1)
+            blk = bt[jnp.arange(B)[:, None], page]
             off = jnp.clip(pos % BS, 0, BS - 1)
             if n_valid is not None:
                 valid = jnp.arange(G)[None, :] < n_valid[:, None]
@@ -162,11 +244,11 @@ def build_serve_programs(
             sv = sv.at[i, blk, off].set(vsc)
         if paged:
             ck, cv = paged_kv_write(
-                ck, cv, i, k, v, start, bt, n_valid, block_size=BS
+                ck, cv, i, k, v, start, bt, n_valid, block_size=BS, ring=ring,
             )
         else:
-            ck = ck.at[i, blk * BS + off].set(k.reshape(B, G, H * D))
-            cv = cv.at[i, blk * BS + off].set(v.reshape(B, G, H * D))
+            ck = ck.at[i, blk * BS + off].set(k.reshape(B, G, KVH * D))
+            cv = cv.at[i, blk * BS + off].set(v.reshape(B, G, KVH * D))
         return ck, cv, sk, sv
 
     def gather_kv(ck, cv, sk, sv, i, bt):
@@ -175,90 +257,206 @@ def build_serve_programs(
         # logical position order — a buffer at the full virtual
         # length, which is what the paged kernel exists to delete
         def lanes(pool, sc):
-            x = pool[i].reshape(-1, BS, H, D)[bt]
+            x = pool[i].reshape(-1, BS, KVH, D)[bt]
             if quant:
                 # the kernel's exact dequant rule, pre-gather
                 x = x.astype(jnp.float32) * sc[i][bt][..., None, None]
-            return x.transpose(0, 3, 1, 2, 4).reshape(B, H, SV, D)
+            return x.transpose(0, 3, 1, 2, 4).reshape(B, KVH, -1, D)
 
         return lanes(ck, sk), lanes(cv, sv)
 
+    def ring_positions(last):
+        # the position each row of a lane's ring holds once positions up
+        # to ``last`` (B,) are written: the newest p <= last with
+        # (p // BS) % R == its page and p % BS == its offset (< 0: none yet)
+        r = jnp.arange(R * BS)[None, :]
+        page_now = (last // BS)[:, None]
+        page = page_now - (page_now - r // BS) % R
+        p = page * BS + r % BS
+        return jnp.where(p > last[:, None], p - R * BS, p)
+
     def embed(params, toks, pos):
         # toks / pos (B, G) -> rows (B * G, hidden)
-        x = params["tok_embed"]["kernel"][toks]
-        x = x + params["pos_embed"]["value"][jnp.clip(pos, 0, S_pos - 1)]
+        x = params[spec.embed]["kernel"][toks]
+        if spec.pos_embed is not None:
+            x = x + params[spec.pos_embed]["value"][jnp.clip(pos, 0, S_pos - 1)]
+        x = x.astype(rdt)
+        if spec.embed_scale != 1.0:
+            x = x * jnp.asarray(spec.embed_scale, x.dtype)
         return x.reshape(-1, x.shape[-1])
 
-    def block(i, params, x, pools, start, bt, n_valid, G, attn):
-        # layer i over rows x (B * G, hidden)
-        p_at = params[f"dec{i}_attn"]
-        h = ln(params[f"dec{i}_ln0"], x)
-        q = h @ p_at["wq"]
-        k = h @ p_at["wk"]
-        v = h @ p_at["wv"]
-        if has_bias:
-            q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
-        q = q.reshape(B, G, H, D)
-        # write all G rows, THEN attend: row g's mask reaches rows 0..g
-        # of this same program, freshly written — and under prefix
-        # sharing a chunk never writes a still-shared block (commit
-        # happens post-chunk, CoW-audited by serve_cow)
-        ck, cv, sk, sv = write_kv(
-            *pools, i, k.reshape(B, G, H, D), v.reshape(B, G, H, D),
-            start, bt, n_valid,
-        )
-        if paged:
-            # fused paged attention (docs/PERF.md), one call for all G
-            # rows: the kernel walks each lane's block table in SMEM and
-            # its visible-page clamp fetches ceil((start + G) / BS) pages
-            # a lane — no dense gather, no (H, SV, D) buffer in the
-            # lowered program (ffcheck ``paged_attn``).  Same mask rule
-            # as ``attend``, online softmax in f32: it agrees to a
-            # float32 tolerance and the greedy argmax streams are
-            # identical (pinned by tests/test_paged_attention.py)
-            o = attn(
-                q, ck, cv, start, bt, scale=scale,
-                scale_k=sk, scale_v=sv, layer=i, block_size=BS,
+    def project(ls, p_at, h, pos, G):
+        # rows h (B * G, hidden) -> q (B, G, H, D), k, v (B, G, KVH, D),
+        # the output gate (B * G, H * D) or None
+        if ls.attn_kind == "mha":
+            q = h @ p_at["wq"]
+            k = h @ p_at["wk"]
+            v = h @ p_at["wv"]
+            if ls.has_bias:
+                q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
+            return (q.reshape(B, G, H, D), k.reshape(B, G, KVH, D),
+                    v.reshape(B, G, KVH, D), None)
+        # gated: per head the query's columns, then the gate's
+        qg = (h @ p_at["wq"]).reshape(B, G, H, 2 * D)
+        q, gate = qg[..., :D], qg[..., D:].reshape(B * G, H * D)
+        k = (h @ p_at["wk"]).reshape(B, G, KVH, D)
+        v = (h @ p_at["wv"]).reshape(B, G, KVH, D)
+        qk_norm = rms_norm_zero_centered if ls.qk_norm_zero_centered else rms_norm_f32
+        q = qk_norm(q, p_at["q_norm"], ls.qk_eps)
+        k = qk_norm(k, p_at["k_norm"], ls.qk_eps)
+        if ls.rotary_dim:
+            q = rotate_half_rope_at(q, pos, ls.rotary_dim, ls.rope_theta)
+            k = rotate_half_rope_at(k, pos, ls.rotary_dim, ls.rope_theta)
+        return q.astype(h.dtype), k.astype(h.dtype), v, gate
+
+    def experts(ls, p, h32, valid):
+        # the routed block over rows h32 (T, hidden), the normed stream in
+        # float32: the router reads it as it is (an expert choice that
+        # flips against the reference moves a whole position's output),
+        # the experts in the compute dtype; ``valid`` (T,): rows of idle
+        # lanes and past n_valid are routed to no expert
+        a = ls.moe
+        n, k = a["n_experts"], a["top_k"]
+        h = h32.astype(cdt)
+        with jax.named_scope("ff.moe.route"):
+            w, idx = route_top_k(h32, p["router"], k, **router_rule(a, p))
+            idx = jnp.where(valid[:, None], idx, n)
+        with jax.named_scope("ff.moe.experts"):
+            out, counts, _, _ = held_experts_part(
+                h, w, idx, 0, serve_pass_rows(h.shape[0] * k),
+                p["w_gate"], p["w_up"], p["w_down"],
             )
+        if a["shared_hidden"]:
+            with jax.named_scope("ff.moe.shared"):
+                out = out + shared_expert_part(a, p, h)
+        load = counts.astype(jnp.float32)
+        rows = jnp.sum(load)
+        stats = jnp.stack([
+            rows, jnp.sum(load > 0).astype(jnp.float32),
+            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+            (rows > 0).astype(jnp.float32),
+        ])
+        return out, stats  # float32
+
+    def block(i, params, x, pools, stats, start, bts, n_valid, G, attn):
+        # layer i over rows x (B * G, hidden)
+        ls = spec.layers[i]
+        group, gi = where[i]
+        ring = group == "window"
+        bt = bts[1] if ring else bts[0]
+        ck, cv, sk, sv, wk, wv = pools
+        pk, pv = (wk, wv) if ring else (ck, cv)
+        p_at = params[ls.attn]
+        pos = start[:, None] + jnp.arange(G)[None, :]
+        with jax.named_scope("ff.attn_window" if ring else "ff.attn_full"):
+            h = norm(params[ls.norm_in], x)
+            q, k, v, gate = project(ls, p_at, h, pos, G)
+            # write all G rows, THEN attend: row g's mask reaches rows 0..g
+            # of this same program, freshly written — and under prefix
+            # sharing a chunk never writes a still-shared block (commit
+            # happens post-chunk, CoW-audited by serve_cow)
+            pk, pv, sk, sv = write_kv(
+                pk, pv, sk, sv, gi, k, v, start, bt, n_valid, ring=ring,
+            )
+            if paged:
+                # fused paged attention (docs/PERF.md), one call for all G
+                # rows: the kernel walks each lane's block table in SMEM and
+                # its visible-page clamp fetches ceil((start + G) / BS) pages
+                # a lane (from the first page a row still sees on, behind a
+                # window) — no dense gather, no (H, SV, D) buffer in the
+                # lowered program (ffcheck ``paged_attn``).  Same mask rule
+                # as ``attend``, online softmax in f32: it agrees to a
+                # float32 tolerance and the greedy argmax streams are
+                # identical (pinned by tests/test_paged_attention.py)
+                o = attn(
+                    q, pk, pv, start, bt, scale=scale,
+                    scale_k=sk, scale_v=sv, layer=gi, block_size=BS,
+                    window=ls.window,
+                )
+            else:
+                keys, vals = gather_kv(pk, pv, sk, sv, gi, bt)
+                if ring:
+                    k_pos = ring_positions(start + G - 1)[:, None, :]  # (B, 1, R*BS)
+                    mask = (
+                        (k_pos <= pos[..., None]) & (k_pos >= 0)
+                        & (k_pos > pos[..., None] - ls.window)
+                    )[:, :, None, :]
+                else:
+                    mask = (
+                        jnp.arange(SV)[None, None, :] <= pos[..., None]
+                    )[:, :, None, :]  # (B, G, 1, SV)
+                if rep > 1:
+                    keys = jnp.repeat(keys, rep, axis=1)
+                    vals = jnp.repeat(vals, rep, axis=1)
+                o = attend(q, keys[:, None], vals[:, None], mask)
+            o = o.reshape(B * G, H * D)
+            if gate is not None:
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            o = o @ p_at["wo"]
+            if ls.has_bias:
+                o = o + p_at["bo"]
+            if ls.norm_post_attn is not None:
+                o = norm(params[ls.norm_post_attn], o, rdt)
+        pools = (ck, cv, sk, sv, pk, pv) if ring else (pk, pv, sk, sv, wk, wv)
+        x = x + o.astype(rdt)
+        moe = ls.ffn_kind == "moe"
+        h = norm(params[ls.norm_pre_ffn], x, jnp.float32 if moe else cdt)
+        if ls.ffn_kind == "gelu":
+            p0, p1 = params[ls.ffn[0]], params[ls.ffn[1]]
+            f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
+            f = f @ p1["kernel"] + p1["bias"]
+        elif ls.ffn_kind == "gated":
+            p0 = params[ls.ffn[0]]
+            with jax.named_scope("ff.ffn_dense"):
+                f = gated_ffn(h, p0["w_gate"], p0["w_up"], p0["w_down"])
         else:
-            pos = start[:, None] + jnp.arange(G)[None, :]
-            mask = (
-                jnp.arange(SV)[None, None, :] <= pos[..., None]
-            )[:, :, None, :]  # (B, G, 1, SV)
-            keys, vals = gather_kv(ck, cv, sk, sv, i, bt)
-            o = attend(q, keys[:, None], vals[:, None], mask)
-        o = o.reshape(B * G, H * D) @ p_at["wo"]
-        if has_bias:
-            o = o + p_at["bo"]
-        x = x + o
-        h = ln(params[f"dec{i}_ln1"], x)
-        p0, p1 = params[f"dec{i}_ff0"], params[f"dec{i}_ff1"]
-        f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
-        f = f @ p1["kernel"] + p1["bias"]
-        return x + f, (ck, cv, sk, sv)
+            if n_valid is None:
+                # a live lane holds a reservation: its first page is not
+                # the trash block
+                valid = jnp.broadcast_to(bts[0][:, :1] > 0, (B, G))
+            else:
+                valid = jnp.arange(G)[None, :] < n_valid[:, None]
+            f, st = experts(ls, params[ls.ffn[0]], h, valid.reshape(-1))
+            stats = stats + st
+        if ls.norm_post_ffn is not None:
+            f = norm(params[ls.norm_post_ffn], f, rdt)
+        return x + f.astype(rdt), pools, stats
 
     def trunk(params, pools, toks, start, bt, *, layers, n_valid=None, attn):
         # toks (B, G) int32, start / n_valid (B,), bt (B, MB) block
-        # tables -> rows (B * G, hidden) after ``layers`` blocks, pools
+        # tables — with a window group the pair (bt, ring tables (B, R))
+        # -> rows (B * G, hidden) after ``layers`` blocks, pools, stats
         G = toks.shape[1]
+        bts = bt if isinstance(bt, (tuple, list)) else (bt, None)
         x = embed(params, toks, start[:, None] + jnp.arange(G)[None, :])
+        stats = jnp.zeros((len(MOE_STATS),), jnp.float32)
         for i in range(layers):
-            x, pools = block(i, params, x, pools, start, bt, n_valid, G, attn)
+            x, pools, stats = block(
+                i, params, x, pools, stats, start, bts, n_valid, G, attn
+            )
         # same boundary as the dense session
-        return jax.lax.optimization_barrier(x), pools
+        return jax.lax.optimization_barrier(x), pools, stats
 
-    def head(params, rows):
-        x = ln(params["final_ln"], rows)
-        logits = x @ params["lm_head"]["kernel"]
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        return jnp.argmax(probs, axis=-1).astype(jnp.int32), probs
+    def head(params, rows, stats=None):
+        x = norm(params[spec.final_norm], rows)
+        logits = (x @ params[spec.head]["kernel"]).astype(jnp.float32)
+        if return_probs:
+            probs = jax.nn.softmax(logits, axis=-1)
+            nxt = jnp.argmax(probs, axis=-1).astype(jnp.int32)
+        else:
+            # greedy: the argmax is all the host needs, and a
+            # (slots, vocab) float32 array does not leave the program
+            probs, nxt = None, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if spec.has_moe and stats is not None:
+            return nxt, probs, stats
+        return nxt, probs
 
     def decode(params, pools, tok, pos, bt, layers=L):
-        x, pools = trunk(
+        x, pools, stats = trunk(
             params, pools, tok[:, None], pos, bt,
             layers=layers, attn=paged_decode_attention,
         )
-        return head(params, x), pools
+        return head(params, x, stats), pools
 
     def prefill(params, pools, toks, start, n_valid, bt):
         # ALL mid-prefill slots' chunks in ONE dispatch (r20): toks
@@ -268,14 +466,14 @@ def build_serve_programs(
         # weight-streaming win: the window streams the decode weights
         # ONCE per chunk-batch instead of once per slot.
         P = toks.shape[1]
-        x, pools = trunk(
+        x, pools, stats = trunk(
             params, pools, toks, start, bt,
             layers=L, n_valid=n_valid, attn=paged_prefill_attention,
         )
         # distribution after each lane's LAST VALID row (layer norm
         # is per-row, so select-then-ln == ln-then-select)
         last = jnp.clip(n_valid - 1, 0, P - 1)
-        return head(params, x.reshape(B, P, -1)[jnp.arange(B), last]), pools
+        return head(params, x.reshape(B, P, -1)[jnp.arange(B), last], stats), pools
 
     # --- speculative decoding (docs/SERVING.md): the chain layout makes
     # a depth-Ld draft model a SLICE of the params (layers 0..Ld-1 plus
@@ -289,16 +487,16 @@ def build_serve_programs(
         # rejected-position K/V this writes is rewritten by whichever
         # program next processes those positions before any row's causal
         # mask can expose it (see SERVING.md)
-        (nxt, _), pools = decode(
+        out, pools = decode(
             params, pools, tok, pos, bt, layers=spec_draft_layers
         )
-        return (nxt,), pools
+        return (out[0],), pools
 
     def verify(params, pools, toks, pos0, bt):
         # toks (B, W): [current, draft_1..draft_k]; row j of slot b sits
         # at position pos0[b] + j, and its argmax is the full model's
         # decode step at that position, bit for bit
-        x, pools = trunk(
+        x, pools, _ = trunk(
             params, pools, toks, pos0, bt,
             layers=L, attn=paged_decode_attention,
         )
@@ -311,17 +509,21 @@ def build_serve_programs(
         next_cur = n[jnp.arange(B), acc]  # the first token NOT yet fed
         return (n, acc, next_cur, pos0 + acc + 1), pools
 
-    n_pools = 4 if quant else 2
+    # which of (ck, cv, sk, sv, wk, wv) this engine's programs thread
+    have = (True, True, quant, quant, bool(n_win), bool(n_win))
+    n_pools = sum(have)
     donate = tuple(range(1, 1 + n_pools))
 
     def program(body):
         # the jitted signature of every program: a quantized pool threads
-        # its two scale pools right after the K/V pools, donated and
-        # returned with them
+        # its two scale pools right after the K/V pools, a model with
+        # window layers its window group's pools after those; all donated
+        # and returned in that order
         def run(params, *args):
-            pools = args[:n_pools] + (None,) * (4 - n_pools)
+            given = iter(args[:n_pools])
+            pools = tuple(next(given) if h else None for h in have)
             outs, pools = body(prep_params(params), pools, *args[n_pools:])
-            return (*outs, *pools[:n_pools])
+            return (*outs, *(x for x, h in zip(pools, have) if h))
 
         run.__name__ = run.__qualname__ = body.__name__
         return jax.jit(run, donate_argnums=donate)
@@ -333,4 +535,5 @@ def build_serve_programs(
         verify=program(verify) if spec_k else None,
         params_arg=params_arg,
         donate=donate,
+        n_head=3 if spec.has_moe else 2,
     )

@@ -97,6 +97,14 @@ def flatten_requests(
             # float8 as raw void bytes, losing the dtype — the
             # kv_dtype meta key is what views them back on decode.
             kv_dtype = kv.get("kv_dtype")
+            if "window" in kv:
+                # refused rather than dropped: a frame without it would
+                # restore a window layer with no keys to look back to
+                raise HandoffError(
+                    "ffdrain/1 and ffkv/1 do not carry the window group's "
+                    "payload of a model with window layers (docs/SERVING.md): "
+                    "its spills restore in memory only"
+                )
             for lname, d in kv["layers"].items():
                 k, v = np.asarray(d["k"]), np.asarray(d["v"])
                 if kv_dtype == "fp8":

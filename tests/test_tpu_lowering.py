@@ -257,3 +257,54 @@ def test_grouped_expert_matmul_and_chunk_solve_lower_for_tpu():
         return jnp.sum(la.gated_delta_rule_chunked(q, k, v, g, beta)[0])
 
     _lower_for_tpu(jax.grad(rule, argnums=(0, 1, 2, 3, 4)), qk, qk, qk, g, g)
+
+
+# Trinity-Mini's serving geometry (ISSUE 32): 32 query / 4 K/V heads of
+# 128, a chunk of 256 rows in tiles of 16 positions, the full group's
+# table (528 pages of 16 for 8,448 positions) and the window group's ring
+# (145 pages: window 2,048 + chunk 256, and one)
+TQH, TKV, TD, TMB, TRING, TWIN = 32, 4, 128, 528, 145, 2048
+
+
+@pytest.mark.parametrize("G", [1, 256])
+@pytest.mark.parametrize("window,mb", [(0, TMB), (TWIN, TRING)])
+def test_grouped_head_attention_lowers_for_tpu(G, window, mb):
+    slots = 4
+    n = slots * mb + 1
+    entry = pa.paged_prefill_attention if G > 1 else pa.paged_decode_attention
+    dt = jnp.bfloat16
+
+    def fn(q, pk, pv, pos, bt):
+        return entry(q, pk, pv, pos, bt, layer=jnp.int32(1), block_size=BS, window=window)
+
+    _lower_for_tpu(
+        fn,
+        jax.ShapeDtypeStruct((slots, G, TQH, TD), dt),
+        jax.ShapeDtypeStruct((2, n * BS, TKV * TD), dt),
+        jax.ShapeDtypeStruct((2, n * BS, TKV * TD), dt),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots, mb), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("G", [1, 256])
+def test_page_write_through_a_ring_lowers_for_tpu(G):
+    slots = 4
+    n = slots * TRING + 1
+    dt = jnp.bfloat16
+
+    def fn(pk, pv, k, v, start, bt, n_valid):
+        return pa.paged_kv_write(
+            pk, pv, jnp.int32(1), k, v, start, bt, n_valid, block_size=BS, ring=True
+        )
+
+    _lower_for_tpu(
+        fn,
+        jax.ShapeDtypeStruct((2, n * BS, TKV * TD), dt),
+        jax.ShapeDtypeStruct((2, n * BS, TKV * TD), dt),
+        jax.ShapeDtypeStruct((slots, G, TKV, TD), dt),
+        jax.ShapeDtypeStruct((slots, G, TKV, TD), dt),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots, TRING), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+    )

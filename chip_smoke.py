@@ -15,8 +15,9 @@ what comes out:
                 once more with an int8 pool (pages of 32) and
                 speculation (k = 3).  Both engines' compiled decode and
                 prefill programs must hold no whole-pool copy
-                (``ServeEngine.pool_relayouts() == 0``); the paged
-                kernel's walk (``attn_walk()``) is printed beside it.
+                (``ServeEngine.pool_relayouts() == 0``) and no convert
+                of a float32 weight (``weight_casts() == 0``); the paged
+                kernel's walk (``attn_walk()``) is printed beside them.
 
 On a host with several chips the trainer also runs under the default
 all-devices mesh and under the searched strategy, asserts where every
@@ -649,8 +650,18 @@ def serve_gpt2(
         f"and prefill programs (pool {built[0].kv.cache_k.shape} "
         f"{built[0].kv.cache_k.dtype})",
     )
+    casts = built[0].weight_casts()
+    check(
+        casts == 0 or built[0].weight_dtype == "int8",
+        f"{casts} converts of a float32 weight to "
+        f"{built[0].model.executor.compute_dtype} in the compiled decode and "
+        "prefill programs: the weights were not handed over cast",
+    )
     walk = built[0].attn_walk()
-    info(f"serve: pool_relayouts {relayouts}, attn_walk {walk}")
+    info(
+        f"serve: pool_relayouts {relayouts}, weight_casts {casts}, "
+        f"attn_walk {walk}"
+    )
     check(
         s["prefill_chunks"] > s["prefill_dispatches"] > 0,
         "prefill chunks were not batched over slots",
@@ -682,6 +693,7 @@ def serve_gpt2(
         )},
         "pool_shape": list(built[0].kv.cache_k.shape),
         "pool_relayouts": relayouts,
+        "weight_casts": casts,
         "attn_walk": walk,
     }
 

@@ -264,8 +264,12 @@ class GPTSpec:
 def make_cast(jnp, dt):
     """Mixed-precision rule shared by every decode/prefill program
     (mirrors ``FFConfig.compute_dtype`` in the executor): float32 master
-    params cast at use, caches/activations in the compute dtype,
-    probabilities back in float32."""
+    params become the compute dtype, caches/activations are in the
+    compute dtype, probabilities back in float32.  WHERE the rule runs:
+    the serve programs' weights are cast with it once, when the programs
+    are built (``serve/programs.py::weights_as_consumed``), and inside
+    a program it finds nothing left to cast; :class:`GPTDecodeSession`
+    below still applies it at use, inside every call (ROADMAP S4)."""
     mixed = dt != jnp.float32
 
     def cast(x):

@@ -94,6 +94,10 @@ class Executor:
         # what init_params makes float32-declared weights in
         # (FFConfig.param_dtype: weights at rest)
         self.param_dtype = jnp.dtype(param_dtype)
+        # the serve programs' once-cast copies of float32 leaves, by the
+        # source array's identity (serve/programs.py::weights_as_consumed):
+        # every engine over this executor shares them
+        self.serve_cast: Dict[Tuple[str, str], Tuple[Any, Any]] = {}
         # ZeRO-1: optimizer moments sharded over the data axis (memory /dp);
         # GSPMD turns the update into slice-update + all-gather of the
         # param delta — a capability the reference lacks entirely (its

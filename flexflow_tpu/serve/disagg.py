@@ -444,6 +444,7 @@ class DisaggregatedCluster:
             # pools stamp spans on ONE run-relative timeline
             self.spans.set_base(t0)
         for eng in (self.prefill, self.decode):
+            eng._refresh_weights()
             eng._t0 = t0
             eng.windows = eng.decode_steps = eng.prefill_chunks = 0
             eng.peak_active = 0

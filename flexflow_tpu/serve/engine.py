@@ -48,7 +48,10 @@ from flexflow_tpu.obs import (
 )
 from flexflow_tpu.runtime.faults import get_fault_plan
 from flexflow_tpu.serve.kvcache import PagedKVCache, kv_pool_dtype
-from flexflow_tpu.serve.programs import build_serve_programs
+from flexflow_tpu.serve.programs import (
+    build_serve_programs,
+    weights_as_consumed,
+)
 from flexflow_tpu.serve.scheduler import (
     ContinuousBatchingScheduler,
     Request,
@@ -60,6 +63,7 @@ __all__ = [
     "ServeReport",
     "UnsupportedServeConfig",
     "count_pool_relayouts",
+    "count_weight_casts",
     "load_drain",
     "save_drain",
 ]
@@ -140,6 +144,78 @@ def count_pool_relayouts(hlo_text: str, pool_nbytes: int) -> int:
         dims = [int(d) for d in made.group(2).split(",") if d]
         n += math.prod(dims) * int(made.group(1)) // 8 == pool_nbytes
     return n
+
+
+_HLO_COMPUTATION = re.compile(r"^(ENTRY )?%(\S+) \(.*\{$")
+_HLO_INSTR = re.compile(
+    r"^\s*(ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w-]+)\(([^)]*)\)(.*)$"
+)
+
+
+def count_weight_casts(hlo_text: str, weight_shapes, dtype) -> int:
+    """How many ``convert`` operations of a compiled program's text
+    (fused ones included) take a float32 weight -- an entry parameter of
+    one of ``weight_shapes``, whole or as any operation over weights
+    alone leaves it (a slice, a bitcast, the compiler's staging copies),
+    followed into and out of the fusions it is handed to -- and make it
+    ``dtype``: the compute-dtype copy that a program handed
+    float32 weights writes at EVERY call before it multiplies anything
+    (PERF.md, PR 33).  The operand is followed by name, not recognised
+    by its shape alone: a chunk's normed rows are float32 ``(slots x
+    chunk, hidden)`` too, and are not counted."""
+    import jax.numpy as jnp
+
+    to = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}[
+        jnp.dtype(dtype).name
+    ]
+    shapes = {",".join(str(int(d)) for d in s) for s in weight_shapes}
+    comps: Dict[str, list] = {}
+    entry = cur = None
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+            continue
+        m = _HLO_INSTR.match(line)
+        if m is not None and cur is not None:
+            root, name, made, op, args, attrs = m.groups()
+            called = re.search(r"calls=%([\w.-]+)", attrs)
+            cur.append((
+                bool(root), name, made, op, re.findall(r"%[\w.-]+", args)
+                or [args], called and called.group(1),
+            ))
+
+    def walk(comp, weights_in):
+        # (casts counted in ``comp``, whether its root is a weight still);
+        # ``weights_in``: which of its parameters are weights, by number
+        n, weight, root_is_weight = 0, set(), False
+        for root, name, made, op, args, called in comps.get(comp, ()):
+            if op == "parameter" and weights_in is None:
+                # the entry computation's: by dtype and shape
+                dims = re.match(r"f32\[([\d,]*)\]", made)
+                is_w = dims is not None and dims.group(1) in shapes
+            elif op == "parameter":
+                is_w = int(args[0]) in weights_in
+            elif op in ("fusion", "call") and called:
+                inner, is_w = walk(
+                    called, {i for i, a in enumerate(args) if a in weight}
+                )
+                n += inner
+            elif op == "convert":
+                is_w = False
+                n += args[0] in weight and made.startswith(to + "[")
+            else:
+                # made of weights alone: a weight moved, cut, staged in
+                # faster memory or put together again (bitcast, slice,
+                # copy-start / -done, slice-done, ConcatBitcast, ...)
+                is_w = all(a in weight for a in args)
+            if is_w:
+                weight.add(name)
+                root_is_weight |= root
+        return n, root_is_weight
+
+    return walk(entry, None)[0] if entry else 0
 
 
 class UnsupportedServeConfig(ValueError):
@@ -613,21 +689,45 @@ class ServeEngine:
         and slow, and this is where it shows.  Read on demand
         (``chip_smoke.py``, the status server's ``/poolz``), never at
         build: it lowers and compiles both programs a second time."""
+        pool = self._kvs()[0]
+        return sum(
+            count_pool_relayouts(t, pool.size * pool.dtype.itemsize)
+            for t in self._program_texts(self._params_arg)
+        )
+
+    def weight_casts(self) -> int:
+        """Operations in the compiled decode and prefill programs that
+        convert a float32 weight to the compute dtype
+        (:func:`count_weight_casts`).  The programs are handed their
+        weights already cast (``programs.weights_as_consumed``), so this
+        is 0 on every engine whose weights are not int8 -- and what the
+        same programs read when lowered with ``executor.params`` of a
+        float32-at-rest model is the cast of every stack, every call.
+        Read on demand, like :meth:`pool_relayouts`."""
+        shapes = {
+            tuple(x.shape)
+            for x in self._jax.tree.leaves(self.model.executor.params)
+        }
+        dt = self.model.executor.compute_dtype
+        return sum(
+            count_weight_casts(t, shapes, dt)
+            for t in self._program_texts(self._params_arg)
+        )
+
+    def _program_texts(self, params) -> List[str]:
+        """The compiled decode and prefill programs' text when handed
+        ``params`` (lowered and compiled anew: two compiles)."""
         # shapes, not the live buffers: a running window donates those
         pools = [
             self._jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
             for x in self._kvs()
         ]
-        return sum(
-            count_pool_relayouts(
-                prog.lower(self._params_arg, *pools, *args)
-                .compile().as_text(),
-                pools[0].size * pools[0].dtype.itemsize,
-            )
+        return [
+            prog.lower(params, *pools, *args).compile().as_text()
             for prog, args in zip(
                 (self._decode, self._prefill), self._idle_args()
             )
-        )
+        ]
 
     def attn_walk(self) -> Optional[Dict[str, Any]]:
         """How the paged kernel of this engine's programs walks a block
@@ -706,6 +806,18 @@ class ServeEngine:
         walked += max(0, lanes - live)
         return walked, lanes * walk["max_blocks"]
 
+    def _refresh_weights(self) -> None:
+        """Before a run (never inside a window): serve what
+        ``set_weights`` or a training step put into the executor since
+        the programs' weights were cast -- ``weights_as_consumed``
+        re-casts the leaves whose source array is another object now, an
+        identity check a leaf and no device work when none is.  The int8
+        arm quantizes once, at build, as it always did."""
+        if self.weight_dtype != "int8":
+            self._params_arg = weights_as_consumed(
+                self.model.executor, self.spec
+            )
+
     def _kvs(self):
         """The live pool buffers in program-argument order: (ck, cv)
         for a full-precision pool, (ck, cv, sk, sv) for a quantized one,
@@ -746,6 +858,7 @@ class ServeEngine:
         open loop)."""
         ex = self.model.executor
         pending = sorted(requests or (), key=lambda r: (r.arrival_s, r.id))
+        self._refresh_weights()
         t0 = self._t0 = self._now()
         if self.spans is not None and self._owns_spans:
             # a shared (cluster-owned) recorder is based by the cluster

@@ -814,6 +814,7 @@ class FleetRouter:
         syncs0 = self.model.executor.host_syncs
         for rep in self.replicas.values():
             eng = rep.engine
+            eng._refresh_weights()
             eng._t0 = t0
             eng.windows = eng.decode_steps = eng.prefill_chunks = 0
             eng.peak_active = 0

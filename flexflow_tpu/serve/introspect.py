@@ -14,11 +14,13 @@ on localhost, serving five endpoints while an engine or cluster runs:
   /spanz?n=    the last ``n`` ffspan/1 records (JSON; default 64)
   /metricz     Prometheus text exposition (obs/export.py)
   /poolz       the K/V pool's geometry, ``pool_relayouts`` (whole-pool
-               copies in the compiled decode and prefill programs) and
-               ``attn_walk`` (the paged kernel's grid and pages a compute
-               block) (JSON; compiles both programs once more on first
-               use, on this server's own thread, then answers from
-               memory)
+               copies in the compiled decode and prefill programs),
+               ``weight_casts`` (converts of a float32 weight to the
+               compute dtype in them: 0, the weights are handed over
+               cast) and ``attn_walk`` (the paged kernel's grid and
+               pages a compute block) (JSON; compiles both programs
+               anew on first use, on this server's own thread, then
+               answers from memory)
   ===========  =========================================================
 
 The zero-sync contract, stated once: the serve hot path NEVER talks to
@@ -314,6 +316,7 @@ class StatusServer:
             "block_size": eng.kv.block_size,
             "attn_kernel": eng.attn_kernel,
             "pool_relayouts": eng.pool_relayouts(),
+            "weight_casts": eng.weight_casts(),
             "attn_walk": eng.attn_walk(),
             "hbm_bytes": eng.kv.hbm_bytes(),
         }

@@ -53,6 +53,18 @@ from flexflow_tpu.serve.kvcache import PagedKVCache, quantize_kv
 
 __all__ = ["ServePrograms", "build_serve_programs", "MOE_STATS"]
 
+# weights an op declares float32 (the router) stay so in a program
+KEEP_F32 = ("router", "router_bias")
+
+
+def keep_float32(spec: GPTSpec):
+    """``(layer name, weight name)`` of every leaf the programs take in
+    float32 whatever the compute dtype."""
+    return [
+        (ls.ffn[0], w) for ls in spec.layers if ls.ffn_kind == "moe"
+        for w in KEEP_F32
+    ]
+
 # what a program of a model with routed experts returns after its
 # tokens, one float32 each, summed over the call's expert layers
 MOE_STATS = ("rows", "experts_touched", "load_max_over_mean", "layer_calls")
@@ -80,6 +92,46 @@ def serve_pass_rows(rows: int) -> int:
     return rows if rows <= 8192 else -(-rows // 64) * 8
 
 
+def weights_as_consumed(executor, spec: GPTSpec):
+    """``executor.params`` as the serve programs multiply them: every
+    leaf ``prep_params`` would cast (:func:`make_cast`: float32 -> the
+    compute dtype where the two differ; the router's leaves stay
+    float32) cast ONCE, here, leaf by leaf on the tree as stored -- a
+    scan-stacked bucket stays one ``(depth, ...)`` array, the cast is
+    elementwise.  A leaf that needs no cast is the executor's own array,
+    never a copy, and when none does the result IS ``executor.params``
+    (bfloat16 at rest, float32 compute).  Casts are remembered on the
+    executor by the source array's identity (``executor.serve_cast``),
+    so engines over one model share one cast tree and a second call
+    casts only the leaves ``set_weights`` or a training step replaced
+    since: :meth:`ServeEngine.run` calls this again before each run."""
+    import weakref
+
+    import jax.numpy as jnp
+
+    cast = make_cast(jnp, executor.compute_dtype)
+    # by the name a leaf is stored under (a stacked bucket's, or the layer's)
+    keep = {
+        (loc[1], w) for lname, w in keep_float32(spec)
+        if (loc := executor.locate_weight(lname, w)) is not None
+    }
+    memo = executor.serve_cast  # (bucket, weight) -> (ref(source), cast)
+    out, changed = {}, False
+    for bname, ws in executor.params.items():
+        out[bname] = {}
+        for w, src in ws.items():
+            hit = memo.get((bname, w))
+            if hit is not None and hit[0]() is src:
+                leaf = hit[1]
+            else:
+                leaf = src if (bname, w) in keep else cast(src)
+                if leaf is not src:
+                    memo[(bname, w)] = (weakref.ref(src), leaf)
+            changed |= leaf is not src
+            out[bname][w] = leaf
+    return out if changed else executor.params
+
+
 def build_serve_programs(
     model,
     kv: PagedKVCache,
@@ -96,8 +148,15 @@ def build_serve_programs(
     resolved decision (``paged`` | ``gather``); ``draft`` and ``verify``
     are built only with ``spec_k``; ``return_probs`` false (greedy
     decoding) leaves the float32 distribution out of the outputs
-    (``None`` in its place).  Nothing is compiled here: each
-    program traces at its first call."""
+    (``None`` in its place).  ``params_arg``, what every program takes
+    first, is the weights as the programs multiply them
+    (:func:`weights_as_consumed`): cast here, once, where the compute
+    dtype is not the dtype at rest -- ``prep_params`` inside a program
+    applies the same rule and finds nothing to do, so a caller may
+    still hand ``executor.params`` and get the same bits with the cast
+    paid in every call.  The int8 arm's argument is the ``(qparams,
+    scales)`` pair.  No serve program is compiled here: each traces at
+    its first call."""
     import jax
     import jax.numpy as jnp
 
@@ -171,12 +230,12 @@ def build_serve_programs(
     if wq:
         params_arg = quantize_weights_int8(jnp, unstack(model.executor.params))
     else:
-        params_arg = model.executor.params
-    # weights an op declares float32 (the router) stay so in a program
-    keep_f32 = [
-        (ls.ffn[0], w) for ls in spec.layers if ls.ffn_kind == "moe"
-        for w in ("router", "router_bias")
-    ]
+        # the weights in the dtype the matmuls take them: cast here, once,
+        # not inside every call (on this tree ``prep_params``' cast below
+        # is the identity and the compiled programs hold no ``convert``
+        # of a weight: ``ServeEngine.weight_casts``)
+        params_arg = weights_as_consumed(model.executor, spec)
+    keep_f32 = keep_float32(spec)
 
     def prep_params(params):
         if wq:

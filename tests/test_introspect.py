@@ -475,6 +475,7 @@ def test_poolz_reports_the_pool_and_its_relayouts(model):
         assert doc["pool_shape"][-1] == eng.kv.heads * eng.kv.head_dim
         assert doc["block_size"] == 8 and doc["attn_kernel"] == "gather"
         assert doc["pool_relayouts"] == 0  # the CPU's scatter is in place
+        assert doc["weight_casts"] == 0  # float32 compute: nothing to cast
         assert doc["attn_walk"] is None  # the gather arm walks no table
         first = srv._poolz
         _get(base, "/poolz", timeout=60.0)

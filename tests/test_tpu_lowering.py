@@ -203,6 +203,67 @@ def test_serve_layers_leave_the_pool_to_the_kernels(G, kv_dtype):
     assert len(re.findall(r"^\s*%decode[.\d]* = \S+ custom-call\(", txt, re.M)) == L
 
 
+def test_serve_programs_at_the_cell_geometry_hold_no_weight_cast():
+    """The ``gpt2_small`` cells' serve programs (12 blocks of 768 / 3072
+    scan-stacked, 24 slots, chunks of 32 -- so a chunk's normed rows are
+    float32 ``(768, 768)`` like a weight; a small vocabulary), compiled
+    for a v5e: handed the executor's float32 tree, ``jit_decode``
+    converts every weight stack to bfloat16 before it multiplies
+    anything (ISSUE 33: 27.5 / 36.9 % of the cells' device time); handed
+    ``params_arg``, the tree cast once at build, neither program holds
+    such a convert, nor a whole-pool copy, and the temporaries shrink by
+    the bfloat16 copies no longer made."""
+    from flexflow_tpu import FFConfig, FFModel, MachineMesh
+    from flexflow_tpu.models.transformer import gpt_decoder
+    from flexflow_tpu.serve.engine import count_weight_casts
+    from flexflow_tpu.serve.kvcache import PagedKVCache
+    from flexflow_tpu.serve.programs import build_serve_programs
+
+    sh = _v5e_sharding()
+    if sh is None:
+        pytest.skip("no v5e topology can be described here")
+    slots, chunk, seq, depth = 24, 32, 1024, 12
+    m = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16"))
+    gpt_decoder(
+        m, slots, seq, hidden=H * D, heads=H, ff_dim=4 * H * D,
+        num_layers=depth, vocab=512, use_flash=False,
+    )
+    m.compile(seed=0, mesh=MachineMesh((1, 1), ("data", "model")))
+    kv = PagedKVCache(
+        depth, H, D, slots=slots, block_size=BS, max_seq_len=seq,
+        dtype=jnp.bfloat16, chunk=chunk,
+    )
+    progs = build_serve_programs(m, kv, attn_kernel="paged", return_probs=False)
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(
+            getattr(x, "shape", x), dtype or x.dtype, sharding=sh
+        )
+
+    stored = m.executor.params
+    shapes = {tuple(x.shape) for x in jax.tree.leaves(stored)}
+    assert (depth, H * D, 4 * H * D) in shapes  # the stacks, as stored
+    pools = [sds(kv.cache_k), sds(kv.cache_v)]
+    lane, bt = sds((slots,), jnp.int32), sds((slots, MB), jnp.int32)
+    decode_args = (lane, lane, bt)
+    prefill_args = (sds((slots, chunk), jnp.int32), lane, lane, bt)
+
+    def compiled(prog, tree, args):
+        c = prog.lower(jax.tree.map(sds, tree), *pools, *args).compile()
+        return c.as_text(), c.memory_analysis().temp_size_in_bytes
+
+    text, temp_f32 = compiled(progs.decode, stored, decode_args)
+    # a convert a stack and more (17 here, jax 0.9.0 / libtpu 0.0.34)
+    assert count_weight_casts(text, shapes, jnp.bfloat16) >= 9
+    nbytes = kv.cache_k.size * kv.cache_k.dtype.itemsize
+    for prog, args in ((progs.prefill, prefill_args), (progs.decode, decode_args)):
+        text, temp = compiled(prog, progs.params_arg, args)
+        assert count_weight_casts(text, shapes, jnp.bfloat16) == 0
+        assert count_pool_relayouts(text, nbytes) == 0
+    # decode's: 173 MB of temporaries handed float32, 3 MB handed bfloat16
+    assert temp_f32 - temp > 100e6, (temp_f32, temp)
+
+
 @pytest.mark.parametrize("dropout", [0.0, 0.1])
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_lowers_for_tpu(causal, dropout):

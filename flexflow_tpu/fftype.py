@@ -138,6 +138,7 @@ class OperatorType(enum.Enum):
     MULTIHEAD_ATTENTION = "multihead_attention"
     GATED_ATTENTION = "gated_attention"
     GATED_DELTA_NET = "gated_delta_net"
+    MAMBA2_MIXER = "mamba2_mixer"
     TOPK = "topk"
     GROUP_BY = "group_by"
     EXPERTS = "experts"

@@ -482,15 +482,22 @@ class FFModel:
         bias: bool = False,
         kernel_initializer: Optional[Initializer] = None,
         name: Optional[str] = None,
+        num_kv_heads: int = 0,
     ) -> Tensor:
         """Reference ``FFModel::multihead_attention``
         (``include/flexflow/model.h:336-554``): ``bias`` adds projection
-        biases (bq/bk/bv/bo) like the reference's bias flag."""
+        biases (bq/bk/bv/bo) like the reference's bias flag.
+        ``num_kv_heads`` (0: ``num_heads``): grouped K/V heads."""
+        # the default stays out of the attrs: a layer built before it
+        # existed keeps its params_key
+        grouped = {"num_kv_heads": int(num_kv_heads)} if num_kv_heads else {}
+        assert not num_kv_heads or num_heads % num_kv_heads == 0
         return self._add_layer(
             OperatorType.MULTIHEAD_ATTENTION,
             self._name("attention", name),
             [query, key, value],
             dict(
+                **grouped,
                 embed_dim=embed_dim,
                 num_heads=num_heads,
                 kdim=kdim or None,
@@ -568,6 +575,31 @@ class FFModel:
                 head_k_dim=head_k_dim, head_v_dim=head_v_dim,
                 conv_kernel=conv_kernel, eps=eps,
             ),
+        )[0]
+
+    def mamba2_mixer(
+        self,
+        input: Tensor,
+        num_heads: int,
+        head_dim: int,
+        n_groups: int,
+        state_size: int,
+        conv_kernel: int = 4,
+        chunk: int = 128,
+        eps: float = 1e-5,
+        time_step_limit: Optional[Tuple[float, float]] = None,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """State-space mixer with a scalar decay a head
+        (:class:`flexflow_tpu.ops.ssm.Mamba2Mixer`)."""
+        attrs = dict(
+            num_heads=num_heads, head_dim=head_dim, n_groups=n_groups,
+            state_size=state_size, conv_kernel=conv_kernel, chunk=chunk, eps=eps,
+        )
+        if time_step_limit is not None:
+            attrs["time_step_limit"] = tuple(float(v) for v in time_step_limit)
+        return self._add_layer(
+            OperatorType.MAMBA2_MIXER, self._name("mamba2_mixer", name), [input], attrs,
         )[0]
 
     def softmax(self, input: Tensor, dim: int = -1, name: Optional[str] = None) -> Tensor:
@@ -736,10 +768,12 @@ class FFModel:
         route_scale: float = 1.0,
         router_bias: bool = False,
         shared_gated: bool = True,
+        expert_form: str = "gated",
         name: Optional[str] = None,
     ) -> Tensor:
         """One share of a dropless sparse-MoE block with gated (SiLU)
-        experts: the router covers all ``n_experts``, this share holds
+        experts -- or, ``expert_form`` ``relu2`` | ``relu``, ungated ones
+        of two matrices: the router covers all ``n_experts``, this share holds
         ``held`` of them from ``first_expert`` on and returns their part
         (plus the shared expert's, when ``shared_hidden`` > 0).  The
         router's rule (``score``, ``route_norm``, ``route_scale``,
@@ -763,6 +797,9 @@ class FFModel:
             attrs["router_bias"] = True
         if not shared_gated:
             attrs["shared_gated"] = False
+        if expert_form != "gated":
+            assert expert_form in ("relu2", "relu"), expert_form
+            attrs["expert_form"] = expert_form
         return self._add_layer(
             OperatorType.ROUTED_EXPERTS,
             self._name("routed_experts", name),

@@ -41,32 +41,120 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["GPTSpec", "LayerSpec", "GPTDecodeSession", "gpt_generate_cached"]
+__all__ = ["GPTSpec", "LayerSpec", "BranchSpec", "GPTDecodeSession", "gpt_generate_cached"]
+
+
+ATTENTION_KINDS = ("mha", "gated")
+FFN_KINDS = ("gelu", "gated_ffn", "moe")
+
+
+@dataclasses.dataclass(frozen=True)
+class BranchSpec:
+    """One residual branch ``x + [norm](mixer(norm(x)))`` as the serve
+    programs run it: the names of the layers whose parameters it reads,
+    and what the mixer is."""
+
+    kind: str  # "mha" | "gated" (attention) | "gelu" | "gated_ffn" | "moe" | "mamba2"
+    norm_in: str
+    mixer: Tuple[str, ...]  # (ff0, ff1) for "gelu", one layer otherwise
+    norm_post: Optional[str] = None  # sandwich norm on the mixer's output
+    attrs: Optional[Dict[str, Any]] = None  # "moe", "mamba2": the op's attrs
+    # --- attention ("mha": q/k/v/o, biases optional, K/V heads grouped or not)
+    heads: int = 0
+    kv_heads: int = 0
+    head_dim: int = 0
+    has_bias: bool = False
+    qk_norm_zero_centered: bool = False  # gated: the per-head norm's weight from 0
+    qk_eps: float = 0.0  # gated: the per-head norm's eps
+    rotary_dim: int = 0  # 0: the layer carries no positions of its own
+    rope_theta: float = 0.0
+    window: int = 0  # 0: every earlier key; else the last ``window`` keys
+
+    @property
+    def is_attention(self) -> bool:
+        return self.kind in ATTENTION_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """One decoder layer as the serve programs run it: the names of the
-    layers whose parameters it reads, and what each of them is."""
+    """One decoder layer: a sequence of residual branches.  GPT-2's and
+    Trinity's layers have two (an attention and an FFN), Nemotron-H's
+    one.  The properties read the attention branch (``norm_in``,
+    ``attn``, ``attn_kind``, ``heads`` ..., ``window``) or the FFN
+    branch (``norm_pre_ffn``, ``ffn``, ``ffn_kind``, ``moe``) of a
+    layer that has one."""
 
-    norm_in: str
-    attn: str
-    norm_post_attn: Optional[str]  # sandwich norm on the mixer's output
-    norm_pre_ffn: str
-    ffn: Tuple[str, ...]  # (ff0, ff1) for "gelu", one layer otherwise
-    norm_post_ffn: Optional[str]
-    attn_kind: str  # "mha" (q/k/v/o, biases optional) | "gated"
-    heads: int
-    kv_heads: int
-    head_dim: int
-    has_bias: bool
-    qk_norm_zero_centered: bool  # gated: the per-head norm's weight from 0
-    qk_eps: float  # gated: the per-head norm's eps
-    rotary_dim: int  # 0: the layer carries no positions of its own
-    rope_theta: float
-    window: int  # 0: every earlier key; else the last ``window`` keys
-    ffn_kind: str  # "gelu" | "gated" | "moe"
-    moe: Optional[Dict[str, Any]]  # the RoutedExperts layer's attrs
+    branches: Tuple[BranchSpec, ...]
+
+    def _one(self, kinds) -> Optional[BranchSpec]:
+        return next((b for b in self.branches if b.kind in kinds), None)
+
+    @property
+    def attention(self) -> Optional[BranchSpec]:
+        return self._one(ATTENTION_KINDS)
+
+    @property
+    def ffn_branch(self) -> Optional[BranchSpec]:
+        return self._one(FFN_KINDS)
+
+    @property
+    def window(self) -> int:
+        at = self.attention
+        return at.window if at else 0
+
+    @property
+    def ffn_kind(self) -> Optional[str]:
+        f = self.ffn_branch
+        return None if f is None else {"gated_ffn": "gated"}.get(f.kind, f.kind)
+
+    @property
+    def ffn(self) -> Optional[Tuple[str, ...]]:
+        f = self.ffn_branch
+        return f.mixer if f else None
+
+    @property
+    def moe(self) -> Optional[Dict[str, Any]]:
+        f = self.ffn_branch
+        return f.attrs if f is not None and f.kind == "moe" else None
+
+    @property
+    def norm_pre_ffn(self) -> Optional[str]:
+        f = self.ffn_branch
+        return f.norm_in if f else None
+
+    @property
+    def norm_post_ffn(self) -> Optional[str]:
+        f = self.ffn_branch
+        return f.norm_post if f else None
+
+    @property
+    def norm_in(self) -> str:
+        return self.branches[0].norm_in
+
+    @property
+    def attn(self) -> Optional[str]:
+        at = self.attention
+        return at.mixer[0] if at else None
+
+    @property
+    def attn_kind(self) -> Optional[str]:
+        at = self.attention
+        return at.kind if at else None
+
+    @property
+    def norm_post_attn(self) -> Optional[str]:
+        at = self.attention
+        return at.norm_post if at else None
+
+    def __getattr__(self, name):
+        # heads, kv_heads, head_dim, has_bias, rotary_dim, ...: the
+        # attention branch's
+        if name in ("heads", "kv_heads", "head_dim", "has_bias", "rotary_dim",
+                    "rope_theta", "qk_eps", "qk_norm_zero_centered"):
+            at = self.attention
+            if at is not None:
+                return getattr(at, name)
+        raise AttributeError(name)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,13 +164,15 @@ class GPTSpec:
     models) and the paged serving programs
     (:mod:`flexflow_tpu.serve.programs`).  Read from the model's layers
     and their attrs, whatever builder named them: an embedding (plus a
-    learned position table, or times a constant), then per layer a norm,
-    an attention op (:class:`MultiHeadAttention` or
-    :class:`GatedAttention`), a residual add, a norm, an FFN (two dense
-    layers around a GELU, a :class:`GatedFFN`, or
-    :class:`RoutedExperts` holding every expert), a residual add --
-    each mixer optionally followed by a norm of its own -- and a final
-    norm and a bias-free head."""
+    learned position table, or times a constant), then a chain of
+    residual branches ``x + [norm](mixer(norm(x)))`` -- the mixer an
+    attention op (:class:`MultiHeadAttention`, K/V heads grouped or not,
+    or :class:`GatedAttention`), an FFN (two dense layers around a
+    GELU, a :class:`GatedFFN`, or :class:`RoutedExperts` holding all or
+    a share of its experts) or a :class:`Mamba2Mixer` -- and a final norm
+    and a bias-free head.  An attention branch and the FFN branch that
+    follows it under the same name prefix are one layer (GPT-2,
+    Trinity); every other branch is a layer of its own (Nemotron-H)."""
 
     num_layers: int
     heads: int
@@ -103,12 +193,16 @@ class GPTSpec:
     layers: Tuple[LayerSpec, ...] = ()
 
     @property
+    def branches(self) -> Tuple[BranchSpec, ...]:
+        return tuple(b for l in self.layers for b in l.branches)
+
+    @property
     def is_gpt(self) -> bool:
         """The shape the dense session and the speculative, int8 and
         quantized-pool serve arms are written for: learned positions,
         LayerNorm, one head count, GELU FFN, no window."""
         return self.pos_embed is not None and self.norm == "layer" and all(
-            l.attn_kind == "mha" and l.ffn_kind == "gelu" and not l.window
+            [b.kind for b in l.branches] == ["mha", "gelu"] and not l.window
             and l.kv_heads == l.heads for l in self.layers
         )
 
@@ -122,7 +216,12 @@ class GPTSpec:
 
     @property
     def has_moe(self) -> bool:
-        return any(l.ffn_kind == "moe" for l in self.layers)
+        return any(b.kind == "moe" for b in self.branches)
+
+    @property
+    def state_layers(self) -> Tuple[BranchSpec, ...]:
+        """The branches that carry a recurrent state a slot."""
+        return tuple(b for b in self.branches if b.kind == "mamba2")
 
     @classmethod
     def from_model(cls, model) -> "GPTSpec":
@@ -136,21 +235,25 @@ class GPTSpec:
             for t in l.inputs:
                 consumers.setdefault(t.guid, []).append(l)
         NORMS = (T.LAYERNORM, T.RMS_NORM)
+        MIXERS = (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.LINEAR,
+                  T.GATED_FFN, T.ROUTED_EXPERTS, T.MAMBA2_MIXER)
 
         def refuse(why):
             raise ValueError(
                 "not a decoder the serve programs know (an embedding, then "
-                "per layer norm -> MultiHeadAttention | GatedAttention -> "
-                "[norm] -> add -> norm -> dense+GELU+dense | GatedFFN | "
-                f"RoutedExperts -> [norm] -> add, a final norm, a head): {why}"
+                "residual branches norm -> MultiHeadAttention | GatedAttention "
+                "| Mamba2Mixer | dense+GELU+dense | GatedFFN | RoutedExperts "
+                f"-> [norm] -> add, a final norm, a head): {why}"
             )
 
         def after(layer, *kinds):
             """The one consumer of ``layer``'s output among ``kinds``
             (a residual add also reads a block's input: not asked for
             unless named)."""
-            hits = [c for c in consumers.get(layer.outputs[0].guid, ())
-                    if c.op_type in kinds]
+            hits = []
+            for c in consumers.get(layer.outputs[0].guid, ()):
+                if c.op_type in kinds and c not in hits:  # q, k, v: one reader
+                    hits.append(c)
             return hits[0] if len(hits) == 1 else None
 
         def norm_kind(l):
@@ -169,76 +272,93 @@ class GPTSpec:
         if final is None or final.op_type not in NORMS:
             refuse(f"the head {head.name!r} does not read a norm")
         pos_embed, embed_scale = None, 1.0
+        stream = embed  # the layer whose output is the residual stream
         nxt = after(embed, T.EW_ADD, T.SCALAR_MULTIPLY)
         if nxt is not None and nxt.op_type == T.EW_ADD:
             other = [producer.get(t.guid) for t in nxt.inputs
                      if producer.get(t.guid) is not embed]
-            if len(other) != 1 or other[0] is None or other[0].op_type != T.WEIGHT:
-                refuse("what is added to the embedding is not a position table")
-            pos_embed = other[0].name
+            # a position table -- or the first branch's residual add, of
+            # a decoder whose embedding is the stream as it is
+            if len(other) == 1 and other[0] is not None and other[0].op_type == T.WEIGHT:
+                pos_embed, stream = other[0].name, nxt
         elif nxt is not None:
-            embed_scale = float(nxt.attrs["scalar"])
+            embed_scale, stream = float(nxt.attrs["scalar"]), nxt
 
-        specs = []
-        for at in layers:
-            if at.op_type not in (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION):
-                continue
+        def attention_of(at):
             a = at.attrs
-            n_in = producer.get(at.inputs[0].guid)
-            if n_in is None or n_in.op_type not in NORMS:
-                refuse(f"{at.name!r} does not read a norm")
             if any(t.guid != at.inputs[0].guid for t in at.inputs):
                 refuse(f"{at.name!r} is not self-attention")
-            post_attn = after(at, *NORMS)
-            res0 = after(post_attn or at, T.EW_ADD)
-            n_ffn = after(res0, *NORMS) if res0 is not None else None
-            f0 = after(n_ffn, T.LINEAR, T.GATED_FFN, T.ROUTED_EXPERTS) if n_ffn else None
-            if f0 is None:
-                refuse(f"no residual add, norm and FFN after {at.name!r}")
-            ffn, moe = (f0,), None
-            if f0.op_type == T.LINEAR:
-                f1 = after(f0, T.LINEAR)
-                if f1 is None or f0.attrs.get("activation") != ActiMode.GELU:
-                    refuse(f"{f0.name!r} is not dense + GELU + dense")
-                ffn, ffn_kind = (f0, f1), "gelu"
-            elif f0.op_type == T.GATED_FFN:
-                ffn_kind = "gated"
-            else:
-                ffn_kind, moe = "moe", dict(f0.attrs)
-                if moe["held"] != moe["n_experts"]:
-                    refuse(f"{f0.name!r} holds {moe['held']} of "
-                           f"{moe['n_experts']} experts: serving needs all")
-            post_ffn = after(ffn[-1], *NORMS)
             if at.op_type == T.MULTIHEAD_ATTENTION:
                 if not a.get("causal"):
                     refuse(f"{at.name!r} is not causal")
                 h = a["num_heads"]
-                kind = dict(attn_kind="mha", heads=h, kv_heads=h,
+                return dict(kind="mha", heads=h, kv_heads=a.get("num_kv_heads") or h,
                             head_dim=a.get("kdim") or a["embed_dim"] // h,
-                            has_bias=bool(a.get("bias")),
-                            qk_norm_zero_centered=False, qk_eps=0.0,
-                            rotary_dim=0, rope_theta=0.0, window=0)
+                            has_bias=bool(a.get("bias")))
+            return dict(kind="gated", heads=a["num_heads"],
+                        kv_heads=a["num_kv_heads"], head_dim=a["head_dim"],
+                        qk_norm_zero_centered=bool(a.get("zero_centered", True)),
+                        qk_eps=float(a.get("eps", 1e-6)),
+                        rotary_dim=int(a["rotary_dim"]),
+                        rope_theta=float(a["rope_theta"]),
+                        window=int(a.get("window", 0)))
+
+        # walk the residual stream: each step is norm -> mixer -> [norm]
+        # -> add back onto the stream, until the norm is the head's
+        branches = []
+        while True:
+            n_in = after(stream, *NORMS)
+            if n_in is None:
+                refuse(f"no one norm reads the residual stream after {stream.name!r}")
+            if n_in is final:
+                break
+            mix = after(n_in, *MIXERS)
+            if mix is None:
+                refuse(f"no mixer the serve programs know reads {n_in.name!r}")
+            names, kind = (mix.name,), {}
+            if mix.op_type in (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION):
+                kind = attention_of(mix)
+            elif mix.op_type == T.LINEAR:
+                f1 = after(mix, T.LINEAR)
+                if f1 is None or mix.attrs.get("activation") != ActiMode.GELU:
+                    refuse(f"{mix.name!r} is not dense + GELU + dense")
+                names, kind, mix = (mix.name, f1.name), dict(kind="gelu"), f1
+            elif mix.op_type == T.GATED_FFN:
+                kind = dict(kind="gated_ffn")
+            elif mix.op_type == T.ROUTED_EXPERTS:
+                kind = dict(kind="moe", attrs=dict(mix.attrs))
             else:
-                kind = dict(attn_kind="gated", heads=a["num_heads"],
-                            kv_heads=a["num_kv_heads"], head_dim=a["head_dim"],
-                            has_bias=False,
-                            qk_norm_zero_centered=bool(a.get("zero_centered", True)),
-                            qk_eps=float(a.get("eps", 1e-6)),
-                            rotary_dim=int(a["rotary_dim"]),
-                            rope_theta=float(a["rope_theta"]),
-                            window=int(a.get("window", 0)))
-            specs.append(LayerSpec(
-                norm_in=n_in.name, attn=at.name,
-                norm_post_attn=post_attn.name if post_attn else None,
-                norm_pre_ffn=n_ffn.name, ffn=tuple(f.name for f in ffn),
-                norm_post_ffn=post_ffn.name if post_ffn else None,
-                ffn_kind=ffn_kind, moe=moe, **kind,
+                kind = dict(kind="mamba2", attrs=dict(mix.attrs))
+            post = after(mix, *NORMS)
+            res = after(post or mix, T.EW_ADD)
+            if res is None or stream.outputs[0].guid not in [t.guid for t in res.inputs]:
+                refuse(f"no residual add after {mix.name!r}")
+            branches.append(BranchSpec(
+                norm_in=n_in.name, mixer=names,
+                norm_post=post.name if post else None, **kind,
             ))
-        if not specs:
+            stream = res
+
+        def prefix(b):
+            return b.norm_in.split("_")[0]
+
+        specs = []
+        for b in branches:
+            last = specs[-1] if specs else None
+            if (b.kind in FFN_KINDS and last is not None and len(last) == 1
+                    and last[0].is_attention and prefix(last[0]) == prefix(b)):
+                last.append(b)
+            else:
+                specs.append([b])
+        specs = [LayerSpec(tuple(bs)) for bs in specs]
+        attn = [b for b in branches if b.is_attention]
+        if not attn:
             refuse("no attention layer")
-        l0 = specs[0]
-        if len({(l.heads, l.kv_heads, l.head_dim) for l in specs}) != 1:
+        l0 = attn[0]
+        if len({(l.heads, l.kv_heads, l.head_dim) for l in attn}) != 1:
             refuse("layers differ in their heads (one K/V pool geometry is served)")
+        if len({tuple(sorted(b.attrs.items())) for b in branches if b.kind == "mamba2"}) > 1:
+            refuse("state-space layers differ in their sizes (one state pool geometry is served)")
         batch, seq = model.graph_inputs[0].shape
         return cls(
             num_layers=len(specs),

@@ -10,6 +10,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     moe,
     norm,
     parallel_ops,
+    ssm,
     tensor_ops,
 )
 from flexflow_tpu.ops.base import OpContext, OpDef, WeightSpec, all_ops, get_op_def

@@ -53,7 +53,9 @@ def sdpa(q, k, v, *, causal: bool = False, dropout_rate: float = 0.0, rng=None,
 class MultiHeadAttention(OpDef):
     """Inputs: query (B, Sq, E), key (B, Sk, Ek), value (B, Sk, Ev).
     Output: (B, Sq, E).  Attrs: embed_dim, num_heads, kdim, vdim, dropout,
-    causal, use_flash."""
+    causal, use_flash, num_kv_heads (absent: one K/V head a query head;
+    else each K/V head serves ``num_heads / num_kv_heads`` query heads,
+    repeated in front of the core)."""
 
     op_type = OperatorType.MULTIHEAD_ATTENTION
 
@@ -68,13 +70,14 @@ class MultiHeadAttention(OpDef):
         e, h = a["embed_dim"], a["num_heads"]
         kd = a.get("kdim") or e // h
         vd = a.get("vdim") or e // h
+        kvh = a.get("num_kv_heads") or h
         init = a.get("kernel_initializer") or default_kernel_initializer()
         dt = q.dtype
         # Layouts put the head(*head_dim) axis last => TP shards the lane dim.
         ws = [
             WeightSpec("wq", (q.shape[-1], h * kd), dt, init, tp_dim=1),
-            WeightSpec("wk", (k.shape[-1], h * kd), dt, init, tp_dim=1),
-            WeightSpec("wv", (v.shape[-1], h * vd), dt, init, tp_dim=1),
+            WeightSpec("wk", (k.shape[-1], kvh * kd), dt, init, tp_dim=1),
+            WeightSpec("wv", (v.shape[-1], kvh * vd), dt, init, tp_dim=1),
             WeightSpec("wo", (h * vd, e), dt, init, tp_dim=0),
         ]
         if a.get("bias"):
@@ -95,6 +98,7 @@ class MultiHeadAttention(OpDef):
         e, h = a["embed_dim"], a["num_heads"]
         kd = a.get("kdim") or e // h
         vd = a.get("vdim") or e // h
+        kvh = a.get("num_kv_heads") or h
         b, sq, _ = q_in.shape
         sk = k_in.shape[1]
 
@@ -103,7 +107,7 @@ class MultiHeadAttention(OpDef):
         # (E, 3HD) weight would misalign with the split offsets and GSPMD
         # would reshard every step
         if (
-            q_in is k_in and k_in is v_in and kd == vd
+            q_in is k_in and k_in is v_in and kd == vd and kvh == h
             and ctx.weight_axis("wq", 1) is None
         ):
             # self-attention: one fused (E, 3·H·D) projection matmul keeps
@@ -129,8 +133,10 @@ class MultiHeadAttention(OpDef):
             if a.get("bias"):
                 qp, kp, vp = qp + params["bq"], kp + params["bk"], vp + params["bv"]
             q = qp.reshape(b, sq, h, kd).transpose(0, 2, 1, 3)
-            k = kp.reshape(b, sk, h, kd).transpose(0, 2, 1, 3)
-            v = vp.reshape(b, sk, h, vd).transpose(0, 2, 1, 3)
+            k = kp.reshape(b, sk, kvh, kd).transpose(0, 2, 1, 3)
+            v = vp.reshape(b, sk, kvh, vd).transpose(0, 2, 1, 3)
+            if kvh != h:
+                k, v = (jnp.repeat(t, h // kvh, axis=1) for t in (k, v))
 
         dropout = a.get("dropout", 0.0) if ctx.training else 0.0
 
@@ -222,10 +228,11 @@ class MultiHeadAttention(OpDef):
         e, h = a["embed_dim"], a["num_heads"]
         kd = a.get("kdim") or e // h
         vd = a.get("vdim") or e // h
+        kvh = a.get("num_kv_heads") or h
         b, sq = q.shape[0], q.shape[1]
         sk = k.shape[1]
-        proj = 2.0 * b * (sq * q.shape[-1] * h * kd + sk * k.shape[-1] * h * kd
-                          + sk * v.shape[-1] * h * vd + sq * h * vd * e)
+        proj = 2.0 * b * (sq * q.shape[-1] * h * kd + sk * k.shape[-1] * kvh * kd
+                          + sk * v.shape[-1] * kvh * vd + sq * h * vd * e)
         core = 2.0 * b * h * sq * sk * (kd + vd)
         return proj + core
 

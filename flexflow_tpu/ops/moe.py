@@ -414,14 +414,23 @@ def pass_rows(tokens: int, k: int, held: int, n_experts: int) -> int:
     return min(tokens * k, -(-rows // 8) * 8)
 
 
-def _pass_part(budget, k, c, x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends):
+# what an ungated expert ``W_d act(W_u x)`` puts between its two matrices
+UNGATED_ACTS = {
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
+    "relu": jax.nn.relu,
+}
+
+
+def _pass_part(budget, k, form, c, x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends):
     """What the sorted assignments ``[c * budget, (c + 1) * budget)``
     add to the output: gather their tokens, three grouped matmuls
     (``lax.ragged_dot``) over the held experts' gated FFNs ``W_d (silu(W_g
-    x) * W_u x)``, scatter back weighted by the router.  Rows past the
-    last held assignment ride in the last group with weight 0."""
+    x) * W_u x)`` -- or, ``form`` ``relu2`` | ``relu`` and ``w_gate``
+    None, two over their ungated ``W_d act(W_u x)`` --, scatter back
+    weighted by the router.  Rows past the last held assignment ride in
+    the last group with weight 0."""
     f32 = jnp.float32
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     rows = jax.lax.dynamic_slice(order, (c * budget,), (budget,))
     row_key = jax.lax.dynamic_slice(sorted_key, (c * budget,), (budget,))
     row_tok = rows // k
@@ -433,13 +442,17 @@ def _pass_part(budget, k, c, x, w_flat, w_gate, w_up, w_down, order, sorted_key,
         return jax.lax.ragged_dot(a, m, sizes, preferred_element_type=f32)
 
     xs = x[row_tok]
-    h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+    if form != "gated":
+        h = UNGATED_ACTS[form](grouped(xs, w_up))
+    else:
+        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
     y = grouped(h.astype(x.dtype), w_down) * row_w[:, None]
     return jnp.zeros(x.shape, f32).at[row_tok].add(y)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_passes(budget, k, x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends, passes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 11))
+def _held_passes(budget, k, x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends, passes,
+                 form="gated"):
     """Sum of :func:`_pass_part` over ``passes`` passes, a number only
     the device knows: a loop of dynamic length, so the work follows the
     rows routed here.  Also returns the held rows the passes really
@@ -450,38 +463,41 @@ def _held_passes(budget, k, x, w_flat, w_gate, w_up, w_down, order, sorted_key, 
 
     def one(c, carry):
         acc, covered = carry
-        part = _pass_part(budget, k, c, x, w_flat, w_gate, w_up, w_down, *ints)
+        part = _pass_part(budget, k, form, c, x, w_flat, w_gate, w_up, w_down, *ints)
         return acc + part, covered + jnp.clip(ends[-1] - c * budget, 0, budget)
 
     zero = (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), ends.dtype))
     return jax.lax.fori_loop(0, passes, one, zero)
 
 
-def _held_passes_fwd(budget, k, x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends, passes):
+def _held_passes_fwd(budget, k, x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends, passes,
+                     form):
     args = (x, w_flat, w_gate, w_up, w_down, order, sorted_key, ends, passes)
-    return _held_passes(budget, k, *args), args
+    return _held_passes(budget, k, *args, form), args
 
 
-def _held_passes_bwd(budget, k, res, cts):
+def _held_passes_bwd(budget, k, form, res, cts):
     *diff, order, sorted_key, ends, passes = res
     ct = cts[0]  # the count has no gradient
 
     def one(c, acc):
         _, vjp = jax.vjp(
-            lambda *d: _pass_part(budget, k, c, *d, order, sorted_key, ends), *diff
+            lambda *d: _pass_part(budget, k, form, c, *d, order, sorted_key, ends), *diff
         )
         return jax.tree.map(lambda a, g: a + g.astype(a.dtype), acc, vjp(ct))
 
-    zeros = tuple(jnp.zeros(d.shape, jnp.float32) for d in diff)
+    # a leaf that is not there (``w_gate`` of ungated experts) has no sum
+    zeros = jax.tree.map(lambda d: jnp.zeros(d.shape, jnp.float32), tuple(diff))
     grads = jax.lax.fori_loop(0, passes, one, zeros)
     no_grad = tuple(np.zeros(a.shape, jax.dtypes.float0) for a in (order, sorted_key, ends, passes))
-    return tuple(g.astype(d.dtype) for g, d in zip(grads, diff)) + no_grad
+    return jax.tree.map(lambda g, d: g.astype(d.dtype), grads, tuple(diff)) + no_grad
 
 
 _held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
 
 
-def held_experts_part(x, w, idx, first_expert: int, budget: int, w_gate, w_up, w_down):
+def held_experts_part(x, w, idx, first_expert: int, budget: int, w_gate, w_up, w_down,
+                      form: str = "gated"):
     """What the experts ``first_expert .. first_expert + held`` add to
     the layer's output, dropless.
 
@@ -490,9 +506,10 @@ def held_experts_part(x, w, idx, first_expert: int, budget: int, w_gate, w_up, w
     need (:func:`_held_passes`), however strongly the router has come to
     prefer this share's experts.  Returns ``(part (t, d) float32, counts
     (held,) int32 -- every held expert's load, passes made, held rows
-    the passes covered)``."""
+    the passes covered)``.  ``form`` other than ``gated``: the experts
+    are ungated (:data:`UNGATED_ACTS`) and ``w_gate`` is None."""
     t, k = idx.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     local = idx - first_expert
     key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
     counts = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32), axis=0)[:held]
@@ -503,7 +520,7 @@ def held_experts_part(x, w, idx, first_expert: int, budget: int, w_gate, w_up, w
     passes = (ends[-1] + budget - 1) // budget
     part, covered = _held_passes(
         budget, k, x, w.reshape(-1), w_gate, w_up, w_down,
-        jnp.pad(order, (0, pad)), sorted_key, ends, passes,
+        jnp.pad(order, (0, pad)), sorted_key, ends, passes, form,
     )
     return part, counts, passes, covered
 
@@ -512,14 +529,21 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+
 def shared_expert_part(attrs, params, x):
     """What a ``RoutedExperts`` layer's shared expert adds for rows ``x``
-    (t, d), float32: its gated FFN, behind a sigmoid gate unless
+    (t, d), float32: an FFN of the experts' form (gated, or ungated:
+    no ``shared_gate_proj``), behind a sigmoid gate unless
     ``shared_gated`` is false."""
-    out = gated_ffn(
-        x, params["shared_gate_proj"], params["shared_up_proj"],
-        params["shared_down_proj"],
-    ).astype(jnp.float32)
+    form = attrs.get("expert_form", "gated")
+    if form != "gated":
+        out = UNGATED_ACTS[form](x @ params["shared_up_proj"]) @ params["shared_down_proj"]
+    else:
+        out = gated_ffn(
+            x, params["shared_gate_proj"], params["shared_up_proj"],
+            params["shared_down_proj"],
+        )
+    out = out.astype(jnp.float32)
     if attrs.get("shared_gated", True):
         out = out * jax.nn.sigmoid((x @ params["shared_gate"]).astype(jnp.float32))
     return out
@@ -583,7 +607,9 @@ class RoutedExperts(OpDef):
     What absent experts would add is left out; no row routed to a held
     expert is (``held_experts_part``).  Attrs: ``n_experts``,
     ``first_expert``, ``held``, ``top_k``, ``hidden``, ``shared_hidden``;
-    the router's ``score`` (``softmax`` | ``sigmoid``), ``route_norm``,
+    ``expert_form`` (``gated``, the default: ``W_d (silu(W_g x) * W_u x)``
+    | ``relu2``: ``W_d relu(W_u x)^2`` | ``relu``, both without
+    ``w_gate``; the shared expert has the same form); the router's ``score`` (``softmax`` | ``sigmoid``), ``route_norm``,
     ``route_scale`` and ``router_bias`` (a weight of ``n_experts`` added
     to the scores for choosing only) -- :func:`route_top_k`.
     After its output the forward returns the values of ``step_counters``
@@ -608,9 +634,11 @@ class RoutedExperts(OpDef):
         d, dt = t.shape[-1], t.dtype
         n, held, f, fs = a["n_experts"], a["held"], a["hidden"], a["shared_hidden"]
         init = a.get("kernel_initializer") or default_kernel_initializer()
-        ws = [
-            WeightSpec("router", (d, n), dt, init),
-            WeightSpec("w_gate", (held, d, f), dt, init),
+        gated = a.get("expert_form", "gated") == "gated"
+        ws = [WeightSpec("router", (d, n), dt, init)]
+        if gated:
+            ws.append(WeightSpec("w_gate", (held, d, f), dt, init))
+        ws += [
             WeightSpec("w_up", (held, d, f), dt, init),
             WeightSpec("w_down", (held, f, d), dt, init),
         ]
@@ -619,8 +647,9 @@ class RoutedExperts(OpDef):
 
             ws.append(WeightSpec("router_bias", (n,), dt, ZeroInitializer()))
         if fs:
+            if gated:
+                ws.append(WeightSpec("shared_gate_proj", (d, fs), dt, init))
             ws += [
-                WeightSpec("shared_gate_proj", (d, fs), dt, init),
                 WeightSpec("shared_up_proj", (d, fs), dt, init),
                 WeightSpec("shared_down_proj", (fs, d), dt, init),
             ]
@@ -637,7 +666,8 @@ class RoutedExperts(OpDef):
         with jax.named_scope("ff.moe.experts"):
             out, counts, passes, covered = held_experts_part(
                 x, w, idx, a["first_expert"], budget,
-                params["w_gate"], params["w_up"], params["w_down"],
+                params.get("w_gate"), params["w_up"], params["w_down"],
+                a.get("expert_form", "gated"),
             )
         if a["shared_hidden"]:
             with jax.named_scope("ff.moe.shared"):
@@ -656,10 +686,11 @@ class RoutedExperts(OpDef):
         t = math.prod(layer.inputs[0].shape[:-1])
         d = layer.inputs[0].shape[-1]
         rows = t * a["top_k"] * a["held"] / a["n_experts"]  # expected
+        mats = 6.0 if a.get("expert_form", "gated") == "gated" else 4.0
         return (
             2.0 * t * d * a["n_experts"]
-            + 6.0 * rows * d * a["hidden"]
-            + 6.0 * t * d * a["shared_hidden"]
+            + mats * rows * d * a["hidden"]
+            + mats * t * d * a["shared_hidden"]
         )
 
     def partitionable_dims(self, layer: Layer):

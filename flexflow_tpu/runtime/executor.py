@@ -1882,6 +1882,7 @@ _REMAT_OPS = frozenset({
     OperatorType.MULTIHEAD_ATTENTION,
     OperatorType.GATED_ATTENTION,
     OperatorType.GATED_DELTA_NET,
+    OperatorType.MAMBA2_MIXER,
 })
 
 

@@ -125,12 +125,16 @@ _HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(")
 _HLO_ARRAY = re.compile(r"[a-z]+(\d+)\w*\[([\d,]*)\]")
 
 
-def count_pool_relayouts(hlo_text: str, pool_nbytes: int) -> int:
+def count_pool_relayouts(hlo_text: str, pool_nbytes: int,
+                         itemsize: Optional[int] = None) -> int:
     """How many operations of a compiled program's text (fused ones
     included) ``copy`` or ``transpose`` into an array of ``pool_nbytes``
     bytes, under any shape: a whole K/V pool re-laid out, which is what
     a pool geometry the kernels cannot read at rest costs at every call
-    (PERF.md, PR 27 and PR 29).  Not counted: ``copy-start`` /
+    (PERF.md, PR 27 and PR 29).  With ``itemsize``, only arrays of
+    elements that wide: a chunk's bfloat16 activations ``(slots, 256,
+    4096)`` are as many bytes as a float32 state ``(slots, 64, 64,
+    128)`` and are not it.  Not counted: ``copy-start`` /
     ``copy-done``, the compiler staging a small array in faster
     memory."""
     n = 0
@@ -142,7 +146,10 @@ def count_pool_relayouts(hlo_text: str, pool_nbytes: int) -> int:
         if made is None:
             continue
         dims = [int(d) for d in made.group(2).split(",") if d]
-        n += math.prod(dims) * int(made.group(1)) // 8 == pool_nbytes
+        bits = int(made.group(1))
+        if itemsize is not None and bits != 8 * itemsize:
+            continue
+        n += math.prod(dims) * bits // 8 == pool_nbytes
     return n
 
 
@@ -300,6 +307,15 @@ class ServeReport:
     moe_experts_touched: Optional[int] = None
     moe_load_max_over_mean: Optional[float] = None
     moe_layers: int = 0
+    # --- the state group (PR 34): its footprint, the most slots whose
+    # state belonged to a request at a window's start, spills and
+    # restores that carried a state, and valid rows through state layers
+    # (positions x state layers, from the finished requests' lengths)
+    state_pool_bytes: int = 0
+    state_slots_held: int = 0
+    state_spills: int = 0
+    state_restores: int = 0
+    ssm_rows: Optional[int] = None
     # --- what it ran on (ServeEngine.device_info) ---
     attn_interpret: bool = False  # paged kernel ran in the Pallas interpreter
     device: Dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -313,9 +329,12 @@ class ServeReport:
 class ServeEngine:
     """Continuous-batching serving over one compiled decoder
     (``models/gpt_decode.py::GPTSpec`` says which: ``gpt_decoder``,
-    ``afmoe_decoder``).  Speculation, int8 weights, a quantized pool and
-    disaggregated or fleet serving are built for ``gpt_decoder``-shaped
-    models and refused for the rest (:class:`UnsupportedServeConfig`).
+    ``afmoe_decoder``, ``nemotron_h_decoder``).  Speculation, int8
+    weights, a quantized pool and disaggregated or fleet serving are
+    built for ``gpt_decoder``-shaped models and refused for the rest --
+    a decoder with window or state layers, routed experts (all held or a
+    share), layers of one branch -- by name
+    (:class:`UnsupportedServeConfig`).
 
     ``slots`` defaults to the model's compiled batch; the KV pool
     defaults to full provisioning (``num_blocks`` =
@@ -438,16 +457,29 @@ class ServeEngine:
                 f"weight_dtype {self.weight_dtype!r}: expected fp32 | int8"
             )
         # the pool's row is the K/V heads'; window layers live in a
-        # group of their own (a ring a slot, kvcache.py)
-        n_win = sum(1 for l in self.spec.layers if l.window)
+        # group of their own (a ring a slot, kvcache.py), state-space
+        # layers' recurrent states in a third, provisioned a slot
+        attn = [b for b in self.spec.branches if b.is_attention]
+        n_win = sum(1 for b in attn if b.window)
+        state = self.spec.state_layers
+        state_shapes = {}
+        if state:
+            from flexflow_tpu.ops.ssm import mamba2_dims
+
+            a = state[0].attrs
+            h, p, _, n, _, cw = mamba2_dims(a)
+            state_shapes = dict(
+                state_conv=(a["conv_kernel"] - 1, cw), state_ssm=(h, p, n),
+            )
         self.kv = PagedKVCache(
-            self.spec.num_layers - n_win, self.spec.kv_heads,
+            len(attn) - n_win, self.spec.kv_heads,
             self.spec.head_dim,
             slots=self.slots, block_size=block_size,
             num_blocks=num_blocks, max_seq_len=self.spec.seq, dtype=dt,
             kv_dtype=self.kv_dtype, prefix_sharing=prefix_sharing,
             window_layers=n_win, window=self.spec.window,
             chunk=self.prefill_chunk,
+            state_layers=len(state), **state_shapes,
         )
         self.sched = ContinuousBatchingScheduler(self.slots, self.kv)
         self.metrics = MetricsStream(metrics_out, max_mb=metrics_max_mb)
@@ -511,7 +543,7 @@ class ServeEngine:
             return_probs=self.temperature > 0.0,
         )
         self._n_head = progs.n_head
-        self._moe_layers = sum(1 for l in self.spec.layers if l.ffn_kind == "moe")
+        self._moe_layers = sum(1 for b in self.spec.branches if b.kind == "moe")
         # the expert layers' counters, summed on the device call by call
         # and read with the window's tokens
         self._moe_acc = None
@@ -600,6 +632,8 @@ class ServeEngine:
         )
         self._moe_tot = np.zeros((4,), np.float64)
         self._pages_peak = {"full": 0, "window": 0}
+        self._state_slots_peak = 0
+        self._state0 = (0, 0)  # the cache's spills and restores at a run's start
         self.spec_drafted = 0  # draft tokens proposed (spec mode)
         self.spec_accepted = 0  # draft tokens the full model confirmed
         self.peak_active = 0
@@ -686,13 +720,18 @@ class ServeEngine:
         (:func:`count_pool_relayouts`).  The pool's geometry exists to
         make this 0 on a TPU; ``H * D`` off the 128-lane grid or a page
         that is not whole sublane tiles can bring a copy back, correct
-        and slow, and this is where it shows.  Read on demand
+        and slow, and this is where it shows.  A state layer's state
+        array counts like a pool: its update is in place on the donated
+        buffer, not a copy of it (the conv's tail beside it is a shift
+        register of a few rows that every call rewrites whole: not
+        counted).  Read on demand
         (``chip_smoke.py``, the status server's ``/poolz``), never at
         build: it lowers and compiles both programs a second time."""
-        pool = self._kvs()[0]
+        pools = [getattr(self.kv, n) for n in self._pool_names()] + self.kv.state_ssm
+        sizes = {(x.size * x.dtype.itemsize, x.dtype.itemsize) for x in pools}
         return sum(
-            count_pool_relayouts(t, pool.size * pool.dtype.itemsize)
-            for t in self._program_texts(self._params_arg)
+            count_pool_relayouts(t, n, item)
+            for t in self._program_texts(self._params_arg) for n, item in sizes
         )
 
     def weight_casts(self) -> int:
@@ -821,9 +860,14 @@ class ServeEngine:
     def _kvs(self):
         """The live pool buffers in program-argument order: (ck, cv)
         for a full-precision pool, (ck, cv, sk, sv) for a quantized one,
-        (ck, cv, wk, wv) with a window group — every program donates and
-        returns exactly this tuple."""
-        return tuple(getattr(self.kv, name) for name in self._pool_names())
+        (ck, cv, wk, wv) with a window group, then every state layer's
+        conv state and every state layer's ssm state — every program
+        donates and returns exactly this tuple."""
+        kv = self.kv
+        return (
+            tuple(getattr(kv, name) for name in self._pool_names())
+            + tuple(kv.state_conv) + tuple(kv.state_ssm)
+        )
 
     def _pool_names(self) -> Tuple[str, ...]:
         kv = self.kv
@@ -837,8 +881,12 @@ class ServeEngine:
     def _store_kvs(self, bufs) -> None:
         """Write a program's returned pool buffers back as the live
         pool (the counterpart of :meth:`_kvs`)."""
-        for name, buf in zip(self._pool_names(), bufs, strict=True):
+        names, n = self._pool_names(), self.kv.state_layers
+        assert len(bufs) == len(names) + 2 * n, (len(bufs), names, n)
+        for name, buf in zip(names, bufs):
             setattr(self.kv, name, buf)
+        self.kv.state_conv = list(bufs[len(names):len(names) + n])
+        self.kv.state_ssm = list(bufs[len(names) + n:])
 
     def _take(self, res):
         """A decode or prefill call's results: keep its pools (and add
@@ -875,6 +923,8 @@ class ServeEngine:
         self._slo_breach_windows = 0
         self._moe_tot[:] = 0.0
         self._pages_peak = {"full": 0, "window": 0}
+        self._state_slots_peak = 0
+        self._state0 = (self.kv.state_spills, self.kv.state_restores)
         fin0 = len(self.sched.finished)
         rej0 = len(self.sched.rejected)
         pre0 = self.sched.preemptions
@@ -1091,6 +1141,7 @@ class ServeEngine:
             self.peak_active = max(self.peak_active, len(self.sched.active))
             for g, n in self.kv.pages_held().items():
                 self._pages_peak[g] = max(self._pages_peak[g], n)
+            self._state_slots_peak = max(self._state_slots_peak, self.kv.state_slots_held)
             wbt_pf = self._pf_wbt
 
             # 1) prefill: ONE batched dispatch covers every mid-prefill
@@ -1543,7 +1594,20 @@ class ServeEngine:
             "kv_dtype": self.kv_dtype,
             "weight_dtype": self.weight_dtype,
             "kv_pages_held": self.kv.pages_held(),
+            "state": self._state_report(),
             "moe": self._moe_report(),
+        }
+
+    def _state_report(self) -> Optional[Dict[str, Any]]:
+        """The state group as it stands (None without state layers)."""
+        kv = self.kv
+        if not kv.state_layers:
+            return None
+        return {
+            "layers": kv.state_layers, "pool_bytes": kv.state_bytes(),
+            "bytes_per_slot": kv.state_bytes_per_slot,
+            "slots_held": kv.state_slots_held,
+            "spills": kv.state_spills, "restores": kv.state_restores,
         }
 
     def _moe_report(self) -> Optional[Dict[str, Any]]:
@@ -1599,8 +1663,15 @@ class ServeEngine:
             self._moe_tot += np.asarray(self._moe_acc, np.float64)
             self._moe_acc = None
         moe = self._moe_report() or {}
+        n_state = self.kv.state_layers
+        ssm_rows = n_state * sum(
+            r.prompt_len - r.shared_prefix_pos + max(0, r.done_tokens - 1) for r in fin
+        ) if n_state else None
         tracer = get_tracer()
         if tracer.enabled:
+            if n_state:
+                tracer.counter("ssm.rows", float(ssm_rows))
+                tracer.counter("kv.state_pool_bytes", float(self.kv.state_bytes()))
             tracer.counter("kv.rows_visible", float(rows_visible))
             tracer.counter("kv.rows_context", float(rows_context))
             for g, n in self._pages_peak.items():
@@ -1675,6 +1746,11 @@ class ServeEngine:
             moe_experts_touched=moe.get("experts_touched"),
             moe_load_max_over_mean=moe.get("load_max_over_mean"),
             moe_layers=self._moe_layers,
+            state_pool_bytes=self.kv.state_bytes(),
+            state_slots_held=self._state_slots_peak,
+            state_spills=self.kv.state_spills - self._state0[0],
+            state_restores=self.kv.state_restores - self._state0[1],
+            ssm_rows=ssm_rows,
         )
         self.metrics.close()
         if self.spans is not None and self._owns_spans:

@@ -325,6 +325,13 @@ class StatusServer:
             out["window_pool_shape"] = list(eng.kv.win_k.shape)
             out["window"] = eng.kv.window
             out["ring_blocks"] = eng.kv.ring_blocks
+        if eng.kv.state_layers:
+            # the state group: two arrays a layer, a row a slot
+            out["state_layers"] = eng.kv.state_layers
+            out["state_conv_shape"] = list(eng.kv.state_conv[0].shape)
+            out["state_ssm_shape"] = list(eng.kv.state_ssm[0].shape)
+            out["state_pool_bytes"] = eng.kv.state_bytes()
+            out["state_bytes_per_slot"] = eng.kv.state_bytes_per_slot
         return out
 
     def poolz(self) -> Dict[str, Any]:

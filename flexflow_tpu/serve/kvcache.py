@@ -82,6 +82,26 @@ declined for such a model (both groups): a prefix re-attached in the
 full group would leave the window layers without the keys their first
 rows look back to.
 
+**A third group, STATE (PR 34, docs/SERVING.md "State layers").**  A
+state-space layer (``ops/ssm.py``) keeps no rows of a request: its
+memory is a recurrent state of fixed size, read and written whole by
+every step.  With ``state_layers`` > 0 the cache holds, a state layer,
+the convolution's last inputs ``state_conv[i]`` ``(slots, taps - 1,
+channels)`` in the compute dtype and the state ``state_ssm[i]``
+``(slots, heads, head_dim, state)`` float32 -- one array a layer, so
+that a program's update of a layer is in place on a donated buffer --,
+PROVISIONED A SLOT, not paged: a slot's state is there while the slot
+is, whatever the request's length, so a free slot is all admission asks
+of this group (the full group's blocks decide the rest).  Nothing is
+zeroed on the device when a slot changes hands: the prefill program
+reads the state of a lane whose chunk starts at position 0 as zero
+(``programs.py``), which is what :meth:`reserve` relies on; a lane that
+rides idle through a program keeps its state bit for bit.
+:meth:`spill` carries a slot's two states with its K/V (a preempted
+request resumes from both), :meth:`restore` writes them back through a
+donated update (no copy of the pool).  Prefix sharing is declined for
+such a model: a re-attached prefix has no state at its boundary.
+
 **Quantized pools (PR 19, docs/SERVING.md "Quantized KV cache").**
 ``kv_dtype="int8" | "fp8"`` stores the pools in 1-byte elements with
 per-block symmetric scale arrays ``scale_k``/``scale_v`` of shape
@@ -105,6 +125,8 @@ from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from flexflow_tpu.obs import get_tracer
 
 __all__ = [
     "PagedKVCache",
@@ -214,10 +236,16 @@ class PagedKVCache:
         window_layers: int = 0,
         window: int = 0,
         chunk: int = 1,
+        state_layers: int = 0,
+        state_conv: Tuple[int, int] = (0, 0),
+        state_ssm: Tuple[int, int, int] = (0, 0, 0),
     ) -> None:
         """``num_layers`` full layers of ``heads`` K/V heads; with
         ``window_layers`` > 0 also that many layers that see ``window``
-        positions back and are written at most ``chunk`` rows a call."""
+        positions back and are written at most ``chunk`` rows a call;
+        with ``state_layers`` > 0 also that many state layers, each a
+        slot ``state_conv`` = (taps - 1, channels) conv inputs and
+        ``state_ssm`` = (heads, head_dim, state) float32."""
         import jax.numpy as jnp
 
         assert block_size >= 1 and slots >= 1
@@ -229,8 +257,11 @@ class PagedKVCache:
         self.window_layers = int(window_layers)
         self.window = int(window) if self.window_layers else 0
         assert not self.window_layers or self.window >= 1
-        # declined for a model with window layers (module docstring)
-        self.prefix_sharing = bool(prefix_sharing) and not self.window_layers
+        self.state_layers = int(state_layers)
+        # declined for a model with window or state layers (module docstring)
+        self.prefix_sharing = (
+            bool(prefix_sharing) and not self.window_layers and not self.state_layers
+        )
         if max_blocks_per_seq is None:
             assert max_seq_len is not None, (
                 "need max_blocks_per_seq or max_seq_len"
@@ -313,6 +344,19 @@ class PagedKVCache:
             )
             self.win_k = jnp.zeros(wshape, self.dtype)
             self.win_v = jnp.zeros(wshape, self.dtype)
+        # the state group: one array a layer (module docstring)
+        self.state_conv: List[Any] = []
+        self.state_ssm: List[Any] = []
+        self.state_spills = self.state_restores = 0
+        if self.state_layers:
+            if self.quantized:
+                raise ValueError("a quantized pool is not built for state layers")
+            assert min(state_conv) >= 1 and min(state_ssm) >= 1, (state_conv, state_ssm)
+            cdt = dtype if dtype is not None else jnp.float32
+            for _ in range(self.state_layers):
+                self.state_conv.append(jnp.zeros((slots,) + tuple(state_conv), cdt))
+                self.state_ssm.append(jnp.zeros((slots,) + tuple(state_ssm), jnp.float32))
+        self._set_row = None  # the jitted donated write of one slot's state
 
     # --- capacity queries --------------------------------------------------
     @property
@@ -636,6 +680,15 @@ class PagedKVCache:
                     for i in range(self.window_layers)
                 },
             }
+        if self.state_layers:
+            with get_tracer().span("state_spill", cat="serve"):
+                payload["state"] = {
+                    "layers": {
+                        f"layer{i}": {"conv": np.asarray(c[slot]), "ssm": np.asarray(s[slot])}
+                        for i, (c, s) in enumerate(zip(self.state_conv, self.state_ssm))
+                    },
+                }
+                self.state_spills += 1
         self.release(slot)
         return payload
 
@@ -712,8 +765,16 @@ class PagedKVCache:
                 "KV payload and pool disagree on window layers: a payload "
                 "restores into a pool of the same layer groups"
             )
+        if ("state" in payload) != bool(self.state_layers):
+            self.release(slot)
+            raise ValueError(
+                "KV payload and pool disagree on state layers: a payload "
+                "restores into a pool of the same layer groups"
+            )
         if self.window_layers:
             self._restore_window(slot, payload["window"], length)
+        if self.state_layers:
+            self._restore_state(slot, payload["state"])
         if length <= shared_pos:
             return shared_pos
         L, H, BS, D = (
@@ -796,6 +857,48 @@ class PagedKVCache:
         self.win_k = self.win_k.at[:, rows].set(jnp.asarray(k, self.dtype))
         self.win_v = self.win_v.at[:, rows].set(jnp.asarray(v, self.dtype))
 
+    def _restore_state(self, slot: int, state: Dict[str, Any]) -> None:
+        """Write a spilled slot's states back, each through a donated
+        update of its array (in place: no copy of a layer's pool)."""
+        import jax
+
+        if self._set_row is None:
+            self._set_row = jax.jit(
+                lambda pool, at, row: pool.at[at].set(row.astype(pool.dtype)),
+                donate_argnums=0,
+            )
+        with get_tracer().span("state_restore", cat="serve"):
+            for i in range(self.state_layers):
+                got = state["layers"][f"layer{i}"]
+                for pools, name in ((self.state_conv, "conv"), (self.state_ssm, "ssm")):
+                    row = np.asarray(got[name])
+                    if row.shape != tuple(pools[i].shape[1:]):
+                        self.release(slot)
+                        raise ValueError(
+                            f"state payload {name} {row.shape} does not match this "
+                            f"pool's {tuple(pools[i].shape[1:])} a slot"
+                        )
+                    pools[i] = self._set_row(pools[i], np.int32(slot), row)
+            self.state_restores += 1
+
+    @property
+    def state_slots_held(self) -> int:
+        """Slots whose state belongs to a request right now."""
+        return len(self._owned) if self.state_layers else 0
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one slot holds over all state layers,
+        whatever its request's length."""
+        return sum(
+            (a.size // self.slots) * a.dtype.itemsize
+            for a in self.state_conv + self.state_ssm
+        )
+
+    def state_bytes(self) -> int:
+        """Physical footprint of the state group."""
+        return self.state_bytes_per_slot * self.slots
+
     def pages_held(self) -> Dict[str, int]:
         """Pages mapped into slots' tables right now, a layer group: the
         full group's follow the requests' budgets, the window group's are
@@ -840,6 +943,12 @@ class PagedKVCache:
                     f"slot {slot}: the window group's table is not "
                     f"{'its ring' if slot in self._owned else 'the trash block'}"
                 )
+        assert len(self.state_conv) == len(self.state_ssm) == self.state_layers
+        for a in self.state_conv + self.state_ssm:
+            assert a.shape[0] == self.slots and not a.is_deleted(), (
+                "a state layer's array is not one row a slot, or was donated "
+                "and not stored back"
+            )
 
     # --- device-side views -------------------------------------------------
     def _rows(self, blocks) -> np.ndarray:
@@ -905,7 +1014,8 @@ class PagedKVCache:
         """HBM bytes one cached position costs across all layers of both
         groups while every layer holds it (k+v elements over the K/V
         heads, plus the 2 float32 scales per layer when quantized) —
-        the ffmetrics/1 ``kv_bytes_per_token`` field."""
+        the ffmetrics/1 ``kv_bytes_per_token`` field.  The state group
+        costs nothing a position: :attr:`state_bytes_per_slot`."""
         layers = self.num_layers + self.window_layers
         elems = 2 * layers * self.heads * self.head_dim
         n = elems * self.cache_k.dtype.itemsize
@@ -914,11 +1024,11 @@ class PagedKVCache:
         return n
 
     def hbm_bytes(self) -> int:
-        """Physical pool footprint (both caches + scales, both layer
-        groups)."""
+        """Physical pool footprint (both caches + scales, all three
+        layer groups)."""
         n = 2 * self.cache_k.size * self.cache_k.dtype.itemsize
         if self.quantized:
             n += 2 * self.scale_k.size * 4
         if self.window_layers:
             n += 2 * self.win_k.size * self.win_k.dtype.itemsize
-        return n
+        return n + self.state_bytes()

@@ -8,14 +8,25 @@ through ``layers`` unrolled blocks that write the rows' K/V into the
 pools and attend over each lane's pages, and a head turns rows into
 their argmax (and, where sampling asks, a float32 distribution).  What a
 block IS comes from the decoder spec (``models/gpt_decode.py::GPTSpec``,
-read off the compiled model's layers): LayerNorm or RMSNorm, with or
-without a norm after each mixer; one head count or grouped K/V heads
-with per-head q/k norm, rotary positions by absolute position and a
-sigmoid output gate; every earlier key or a window (then the layer
-lives in the pool's WINDOW group and its walk starts at the first
-position a row still sees); GELU, gated dense or routed experts
-(``ops/moe.py``'s sorted rows and grouped matmuls, dropless, every
-expert held; rows of idle lanes and past ``n_valid`` routed nowhere).  They differ in rows a lane
+read off the compiled model's layers): a layer is a SEQUENCE OF RESIDUAL
+BRANCHES ``x + [norm](mixer(norm(x)))`` -- two in GPT-2 and Trinity (an
+attention and an FFN), one in Nemotron-H -- LayerNorm or RMSNorm; the
+mixer an attention with one head count or grouped K/V heads, with or
+without per-head q/k norm, rotary positions by absolute position and a
+sigmoid output gate, seeing every earlier key or a window (then the
+layer lives in the pool's WINDOW group and its walk starts at the first
+position a row still sees); an FFN: GELU, gated dense or routed experts
+(``ops/moe.py``'s sorted rows and grouped matmuls, dropless, gated or
+``relu2``, all held or the share the layer's own ``first_expert`` and
+weights say -- the router stays as wide as published and what absent
+experts would add is left out; rows of idle lanes and past ``n_valid``
+routed nowhere); or a Mamba-2 state-space mixer
+(``ops/ssm.py::mamba2_mixer``, the op's own function) whose two states
+live in the pool's STATE group, a row a slot: a decode step is one step
+of the recurrence, a prefill chunk the chunked scan from the slot's
+state (zero where the chunk starts at position 0), the arrays donated
+and updated in place, rows past ``n_valid`` and idle lanes changing
+neither.  They differ in rows a lane
 (``G`` = 1, ``P``, 1, ``k + 1``), layers run (all, or the first
 ``spec_draft_layers``), whether ``n_valid`` masks padded rows, and
 which rows reach the head.  Inactive lanes carry an all-zero table row,
@@ -28,9 +39,10 @@ program with another compile time (ROADMAP S4).
 
 What reads the programs from outside, and so may not move: the jitted
 functions' names (``jit_decode`` ... in a trace and in the compile
-cache), their arguments ``(params, ck, cv[, sk, sv][, wk, wv], ...)``
-and outputs ``(nxt, probs | None, [moe stats,] ck, cv[, sk, sv][, wk,
-wv])`` (analysis/capture.py, the benchmark's tests),
+cache), their arguments ``(params, ck, cv[, sk, sv][, wk, wv][, conv
+states, ssm states], ...)`` and outputs ``(nxt, probs | None, [moe
+stats,] ck, cv[, sk, sv][, wk, wv][, conv states, ssm states])``
+(analysis/capture.py, the benchmark's tests),
 and the paged kernel's two names — ``prefill`` calls it as
 ``%prefill.N``, the other three as ``%decode.N``, which is how the
 benchmark's ``paged_attention_roofline.*`` tells a chunk's call from a
@@ -53,16 +65,18 @@ from flexflow_tpu.serve.kvcache import PagedKVCache, quantize_kv
 
 __all__ = ["ServePrograms", "build_serve_programs", "MOE_STATS"]
 
-# weights an op declares float32 (the router) stay so in a program
+# weights an op declares float32 stay so in a program: the router's,
+# and a state-space layer's decay, step bias and skip
 KEEP_F32 = ("router", "router_bias")
+KEEP_F32_STATE = ("A_log", "dt_bias", "D")
 
 
 def keep_float32(spec: GPTSpec):
     """``(layer name, weight name)`` of every leaf the programs take in
     float32 whatever the compute dtype."""
+    kept = {"moe": KEEP_F32, "mamba2": KEEP_F32_STATE}
     return [
-        (ls.ffn[0], w) for ls in spec.layers if ls.ffn_kind == "moe"
-        for w in KEEP_F32
+        (br.mixer[0], w) for br in spec.branches for w in kept.get(br.kind, ())
     ]
 
 # what a program of a model with routed experts returns after its
@@ -143,8 +157,13 @@ def build_serve_programs(
     return_probs: bool = True,
 ) -> ServePrograms:
     """Jit the serve programs of a compiled decoder (anything
-    ``GPTSpec.from_model`` reads: ``gpt_decoder``, ``afmoe_decoder``)
-    over the pool geometry of ``kv``.  ``attn_kernel`` is the engine's
+    ``GPTSpec.from_model`` reads: ``gpt_decoder``, ``afmoe_decoder``,
+    ``nemotron_h_decoder`` -- layers of one or two residual branches,
+    attention over the full or the window group, state-space layers over
+    the state group, routed experts all held or a share) over the pool
+    geometry of ``kv``.  ``draft`` / ``verify``, int8 weights and a
+    quantized pool are built for ``gpt_decoder``-shaped models only
+    (``ServeEngine`` refuses the rest by name).  ``attn_kernel`` is the engine's
     resolved decision (``paged`` | ``gather``); ``draft`` and ``verify``
     are built only with ``spec_k``; ``return_probs`` false (greedy
     decoding) leaves the float32 distribution out of the outputs
@@ -169,6 +188,7 @@ def build_serve_programs(
         shared_expert_part,
     )
     from flexflow_tpu.ops.norm import rms_norm_f32, rms_norm_zero_centered
+    from flexflow_tpu.ops.ssm import mamba2_mixer
     from flexflow_tpu.ops.pallas.paged_attention import (
         paged_decode_attention,
         paged_kv_write,
@@ -192,19 +212,26 @@ def build_serve_programs(
     # and a routed layer turns that into another choice of experts.  The
     # norms read it in float32 and hand the matmuls the compute dtype
     rdt = cdt if spec.is_gpt else jnp.float32
-    # which pool a layer's K/V live in, and where: a window layer in the
-    # window group's ring, every other in the full group
-    where, n_full, n_win = [], 0, 0
-    for ls in spec.layers:
-        if ls.window:
-            where.append(("window", n_win))
-            n_win += 1
-        else:
-            where.append(("full", n_full))
-            n_full += 1
-    assert (n_full, n_win) == (kv.num_layers, kv.window_layers), (
+    # which pool a branch's memory lives in, and where: a window layer's
+    # K/V in the window group's ring, every other attention's in the full
+    # group, and a state-space layer's two states in the state group
+    where, n_full, n_win, n_state = {}, 0, 0, 0
+    for i, ls in enumerate(spec.layers):
+        for j, br in enumerate(ls.branches):
+            if br.kind == "mamba2":
+                where[(i, j)] = ("state", n_state)
+                n_state += 1
+            elif br.is_attention and br.window:
+                where[(i, j)] = ("window", n_win)
+                n_win += 1
+            elif br.is_attention:
+                where[(i, j)] = ("full", n_full)
+                n_full += 1
+            else:
+                where[(i, j)] = None
+    assert (n_full, n_win, n_state) == (kv.num_layers, kv.window_layers, kv.state_layers), (
         "the pool's layer groups are not the model's",
-        (n_full, n_win), (kv.num_layers, kv.window_layers),
+        (n_full, n_win, n_state), (kv.num_layers, kv.window_layers, kv.state_layers),
     )
     R = kv.ring_blocks
     # quantized-pool trace-time switch: with ``quant`` the programs
@@ -344,14 +371,14 @@ def build_serve_programs(
             x = x * jnp.asarray(spec.embed_scale, x.dtype)
         return x.reshape(-1, x.shape[-1])
 
-    def project(ls, p_at, h, pos, G):
+    def project(br, p_at, h, pos, G):
         # rows h (B * G, hidden) -> q (B, G, H, D), k, v (B, G, KVH, D),
         # the output gate (B * G, H * D) or None
-        if ls.attn_kind == "mha":
+        if br.kind == "mha":
             q = h @ p_at["wq"]
             k = h @ p_at["wk"]
             v = h @ p_at["wv"]
-            if ls.has_bias:
+            if br.has_bias:
                 q, k, v = q + p_at["bq"], k + p_at["bk"], v + p_at["bv"]
             return (q.reshape(B, G, H, D), k.reshape(B, G, KVH, D),
                     v.reshape(B, G, KVH, D), None)
@@ -360,30 +387,34 @@ def build_serve_programs(
         q, gate = qg[..., :D], qg[..., D:].reshape(B * G, H * D)
         k = (h @ p_at["wk"]).reshape(B, G, KVH, D)
         v = (h @ p_at["wv"]).reshape(B, G, KVH, D)
-        qk_norm = rms_norm_zero_centered if ls.qk_norm_zero_centered else rms_norm_f32
-        q = qk_norm(q, p_at["q_norm"], ls.qk_eps)
-        k = qk_norm(k, p_at["k_norm"], ls.qk_eps)
-        if ls.rotary_dim:
-            q = rotate_half_rope_at(q, pos, ls.rotary_dim, ls.rope_theta)
-            k = rotate_half_rope_at(k, pos, ls.rotary_dim, ls.rope_theta)
+        qk_norm = rms_norm_zero_centered if br.qk_norm_zero_centered else rms_norm_f32
+        q = qk_norm(q, p_at["q_norm"], br.qk_eps)
+        k = qk_norm(k, p_at["k_norm"], br.qk_eps)
+        if br.rotary_dim:
+            q = rotate_half_rope_at(q, pos, br.rotary_dim, br.rope_theta)
+            k = rotate_half_rope_at(k, pos, br.rotary_dim, br.rope_theta)
         return q.astype(h.dtype), k.astype(h.dtype), v, gate
 
-    def experts(ls, p, h32, valid):
+    def experts(br, p, h32, valid):
         # the routed block over rows h32 (T, hidden), the normed stream in
         # float32: the router reads it as it is (an expert choice that
         # flips against the reference moves a whole position's output),
         # the experts in the compute dtype; ``valid`` (T,): rows of idle
         # lanes and past n_valid are routed to no expert
-        a = ls.moe
+        a = br.attrs
         n, k = a["n_experts"], a["top_k"]
         h = h32.astype(cdt)
         with jax.named_scope("ff.moe.route"):
             w, idx = route_top_k(h32, p["router"], k, **router_rule(a, p))
             idx = jnp.where(valid[:, None], idx, n)
         with jax.named_scope("ff.moe.experts"):
+            # the op's own share: the router is n wide, the experts
+            # held are those the weights hold, and what the rest would
+            # add is left out (no code stands in for their chip)
             out, counts, _, _ = held_experts_part(
-                h, w, idx, 0, serve_pass_rows(h.shape[0] * k),
-                p["w_gate"], p["w_up"], p["w_down"],
+                h, w, idx, a["first_expert"], serve_pass_rows(h.shape[0] * k),
+                p.get("w_gate"), p["w_up"], p["w_down"],
+                a.get("expert_form", "gated"),
             )
         if a["shared_hidden"]:
             with jax.named_scope("ff.moe.shared"):
@@ -397,19 +428,19 @@ def build_serve_programs(
         ])
         return out, stats  # float32
 
-    def block(i, params, x, pools, stats, start, bts, n_valid, G, attn):
-        # layer i over rows x (B * G, hidden)
-        ls = spec.layers[i]
-        group, gi = where[i]
+    def attention_branch(br, at, params, x, pools, start, bts, n_valid, G, attn):
+        # an attention branch over rows x (B * G, hidden) -> (its output
+        # before the residual add, pools)
+        group, gi = at
         ring = group == "window"
         bt = bts[1] if ring else bts[0]
-        ck, cv, sk, sv, wk, wv = pools
+        ck, cv, sk, sv, wk, wv = pools[:6]
         pk, pv = (wk, wv) if ring else (ck, cv)
-        p_at = params[ls.attn]
+        p_at = params[br.mixer[0]]
         pos = start[:, None] + jnp.arange(G)[None, :]
         with jax.named_scope("ff.attn_window" if ring else "ff.attn_full"):
-            h = norm(params[ls.norm_in], x)
-            q, k, v, gate = project(ls, p_at, h, pos, G)
+            h = norm(params[br.norm_in], x)
+            q, k, v, gate = project(br, p_at, h, pos, G)
             # write all G rows, THEN attend: row g's mask reaches rows 0..g
             # of this same program, freshly written — and under prefix
             # sharing a chunk never writes a still-shared block (commit
@@ -430,7 +461,7 @@ def build_serve_programs(
                 o = attn(
                     q, pk, pv, start, bt, scale=scale,
                     scale_k=sk, scale_v=sv, layer=gi, block_size=BS,
-                    window=ls.window,
+                    window=br.window,
                 )
             else:
                 keys, vals = gather_kv(pk, pv, sk, sv, gi, bt)
@@ -438,7 +469,7 @@ def build_serve_programs(
                     k_pos = ring_positions(start + G - 1)[:, None, :]  # (B, 1, R*BS)
                     mask = (
                         (k_pos <= pos[..., None]) & (k_pos >= 0)
-                        & (k_pos > pos[..., None] - ls.window)
+                        & (k_pos > pos[..., None] - br.window)
                     )[:, :, None, :]
                 else:
                     mask = (
@@ -452,34 +483,82 @@ def build_serve_programs(
             if gate is not None:
                 o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
             o = o @ p_at["wo"]
-            if ls.has_bias:
+            if br.has_bias:
                 o = o + p_at["bo"]
-            if ls.norm_post_attn is not None:
-                o = norm(params[ls.norm_post_attn], o, rdt)
-        pools = (ck, cv, sk, sv, pk, pv) if ring else (pk, pv, sk, sv, wk, wv)
-        x = x + o.astype(rdt)
-        moe = ls.ffn_kind == "moe"
-        h = norm(params[ls.norm_pre_ffn], x, jnp.float32 if moe else cdt)
-        if ls.ffn_kind == "gelu":
-            p0, p1 = params[ls.ffn[0]], params[ls.ffn[1]]
+            if br.norm_post is not None:
+                o = norm(params[br.norm_post], o, rdt)
+        kvs = (ck, cv, sk, sv, pk, pv) if ring else (pk, pv, sk, sv, wk, wv)
+        return o, kvs + pools[6:]
+
+    def live_rows(bts, n_valid, G):
+        # (B, G) bool: the rows that belong to a request.  A decode-width
+        # program has no n_valid: a live lane holds a reservation, so its
+        # first page is not the trash block
+        if n_valid is None:
+            return jnp.broadcast_to(bts[0][:, :1] > 0, (B, G))
+        return jnp.arange(G)[None, :] < n_valid[:, None]
+
+    def state_branch(br, si, params, x, pools, start, bts, n_valid, G):
+        # a state-space branch (ops/ssm.py::mamba2_mixer, the op's own
+        # function) from layer si's two states, which it hands back: a
+        # lane whose chunk starts at position 0 reads them as zero (a
+        # slot is recycled without touching the device), rows past
+        # n_valid change neither, and a lane with no live row keeps both
+        # bit for bit
+        conv, ssm = pools[6][si], pools[7][si]
+        if n_valid is None:  # decode width: a live lane's one row
+            n_live = (bts[0][:, 0] > 0).astype(jnp.int32)
+            conv_in, ssm_in = conv, ssm
+        else:
+            n_live = n_valid
+            fresh = (start == 0) & (n_live > 0)
+            conv_in = jnp.where(fresh[:, None, None], jnp.zeros_like(conv), conv)
+            ssm_in = jnp.where(fresh[:, None, None, None], jnp.zeros_like(ssm), ssm)
+        h = norm(params[br.norm_in], x).reshape(B, G, -1)
+        o, conv_out, ssm_out = mamba2_mixer(
+            params[br.mixer[0]], h, br.attrs, conv_in, ssm_in, n_live,
+        )
+        ssm_out = jnp.where((n_live > 0)[:, None, None, None], ssm_out, ssm)
+        o = o.reshape(B * G, -1)
+        if br.norm_post is not None:
+            o = norm(params[br.norm_post], o, rdt)
+        convs, ssms = list(pools[6]), list(pools[7])
+        convs[si], ssms[si] = conv_out.astype(conv.dtype), ssm_out
+        return o, pools[:6] + (tuple(convs), tuple(ssms))
+
+    def ffn_branch(br, params, x, stats, bts, n_valid, G):
+        moe = br.kind == "moe"
+        h = norm(params[br.norm_in], x, jnp.float32 if moe else cdt)
+        if br.kind == "gelu":
+            p0, p1 = params[br.mixer[0]], params[br.mixer[1]]
             f = jax.nn.gelu(h @ p0["kernel"] + p0["bias"])
             f = f @ p1["kernel"] + p1["bias"]
-        elif ls.ffn_kind == "gated":
-            p0 = params[ls.ffn[0]]
+        elif br.kind == "gated_ffn":
+            p0 = params[br.mixer[0]]
             with jax.named_scope("ff.ffn_dense"):
                 f = gated_ffn(h, p0["w_gate"], p0["w_up"], p0["w_down"])
         else:
-            if n_valid is None:
-                # a live lane holds a reservation: its first page is not
-                # the trash block
-                valid = jnp.broadcast_to(bts[0][:, :1] > 0, (B, G))
-            else:
-                valid = jnp.arange(G)[None, :] < n_valid[:, None]
-            f, st = experts(ls, params[ls.ffn[0]], h, valid.reshape(-1))
+            valid = live_rows(bts, n_valid, G)
+            f, st = experts(br, params[br.mixer[0]], h, valid.reshape(-1))
             stats = stats + st
-        if ls.norm_post_ffn is not None:
-            f = norm(params[ls.norm_post_ffn], f, rdt)
-        return x + f.astype(rdt), pools, stats
+        if br.norm_post is not None:
+            f = norm(params[br.norm_post], f, rdt)
+        return f, stats
+
+    def block(i, params, x, pools, stats, start, bts, n_valid, G, attn):
+        # layer i over rows x (B * G, hidden): its residual branches, in order
+        for j, br in enumerate(spec.layers[i].branches):
+            at = where[(i, j)]
+            if br.is_attention:
+                f, pools = attention_branch(
+                    br, at, params, x, pools, start, bts, n_valid, G, attn)
+            elif br.kind == "mamba2":
+                f, pools = state_branch(
+                    br, at[1], params, x, pools, start, bts, n_valid, G)
+            else:
+                f, stats = ffn_branch(br, params, x, stats, bts, n_valid, G)
+            x = x + f.astype(rdt)
+        return x, pools, stats
 
     def trunk(params, pools, toks, start, bt, *, layers, n_valid=None, attn):
         # toks (B, G) int32, start / n_valid (B,), bt (B, MB) block
@@ -570,19 +649,23 @@ def build_serve_programs(
 
     # which of (ck, cv, sk, sv, wk, wv) this engine's programs thread
     have = (True, True, quant, quant, bool(n_win), bool(n_win))
-    n_pools = sum(have)
+    n_kv = sum(have)
+    n_pools = n_kv + 2 * n_state
     donate = tuple(range(1, 1 + n_pools))
 
     def program(body):
         # the jitted signature of every program: a quantized pool threads
         # its two scale pools right after the K/V pools, a model with
-        # window layers its window group's pools after those; all donated
-        # and returned in that order
+        # window layers its window group's pools after those, one with
+        # state layers every layer's conv state and then every layer's
+        # ssm state last; all donated and returned in that order
         def run(params, *args):
-            given = iter(args[:n_pools])
-            pools = tuple(next(given) if h else None for h in have)
+            given = iter(args[:n_kv])
+            pools = tuple(next(given) if h else None for h in have) + (
+                tuple(args[n_kv:n_kv + n_state]), tuple(args[n_kv + n_state:n_pools]),
+            )
             outs, pools = body(prep_params(params), pools, *args[n_pools:])
-            return (*outs, *(x for x, h in zip(pools, have) if h))
+            return (*outs, *(x for x, h in zip(pools, have) if h), *pools[6], *pools[7])
 
         run.__name__ = run.__qualname__ = body.__name__
         return jax.jit(run, donate_argnums=donate)

@@ -369,3 +369,73 @@ def test_page_write_through_a_ring_lowers_for_tpu(G):
         jax.ShapeDtypeStruct((slots, TRING), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.int32),
     )
+
+
+# Nemotron-3-Nano's serving geometry (ISSUE 34): 32 query / 2 K/V heads
+# of 128 (a pool row of 256 lanes, 16 query heads a K/V head), a table
+# of 136 pages of 16 for 2,176 positions; Mamba-2 with 64 heads of 64,
+# state 128, 8 groups, conv 4 over 6,144 channels, hidden 2,688
+NQH, NKV, ND, NMB = 32, 2, 128, 136
+NH, NP, NG, NN, NE = 64, 64, 8, 128, 2688
+
+
+@pytest.mark.parametrize("G", [1, 128, 256])
+def test_attention_and_page_write_lower_at_16_query_heads_a_kv_head(G):
+    slots = 8
+    n = slots * NMB + 1
+    entry = pa.paged_prefill_attention if G > 1 else pa.paged_decode_attention
+    dt = jnp.bfloat16
+    pool = jax.ShapeDtypeStruct((1, n * BS, NKV * ND), dt)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    bt = jax.ShapeDtypeStruct((slots, NMB), jnp.int32)
+
+    def attend(q, pk, pv, pos, bt):
+        return entry(q, pk, pv, pos, bt, layer=jnp.int32(0), block_size=BS)
+
+    _lower_for_tpu(attend, jax.ShapeDtypeStruct((slots, G, NQH, ND), dt), pool, pool, ints, bt)
+
+    def write(pk, pv, k, v, start, bt, n_valid):
+        return pa.paged_kv_write(pk, pv, jnp.int32(0), k, v, start, bt, n_valid, block_size=BS)
+
+    kv = jax.ShapeDtypeStruct((slots, G, NKV, ND), dt)
+    _lower_for_tpu(write, pool, pool, kv, kv, ints, bt, ints)
+
+
+@pytest.mark.parametrize("G", [1, 128])
+def test_state_layer_updates_its_state_in_place_on_the_tpu(G):
+    """The Mamba-2 mixer at the published widths, as a serve program
+    runs it (one step, or a chunk from a slot's state), both states
+    donated: the compiled module updates the state (2.1 MB a slot) in
+    place -- no copy of the whole array; the conv's tail (36 KB a slot,
+    a shift register that every call rewrites whole) is not held to
+    that."""
+    from flexflow_tpu.ops import ssm
+
+    sh = _v5e_sharding()
+    if sh is None:
+        pytest.skip("no compile-only v5e topology")
+    slots, dt = 16, jnp.bfloat16
+    attrs = dict(num_heads=NH, head_dim=NP, n_groups=NG, state_size=NN, conv_kernel=4,
+                 chunk=128, eps=1e-5)
+    d, cw = NH * NP, NH * NP + 2 * NG * NN
+    shapes = {
+        "in_proj": ((NE, d + cw + NH), dt), "conv": ((cw, 4), dt), "conv_bias": ((cw,), dt),
+        "A_log": ((NH,), jnp.float32), "dt_bias": ((NH,), jnp.float32),
+        "D": ((NH,), jnp.float32), "scale": ((d,), dt), "out_proj": ((d, NE), dt),
+    }
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    params = {k: place(*v) for k, v in shapes.items()}
+    conv, state = place((slots, 3, cw), dt), place((slots, NH, NP, NN), jnp.float32)
+
+    def layer(params, conv, state, u, n_valid):
+        o, c, s = ssm.mamba2_mixer(params, u, attrs, conv, state, n_valid)
+        return o, c, jnp.where((n_valid > 0)[:, None, None, None], s, state)
+
+    text = jax.jit(layer, donate_argnums=(1, 2)).lower(
+        params, conv, state, place((slots, G, NE), dt), place((slots,), jnp.int32),
+    ).compile().as_text()
+    assert "input_output_alias={ {1}: (8, {}, may-alias), {2}: (9, {}, may-alias) }" in text
+    assert count_pool_relayouts(text, slots * NH * NP * NN * 4) == 0

@@ -12,7 +12,8 @@ says so and the sweep goes on.  ``--adaptive`` tries every ``slots`` at the
 middle chunk first and the other chunks only at the best of those.
 ``--check-kernel`` first compares the paged kernel at the cell's head
 geometry (grouped heads, window, ring) with a dense float32 softmax on the
-chip.  Not part of a benchmark run; ``PERF.md`` records what it read.
+chip; ``--audit`` counts the first pair's compiled programs' whole-pool
+copies and weight casts.  Not part of a benchmark run; ``PERF.md`` records what it read.
 """
 
 from __future__ import annotations
@@ -36,10 +37,10 @@ def check_kernel(m, e):
     from flexflow_tpu.ops.pallas import paged_attention as pa
 
     QH, KVH, D = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    BS, W, P = e["block_size"], int(m["sliding_window"]), e["prefill_chunk"]
+    BS, W, P = e["block_size"], int(m.get("sliding_window") or 0), e["prefill_chunk"]
     pos = np.asarray([5, W + 52, 3 * W + 777], np.int32)
     out = {}
-    for window in (0, W):
+    for window in sorted({0, W}):  # a model without window layers: the full walk alone
         for G in (1, P):
             S = int(pos.max()) + G
             R = -(-(W + P) // BS) + 1 if window else -(-S // BS)
@@ -92,15 +93,20 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--adaptive", action="store_true")
     ap.add_argument("--check-kernel", action="store_true")
+    ap.add_argument("--audit", action="store_true",
+                    help="on the first pair, also count the compiled programs' pool "
+                         "copies and weight casts (four more compiles)")
     args = ap.parse_args(argv)
 
     from benchmarks import run as R
     from benchmarks import traffic as T
-    from benchmarks import weights_by_leaf as WL
     from benchmarks.jobs import serve as S
 
     cell, config, _, device, _ = R.prepare(args.workload)
     import importlib
+
+    # the cell's job names the module its weights are drawn by
+    WL = importlib.import_module(f"benchmarks.jobs.{cell['job']}").WL
 
     import jax
 
@@ -127,7 +133,10 @@ def main(argv=None) -> int:
     print(json.dumps({"model_s": time.perf_counter() - t0, "device": device,
                       "held_bytes": R.memory_stats().get("bytes_in_use")}), flush=True)
 
+    audit = args.audit  # the first pair only
+
     def one(n_slots, chunk):
+        nonlocal audit
         row = {"slots": n_slots, "prefill_chunk": chunk}
         try:
             t1 = time.perf_counter()
@@ -153,6 +162,13 @@ def main(argv=None) -> int:
                 prefill_positions=sum(int(r.prefill_pos) for r in reqs),
                 peak_bytes=R.memory_peak_bytes(),
             )
+            if audit:
+                audit = False
+                row.update(pool_relayouts=engine.pool_relayouts(),
+                           weight_casts=engine.weight_casts(),
+                           attn_interpret=engine.attn_interpret,
+                           pool_bytes=engine.kv.hbm_bytes(),
+                           state_pool_bytes=engine.kv.state_bytes())
             del engine, reqs, report
         except Exception as exc:  # noqa: BLE001 -- a pair that does not fit is a reading
             row["error"] = f"{type(exc).__name__}: {str(exc)[:300]}"

@@ -30,10 +30,12 @@ import time
 
 import numpy as np
 
+from benchmarks import trace_reduce as TR
 from benchmarks import traffic as T
 from benchmarks import weights as W
 
 SAMPLE_REQUESTS = 6  # requests the reference re-reads: some hundreds of tokens
+PROGRAMS = r"^jit_(decode|prefill)"  # the engine's programs on the trace's XLA Modules line
 
 
 def build_engine(config: dict, cell: dict, seed: int, shapes: dict):
@@ -101,7 +103,7 @@ def drive(engine, reqs, *, seconds: float, backlog: bool, trace=None):
             if stop.wait(trace["start_s"]):
                 return
             c0 = (engine.windows, engine.decode_steps, engine.prefill_dispatches)
-            jax.profiler.start_trace(trace["dir"])
+            TR.start_trace(trace["dir"])
             t0 = time.perf_counter()
             stop.wait(trace["seconds"])
             slice_["window_s"] = time.perf_counter() - t0
@@ -110,6 +112,10 @@ def drive(engine, reqs, *, seconds: float, backlog: bool, trace=None):
             slice_["windows"] = c1[0] - c0[0]
             slice_["decode_steps"] = c1[1] - c0[1]
             slice_["prefill_dispatches"] = c1[2] - c0[2]
+            slice_["steps"] = slice_["program_calls"] = (
+                slice_["decode_steps"] + slice_["prefill_dispatches"]
+            )
+            slice_["programs"] = PROGRAMS
 
         threads.append(threading.Thread(target=profile, daemon=True))
     for t in threads:
@@ -138,6 +144,31 @@ def request_work(reqs, prefill_chunk: int, work) -> dict:
         for k in tot:
             tot[k] += w[k]
     return tot
+
+
+def prefill_rows(reqs, report, engine_settings: dict) -> dict:
+    """Two facts: the prompt positions the window's requests were
+    prefilled by, and the rows its prefill dispatches computed -- each
+    dispatch ``slots x prefill_chunk``, whoever is mid-prompt."""
+    e = engine_settings
+    return {
+        "prefill_positions": sum(
+            r.prompt_len if r.tokens else int(r.prefill_pos) for r in reqs
+        ),
+        "prefill_rows_computed": report.prefill_dispatches * e["slots"] * e["prefill_chunk"],
+    }
+
+
+def program_relayouts(ctx, engine) -> dict:
+    """A fact of a traced run: the copies of a whole pool, the casts of a
+    float32 weight and the copies of a whole expert stack in the engine's
+    compiled decode and prefill programs (it lowers and compiles both
+    again, after the window: seconds that an untraced run is spared)."""
+    if not ctx.trace:
+        return {}
+    t0 = time.perf_counter()
+    n = engine.pool_relayouts() + engine.weight_casts() + engine.expert_relayouts()
+    return {"program_relayouts": n, "program_relayouts_s": time.perf_counter() - t0}
 
 
 def finished_rows(reqs) -> list:
@@ -335,6 +366,8 @@ def run(ctx) -> dict:
         "memory_stats_after_window": memory_stats,
         "setup_parts_s": {n: tm - marks[i][1] for i, (n, tm) in enumerate(marks[1:])},
         "serve_compile_s": marks[1][1] - marks[0][1],
+        **prefill_rows(reqs, report, e),
+        **program_relayouts(ctx, engine),
     }
     sample = pick_sample(fin_rows, ctx.seed)
 
@@ -346,8 +379,6 @@ def run(ctx) -> dict:
     checks = checks_from(ctx, sample, fin_rows)
     facts["reference_s"] = time.perf_counter() - t_ref
     facts["sample_tokens"] = sum(r["n_tokens"] for r in sample)
-    if slice_ is not None:
-        slice_["steps"] = slice_["decode_steps"] + slice_["prefill_dispatches"]
     return {
         "t_window_start": t_start,
         "metrics": metrics,

@@ -46,6 +46,8 @@ from benchmarks.jobs.serve import (
     pack_sample,
     pct,
     pick_sample,
+    prefill_rows,
+    program_relayouts,
     queue_depths,
     to_requests,
 )
@@ -215,6 +217,8 @@ def run(ctx) -> dict:
         "memory_peak_bytes_setup": setup_peak,
         "setup_parts_s": {n: tm - marks[i][1] for i, (n, tm) in enumerate(marks[1:])},
         "serve_compile_s": marks[1][1] - marks[0][1],
+        **prefill_rows(reqs, report, e),
+        **program_relayouts(ctx, engine),
     }
     sample = pick_sample(fin_rows, ctx.seed)
 
@@ -227,8 +231,6 @@ def run(ctx) -> dict:
     facts["reference_s"] = time.perf_counter() - t_ref
     facts["sample_tokens"] = sum(r["n_tokens"] for r in sample)
     facts["sample_longest"] = max((len(r["prompt"]) + r["n_tokens"] for r in sample), default=0)
-    if slice_ is not None:
-        slice_["steps"] = slice_["decode_steps"] + slice_["prefill_dispatches"]
     return {
         "t_window_start": t_start,
         "metrics": metrics,

@@ -20,7 +20,10 @@ import time
 
 import numpy as np
 
+from benchmarks import trace_reduce as TR
 from benchmarks import weights as W
+
+PROGRAMS = r"^jit_step"  # the executor's step program on the trace's XLA Modules line
 
 
 def synthetic_classes(n, seq, hidden, classes, seed):
@@ -213,12 +216,13 @@ def run(ctx) -> dict:
             break
         tracing = ctx.trace and trace is None and elapsed >= 0.4 * ctx.seconds
         if tracing:
-            jax.profiler.start_trace(ctx.trace_dir)
+            TR.start_trace(ctx.trace_dir)
             t_tr = time.perf_counter()
         fit_once(model, x, y)
         if tracing:
             jax.block_until_ready(ex.params)
-            trace = {"window_s": time.perf_counter() - t_tr, "steps": steps_per_fit}
+            trace = {"window_s": time.perf_counter() - t_tr, "steps": steps_per_fit,
+                     "programs": PROGRAMS, "program_calls": steps_per_fit}
             jax.profiler.stop_trace()
         steps += steps_per_fit
     jax.block_until_ready(ex.params)

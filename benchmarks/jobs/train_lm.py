@@ -40,9 +40,10 @@ from unittest import mock
 
 import numpy as np
 
+from benchmarks import trace_reduce as TR
 from benchmarks import weights as W
 from benchmarks import work_qwen3_next as work_lm
-from benchmarks.jobs.train import compare, per_layer_norms
+from benchmarks.jobs.train import PROGRAMS, compare, per_layer_norms
 
 CHECK_STEPS = 3
 
@@ -241,12 +242,13 @@ def run(ctx) -> dict:
             break
         tracing = ctx.trace and trace is None and elapsed >= 0.4 * ctx.seconds
         if tracing:
-            jax.profiler.start_trace(ctx.trace_dir)
+            TR.start_trace(ctx.trace_dir)
             t_tr = time.perf_counter()
         window.fit(model, x, y)
         if tracing:
             jax.block_until_ready(ex.params)
-            trace = {"window_s": time.perf_counter() - t_tr, "steps": steps_per_fit}
+            trace = {"window_s": time.perf_counter() - t_tr, "steps": steps_per_fit,
+                     "programs": PROGRAMS, "program_calls": steps_per_fit}
             jax.profiler.stop_trace()
         steps += steps_per_fit
     jax.block_until_ready(ex.params)

@@ -11,7 +11,8 @@ None), ``peaks`` (this chip's row of ``peaks.json``) and ``chips``.
 A reader that finds nothing to read returns None and the metric is left
 out of the line.  None of them returns 0 for a share of a peak.
 A later PR that needs another reader adds a module and names it by its
-dotted path (``"reader": "benchmarks.more_readers:fn"``).
+dotted path (``"reader": "benchmarks.more_readers:fn"``), as the readers
+of the program's ``ff.*`` spans are named (``benchmarks/span_readers.py``).
 """
 
 from __future__ import annotations
@@ -104,3 +105,31 @@ def op_time_share(run, *, regex):
         return None
     seconds, _ = TR.time_by_regex(run.trace["events"], regex)
     return 100.0 * seconds / run.trace["busy_s"]
+
+
+def _module_time(run, regex):
+    if not run.trace:
+        return None
+    seconds, calls = TR.time_by_regex(run.trace["events"], regex, line=TR.MODULES_LINE)
+    return (seconds, calls) if calls and seconds > 0 else None
+
+
+def module_time_share(run, *, regex):
+    """The share of the device's busy time in the traced slice that the
+    programs matching ``regex`` took (one ``XLA Modules`` event an
+    executed program, named ``jit_<function>``).  A program's event
+    spans its operations and the gaps between them, so the shares of all
+    programs can sum to a little over 100.  None where the line holds no
+    such program: a trace from before the program had that name has
+    nothing to read."""
+    got = _module_time(run, regex)
+    if got is None or run.trace["busy_s"] <= 0:
+        return None
+    return 100.0 * got[0] / run.trace["busy_s"]
+
+
+def module_ms_per_call(run, *, regex):
+    """Mean device milliseconds a call of the programs matching ``regex``
+    in the traced slice: summed ``XLA Modules`` time over its events."""
+    got = _module_time(run, regex)
+    return None if got is None else 1e3 * got[0] / got[1]

@@ -7,7 +7,8 @@ file under ``workloads/``, its traffic mix under ``traffic_mixes/``, its
 configuration under ``configs/``, the
 configuration's plain reference under ``reference/``, the cell's job
 under ``jobs/``, each per-layer metric under ``layer_metrics/`` and its
-reader in ``readers.py``.  This file holds no table of cells, models or
+reader in ``readers.py`` or, named ``module:function``, in a module of
+its own (``span_readers.py``).  This file holds no table of cells, models or
 metrics.
 
 Needs a TPU whose ``device_kind`` is in ``peaks.json`` and as many chips
@@ -115,6 +116,7 @@ def memory_peak_bytes() -> int:
 
 
 def reduce_trace(trace_dir: str, slice_facts: dict) -> dict:
+    from benchmarks import span_readers as SR
     from benchmarks import trace_reduce as TR
 
     events = TR.load(TR.find_xplane(trace_dir))
@@ -122,7 +124,10 @@ def reduce_trace(trace_dir: str, slice_facts: dict) -> dict:
     out["events"] = events
     out["busy_s"] = TR.busy_seconds(events)
     out["top_ops"] = TR.top_ops(events)
-    out["idle_gaps"] = TR.idle_gaps(events)
+    out["idle_gaps"] = SR.idle_gaps(events)
+    out["traced_calls_share"] = TR.traced_calls_share(
+        events, slice_facts.get("programs", "^$"), slice_facts.get("program_calls", 0)
+    )
     return out
 
 
@@ -206,6 +211,8 @@ def main(argv=None) -> int:
                 metrics[name] = {"value": v, "unit": spec["unit"]}
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
+        if trace["traced_calls_share"] is not None:
+            device["traced_calls_share"] = trace["traced_calls_share"]
         out["metrics"] = metrics
         out["device"] = device
         out["breakdown"] = {

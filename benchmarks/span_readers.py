@@ -9,7 +9,13 @@ device's ``XLA Ops``.  These readers lay the two over each other:
 * which phase the host was in while the device sat idle
   (``idle_under_spans_ms_per_unit``) and how much of the idle time no
   span covers (``idle_unattributed_share``),
-* how many marks of a kind the slice holds (``span_count``: compiles).
+* how many marks of a kind the slice holds (``span_count``: compiles),
+* what the host was doing in each of the device's longest idle gaps
+  (``idle_gaps``: the result line's ``breakdown.idle_gaps``).
+
+Each is a metric of its own file under ``layer_metrics/`` (``reader``:
+``benchmarks.span_readers:<function>``), listed by the cells it is read
+in like any other.
 
 A unit is one turn of the program's loop, marked by a ``unit`` span
 (``ff.serve.window``, ``ff.fit.step_dispatch``).  Two shapes of slice:
@@ -133,6 +139,21 @@ def innermost_cover(spans: Sequence[Span]) -> List[Span]:
     return out
 
 
+def _under(cover: Sequence[Span], starts: Sequence[float], g0: float, g1: float,
+           ) -> Dict[str, float]:
+    """Seconds of [g0, g1] under each name of ``cover`` (the disjoint
+    pieces of ``innermost_cover``; ``starts`` their starts)."""
+    by: Dict[str, float] = {}
+    i = max(0, bisect.bisect_right(starts, g0) - 1)
+    while i < len(cover) and cover[i][1] < g1:
+        name, s, e = cover[i]
+        ov = _clipped(s, e, g0, g1)
+        if ov > 0:
+            by[name] = by.get(name, 0.0) + ov
+        i += 1
+    return by
+
+
 def idle_by_span(events: dict, unit: str, frame: Optional[str] = None,
                  ) -> Optional[Tuple[Dict[Optional[str], float], int]]:
     """Seconds of device idle time in the whole units by the innermost
@@ -147,15 +168,10 @@ def idle_by_span(events: dict, unit: str, frame: Optional[str] = None,
     starts = [c[1] for c in cover]
     by: Dict[Optional[str], float] = {}
     for g0, g1 in device_gaps(events, lo, hi):
-        rest = g1 - g0
-        i = max(0, bisect.bisect_right(starts, g0) - 1)
-        while i < len(cover) and cover[i][1] < g1:
-            name, s, e = cover[i]
-            ov = _clipped(s, e, g0, g1)
-            if ov > 0:
-                by[name] = by.get(name, 0.0) + ov
-                rest -= ov
-            i += 1
+        under = _under(cover, starts, g0, g1)
+        for name, ov in under.items():
+            by[name] = by.get(name, 0.0) + ov
+        rest = (g1 - g0) - sum(under.values())
         if rest > 1e-12:
             by[None] = by.get(None, 0.0) + rest
     return by, units
@@ -224,6 +240,42 @@ def span_count(run, *, span):
     return float(sum(1 for name, _, _ in spans if name == span))
 
 
+# ---- the result line's breakdown ------------------------------------------
+
+def idle_gaps(events: dict, k: int = 10, longest: int = 200) -> List[List]:
+    """The ``longest`` gaps between device operations on the first chip,
+    summed by what the host was doing, the ``k`` largest sums.  A gap goes
+    whole to the innermost ``ff.`` span that covers most of it (a child
+    beats the parent around it, and any ``ff.`` span beats another host
+    event however long: a Python frame that always waits covers every
+    gap and explains none); where no ``ff.`` span touches it, to the
+    runtime's own host event that covers most of it; to ``unattributed``
+    where nothing does."""
+    ops = first_chip_ops(events)
+    if not ops:
+        return []
+    gaps = device_gaps(events, ops[0][1], max(s + d for _, s, d in ops))
+    gaps.sort(key=lambda g: g[0] - g[1])
+    cover = innermost_cover(ff_spans(events))
+    starts = [c[1] for c in cover]
+    other = [
+        (name, start, start + dur)
+        for evs in events.get(HOST_PLANE, {}).values() for name, start, dur in evs
+        if dur > 1e-4 and not name.startswith(PREFIX)  # shorter ones name no gap worth listing
+    ]
+    by_name: Dict[str, float] = {}
+    for g0, g1 in gaps[:longest]:
+        under = _under(cover, starts, g0, g1)
+        if not under:
+            for name, s, e in other:
+                ov = _clipped(s, e, g0, g1)
+                if ov > under.get(name, 0.0):
+                    under[name] = ov
+        best = max(under, key=under.get) if under else "unattributed"
+        by_name[best] = by_name.get(best, 0.0) + (g1 - g0)
+    return [[n, sec] for n, sec in sorted(by_name.items(), key=lambda kv: -kv[1])[:k]]
+
+
 # ---- by hand ------------------------------------------------------------
 
 def sync_after_device_ms(events: dict, sync: str = "ff.serve.sync") -> List[float]:
@@ -262,22 +314,27 @@ def op_after_dispatch_ms(events: dict, sync: str = "ff.serve.sync",
 
 
 def describe(events: dict, cell: str) -> dict:
-    """Every metric of ``span_metrics.json`` that ``cell`` is to report,
-    the idle time per unit by span, and the clocks' agreement."""
+    """Every metric of this module that ``cell`` lists (its own files:
+    ``workloads/<cell>.json``, ``layer_metrics/<name>.json``), the idle
+    time per unit by span, and the clocks' agreement."""
     import statistics
     import types
 
     here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "span_metrics.json")) as f:
-        specs = json.load(f)
+
+    def load(*parts):
+        with open(os.path.join(here, *parts)) as f:
+            return json.load(f)
+
     run = types.SimpleNamespace(trace={"events": events}, facts={})
     out: dict = {"metrics": {}}
     unit = frame = None
-    for name, spec in specs.items():
-        if cell not in spec["cells"]:
+    for name in load("workloads", f"{cell}.json")["layer_metrics"]:
+        spec = load("layer_metrics", f"{name}.json")
+        mod, _, fn = spec["reader"].partition(":")
+        if mod != "benchmarks.span_readers":
             continue
-        fn = globals()[spec["reader"].partition(":")[2]]
-        out["metrics"][name] = fn(run, **spec["args"])
+        out["metrics"][name] = globals()[fn](run, **spec["args"])
         unit, frame = spec["args"].get("unit", unit), spec["args"].get("frame", frame)
     spans = ff_spans(events)
     rng = unit and whole_units(spans, unit, frame)

@@ -24,6 +24,20 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
 
+def start_trace(trace_dir: str) -> None:
+    """``jax.profiler.start_trace`` with the Python-frame tracer off.  On
+    by default, it records every Python call of every thread, which slows
+    the host the traced slice is meant to show (a serve window by 2-5 %,
+    admission 2.5 x: PERF.md) and names idle gaps by frames such as
+    ``_threading.py:637_wait``.  Host TraceMe events stay on: they carry
+    the program's ``ff.*`` annotations and the runtime's own."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+
+
 def find_xplane(trace_dir: str) -> str:
     files = sorted(
         glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
@@ -123,34 +137,19 @@ def top_ops(trace: dict, k: int = 10) -> List[List]:
     return [[name, s / n] for name, s in sorted(agg.items(), key=lambda kv: -kv[1])[:k]]
 
 
-def idle_gaps(trace: dict, k: int = 10) -> List[List]:
-    """The longest gaps between device operations on the first chip, each
-    named by the host-side event that covers most of it (the program has
-    no spans of its own in the profiler's trace yet, so this is whatever
-    the runtime's own host events say)."""
+def traced_calls_share(trace: dict, pattern: str, calls: int):
+    """``XLA Modules`` events matching ``pattern`` on the first chip over
+    the program calls the host counted while the profiler ran.  A sound
+    trace reads about 1 (the slice's ragged edges put it a call or two
+    off); a device line that came back truncated reads far under it, and
+    the idle share beside it is then not to be believed.  None where the
+    host counted no call or the trace holds no device."""
     planes = device_planes(trace)
-    if not planes:
-        return []
-    evs = sorted(trace[planes[0]][OPS_LINE], key=lambda e: e[1])
-    gaps, end = [], None
-    for _, start, dur in evs:
-        if end is not None and start > end:
-            gaps.append((end, start - end))
-        end = max(end or 0.0, start + dur)
-    gaps.sort(key=lambda g: -g[1])
-    host = [
-        e for line, es in trace.get("/host:CPU", {}).items() for e in es
-        if e[2] > 1e-4  # shorter host events cannot name a gap worth listing
-    ]
-    by_name: Dict[str, float] = {}
-    for g0, glen in gaps[:200]:
-        best, cover = "unattributed", 0.0
-        for name, start, dur in host:
-            ov = min(g0 + glen, start + dur) - max(g0, start)
-            if ov > cover:
-                best, cover = name, ov
-        by_name[best] = by_name.get(best, 0.0) + glen
-    return [[n, s] for n, s in sorted(by_name.items(), key=lambda kv: -kv[1])[:k]]
+    if not planes or not calls:
+        return None
+    rx = re.compile(pattern)
+    seen = sum(1 for name, _, _ in trace[planes[0]].get(MODULES_LINE, []) if rx.search(name))
+    return seen / calls
 
 
 def describe(trace: dict, k: int = 80) -> dict:

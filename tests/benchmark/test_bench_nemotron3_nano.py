@@ -231,6 +231,6 @@ def test_published_file_holds_the_published_widths():
         2176, 16, 4, "auto", "fp32")
     assert e["slots"] in (64, 128, 256) and e["prefill_chunk"] in (128, 256)
     assert cell["chips"] == 1 and cell["job"] == "serve_hybrid_lm" and len(cell["why"]) <= 200
-    assert len(cell["layer_metrics"]) == 12
+    assert len(cell["layer_metrics"]) == 22  # PR 34's twelve, then PR 36's spans, programs, relayouts
     assert "pool_copy_share.tput" not in cell["layer_metrics"]
     assert "kv_rows_visible_share.tput" not in cell["layer_metrics"]

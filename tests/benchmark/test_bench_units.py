@@ -5,14 +5,18 @@ import glob
 import json
 import os
 
+import types
+
 import numpy as np
 import pytest
 
 from bench_fixtures import REPO, TINY_BERT, TINY_TRAIN_CELL, TINY_TRAIN_MIX, tmp_checkout, load_run_module
 
+from benchmarks import readers
 from benchmarks import trace_reduce as TR
 from benchmarks import traffic as T
 from benchmarks import work
+from benchmarks.jobs import serve
 
 BENCH = os.path.join(REPO, "benchmarks")
 
@@ -147,14 +151,91 @@ def test_recorded_trace_of_the_training_step():
     assert k > 100 and 0.5 * busy < fus <= busy
 
 
+# ---------------------------------------- device time by program (XLA Modules)
+# Two chips, the same programs on each: 3 prefill dispatches of 40, 50, 60 ms
+# and 4 decode steps of 10 ms a chip; operations busy 0.16 s of a 0.25 s slice.
+def _module_run(modules=True):
+    plane = {
+        TR.OPS_LINE: [("%fusion.1 = bf16[8]", 0.00, 0.10), ("%fusion.2 = bf16[8]", 0.12, 0.06)],
+        TR.MODULES_LINE: [
+            ("jit_prefill(123)", 0.00, 0.04), ("jit_decode(456)", 0.04, 0.01),
+            ("jit_decode(456)", 0.05, 0.01), ("jit_prefill(123)", 0.06, 0.05),
+            ("jit_decode(456)", 0.12, 0.01), ("jit_decode(456)", 0.13, 0.01),
+            ("jit_prefill(123)", 0.14, 0.06), ("jit_convert_element_type(9)", 0.21, 0.001),
+        ] if modules else [],
+    }
+    events = {"/device:TPU:0": plane, "/device:TPU:1": plane, "/host:CPU": {}}
+    return types.SimpleNamespace(
+        trace={"events": events, "busy_s": TR.busy_seconds(events), "window_s": 0.25},
+        facts={}, peaks={}, chips=2)
+
+
+@pytest.mark.parametrize("reader,regex,want", [
+    ("module_time_share", "^jit_prefill", 100 * 0.15 / 0.16),
+    ("module_time_share", "^jit_decode", 100 * 0.04 / 0.16),
+    ("module_ms_per_call", "^jit_prefill", 50.0),
+    ("module_ms_per_call", "^jit_decode", 10.0),
+    ("module_ms_per_call", r"^jit_(decode|prefill)", 1e3 * 0.19 / 7),
+])
+def test_device_time_by_program_by_hand(reader, regex, want):
+    run = _module_run()
+    assert run.trace["busy_s"] == pytest.approx(0.16)
+    assert getattr(readers, reader)(run, regex=regex) == pytest.approx(want, rel=1e-9)
+
+
+@pytest.mark.parametrize("reader", ["module_time_share", "module_ms_per_call"])
+def test_no_such_program_on_the_line_is_none(reader):
+    fn = getattr(readers, reader)
+    assert fn(_module_run(), regex="^jit_verify") is None  # never 0 for a program that did not run
+    assert fn(_module_run(modules=False), regex="^jit_prefill") is None
+    assert fn(types.SimpleNamespace(trace=None, facts={}), regex="^jit_prefill") is None
+
+
+@pytest.mark.parametrize("calls,want", [(7, 1.0), (8, 7 / 8), (210, 7 / 210), (0, None)])
+def test_traced_calls_share(calls, want):
+    """Module events on the first chip over the calls the host counted: a
+    device line that came back truncated (one call of thirty) shows."""
+    events = _module_run().trace["events"]
+    got = TR.traced_calls_share(events, serve.PROGRAMS, calls)
+    assert got == (want if want is None else pytest.approx(want))
+    assert TR.traced_calls_share({"/host:CPU": {}}, serve.PROGRAMS, 7) is None
+
+
+def test_prefill_rows_valid_share_from_hand_made_requests():
+    """Prompt positions prefilled over the rows the dispatches computed:
+    a finished request counts its whole prompt, one cut mid-prompt what
+    it got to, one never started nothing."""
+    R = types.SimpleNamespace
+    reqs = [R(prompt_len=100, tokens=[5, 6], prefill_pos=100),
+            R(prompt_len=70, tokens=[], prefill_pos=64),
+            R(prompt_len=30, tokens=[], prefill_pos=0)]
+    facts = serve.prefill_rows(reqs, R(prefill_dispatches=4), {"slots": 4, "prefill_chunk": 32})
+    assert facts == {"prefill_positions": 164, "prefill_rows_computed": 512}
+    spec = json.load(open(os.path.join(BENCH, "layer_metrics", "prefill_rows_valid_share.tput.json")))
+    run = types.SimpleNamespace(facts=facts, trace=None)
+    assert getattr(readers, spec["reader"])(run, **spec["args"]) == pytest.approx(100 * 164 / 512)
+    none = types.SimpleNamespace(facts=dict(facts, prefill_rows_computed=0), trace=None)
+    assert getattr(readers, spec["reader"])(none, **spec["args"]) is None  # no dispatch, no share
+
+
+def test_the_traced_slice_is_taken_without_the_python_frame_tracer(monkeypatch):
+    import jax
+
+    seen = {}
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: seen.update(dir=d, **kw))
+    TR.start_trace("/somewhere")
+    assert seen["dir"] == "/somewhere"
+    assert seen["profiler_options"].python_tracer_level == 0
+    assert seen["profiler_options"].host_tracer_level > 0  # TraceMe events carry the ff.* spans
+
+
 # ------------------------------------------------------------ the data files
 def _names(sub):
     return sorted(os.path.basename(p)[:-5] for p in glob.glob(os.path.join(BENCH, sub, "*.json")))
 
 
 def test_every_file_loads_and_names_things_that_exist():
-    from benchmarks import readers
-
     mod = load_run_module(REPO)
     manifest = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
@@ -172,7 +253,7 @@ def test_every_file_loads_and_names_things_that_exist():
             assert e2e[name]["unit"] == unit
             assert "workloads" not in e2e[name] or w["name"] in e2e[name]["workloads"]
         for name, spec in metrics.items():
-            assert callable(getattr(readers, spec["reader"]))
+            assert callable(mod.resolve_reader(spec["reader"]))
             m = per_layer[name]
             assert (m["unit"], m["layer"], m["moves"], m["source"], m["better"]) == (
                 spec["unit"], spec["layer"], spec["moves"], spec["source"], spec["better"])
@@ -185,6 +266,33 @@ def test_every_file_loads_and_names_things_that_exist():
         assert {b["hidden"], b["heads"], b["ff_dim"], b["num_layers"]} <= widths
     for name in _names("layer_metrics"):
         assert name in per_layer, f"{name} has a file and no entry in BENCHMARK.json"
+    # ... and the other way round: an entry has a file, and each cell it names lists it
+    cells = {w["name"]: mod.load_cell(w["name"])[0] for w in manifest["workloads"]}
+    for name, m in per_layer.items():
+        assert name in _names("layer_metrics"), f"{name} has an entry and no file"
+        assert m["workloads"], name
+        for c in m["workloads"]:
+            assert name in cells[c]["layer_metrics"], f"{c} does not list {name}"
+
+
+def test_retired_and_new_serve_metrics():
+    """``pool_copy_share.*`` (could only read 0 since the pool turned
+    position-major) is gone from files, cells and manifest alike;
+    ``program_relayouts.*`` stands in all four serve cells, the
+    program-time metrics and S1's valid-rows share in the backlog cells as
+    ``.tput`` and in the steady cell as ``.lat``."""
+    manifest = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    assert not [n for n in list(per_layer) + _names("layer_metrics") if n.startswith("pool_copy_share")]
+    backlog = {w["name"] for w in manifest["workloads"] if "serve_saturated" in w["traffic"]}
+    steady = {"gpt2_small.serve_steady"}
+    assert len(backlog) == 3
+    for stem in ("prefill_device_share", "prefill_ms_per_dispatch", "decode_ms_per_step",
+                 "prefill_rows_valid_share", "program_relayouts"):
+        assert set(per_layer[f"{stem}.tput"]["workloads"]) == backlog, stem
+        assert set(per_layer[f"{stem}.lat"]["workloads"]) == steady, stem
+        assert per_layer[f"{stem}.tput"]["moves"] == "serve_tokens_per_s"
+        assert per_layer[f"{stem}.lat"]["moves"] == "tpot_p95_ms"
 
 
 def test_a_cell_config_reference_metric_and_reader_are_added_as_files(tmp_path):

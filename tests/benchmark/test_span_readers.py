@@ -3,6 +3,7 @@ the device's operations, on hand-made traces with hand-computed answers,
 and through ``run.py`` itself in a tiny cell that lists the metrics (a
 copy of the benchmark: no file of it is edited)."""
 
+import glob
 import json
 import os
 import types
@@ -13,7 +14,13 @@ import bench_fixtures as F
 
 from benchmarks import span_readers as SR
 
-SPECS = json.load(open(os.path.join(F.REPO, "benchmarks", "span_metrics.json")))
+# every metric whose file names a reader of this module
+SPECS = {
+    os.path.basename(p)[:-5]: spec
+    for p in sorted(glob.glob(os.path.join(F.REPO, "benchmarks", "layer_metrics", "*.json")))
+    for spec in [json.load(open(p))]
+    if spec["reader"].startswith("benchmarks.span_readers:")
+}
 WINDOW, STEP = "ff.serve.window", "ff.fit.step_dispatch"
 
 
@@ -150,57 +157,164 @@ def test_idle_needs_a_device_and_the_clock_check_reads_both():
     assert SR.op_after_dispatch_ms(clocks.trace["events"]) == pytest.approx([0.1, 40.0])
 
 
-def test_span_metrics_file_names_readers_layers_and_cells_that_exist():
+def test_the_span_metrics_are_metrics_of_the_cells():
+    """Fifteen files, each a ``per_layer`` entry listed by the cells that
+    read it; ``span_metrics.json``, which held them unread, is gone."""
     manifest = json.load(open(os.path.join(F.REPO, "BENCHMARK.json")))
-    cells = {w["name"]: w for w in manifest["workloads"]}
-    layers = {m["layer"] for m in manifest["per_layer"]}
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    assert len(SPECS) == 15
+    assert not os.path.exists(os.path.join(F.REPO, "benchmarks", "span_metrics.json"))
     for name, spec in SPECS.items():
-        mod, _, fn = spec["reader"].partition(":")
-        assert mod == "benchmarks.span_readers" and callable(getattr(SR, fn))
-        assert spec["layer"] in layers and spec["source"] == "program_span"
-        assert spec["better"] == "lower" and spec["cells"]
-        for c in spec["cells"]:
-            assert c in cells and c in e2e[spec["moves"]]["workloads"]
-        assert name not in {m["name"] for m in manifest["per_layer"]}
+        assert callable(getattr(SR, spec["reader"].partition(":")[2]))
+        assert spec["source"] == "program_span" and spec["better"] == "lower"
+        assert "cells" not in spec  # a metric's file never names cells
+        cells = per_layer[name]["workloads"]
+        assert cells and set(cells) <= set(e2e[spec["moves"]]["workloads"])
+        kind = {"tput": "serve_saturated", "lat": "serve_steady", "train": "train_"}[
+            name.rpartition(".")[2]]
+        assert all(kind in c for c in cells)
+    assert len(per_layer["host_ms_per_window.tput"]["workloads"]) == 3
+    assert len(per_layer["compiles_in_slice.train"]["workloads"]) == 2
 
 
-# ------------------------------------------------ through run.py, unedited
-def _metric_files(suffix):
+# ------------------------------------------------------------- idle_gaps
+# A Python frame that always waits covers every gap: it may name a gap
+# only where nothing better does.
+WAIT = ("_threading.py:637_wait", 0.0, 10.0)
+
+
+def _gap_events(host, ops):
     return {
-        f"layer_metrics/{name}.json": {k: v for k, v in spec.items() if k != "cells"}
-        for name, spec in SPECS.items() if name.endswith(suffix)
+        "/host:CPU": {"main": [(n, s, e - s) for n, s, e in host]},
+        "/device:TPU:0": {"XLA Ops": [("%op", s, e - s) for s, e in ops]},
     }
 
 
-def test_a_traced_serve_run_prints_the_span_metrics(tmp_path, monkeypatch, capsys):
-    files = _metric_files(".tput")
-    names = [os.path.basename(p)[:-5] for p in files]
+def test_idle_gaps_an_ff_span_beats_a_longer_covering_frame():
+    # gaps [1.0, 1.3] and [2.0, 2.1]; the window covers all of the first,
+    # the admit inside it 0.2 of its 0.3: the child beats the parent, and
+    # both beat the frame that covers everything
+    events = _gap_events(
+        [WAIT, ("ff.serve.window", 0.9, 1.4), ("ff.serve.admit", 1.05, 1.25),
+         ("ff.serve.sync", 1.95, 2.2)],
+        [(0.5, 1.0), (1.3, 2.0), (2.1, 2.5)],
+    )
+    assert SR.idle_gaps(events) == [
+        ["ff.serve.admit", pytest.approx(0.3)], ["ff.serve.sync", pytest.approx(0.1)]]
+
+
+def test_idle_gaps_the_runtimes_event_where_no_span_covers_and_unattributed():
+    events = _gap_events(
+        [("PjitFunction(step)", 1.0, 1.25), ("TransferToDevice", 1.2, 1.3),
+         ("ff.fit", 3.0, 4.0), ("tiny", 5.05, 5.05005)],
+        [(0.5, 1.0), (1.3, 3.2), (3.4, 5.0), (5.1, 5.2)],
+    )
+    # [1.0, 1.3]: no ff. span; PjitFunction covers 0.25 of it, the transfer 0.1.
+    # [3.2, 3.4]: under ff.fit.  [5.0, 5.1]: only an event too short to name a gap
+    assert SR.idle_gaps(events) == [
+        ["PjitFunction(step)", pytest.approx(0.3)], ["ff.fit", pytest.approx(0.2)],
+        ["unattributed", pytest.approx(0.1)]]
+
+
+def test_idle_gaps_sums_by_name_keeps_the_k_largest_and_needs_a_device():
+    host = [("ff.serve.window", 0.0, 10.0), ("ff.serve.admit", 1.0, 1.1),
+            ("ff.serve.admit", 2.0, 2.1), ("ff.serve.flush", 3.0, 3.05)]
+    ops = [(0.5, 1.0), (1.1, 2.0), (2.1, 3.0), (3.05, 4.0), (4.01, 5.0)]
+    events = _gap_events(host, ops)
+    assert SR.idle_gaps(events) == [
+        ["ff.serve.admit", pytest.approx(0.2)], ["ff.serve.flush", pytest.approx(0.05)],
+        ["ff.serve.window", pytest.approx(0.01)]]
+    assert [n for n, _ in SR.idle_gaps(events, k=1)] == ["ff.serve.admit"]
+    # only the longest gaps are named: with one, the rest are not summed in
+    assert SR.idle_gaps(events, longest=1) == [["ff.serve.admit", pytest.approx(0.1)]]
+    assert SR.idle_gaps({"/host:CPU": events["/host:CPU"]}) == []
+    assert SR.idle_gaps({}) == []
+
+
+# ------------------------------------------------ through run.py, unedited
+PROGRAM_METRICS = ["prefill_device_share.tput", "prefill_ms_per_dispatch.tput",
+                   "decode_ms_per_step.tput", "prefill_rows_valid_share.tput",
+                   "program_relayouts.tput"]
+
+
+def _serve_checkout(tmp_path):
+    names = [n for n in SPECS if n.endswith(".tput")] + PROGRAM_METRICS
     cell = F.tiny_serve_cell("tiny_gpt.backlog", "tiny_backlog",
                              {"serve_tokens_per_s": "tokens/s"}, ["window_wall_ms.tput"] + names)
-    root = F.tmp_checkout(tmp_path, {
+    return F.tmp_checkout(tmp_path, {
         "configs/tiny_gpt.json": F.TINY_GPT, "workloads/tiny_gpt.backlog.json": cell,
-        "traffic_mixes/tiny_backlog.json": F.TINY_BACKLOG_MIX, **files,
+        "traffic_mixes/tiny_backlog.json": F.TINY_BACKLOG_MIX,
     })
-    rc, res, _ = F.run_main(root, ["--workload", "tiny_gpt.backlog", "--seed", "7",
-                                   "--seconds", "2", "--trace", "1"], monkeypatch, capsys)
+
+
+SERVE_ARGV = ["--workload", "tiny_gpt.backlog", "--seed", "7", "--seconds", "2", "--trace", "1"]
+
+
+def test_a_traced_serve_run_prints_the_span_metrics(tmp_path, monkeypatch, capsys):
+    rc, res, _ = F.run_main(_serve_checkout(tmp_path), SERVE_ARGV, monkeypatch, capsys)
     assert rc == 0 and res["correct"] is True
     got = res["metrics"]
-    # the CPU has no device plane: the two idle metrics are left out, the rest are numbers
+    # the CPU has no device plane: the idle and program-time metrics are left
+    # out, the spans (recorded with the Python-frame tracer off) and the
+    # counters are numbers
     assert set(got) == {"window_wall_ms.tput", "host_ms_per_window.tput",
-                        "admit_ms_per_window.tput", "compiles_in_slice.tput"}
+                        "admit_ms_per_window.tput", "compiles_in_slice.tput",
+                        "prefill_rows_valid_share.tput", "program_relayouts.tput"}
     assert 0 < got["host_ms_per_window.tput"]["value"] < got["window_wall_ms.tput"]["value"] * 3
     assert got["admit_ms_per_window.tput"]["value"] > 0
     assert got["compiles_in_slice.tput"]["value"] == 0.0  # every shape was warmed
+    assert 0 < got["prefill_rows_valid_share.tput"]["value"] <= 100
+    assert got["program_relayouts.tput"]["value"] >= 0  # a count; 0 is a reading
+    assert "traced_calls_share" not in res["device"] and res["breakdown"]["idle_gaps"] == []
+    f = res["facts"]
+    assert f["prefill_rows_computed"] == f["prefill_dispatches"] * 4 * 8
+    assert 0 < f["prefill_positions"] <= f["positions"]
+
+
+def test_a_traced_serve_run_with_a_device_line(tmp_path, monkeypatch, capsys):
+    """The same run, its real host spans with a made-up chip beside them
+    (the CPU writes no device plane): two programs on ``XLA Modules``,
+    operations that leave each traced window but its first 30 % idle."""
+    from benchmarks import trace_reduce as TR
+
+    real_load = TR.load
+    made = {}
+
+    def load(path):
+        events = real_load(path)
+        wins = [(s, e) for n, s, e in SR.ff_spans(events) if n == WINDOW]
+        made["windows"] = len(wins)
+        events["/device:TPU:0"] = {
+            TR.OPS_LINE: [("%fusion.1 = f32[8]", s, 0.3 * (e - s)) for s, e in wins],
+            TR.MODULES_LINE: [
+                (("jit_prefill(1)", "jit_decode(2)")[i % 2], s, 0.3 * (e - s))
+                for i, (s, e) in enumerate(wins)
+            ],
+        }
+        return events
+
+    monkeypatch.setattr(TR, "load", load)
+    rc, res, _ = F.run_main(_serve_checkout(tmp_path), SERVE_ARGV, monkeypatch, capsys)
+    assert rc == 0 and res["correct"] is True and made["windows"] >= 4
+    got, dev = res["metrics"], res["device"]
+    assert set(PROGRAM_METRICS) <= set(got) and "idle_unattributed_share.tput" in got
+    assert 0 < got["prefill_device_share.tput"]["value"] < 100
+    assert got["prefill_ms_per_dispatch.tput"]["value"] > 0
+    assert got["decode_ms_per_step.tput"]["value"] > 0
+    assert got["idle_in_host_ms_per_window.tput"]["value"] > 0
+    assert dev["busy_s"] > 0 and dev["traced_calls_share"] > 0
+    gaps = res["breakdown"]["idle_gaps"]
+    assert gaps and all(name.startswith("ff.serve.") for name, _ in gaps[:2])
+    assert not any("_threading" in name for name, _ in gaps)
 
 
 def test_a_traced_train_run_prints_the_span_metrics(tmp_path, monkeypatch, capsys):
-    files = _metric_files(".train")
-    names = [os.path.basename(p)[:-5] for p in files]
+    names = [n for n in SPECS if n.endswith(".train")]
     root = F.tmp_checkout(tmp_path, {
         "configs/tiny_bert.json": F.TINY_BERT,
         "workloads/tiny_bert.train.json": dict(F.TINY_TRAIN_CELL, layer_metrics=names),
-        "traffic_mixes/tiny_train.json": F.TINY_TRAIN_MIX, **files,
+        "traffic_mixes/tiny_train.json": F.TINY_TRAIN_MIX,
     })
     rc, res, _ = F.run_main(root, ["--workload", "tiny_bert.train", "--seed", "7",
                                    "--seconds", "1", "--trace", "1"], monkeypatch, capsys)

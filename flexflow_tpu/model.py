@@ -63,6 +63,7 @@ from flexflow_tpu.obs import (
     configure_monitor_from_config,
     get_monitor,
     get_tracer,
+    setup_span,
 )
 from flexflow_tpu.ops.base import get_op_def
 from flexflow_tpu.optimizer import Optimizer, SGDOptimizer
@@ -983,6 +984,7 @@ class FFModel:
         return self._unary(OperatorType.SCALAR_TRUE_DIV, x, name, scalar=scalar)
 
     # --------------------------------------------------------------- compile
+    @setup_span("model")
     def compile(
         self,
         optimizer: Optional[Optimizer] = None,

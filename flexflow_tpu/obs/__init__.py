@@ -3,7 +3,8 @@
 ``get_tracer()`` returns the process-wide :class:`Tracer`; the runtime,
 search, and fit loops record spans/counters into it, and ``--trace-out``
 exports Chrome-trace JSON readable by chrome://tracing / Perfetto and by
-``tools/trace_report.py``.
+``tools/trace_report.py``.  ``setup_summary()`` reads what set-up cost
+(the ``ff.setup`` spans and jax's compiles under them) at any level.
 
 ``get_monitor()`` returns the process-wide :class:`HealthMonitor` — the
 per-step metrics stream (``--metrics-out`` JSONL), the NaN/loss-spike
@@ -61,13 +62,19 @@ from flexflow_tpu.obs.trace import (
     configure,
     configure_from_config,
     get_tracer,
+    persistent_cache_hits,
     set_tracer,
+    setup_span,
+    setup_summary,
 )
 
 __all__ = [
     "Tracer",
     "get_tracer",
     "set_tracer",
+    "setup_summary",
+    "setup_span",
+    "persistent_cache_hits",
     "configure",
     "configure_from_config",
     "CORE_COUNTERS",

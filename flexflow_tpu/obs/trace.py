@@ -35,10 +35,19 @@ Design constraints:
   * Spans nest: events are "X" (complete) records stamped at span EXIT
     with the entry timestamp, so a child (which closes first) always
     lies inside its parent's [ts, ts+dur] window on the same tid.
+  * Set-up is timed always.  A span of category ``setup``
+    (``ff.setup.<name>``: the model's compile, the step program's first
+    build, the serve engine's build and warm-up) is timed at every level,
+    ``off`` included, into one process-wide tally that ``set_tracer``
+    does not replace; jax's own trace, lowering, compile-or-load and
+    persistent-cache events are counted under the innermost open one.
+    ``setup_summary()`` reads it.  Nothing of it runs in a serve window
+    or a step of ``fit``.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -91,6 +100,16 @@ _NULL_SPAN = _NullSpan()
 
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# jax's compile phases, by the name the set-up tally counts them under
+PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    COMPILE_EVENT: "compile",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
 
 _ANNOTATION = None  # the profiler-only span class, built at the first span
 
@@ -99,7 +118,7 @@ def _annotation(name: str, cat: str):
     """The profiler's sink of one span: a ``jax.profiler.TraceAnnotation``
     that also answers ``.set()``.  jax is imported here, at the first
     span, so ``obs`` imports without a backend; the same moment registers
-    the one listener that marks compiles."""
+    the listeners for jax's compile phases and persistent-cache events."""
     global _ANNOTATION
     if _ANNOTATION is None:
         import jax
@@ -110,20 +129,34 @@ def _annotation(name: str, cat: str):
             def set(self, **args) -> None:
                 pass  # annotation arguments are event stats no reader keeps
 
-        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        jax.monitoring.register_event_time_span_listener(_on_jax_phase)
+        jax.monitoring.register_event_listener(_on_jax_event)
         _ANNOTATION = _Annotation
     return _ANNOTATION(f"ff.{cat}" if name == cat else f"ff.{cat}.{name}")
 
 
-def _on_jax_duration(event: str, duration_s: float, **_kw) -> None:
-    """A zero-length ``ff.compile`` mark whenever XLA builds (or loads
-    from the persistent cache) a program: a compile inside a measured
-    window shows in the trace at the end of the gap it caused.  Runs
-    only when something compiles — nothing on a warm call."""
-    if event == COMPILE_EVENT:
+def _on_jax_phase(event: str, t0: float, t1: float, fun_name: str = "", **_kw) -> None:
+    """jax's trace, lowering and compile-or-load of a program, counted in
+    the set-up tally; and a zero-length ``ff.compile`` mark whenever XLA
+    builds (or loads from the persistent cache) a program: a compile
+    inside a measured window shows in the trace at the end of the gap it
+    caused.  Runs only when something compiles — nothing on a warm call."""
+    phase = PHASE_EVENTS.get(event)
+    if phase is None:
+        return
+    _SETUP.phase(phase, t0, t1, fun_name)
+    if phase == "compile":
         with _annotation("compile", "compile"):
             pass
-        _TRACER.instant("compile", cat="compile", seconds=duration_s)
+        _TRACER.instant("compile", cat="compile", seconds=t1 - t0)
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    """A persistent-cache hit or miss: jax records it inside the compile
+    it belongs to, before that compile's own event."""
+    field = CACHE_EVENTS.get(event)
+    if field is not None:
+        _SETUP.cache(field)
 
 
 class _Span:
@@ -155,6 +188,144 @@ class _Span:
         )
         self._ann.__exit__(*exc)
         return False
+
+
+class _SetupSpan(_Span):
+    """A span of category ``setup``: timed into the process-wide tally
+    whatever the tracer's level, and into the tracer too when it is on
+    (``tracer`` is None when it is off)."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_SetupSpan":
+        self._ann.__enter__()
+        _SETUP.open(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        _SETUP.close(self, t1 - self._t0)
+        if self.tracer is not None:
+            self.tracer._record_span(self.name, self.cat, self._t0, t1, self.args)
+        self._ann.__exit__(*exc)
+        return False
+
+
+_PHASE_FIELDS = {"trace": ("traces", "trace_s"), "lower": ("lowerings", "lower_s"),
+                 "compile": ("compiles", "compile_s")}
+
+
+class _SetupTally:
+    """What set-up cost, by ``ff.setup`` span and by program.  jax's
+    phase events land under the innermost open ``ff.setup`` span and the
+    function's name; outside every such span they are not counted, so
+    a serve window or a step of ``fit`` adds nothing here even where it
+    compiles.  A jaxpr traced inside another's trace (a jitted function
+    called by a jitted function) counts once, and its seconds once."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._open: List[_SetupSpan] = []
+        self._local = threading.local()  # cache events awaiting their compile
+        self.spans: Dict[str, List[float]] = {}  # name -> [count, seconds]
+        self.outer_s = 0.0
+        self.programs: Dict[tuple, Dict[str, float]] = {}
+        self.persistent_hits = 0  # every hit of the process, in a span or not
+
+    def open(self, span: _SetupSpan) -> None:
+        with self._lock:
+            self._open.append(span)
+
+    def close(self, span: _SetupSpan, seconds: float) -> None:
+        with self._lock:
+            if span in self._open:
+                self._open.remove(span)
+            agg = self.spans.setdefault(span.name, [0, 0.0])
+            agg[0] += 1
+            agg[1] += seconds
+            if not self._open:
+                self.outer_s += seconds
+                self._local.traced = []
+
+    def _program(self, fun_name: str) -> Optional[Dict[str, float]]:
+        if not self._open:
+            return None
+        key = (self._open[-1].name, fun_name)
+        rec = self.programs.get(key)
+        if rec is None:
+            rec = self.programs[key] = {
+                "traces": 0, "trace_s": 0.0, "lowerings": 0, "lower_s": 0.0,
+                "compiles": 0, "compile_s": 0.0, "cache_hits": 0, "cache_misses": 0,
+            }
+        return rec
+
+    def phase(self, phase: str, t0: float, t1: float, fun_name: str) -> None:
+        count, secs = _PHASE_FIELDS[phase]
+        if fun_name.startswith("jit(") and fun_name.endswith(")"):
+            fun_name = fun_name[4:-1]  # a module's name: the function's under jit
+        with self._lock:
+            rec = self._program(fun_name)
+            if rec is None:
+                self._local.pending = None
+                return
+            seconds = t1 - t0
+            if phase == "trace":
+                # events arrive as traces end, inner ones first: those
+                # that began inside this one are the last on the list
+                done = getattr(self._local, "traced", None) or []
+                while done and done[-1][0] >= t0:
+                    s0, s1 = done.pop()
+                    seconds -= s1 - s0
+                done.append((t0, t1))
+                self._local.traced = done
+            rec[count] += 1
+            rec[secs] += seconds
+            if phase == "compile":
+                for field, n in (getattr(self._local, "pending", None) or {}).items():
+                    rec[field] += n
+                self._local.pending = None
+
+    def cache(self, field: str) -> None:
+        with self._lock:
+            if field == "cache_hits":
+                self.persistent_hits += 1
+            pending = getattr(self._local, "pending", None) or {}
+            pending[field] = pending.get(field, 0) + 1
+            self._local.pending = pending
+
+    def summary(self) -> Dict[str, Any]:
+        with self._lock:
+            programs: Dict[str, Dict[str, Dict[str, float]]] = {}
+            for (span, fun), rec in self.programs.items():
+                programs.setdefault(span, {})[fun] = dict(rec)
+            return {
+                "spans": {n: {"count": int(c), "seconds": s}
+                          for n, (c, s) in self.spans.items()},
+                "outer_s": self.outer_s,
+                "programs": programs,
+            }
+
+
+_SETUP = _SetupTally()
+
+
+def setup_summary() -> Dict[str, Any]:
+    """The process's set-up, from its start: ``spans`` (per ``ff.setup``
+    span name, ``count`` and ``seconds``), ``outer_s`` (seconds of the
+    outermost ``ff.setup`` spans: what the program owns of the time to
+    ready) and ``programs`` (span name -> the function's name -> jax's
+    ``traces``, ``lowerings``, ``compiles`` — a compile or a load from
+    the persistent cache — with their seconds ``trace_s``, ``lower_s``,
+    ``compile_s``, and ``cache_hits`` / ``cache_misses`` of the
+    persistent cache).  docs/OBSERVABILITY.md has the vocabulary."""
+    return _SETUP.summary()
+
+
+def persistent_cache_hits() -> int:
+    """Programs the persistent compilation cache has served this process
+    (jax's own ``cache_hits`` event), in a set-up span or not."""
+    return _SETUP.persistent_hits
 
 
 class Tracer:
@@ -190,6 +361,8 @@ class Tracer:
         is on, and a Chrome event besides when it is."""
         if level == "op" and not self.op_level:
             return _NULL_SPAN
+        if cat == "setup":
+            return _SetupSpan(self if self.enabled else None, name, cat, args)
         if not self.enabled:
             return _annotation(name, cat)
         return _Span(self, name, cat, args)
@@ -334,6 +507,22 @@ def set_tracer(tracer: Tracer) -> Tracer:
     global _TRACER
     _TRACER = tracer
     return _TRACER
+
+
+def setup_span(name: str):
+    """Decorator: each call of the function is one ``ff.setup.<name>``
+    span of the process tracer (the whole of ``FFModel.compile``, of
+    ``ServeEngine.__init__``)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _TRACER.span(name, cat="setup"):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
 
 
 def configure(level: str = "step", out_path: Optional[str] = None) -> Tracer:

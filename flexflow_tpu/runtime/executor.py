@@ -29,7 +29,6 @@ cost model already assumes (``search/cost.py``).
 
 from __future__ import annotations
 
-import os
 import time
 import zlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -43,7 +42,7 @@ from flexflow_tpu.blocks import BlockChain, detect_block_chains
 from flexflow_tpu.fftype import LossType, OperatorType
 from flexflow_tpu.loss import get_loss_fn
 from flexflow_tpu.metrics import COUNTER_PREFIX, GAUGE_PREFIX, Metrics
-from flexflow_tpu.obs import get_monitor, get_tracer
+from flexflow_tpu.obs import get_monitor, get_tracer, persistent_cache_hits
 from flexflow_tpu.ops.base import OpContext, get_op_def
 from flexflow_tpu.ops.parallel_ops import resolve_parallel_sharding
 from flexflow_tpu.optimizer import Optimizer
@@ -1601,32 +1600,42 @@ class Executor:
             plan.on_train_step(self)
         tracer = get_tracer()
         if not (tracer.enabled or self.profiling or get_monitor().enabled):
-            # fast path — no clock reads, no forced device sync (async
-            # dispatch stays pipelined).  An AOT executable left by an
-            # earlier instrumented step (e.g. bench.py's compile-capture
-            # step) is reused so the program never compiles twice.
             if self._step_jit is None:
-                self._step_jit = self._build_step()
-                self._step_compiled = None
-                self._verified_step = False
-            inputs = [
-                self._place(x, self._input_pspec(t), t.shape[0])
-                for x, t in zip(inputs, self.graph_inputs)
-            ]
-            labels = self._place(labels, self._label_pspec(), self.graph_inputs[0].shape[0])
-            fn = self._step_compiled or self._step_jit
-            args = (
-                self.params, self.state, self.opt_state, inputs, labels,
-                self._step_count,
-            )
-            if self.verify_compiled != "off":
-                self._maybe_verify_compiled(args)
-                fn = self._step_compiled or fn
-            out = self._run_step(fn, args, tracer)
-            self.params, self.state, self.opt_state, loss, m = out
-            self._step_count += 1
-            return loss, m
+                # the first build, lowering and compile (or cache load)
+                # of the step program happen in this call
+                with tracer.span("step_program", cat="setup"):
+                    return self._train_step_fast(tracer, inputs, labels)
+            return self._train_step_fast(tracer, inputs, labels)
         return self._train_step_instrumented(tracer, inputs, labels)
+
+    def _train_step_fast(
+        self, tracer, inputs: Sequence[Any], labels: Any
+    ) -> Tuple[float, Dict[str, float]]:
+        """No clock reads, no forced device sync (async dispatch stays
+        pipelined).  An AOT executable left by an earlier instrumented
+        step (e.g. bench.py's compile-capture step) is reused so the
+        program never compiles twice."""
+        if self._step_jit is None:
+            self._step_jit = self._build_step()
+            self._step_compiled = None
+            self._verified_step = False
+        inputs = [
+            self._place(x, self._input_pspec(t), t.shape[0])
+            for x, t in zip(inputs, self.graph_inputs)
+        ]
+        labels = self._place(labels, self._label_pspec(), self.graph_inputs[0].shape[0])
+        fn = self._step_compiled or self._step_jit
+        args = (
+            self.params, self.state, self.opt_state, inputs, labels,
+            self._step_count,
+        )
+        if self.verify_compiled != "off":
+            self._maybe_verify_compiled(args)
+            fn = self._step_compiled or fn
+        out = self._run_step(fn, args, tracer)
+        self.params, self.state, self.opt_state, loss, m = out
+        self._step_count += 1
+        return loss, m
 
     def _train_step_instrumented(
         self, tracer, inputs: Sequence[Any], labels: Any
@@ -1643,10 +1652,7 @@ class Executor:
         step_no = self._step_count
         with tracer.span("train_step", cat="step", step=step_no):
             if self._step_jit is None:
-                with tracer.span("build_step", cat="compile"):
-                    self._step_jit = self._build_step()
                 self._step_compiled = None
-                self._verified_step = False
             with tracer.span("h2d_place", cat="step", level="op"):
                 inputs = [
                     self._place(x, self._input_pspec(t), t.shape[0])
@@ -1661,20 +1667,22 @@ class Executor:
             )
             compile_s = 0.0
             if self._step_compiled is None:
-                t0 = time.perf_counter()
-                cache_before = _compile_cache_entries()
-                with tracer.span("jit_compile", cat="compile", fn="train_step"):
-                    self._step_compiled = self._step_jit.lower(*args).compile()
-                compile_s = time.perf_counter() - t0
+                with tracer.span("step_program", cat="setup"):
+                    if self._step_jit is None:
+                        with tracer.span("build_step", cat="compile"):
+                            self._step_jit = self._build_step()
+                        self._verified_step = False
+                    t0 = time.perf_counter()
+                    hits = persistent_cache_hits()
+                    with tracer.span("jit_compile", cat="compile", fn="train_step"):
+                        self._step_compiled = self._step_jit.lower(*args).compile()
+                    compile_s = time.perf_counter() - t0
                 tracer.counter("jit.cache_miss")
-                # persistent compilation cache: a compile that wrote no
-                # new cache entry was served from disk — count it so a
-                # repeated run can prove it skipped the recompile
+                # persistent compilation cache: jax's own hit event
+                # inside the compile says the executable came from disk
                 # (docs/OBSERVABILITY.md)
-                if cache_before is not None:
-                    after = _compile_cache_entries()
-                    if after is not None and after <= cache_before:
-                        tracer.counter("jit_cache.persistent_hit")
+                if persistent_cache_hits() > hits:
+                    tracer.counter("jit_cache.persistent_hit")
                 self._record_memory_snapshot(tracer)
             else:
                 tracer.counter("jit.cache_hit")
@@ -1884,18 +1892,3 @@ _REMAT_OPS = frozenset({
     OperatorType.GATED_DELTA_NET,
     OperatorType.MAMBA2_MIXER,
 })
-
-
-def _compile_cache_entries() -> Optional[frozenset]:
-    """Names of the persistent compilation cache's entry files, or None
-    when the cache is off or still empty.  Only ``*-cache`` payloads
-    count — the cache touches ``*-atime`` markers on every hit, which
-    must not read as a new compile."""
-    d = jax.config.jax_compilation_cache_dir
-    if (
-        not d
-        or not jax.config.jax_enable_compilation_cache
-        or not os.path.isdir(d)
-    ):
-        return None
-    return frozenset(f for f in os.listdir(d) if not f.endswith("-atime"))

@@ -44,6 +44,7 @@ from flexflow_tpu.obs import (
     MetricsStream,
     SpanRecorder,
     get_tracer,
+    setup_span,
     step_record,
 )
 from flexflow_tpu.runtime.faults import get_fault_plan
@@ -389,6 +390,7 @@ class ServeEngine:
     waits on the free list; see the HBM-sharing test).
     """
 
+    @setup_span("engine")
     def __init__(
         self,
         model,
@@ -580,14 +582,15 @@ class ServeEngine:
         self.predicted_tok_s = sp.get("tok_s")
 
         # --- the compiled programs: one trunk, four programs (programs.py)
-        progs = build_serve_programs(
-            model, self.kv, attn_kernel=self.attn_kernel,
-            weight_dtype=self.weight_dtype, spec_k=self.spec_k,
-            spec_draft_layers=self.spec_draft_layers,
-            # greedy decoding needs the argmax alone: the distribution
-            # stays on the device (and out of the program)
-            return_probs=self.temperature > 0.0,
-        )
+        with get_tracer().span("serve_programs", cat="setup"):
+            progs = build_serve_programs(
+                model, self.kv, attn_kernel=self.attn_kernel,
+                weight_dtype=self.weight_dtype, spec_k=self.spec_k,
+                spec_draft_layers=self.spec_draft_layers,
+                # greedy decoding needs the argmax alone: the distribution
+                # stays on the device (and out of the program)
+                return_probs=self.temperature > 0.0,
+            )
         self._n_head = progs.n_head
         self._moe_layers = sum(1 for b in self.spec.branches if b.kind == "moe")
         # the expert layers' counters, summed on the device call by call
@@ -602,37 +605,38 @@ class ServeEngine:
         # warmup both programs once so the cache layout/sharding
         # stabilizes (same rationale as GPTDecodeSession) and steady
         # state replays compiled code only
-        idle_decode, idle_prefill = self._idle_args()
-        z, _, bt0 = idle_decode
-        nh = self._n_head
-        res = self._decode(self._params_arg, *self._kvs(), *idle_decode)
-        bufs = res[nh:]
-        res = self._prefill(self._params_arg, *bufs, *idle_prefill)
-        bufs = res[nh:]
-        # chain one more decode on the prefill's outputs so BOTH
-        # programs have seen the other's cache layout — steady state
-        # then replays compiled code regardless of phase interleaving
-        res = self._decode(self._params_arg, *bufs, z, z, bt0)
-        bufs = res[nh:]
-        if self.spec_k:
-            # the speculative programs join the same warmup chain so
-            # all four agree on ONE buffer layout (a second layout
-            # would recompile every donated program once per layout)
-            res = self._draft(self._params_arg, *bufs, z, z, bt0)
-            bufs = res[1:]
-            res = self._verify(
-                self._params_arg, *bufs,
-                jnp.zeros((B, self.spec_k + 1), jnp.int32), z, bt0,
-            )
-            bufs = res[4:]
+        with get_tracer().span("warmup", cat="setup"):
+            idle_decode, idle_prefill = self._idle_args()
+            z, _, bt0 = idle_decode
+            nh = self._n_head
+            res = self._decode(self._params_arg, *self._kvs(), *idle_decode)
+            bufs = res[nh:]
+            res = self._prefill(self._params_arg, *bufs, *idle_prefill)
+            bufs = res[nh:]
+            # chain one more decode on the prefill's outputs so BOTH
+            # programs have seen the other's cache layout — steady state
+            # then replays compiled code regardless of phase interleaving
             res = self._decode(self._params_arg, *bufs, z, z, bt0)
             bufs = res[nh:]
-        # keep the CHAINED warmup buffers as the live pool: the warmup
-        # only ever wrote the trash block (all tables were zero), so
-        # every real block still holds zeros — and replacing them with
-        # fresh device_put arrays would introduce a second buffer
-        # layout, recompiling both donated programs once per layout
-        self._store_kvs(bufs)
+            if self.spec_k:
+                # the speculative programs join the same warmup chain so
+                # all four agree on ONE buffer layout (a second layout
+                # would recompile every donated program once per layout)
+                res = self._draft(self._params_arg, *bufs, z, z, bt0)
+                bufs = res[1:]
+                res = self._verify(
+                    self._params_arg, *bufs,
+                    jnp.zeros((B, self.spec_k + 1), jnp.int32), z, bt0,
+                )
+                bufs = res[4:]
+                res = self._decode(self._params_arg, *bufs, z, z, bt0)
+                bufs = res[nh:]
+            # keep the CHAINED warmup buffers as the live pool: the warmup
+            # only ever wrote the trash block (all tables were zero), so
+            # every real block still holds zeros — and replacing them with
+            # fresh device_put arrays would introduce a second buffer
+            # layout, recompiling both donated programs once per layout
+            self._store_kvs(bufs)
 
         # --verify-compiled (docs/ANALYSIS.md): the executor's post-
         # compile ffcheck pass, applied to the serve programs — the

@@ -139,6 +139,10 @@ class OperatorType(enum.Enum):
     GATED_ATTENTION = "gated_attention"
     GATED_DELTA_NET = "gated_delta_net"
     MAMBA2_MIXER = "mamba2_mixer"
+    MAMBA1_MIXER = "mamba1_mixer"
+    DIFF_ATTENTION = "diff_attention"
+    GATED_MEMORY = "gated_memory"
+    TIED_HEAD = "tied_head"
     TOPK = "topk"
     GROUP_BY = "group_by"
     EXPERTS = "experts"

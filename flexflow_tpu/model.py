@@ -603,6 +603,91 @@ class FFModel:
             OperatorType.MAMBA2_MIXER, self._name("mamba2_mixer", name), [input], attrs,
         )[0]
 
+    def mamba1_mixer(
+        self,
+        input: Tensor,
+        d_inner: int,
+        state_size: int,
+        dt_rank: int,
+        conv_kernel: int = 4,
+        exports_memory: bool = False,
+        name: Optional[str] = None,
+    ):
+        """State-space mixer with a decay a (channel, state) pair
+        (:class:`flexflow_tpu.ops.ssm.Mamba1Mixer`).  With
+        ``exports_memory`` returns ``(out, m)``, ``m`` the gated scan
+        output a :meth:`gated_memory` reads; else ``out``."""
+        attrs = dict(d_inner=d_inner, state_size=state_size, dt_rank=dt_rank,
+                     conv_kernel=conv_kernel)
+        if exports_memory:
+            attrs["exports_memory"] = True
+        outs = self._add_layer(
+            OperatorType.MAMBA1_MIXER, self._name("mamba1_mixer", name), [input], attrs,
+        )
+        return tuple(outs) if exports_memory else outs[0]
+
+    def differential_attention(
+        self,
+        input: Tensor,
+        num_heads: int,
+        num_kv_heads: int,
+        head_dim: int,
+        layer_idx: int,
+        window: int = 0,
+        eps: float = 1e-5,
+        kv: Optional[Tensor] = None,
+        exports_kv: bool = False,
+        lambda_scale: float = 1.0,
+        name: Optional[str] = None,
+    ):
+        """Causal differential attention
+        (:class:`flexflow_tpu.ops.attention.DifferentialAttention`).
+        ``kv``: another layer's exported ``[k | v]`` -- a cross layer,
+        which projects only its query.  With ``exports_kv`` returns
+        ``(out, kv)``; else ``out``.  ``lambda_scale`` multiplies the
+        learned ``lambda`` (0: the second map is not subtracted)."""
+        attrs = dict(num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
+                     layer_idx=int(layer_idx), eps=eps)
+        if window:
+            attrs["window"] = int(window)
+        if kv is not None:
+            attrs["cross"] = True
+        if exports_kv:
+            attrs["exports_kv"] = True
+        if lambda_scale != 1.0:
+            attrs["lambda_scale"] = float(lambda_scale)
+        outs = self._add_layer(
+            OperatorType.DIFF_ATTENTION, self._name("diff_attention", name),
+            [input] if kv is None else [input, kv], attrs,
+        )
+        return tuple(outs) if exports_kv else outs[0]
+
+    def gated_memory(
+        self, input: Tensor, memory: Tensor, memory_ones: bool = False,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """``(silu(x W_in) * memory) W_out``
+        (:class:`flexflow_tpu.ops.ssm.GatedMemoryUnit`); ``memory_ones``
+        reads ones in the memory's place."""
+        attrs = dict(d_mem=memory.shape[-1])
+        if memory_ones:
+            attrs["memory_ones"] = True
+        return self._add_layer(
+            OperatorType.GATED_MEMORY, self._name("gated_memory", name), [input, memory],
+            attrs,
+        )[0]
+
+    def tied_head(self, input: Tensor, embedding: Tensor, name: Optional[str] = None) -> Tensor:
+        """Logits over ``embedding``'s table: ``x E^T``, the head tied to
+        the embedding layer that made ``embedding``
+        (:class:`flexflow_tpu.ops.embedding.TiedHead`)."""
+        table = embedding.owner_layer
+        assert table is not None and table.op_type == OperatorType.EMBEDDING, table
+        return self._add_layer(
+            OperatorType.TIED_HEAD, self._name("tied_head", name), [input],
+            dict(tied_to=table.name, num_entries=table.attrs["num_entries"]),
+        )[0]
+
     def softmax(self, input: Tensor, dim: int = -1, name: Optional[str] = None) -> Tensor:
         return self._add_layer(
             OperatorType.SOFTMAX, self._name("softmax", name), [input], dict(dim=dim)

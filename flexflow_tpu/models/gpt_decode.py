@@ -44,8 +44,9 @@ import numpy as np
 __all__ = ["GPTSpec", "LayerSpec", "BranchSpec", "GPTDecodeSession", "gpt_generate_cached"]
 
 
-ATTENTION_KINDS = ("mha", "gated")
+ATTENTION_KINDS = ("mha", "gated", "diff")
 FFN_KINDS = ("gelu", "gated_ffn", "moe")
+STATE_KINDS = ("mamba2", "mamba1")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,11 +55,13 @@ class BranchSpec:
     programs run it: the names of the layers whose parameters it reads,
     and what the mixer is."""
 
-    kind: str  # "mha" | "gated" (attention) | "gelu" | "gated_ffn" | "moe" | "mamba2"
+    # "mha" | "gated" | "diff" (attention) | "gelu" | "gated_ffn" | "moe"
+    # (FFN) | "mamba2" | "mamba1" (state) | "gmu" (a gated memory unit)
+    kind: str
     norm_in: str
     mixer: Tuple[str, ...]  # (ff0, ff1) for "gelu", one layer otherwise
     norm_post: Optional[str] = None  # sandwich norm on the mixer's output
-    attrs: Optional[Dict[str, Any]] = None  # "moe", "mamba2": the op's attrs
+    attrs: Optional[Dict[str, Any]] = None  # "moe", "mamba*", "gmu": the op's attrs
     # --- attention ("mha": q/k/v/o, biases optional, K/V heads grouped or not)
     heads: int = 0
     kv_heads: int = 0
@@ -69,10 +72,29 @@ class BranchSpec:
     rotary_dim: int = 0  # 0: the layer carries no positions of its own
     rope_theta: float = 0.0
     window: int = 0  # 0: every earlier key; else the last ``window`` keys
+    # --- differential ("diff"): heads, kv_heads and head_dim are the
+    # pool's geometry -- a K/V PAIR one head of 2 x the published head
+    # size, a query two heads of that size (``[q1 | 0]``, ``[0 | q2]``);
+    # qk_eps is the per-pair norm's eps
+    lambda_init: float = 0.0
+    lambda_scale: float = 1.0
+    # the layer whose K/V this attention reads (a cross layer: it writes
+    # none); -1: its own
+    kv_source: int = -1
 
     @property
     def is_attention(self) -> bool:
         return self.kind in ATTENTION_KINDS
+
+    @property
+    def differential(self) -> bool:
+        return self.kind == "diff"
+
+    @property
+    def owns_memory(self) -> bool:
+        """Whether the branch writes a pool: an attention with K/V of its
+        own, or a state layer."""
+        return (self.is_attention and self.kv_source < 0) or self.kind in STATE_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,12 +189,16 @@ class GPTSpec:
     learned position table, or times a constant), then a chain of
     residual branches ``x + [norm](mixer(norm(x)))`` -- the mixer an
     attention op (:class:`MultiHeadAttention`, K/V heads grouped or not,
-    or :class:`GatedAttention`), an FFN (two dense layers around a
-    GELU, a :class:`GatedFFN`, or :class:`RoutedExperts` holding all or
-    a share of its experts) or a :class:`Mamba2Mixer` -- and a final norm
-    and a bias-free head.  An attention branch and the FFN branch that
-    follows it under the same name prefix are one layer (GPT-2,
-    Trinity); every other branch is a layer of its own (Nemotron-H)."""
+    :class:`GatedAttention`, or :class:`DifferentialAttention` over its
+    own K/V or, a cross layer, another layer's), an FFN (two dense
+    layers around a GELU, a :class:`GatedFFN`, or :class:`RoutedExperts`
+    holding all or a share of its experts), a :class:`Mamba2Mixer` or
+    :class:`Mamba1Mixer`, or a :class:`GatedMemoryUnit` over the memory a
+    Mamba-1 layer exports -- and a final norm and a bias-free head, its
+    own or tied to the embedding's table.  A mixer branch and the FFN
+    branch that follows it under the same name prefix are one layer
+    (GPT-2, Trinity, phi4flash); every other branch is a layer of its
+    own (Nemotron-H)."""
 
     num_layers: int
     heads: int
@@ -191,6 +217,7 @@ class GPTSpec:
     final_norm: str = "final_ln"
     head: str = "lm_head"
     layers: Tuple[LayerSpec, ...] = ()
+    head_tied: bool = False  # the head reads the embedding's table
 
     @property
     def branches(self) -> Tuple[BranchSpec, ...]:
@@ -221,7 +248,17 @@ class GPTSpec:
     @property
     def state_layers(self) -> Tuple[BranchSpec, ...]:
         """The branches that carry a recurrent state a slot."""
-        return tuple(b for b in self.branches if b.kind == "mamba2")
+        return tuple(b for b in self.branches if b.kind in STATE_KINDS)
+
+    @property
+    def self_layers(self) -> int:
+        """Layers a prefill runs on every row of a chunk: up to the last
+        layer that writes a pool.  The layers after it (a YOCO-style
+        cross-decoder: memory units, cross-attention over another
+        layer's K/V, FFNs) write nothing a later row reads, so a prefill
+        runs them on the rows whose logits are needed only."""
+        own = [i for i, l in enumerate(self.layers) if any(b.owns_memory for b in l.branches)]
+        return own[-1] + 1 if own else self.num_layers
 
     @classmethod
     def from_model(cls, model) -> "GPTSpec":
@@ -235,14 +272,16 @@ class GPTSpec:
             for t in l.inputs:
                 consumers.setdefault(t.guid, []).append(l)
         NORMS = (T.LAYERNORM, T.RMS_NORM)
-        MIXERS = (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.LINEAR,
-                  T.GATED_FFN, T.ROUTED_EXPERTS, T.MAMBA2_MIXER)
+        MIXERS = (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.DIFF_ATTENTION, T.LINEAR,
+                  T.GATED_FFN, T.ROUTED_EXPERTS, T.MAMBA2_MIXER, T.MAMBA1_MIXER,
+                  T.GATED_MEMORY)
 
         def refuse(why):
             raise ValueError(
                 "not a decoder the serve programs know (an embedding, then "
                 "residual branches norm -> MultiHeadAttention | GatedAttention "
-                "| Mamba2Mixer | dense+GELU+dense | GatedFFN | RoutedExperts "
+                "| DifferentialAttention | Mamba2Mixer | Mamba1Mixer | "
+                "GatedMemoryUnit | dense+GELU+dense | GatedFFN | RoutedExperts "
                 f"-> [norm] -> add, a final norm, a head): {why}"
             )
 
@@ -262,11 +301,14 @@ class GPTSpec:
             return "rms_zero_centered" if l.attrs.get("zero_centered") else "rms"
 
         embeds = [l for l in layers if l.op_type == T.EMBEDDING]
-        heads_ = [l for l in layers if l.op_type == T.LINEAR]
+        heads_ = [l for l in layers if l.op_type in (T.LINEAR, T.TIED_HEAD)]
         if len(embeds) != 1 or not heads_:
             refuse("it needs one token embedding and an output head")
         embed, head = embeds[0], heads_[-1]
-        if head.attrs.get("use_bias", True):
+        tied = head.op_type == T.TIED_HEAD
+        if tied and head.attrs["tied_to"] != embed.name:
+            refuse(f"the head {head.name!r} is tied to another table than the embedding's")
+        if not tied and head.attrs.get("use_bias", True):
             refuse(f"the head {head.name!r} has a bias")
         final = producer.get(head.inputs[0].guid)
         if final is None or final.op_type not in NORMS:
@@ -284,8 +326,30 @@ class GPTSpec:
         elif nxt is not None:
             embed_scale, stream = float(nxt.attrs["scalar"]), nxt
 
+        cross_source = {}  # a cross layer's name -> the layer whose K/V it reads
+
         def attention_of(at):
             a = at.attrs
+            if at.op_type == T.DIFF_ATTENTION:
+                # the pool's geometry: a K/V pair one head of 2 d, a query
+                # pair two heads of 2 d (BranchSpec)
+                from flexflow_tpu.ops.attention import DifferentialAttention
+
+                d = dict(kind="diff", heads=a["num_heads"], kv_heads=a["num_kv_heads"] // 2,
+                         head_dim=2 * a["head_dim"], qk_eps=float(a["eps"]),
+                         window=int(a.get("window", 0)),
+                         lambda_init=DifferentialAttention.lambda_init(a),
+                         lambda_scale=float(a.get("lambda_scale", 1.0)))
+                if a.get("cross"):
+                    src = producer.get(at.inputs[1].guid)
+                    if src is None or src.op_type != T.DIFF_ATTENTION or not src.attrs.get("exports_kv"):
+                        refuse(f"{at.name!r} reads K/V no attention layer exported")
+                    if src.attrs.get("window"):
+                        refuse(f"{at.name!r} reads a window layer's K/V (cross layers read the full group)")
+                    cross_source[at.name] = src.name
+                elif len(at.inputs) != 1:
+                    refuse(f"{at.name!r} is not self-attention")
+                return d
             if any(t.guid != at.inputs[0].guid for t in at.inputs):
                 refuse(f"{at.name!r} is not self-attention")
             if at.op_type == T.MULTIHEAD_ATTENTION:
@@ -316,7 +380,7 @@ class GPTSpec:
             if mix is None:
                 refuse(f"no mixer the serve programs know reads {n_in.name!r}")
             names, kind = (mix.name,), {}
-            if mix.op_type in (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION):
+            if mix.op_type in (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.DIFF_ATTENTION):
                 kind = attention_of(mix)
             elif mix.op_type == T.LINEAR:
                 f1 = after(mix, T.LINEAR)
@@ -327,6 +391,13 @@ class GPTSpec:
                 kind = dict(kind="gated_ffn")
             elif mix.op_type == T.ROUTED_EXPERTS:
                 kind = dict(kind="moe", attrs=dict(mix.attrs))
+            elif mix.op_type == T.GATED_MEMORY:
+                src = producer.get(mix.inputs[1].guid)
+                if src is None or src.op_type != T.MAMBA1_MIXER:
+                    refuse(f"{mix.name!r} reads a memory no state-space layer exported")
+                kind = dict(kind="gmu", attrs=dict(mix.attrs))
+            elif mix.op_type == T.MAMBA1_MIXER:
+                kind = dict(kind="mamba1", attrs=dict(mix.attrs))
             else:
                 kind = dict(kind="mamba2", attrs=dict(mix.attrs))
             post = after(mix, *NORMS)
@@ -346,19 +417,22 @@ class GPTSpec:
         for b in branches:
             last = specs[-1] if specs else None
             if (b.kind in FFN_KINDS and last is not None and len(last) == 1
-                    and last[0].is_attention and prefix(last[0]) == prefix(b)):
+                    and last[0].kind not in FFN_KINDS and prefix(last[0]) == prefix(b)):
                 last.append(b)
             else:
                 specs.append([b])
-        specs = [LayerSpec(tuple(bs)) for bs in specs]
-        attn = [b for b in branches if b.is_attention]
+        # a cross layer's K/V source, by the index of the layer that owns them
+        at_layer = {b.mixer[0]: i for i, bs in enumerate(specs) for b in bs}
+        specs = [LayerSpec(tuple(
+            dataclasses.replace(b, kv_source=at_layer[cross_source[b.mixer[0]]])
+            if b.mixer[0] in cross_source else b for b in bs
+        )) for bs in specs]
+        attn = [b for l in specs for b in l.branches if b.is_attention]
         if not attn:
             refuse("no attention layer")
         l0 = attn[0]
         if len({(l.heads, l.kv_heads, l.head_dim) for l in attn}) != 1:
             refuse("layers differ in their heads (one K/V pool geometry is served)")
-        if len({tuple(sorted(b.attrs.items())) for b in branches if b.kind == "mamba2"}) > 1:
-            refuse("state-space layers differ in their sizes (one state pool geometry is served)")
         batch, seq = model.graph_inputs[0].shape
         return cls(
             num_layers=len(specs),
@@ -378,6 +452,7 @@ class GPTSpec:
             final_norm=final.name,
             head=head.name,
             layers=tuple(specs),
+            head_tied=tied,
         )
 
 

@@ -345,6 +345,119 @@ class GatedAttention(OpDef):
         return {0: "sample"}
 
 
+def diff_lambda(p, lambda_init):
+    """Differential attention's ``lambda = exp(lq1 . lk1) - exp(lq2 .
+    lk2) + lambda_init``, float32, from a layer's four vectors."""
+    f32 = jnp.float32
+    dot = lambda a, b: jnp.sum(p[a].astype(f32) * p[b].astype(f32))  # noqa: E731
+    return (jnp.exp(dot("lambda_q1", "lambda_k1"))
+            - jnp.exp(dot("lambda_q2", "lambda_k2")) + lambda_init)
+
+
+def diff_combine(a1, a2, p, lambda_init, eps, lambda_scale=1.0):
+    """``RMSNorm(a1 - lambda a2) * (1 - lambda_init)`` over each pair's
+    2 x head_dim outputs (..., pairs, 2 d), float32 (``subln`` the norm's
+    weight; ``lambda`` times ``lambda_scale``)."""
+    lam = lambda_scale * diff_lambda(p, lambda_init)
+    o = a1.astype(jnp.float32) - lam * a2.astype(jnp.float32)
+    return rms_norm_f32(o, p["subln"], eps) * (1.0 - lambda_init)
+
+
+class DifferentialAttention(OpDef):
+    """Differential attention (Ye et al., arXiv:2410.05258; the
+    ``phi4flash`` family's attention layers): ``num_heads`` query heads
+    and ``num_kv_heads`` K/V heads of ``head_dim`` form pairs (heads
+    ``2i, 2i + 1``); query pair ``i`` reads K/V pair ``i // (num_heads /
+    num_kv_heads)``.  A pair gives two softmax maps over the same values
+    ``[v1 | v2]`` (2 x head_dim wide), the second subtracted with a
+    learned ``lambda``, then a per-pair RMSNorm and ``1 - lambda_init``::
+
+        A_j = softmax(q_j k_j^T / sqrt(head_dim) + mask) [v1 | v2]
+        out = RMSNorm(A_1 - lambda A_2) (1 - lambda_init)
+
+    ``lambda_init = 0.8 - 0.6 exp(-0.3 layer_idx)``.  Causal, and with
+    ``window`` a row sees its last ``window`` keys.  Self layers take
+    ``x`` (B, S, E) and project ``[q | k | v]`` with ``wqkv``; with
+    ``exports_kv`` they also output ``[k | v]`` (B, S, 2 KV d).  A
+    ``cross`` layer takes ``(x, kv)`` -- another layer's exported K/V --
+    and projects only ``wq``.  Attrs: ``num_heads``, ``num_kv_heads``,
+    ``head_dim``, ``layer_idx``, ``window``, ``eps`` (the subln's),
+    ``exports_kv``, ``cross``, ``lambda_scale`` (multiplies ``lambda``;
+    absent: 1).  No positional encoding."""
+
+    op_type = OperatorType.DIFF_ATTENTION
+    fp32_weights = frozenset({"lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"})
+
+    @staticmethod
+    def lambda_init(a) -> float:
+        return 0.8 - 0.6 * math.exp(-0.3 * a["layer_idx"])
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        a = layer.attrs
+        outs = [(t.shape, t.dtype)]
+        if a.get("exports_kv"):
+            outs.append((t.shape[:-1] + (2 * a["num_kv_heads"] * a["head_dim"],), t.dtype))
+        return outs
+
+    def weights(self, layer: Layer) -> List[WeightSpec]:
+        from flexflow_tpu.initializer import NormInitializer, OnesInitializer
+
+        t = layer.inputs[0]
+        a = layer.attrs
+        e, dt = t.shape[-1], t.dtype
+        h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
+        init = a.get("kernel_initializer") or default_kernel_initializer()
+        proj = (WeightSpec("wq", (e, h * d), dt, init) if a.get("cross")
+                else WeightSpec("wqkv", (e, (h + 2 * kv) * d), dt, init))
+        lam = NormInitializer(mean=0.0, stddev=0.1)
+        return [proj, WeightSpec("wo", (h * d, e), dt, init)] + [
+            WeightSpec(n, (d,), dt, lam)
+            for n in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+        ] + [WeightSpec("subln", (2 * d,), dt, OnesInitializer())]
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        x = inputs[0]
+        a = layer.attrs
+        h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
+        b, s, _ = x.shape
+        scope = "ff.attn_cross" if a.get("cross") else "ff.attn_diff"
+        with jax.named_scope(scope):
+            if a.get("cross"):
+                q = x @ params["wq"]
+                k, v = inputs[1][..., :kv * d], inputs[1][..., kv * d:]
+            else:
+                q, k, v = jnp.split(x @ params["wqkv"], [h * d, (h + kv) * d], axis=-1)
+            # pairs: (b, pairs, s, 2, d); values of a pair are [v1 | v2]
+            q = q.reshape(b, s, h // 2, 2, d).transpose(0, 2, 1, 3, 4)
+            kp = k.reshape(b, s, kv // 2, 2, d).transpose(0, 2, 1, 3, 4)
+            vp = v.reshape(b, s, kv // 2, 2 * d).transpose(0, 2, 1, 3)
+            rep = h // kv
+            kp, vp = jnp.repeat(kp, rep, axis=1), jnp.repeat(vp, rep, axis=1)
+            maps = [sdpa(q[..., j, :], kp[..., j, :], vp, causal=True,
+                         window=a.get("window", 0)) for j in (0, 1)]
+            o = diff_combine(maps[0], maps[1], params, self.lambda_init(a), a["eps"],
+                             a.get("lambda_scale", 1.0))
+            o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, s, h * d)
+            out = o @ params["wo"]
+        if a.get("exports_kv"):
+            return [out, jnp.concatenate([k, v], axis=-1)]
+        return [out]
+
+    def flops(self, layer: Layer) -> float:
+        b, s, e = layer.inputs[0].shape
+        a = layer.attrs
+        h, kv, d = a["num_heads"], a["num_kv_heads"], a["head_dim"]
+        qkv = h * d if a.get("cross") else (h + 2 * kv) * d
+        seen = min(s, a.get("window", 0) or s)  # keys a late query sees
+        # two maps a pair, each d wide in the scores and 2 d in the values;
+        # causal: half of the pairs
+        return 2.0 * b * s * e * (qkv + h * d) + 2.0 * b * (h // 2) * s * seen * 3 * d
+
+    def partitionable_dims(self, layer):
+        return {0: "sample"}
+
+
 # Above this many bytes of materialized (b, h, sq, sk) score matrix the
 # O(S^2) sdpa path becomes memory-prohibitive and flash pays; below it,
 # XLA's fused attention measured consistently faster than the Pallas
@@ -382,3 +495,4 @@ def _flash_ok(sq: int, sk: int, d: int, bh_local: int = 1) -> bool:
 
 register_op(MultiHeadAttention())
 register_op(GatedAttention())
+register_op(DifferentialAttention())

@@ -153,5 +153,32 @@ class Gather(OpDef):
         return [jnp.take_along_axis(data, index, axis=dim)]
 
 
+class TiedHead(OpDef):
+    """An output head tied to an embedding's table: ``x E^T``, no bias.
+    Input (..., E) -> (..., num_entries).  It holds no weights: attr
+    ``tied_to`` names the embedding layer whose ``kernel`` it reads (the
+    executor hands it that layer's weights), ``num_entries`` the rows."""
+
+    op_type = OperatorType.TIED_HEAD
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        return [(t.shape[:-1] + (layer.attrs["num_entries"],), t.dtype)]
+
+    def weights(self, layer: Layer) -> List[WeightSpec]:
+        return []
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        x = inputs[0]
+        return [jax.lax.dot_general(
+            x, params["kernel"].astype(x.dtype), (((x.ndim - 1,), (1,)), ((), ()))
+        )]
+
+    def flops(self, layer: Layer) -> float:
+        t = layer.inputs[0]
+        return 2.0 * math.prod(t.shape) * layer.attrs["num_entries"]
+
+
 register_op(Embedding())
 register_op(Gather())
+register_op(TiedHead())

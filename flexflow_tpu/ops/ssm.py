@@ -32,6 +32,22 @@ The functional core is ONE set of functions, called by the op's forward
 
 A row with ``dt = 0`` leaves ``S`` as it was (decay 1, nothing added):
 that is how rows past ``n_valid`` and idle lanes pass through.
+
+Mamba-1 (Gu & Dao, arXiv:2312.00752; the ``phi4flash`` family's
+state-space layers) decays every (channel, state) pair by its own
+factor, so the SSD matrix form does not apply: ``[x | z] = u W_in``,
+``x = silu(conv(x))``, ``[delta | B | C] = x W_x``, ``dt =
+softplus(delta W_dt + b_dt)`` a channel, ``A = -exp(A_log)`` of shape
+(channels, state), and a state ``S`` (channels x state, float32)::
+
+    S_t = exp(dt_t (.) A) (.) S_{t-1} + (dt_t x_t) (x) B_t;    y_t = S_t C_t + D x_t
+
+then ``m = y * silu(z)`` and ``out = m W_out``.  Its functions:
+:func:`selective_step` (one position), :func:`selective_scan` (a
+``lax.scan`` over positions from a state handed in) and
+:func:`mamba1_mixer`; :func:`conv_with_state` is shared.  The layer may
+export ``m``, the memory the ``phi4flash`` family's gated memory units
+(:func:`gated_memory`) read.
 """
 
 from __future__ import annotations
@@ -280,3 +296,196 @@ class Mamba2Mixer(OpDef):
 
 
 register_op(Mamba2Mixer())
+
+
+# ----------------------------------------------------------------- Mamba-1
+def selective_step(x, dt, A, B, C, D, state):
+    """One position: ``x``, ``dt`` (b, d), ``A`` (d, n) < 0, ``B``, ``C``
+    (b, n) (shared by the channels), ``D`` (d,), ``state`` (b, d, n)
+    float32.  Returns ``(y (b, d) float32, state')``."""
+    f32 = jnp.float32
+    x, dt = x.astype(f32), dt.astype(f32)
+    S = (jnp.exp(dt[..., None] * A.astype(f32)) * state
+         + (dt * x)[..., None] * B.astype(f32)[:, None, :])
+    y = jnp.sum(S * C.astype(f32)[:, None, :], axis=-1) + D.astype(f32) * x
+    return y, S
+
+
+def selective_scan(x, dt, A, B, C, D, state=None, n_valid=None, unroll: int = 1):
+    """:func:`selective_step` over ``x``, ``dt`` (b, s, d) and ``B``,
+    ``C`` (b, s, n), a ``lax.scan`` over positions that carries the
+    float32 (b, d, n) state (zeros when None).  ``n_valid`` (b,): rows at
+    or past it leave the state alone (``dt`` 0: decay 1, nothing added).
+    Returns ``(y (b, s, d) float32, state')``."""
+    b, s, d = x.shape
+    if state is None:
+        state = jnp.zeros((b, d, A.shape[-1]), jnp.float32)
+    if n_valid is not None:
+        dt = jnp.where(jnp.arange(s)[None, :, None] < n_valid[:, None, None], dt, 0)
+
+    def step(S, t):
+        y, S = selective_step(t[0], t[1], A, t[2], t[3], D, S)
+        return S, y
+
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, B, C))
+    state, y = jax.lax.scan(step, state, xs, unroll=max(1, min(unroll, s)))
+    return jnp.moveaxis(y, 0, 1), state
+
+
+# positions a prefill's scan takes a loop iteration: the loop's overhead a
+# position is then an eighth of one step's
+SCAN_UNROLL = 8
+
+
+def mamba1_mixer(params, u, a: dict, conv_state=None, ssm_state=None, n_valid=None):
+    """The Mamba-1 layer over ``u`` (batch, seq, hidden) from weights
+    ``params`` and attrs ``a`` (``d_inner``, ``state_size``,
+    ``conv_kernel``, ``dt_rank``); matmul operands in ``u``'s dtype.  As
+    :func:`mamba2_mixer`: no state handed in is a lane that starts from
+    nothing, ``n_valid`` (batch,) keeps rows at or past it out of both
+    states, one row a lane with a state takes the single step.  Returns
+    ``(out, conv state', ssm state', m)``: ``m = y * silu(z)`` (batch,
+    seq, d_inner) in ``u``'s dtype, the tensor ``out_proj`` reads."""
+    f32 = jnp.float32
+    d, n, r = a["d_inner"], a["state_size"], a["dt_rank"]
+    s = u.shape[1]
+    with jax.named_scope("ff.ssm"):
+        xz = u @ params["in_proj"]
+        x, z = xz[..., :d], xz[..., d:]
+        with jax.named_scope("ff.ssm.conv"):
+            x, conv_state = conv_with_state(
+                x, params["conv"], params["conv_bias"], conv_state, n_valid
+            )
+            x = jax.nn.silu(x)
+        dbc = x @ params["x_proj"]
+        dt = jax.nn.softplus(
+            (dbc[..., :r] @ params["dt_proj"]).astype(f32) + params["dt_bias"].astype(f32)
+        )
+        B, C = dbc[..., r:r + n], dbc[..., r + n:r + 2 * n]
+        A = -jnp.exp(params["A_log"].astype(f32))
+        if s == 1 and ssm_state is not None:
+            with jax.named_scope("ff.ssm.step"):
+                if n_valid is not None:
+                    dt = jnp.where(n_valid[:, None, None] > 0, dt, 0.0)
+                y, ssm_state = selective_step(
+                    x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], params["D"], ssm_state
+                )
+                y = y[:, None]
+        else:
+            with jax.named_scope("ff.ssm.scan"):
+                y, ssm_state = selective_scan(
+                    x, dt, A, B, C, params["D"], ssm_state, n_valid, SCAN_UNROLL
+                )
+        m = (y * jax.nn.silu(z.astype(f32))).astype(u.dtype)
+        return m @ params["out_proj"], conv_state, ssm_state, m
+
+
+def state_shapes(kind: str, a: dict):
+    """``(conv state, ssm state)`` shapes a slot of a state layer of
+    ``kind`` (``mamba2`` | ``mamba1``) with attrs ``a`` holds."""
+    if kind == "mamba2":
+        h, p, _, n, _, cw = mamba2_dims(a)
+        return (a["conv_kernel"] - 1, cw), (h, p, n)
+    return (a["conv_kernel"] - 1, a["d_inner"]), (a["d_inner"], a["state_size"])
+
+
+class Mamba1Mixer(OpDef):
+    """Input (B, S, E) -> output (B, S, E), and with ``exports_memory``
+    also ``m`` (B, S, d_inner).  Attrs: ``d_inner``, ``state_size``,
+    ``conv_kernel``, ``dt_rank``, ``exports_memory``.  Weights (``in_proj``'s
+    columns ``[x | z]``, ``x_proj``'s ``[delta | B | C]``)::
+
+        in_proj (E, 2 d)    conv (d, K), conv_bias (d,)    x_proj (d, R + 2 N)
+        dt_proj (R, d)      dt_bias, D (d,), A_log (d, N)  float32
+        out_proj (d, E)
+    """
+
+    op_type = OperatorType.MAMBA1_MIXER
+    fp32_weights = frozenset({"A_log", "dt_bias", "D"})
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        outs = [(t.shape, t.dtype)]
+        if layer.attrs.get("exports_memory"):
+            outs.append((t.shape[:-1] + (layer.attrs["d_inner"],), t.dtype))
+        return outs
+
+    def weights(self, layer: Layer) -> List[WeightSpec]:
+        t = layer.inputs[0]
+        e, dt = t.shape[-1], t.dtype
+        a = layer.attrs
+        d, n, r = a["d_inner"], a["state_size"], a["dt_rank"]
+        init = a.get("kernel_initializer") or default_kernel_initializer()
+        return [
+            WeightSpec("in_proj", (e, 2 * d), dt, init),
+            WeightSpec("conv", (d, a["conv_kernel"]), dt, init),
+            WeightSpec("conv_bias", (d,), dt, ZeroInitializer()),
+            WeightSpec("x_proj", (d, r + 2 * n), dt, init),
+            WeightSpec("dt_proj", (r, d), dt, init),
+            WeightSpec("dt_bias", (d,), dt, ZeroInitializer()),
+            WeightSpec("A_log", (d, n), dt, ZeroInitializer()),
+            WeightSpec("D", (d,), dt, OnesInitializer()),
+            WeightSpec("out_proj", (d, e), dt, init),
+        ]
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        out, _, _, m = mamba1_mixer(params, inputs[0], layer.attrs)
+        return [out, m] if layer.attrs.get("exports_memory") else [out]
+
+    def flops(self, layer: Layer) -> float:
+        b, s, e = layer.inputs[0].shape
+        a = layer.attrs
+        d, n, r = a["d_inner"], a["state_size"], a["dt_rank"]
+        proj = 2.0 * b * s * (e * 2 * d + d * (r + 2 * n) + r * d + d * e)
+        # a position: the conv's taps, the state's decay, update and read-out
+        return proj + 2.0 * b * s * d * (a["conv_kernel"] + 3 * n)
+
+    def partitionable_dims(self, layer):
+        return {0: "sample"}  # the recurrence runs along dim 1
+
+
+register_op(Mamba1Mixer())
+
+
+def gated_memory(h, m, w_in, w_out):
+    """A gated memory unit: ``(silu(h W_in) * m) W_out``, the gate and
+    the product in float32, matmul operands in ``h``'s dtype."""
+    g = jax.nn.silu((h @ w_in).astype(jnp.float32)) * m.astype(jnp.float32)
+    return g.astype(h.dtype) @ w_out
+
+
+class GatedMemoryUnit(OpDef):
+    """Inputs ``h`` (B, S, E) and a memory ``m`` (B, S, d) another layer
+    exported (a :class:`Mamba1Mixer`'s) -> (B, S, E).  Attrs: ``d_mem``,
+    ``memory_ones`` (ones read in the memory's place).  Weights ``w_in``
+    (E, d), ``w_out`` (d, E)."""
+
+    op_type = OperatorType.GATED_MEMORY
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        return [(t.shape, t.dtype)]
+
+    def weights(self, layer: Layer) -> List[WeightSpec]:
+        t = layer.inputs[0]
+        e, d = t.shape[-1], layer.attrs["d_mem"]
+        init = layer.attrs.get("kernel_initializer") or default_kernel_initializer()
+        return [WeightSpec("w_in", (e, d), t.dtype, init),
+                WeightSpec("w_out", (d, e), t.dtype, init)]
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        m = inputs[1]
+        if layer.attrs.get("memory_ones"):
+            m = jnp.ones_like(m)
+        with jax.named_scope("ff.gmu"):
+            return [gated_memory(inputs[0], m, params["w_in"], params["w_out"])]
+
+    def flops(self, layer: Layer) -> float:
+        b, s, e = layer.inputs[0].shape
+        return 4.0 * b * s * e * layer.attrs["d_mem"]
+
+    def partitionable_dims(self, layer):
+        return {0: "sample", 1: "seq"}
+
+
+register_op(GatedMemoryUnit())

@@ -400,7 +400,9 @@ class Executor:
         derived from the scan's xs instead of the layer-name fold)."""
         opdef = get_op_def(layer.op_type)
         ins = [values[t.guid] for t in layer.inputs]
-        lp32 = dict(params.get(layer.name, {}))
+        # a layer that reads another's weights (a head tied to the
+        # embedding's table) names it in ``tied_to``
+        lp32 = dict(params.get(layer.attrs.get("tied_to") or layer.name, {}))
         lp32.update(state.get(layer.name, {}))
         lp = {
             k: v if k in opdef.fp32_weights else self._cast_compute(v)
@@ -1891,4 +1893,6 @@ _REMAT_OPS = frozenset({
     OperatorType.GATED_ATTENTION,
     OperatorType.GATED_DELTA_NET,
     OperatorType.MAMBA2_MIXER,
+    OperatorType.MAMBA1_MIXER,
+    OperatorType.DIFF_ATTENTION,
 })

@@ -333,6 +333,10 @@ class ServeReport:
     # group is all of them (PR 38); ÷ (dispatches x slots) is the share of
     # full-width rows still computed
     prefill_lanes_computed: int = 0
+    # rows the layers after the last one that writes a pool computed: a
+    # prefill runs them on each lane's last row only, a decode
+    # step on every slot's; None where no such layer exists
+    cross_decoder_rows: Optional[int] = None
     prefill_attn_kernel: Optional[str] = None  # kernel prefill ran on
     kv_write: Optional[str] = None  # "page_kernel" | "xla_scatter"
     # compute blocks the paged kernel's lanes walked over the run's decode
@@ -383,7 +387,7 @@ class ServeReport:
 class ServeEngine:
     """Continuous-batching serving over one compiled decoder
     (``models/gpt_decode.py::GPTSpec`` says which: ``gpt_decoder``,
-    ``afmoe_decoder``, ``nemotron_h_decoder``).  Speculation, int8
+    ``afmoe_decoder``, ``nemotron_h_decoder``, ``phi4flash_decoder``).  Speculation, int8
     weights, a quantized pool and disaggregated or fleet serving are
     built for ``gpt_decoder``-shaped models and refused for the rest --
     a decoder with window or state layers, routed experts (all held or a
@@ -514,18 +518,11 @@ class ServeEngine:
         # the pool's row is the K/V heads'; window layers live in a
         # group of their own (a ring a slot, kvcache.py), state-space
         # layers' recurrent states in a third, provisioned a slot
-        attn = [b for b in self.spec.branches if b.is_attention]
-        n_win = sum(1 for b in attn if b.window)
-        state = self.spec.state_layers
-        state_shapes = {}
-        if state:
-            from flexflow_tpu.ops.ssm import mamba2_dims
+        # (a cross layer reads another layer's K/V and owns no rows)
+        from flexflow_tpu.ops.ssm import state_shapes
 
-            a = state[0].attrs
-            h, p, _, n, _, cw = mamba2_dims(a)
-            state_shapes = dict(
-                state_conv=(a["conv_kernel"] - 1, cw), state_ssm=(h, p, n),
-            )
+        attn = [b for b in self.spec.branches if b.is_attention and b.kv_source < 0]
+        n_win = sum(1 for b in attn if b.window)
         self.kv = PagedKVCache(
             len(attn) - n_win, self.spec.kv_heads,
             self.spec.head_dim,
@@ -534,8 +531,12 @@ class ServeEngine:
             kv_dtype=self.kv_dtype, prefix_sharing=prefix_sharing,
             window_layers=n_win, window=self.spec.window,
             chunk=self.prefill_chunk,
-            state_layers=len(state), **state_shapes,
+            state_shapes=[state_shapes(b.kind, b.attrs) for b in self.spec.state_layers],
         )
+        # attention layers that read the full group's cache without owning
+        # any of it
+        self._cross_layers = sum(
+            1 for b in self.spec.branches if b.is_attention and b.kv_source >= 0)
         self.sched = ContinuousBatchingScheduler(self.slots, self.kv)
         self.metrics = MetricsStream(metrics_out, max_mb=metrics_max_mb)
         # SLO burn-rate engine (obs/slo.py): fed the SAME window record
@@ -681,6 +682,11 @@ class ServeEngine:
         # per window with any mid-prefill slot, pinned by tests
         self.prefill_dispatches = 0
         self.prefill_lanes_computed = 0
+        # rows the layers after the last one that writes a pool computed
+        # (programs.py runs them on a prefill's last rows only: one a lane
+        # a prefill group, every slot a decode step); None without them
+        self.cross_decoder_rows = (
+            0 if self.spec.self_layers < self.spec.num_layers else None)
         # persistent host staging buffers for the batched prefill
         # chunk arrays — refilled per window, never reallocated
         self._pf_toks = np.zeros((B, P), np.int32)
@@ -903,7 +909,8 @@ class ServeEngine:
         full.  Reckoned at the end of a run from the requests' lengths,
         like :meth:`_attn_blocks`."""
         W, P = self.kv.window, self.prefill_chunk
-        n_win, n_full = self.kv.window_layers, self.kv.num_layers
+        # a cross layer reads the full group's cache as a full layer does
+        n_win, n_full = self.kv.window_layers, self.kv.num_layers + self._cross_layers
         full = win = 0
         for r in fin:
             lo = np.arange(r.shared_prefix_pos, r.prompt_len, P)
@@ -1016,6 +1023,8 @@ class ServeEngine:
         # are per-run (the compiled programs and the pool persist)
         self.windows = self.decode_steps = self.prefill_chunks = 0
         self.prefill_dispatches = self.prefill_lanes_computed = 0
+        if self.cross_decoder_rows is not None:
+            self.cross_decoder_rows = 0
         self.spec_drafted = self.spec_accepted = 0
         self.peak_active = 0
         self._occ_sum = 0.0
@@ -1308,6 +1317,8 @@ class ServeEngine:
                     self.prefill_dispatches += 1
                     lanes_w = prefill_lanes(len(chunks), self.slots, self.prefill_chunk)
                     self.prefill_lanes_computed += lanes_w
+                    if self.cross_decoder_rows is not None:
+                        self.cross_decoder_rows += lanes_w
                     t_c1 = spans.now() if spans is not None else 0.0
                     for slot, lo, hi in chunks:
                         req = self.sched.active[slot]
@@ -1418,6 +1429,8 @@ class ServeEngine:
                             for s in dec_slots:
                                 pos[s] += 1
                     self.decode_steps += steps
+                    if self.cross_decoder_rows is not None:
+                        self.cross_decoder_rows += steps * B
 
             # 3) flush: the window's ONE deliberate host sync
             with tracer.span("sync", cat="serve"):
@@ -1452,6 +1465,8 @@ class ServeEngine:
                 ex.count_host_sync(1, stall)
                 if lanes_w and tracer.enabled:
                     tracer.counter("serve.prefill_lanes_computed", float(lanes_w))
+                if self.cross_decoder_rows is not None and tracer.enabled:
+                    tracer.counter("serve.cross_rows", float(lanes_w + steps * B))
                 flushed_tokens = 0
                 spec_drafted_w = spec_accepted_w = 0
 
@@ -1702,6 +1717,7 @@ class ServeEngine:
             "weight_dtype": self.weight_dtype,
             "kv_pages_held": self.kv.pages_held(),
             "prefill_lanes_computed": self.prefill_lanes_computed,
+            "cross_decoder_rows": self.cross_decoder_rows,
             "state": self._state_report(),
             "moe": self._moe_report(),
         }
@@ -1841,6 +1857,7 @@ class ServeEngine:
             watchdog_fires=self.watchdog_fires,
             prefill_dispatches=self.prefill_dispatches,
             prefill_lanes_computed=self.prefill_lanes_computed,
+            cross_decoder_rows=self.cross_decoder_rows,
             prefill_attn_kernel=self.attn_kernel,
             kv_write=self.kv_write,
             attn_blocks_walked=blocks_walked,

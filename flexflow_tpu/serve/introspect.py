@@ -24,7 +24,9 @@ on localhost, serving five endpoints while an engine or cluster runs:
                pages a compute block), ``prefill_group_lanes`` and,
                once a window has run, ``prefill_lanes_computed``
                (lanes a prefill group computes; lanes the run's
-               dispatches computed) (JSON; compiles both programs
+               dispatches computed) and ``cross_decoder_rows`` (rows
+               the layers after the last one that writes a pool
+               computed; None without such layers) (JSON; compiles both programs
                anew on first use, on this server's own thread, then
                answers from memory)
   ===========  =========================================================
@@ -361,7 +363,8 @@ class StatusServer:
         if isinstance(snap, dict) and "kv_pages_held" in snap:
             return dict(self._poolz, kv_pages_held=snap["kv_pages_held"],
                         moe=snap.get("moe"),
-                        prefill_lanes_computed=snap.get("prefill_lanes_computed"))
+                        prefill_lanes_computed=snap.get("prefill_lanes_computed"),
+                        cross_decoder_rows=snap.get("cross_decoder_rows"))
         return self._poolz
 
     def spanz(self, n: int = 64) -> Dict[str, Any]:

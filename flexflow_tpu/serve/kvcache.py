@@ -87,9 +87,11 @@ state-space layer (``ops/ssm.py``) keeps no rows of a request: its
 memory is a recurrent state of fixed size, read and written whole by
 every step.  With ``state_layers`` > 0 the cache holds, a state layer,
 the convolution's last inputs ``state_conv[i]`` ``(slots, taps - 1,
-channels)`` in the compute dtype and the state ``state_ssm[i]``
-``(slots, heads, head_dim, state)`` float32 -- one array a layer, so
-that a program's update of a layer is in place on a donated buffer --,
+channels)`` in the compute dtype and the state ``state_ssm[i]`` float32
+-- ``(slots, heads, head_dim, state)`` for Mamba-2, ``(slots, channels,
+state)`` for Mamba-1; each layer's shapes its own (``state_shapes``) --
+one array a layer, so that a program's update of a layer is in place
+on a donated buffer --,
 PROVISIONED A SLOT, not paged: a slot's state is there while the slot
 is, whatever the request's length, so a free slot is all admission asks
 of this group (the full group's blocks decide the rest).  Nothing is
@@ -122,7 +124,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict, deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -236,16 +238,15 @@ class PagedKVCache:
         window_layers: int = 0,
         window: int = 0,
         chunk: int = 1,
-        state_layers: int = 0,
-        state_conv: Tuple[int, int] = (0, 0),
-        state_ssm: Tuple[int, int, int] = (0, 0, 0),
+        state_shapes: Sequence[Tuple[Tuple[int, ...], Tuple[int, ...]]] = (),
     ) -> None:
         """``num_layers`` full layers of ``heads`` K/V heads; with
         ``window_layers`` > 0 also that many layers that see ``window``
         positions back and are written at most ``chunk`` rows a call;
-        with ``state_layers`` > 0 also that many state layers, each a
-        slot ``state_conv`` = (taps - 1, channels) conv inputs and
-        ``state_ssm`` = (heads, head_dim, state) float32."""
+        and a state layer for each ``(conv, ssm)`` pair of
+        ``state_shapes``: a slot's ``conv`` = (taps - 1, channels) conv
+        inputs and its float32 ``ssm`` state (``(heads, head_dim,
+        state)`` for Mamba-2, ``(channels, state)`` for Mamba-1)."""
         import jax.numpy as jnp
 
         assert block_size >= 1 and slots >= 1
@@ -257,7 +258,8 @@ class PagedKVCache:
         self.window_layers = int(window_layers)
         self.window = int(window) if self.window_layers else 0
         assert not self.window_layers or self.window >= 1
-        self.state_layers = int(state_layers)
+        state_shapes = [(tuple(c), tuple(m)) for c, m in state_shapes]
+        self.state_layers = len(state_shapes)
         # declined for a model with window or state layers (module docstring)
         self.prefix_sharing = (
             bool(prefix_sharing) and not self.window_layers and not self.state_layers
@@ -351,11 +353,11 @@ class PagedKVCache:
         if self.state_layers:
             if self.quantized:
                 raise ValueError("a quantized pool is not built for state layers")
-            assert min(state_conv) >= 1 and min(state_ssm) >= 1, (state_conv, state_ssm)
             cdt = dtype if dtype is not None else jnp.float32
-            for _ in range(self.state_layers):
-                self.state_conv.append(jnp.zeros((slots,) + tuple(state_conv), cdt))
-                self.state_ssm.append(jnp.zeros((slots,) + tuple(state_ssm), jnp.float32))
+            for conv, ssm in state_shapes:
+                assert min(conv) >= 1 and min(ssm) >= 1, (conv, ssm)
+                self.state_conv.append(jnp.zeros((slots,) + conv, cdt))
+                self.state_ssm.append(jnp.zeros((slots,) + ssm, jnp.float32))
         self._set_row = None  # the jitted donated write of one slot's state
 
     # --- capacity queries --------------------------------------------------

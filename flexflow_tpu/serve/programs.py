@@ -20,13 +20,20 @@ position a row still sees); an FFN: GELU, gated dense or routed experts
 ``relu2``, all held or the share the layer's own ``first_expert`` and
 weights say -- the router stays as wide as published and what absent
 experts would add is left out; rows of idle lanes and past ``n_valid``
-routed nowhere); or a Mamba-2 state-space mixer
-(``ops/ssm.py::mamba2_mixer``, the op's own function) whose two states
-live in the pool's STATE group, a row a slot: a decode step is one step
-of the recurrence, a prefill chunk the chunked scan from the slot's
-state (zero where the chunk starts at position 0), the arrays donated
-and updated in place, rows past ``n_valid`` and idle lanes changing
-neither.  They differ in rows a lane
+routed nowhere); or a Mamba-2 or Mamba-1 state-space mixer
+(``ops/ssm.py::mamba2_mixer`` / ``mamba1_mixer``, the op's own function)
+whose two states live in the pool's STATE group, a row a slot: a decode
+step is one step of the recurrence, a prefill chunk the scan from the
+slot's state (zero where the chunk starts at position 0), the arrays
+donated and updated in place, rows past ``n_valid`` and idle lanes
+changing neither; a Mamba-1 layer may export its gated scan output, a
+memory the trunk carries beside the stream to the gated memory units
+that read it.  Differential attention rides the kernel with a K/V pair
+one pool head and a query pair two heads (``project``); a cross layer
+reads its source layer's rows of the full group and writes none.  The
+layers after the last one that writes a pool (``GPTSpec.self_layers``)
+run, in a prefill, on each lane's last valid row only.  They differ in
+rows a lane
 (``G`` = 1, ``P``, 1, ``k + 1``), layers run (all, or the first
 ``spec_draft_layers``), whether ``n_valid`` masks padded rows, and
 which rows reach the head.  Inactive lanes carry an all-zero table row,
@@ -68,15 +75,18 @@ from flexflow_tpu.serve.kvcache import PagedKVCache, quantize_kv
 __all__ = ["ServePrograms", "build_serve_programs", "MOE_STATS"]
 
 # weights an op declares float32 stay so in a program: the router's,
-# and a state-space layer's decay, step bias and skip
+# a state-space layer's decay, step bias and skip, and differential
+# attention's lambda vectors
 KEEP_F32 = ("router", "router_bias")
 KEEP_F32_STATE = ("A_log", "dt_bias", "D")
+KEEP_F32_DIFF = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
 
 
 def keep_float32(spec: GPTSpec):
     """``(layer name, weight name)`` of every leaf the programs take in
     float32 whatever the compute dtype."""
-    kept = {"moe": KEEP_F32, "mamba2": KEEP_F32_STATE}
+    kept = {"moe": KEEP_F32, "mamba2": KEEP_F32_STATE, "mamba1": KEEP_F32_STATE,
+            "diff": KEEP_F32_DIFF}
     return [
         (br.mixer[0], w) for br in spec.branches for w in kept.get(br.kind, ())
     ]
@@ -190,7 +200,7 @@ def build_serve_programs(
 ) -> ServePrograms:
     """Jit the serve programs of a compiled decoder (anything
     ``GPTSpec.from_model`` reads: ``gpt_decoder``, ``afmoe_decoder``,
-    ``nemotron_h_decoder`` -- layers of one or two residual branches,
+    ``nemotron_h_decoder``, ``phi4flash_decoder`` -- layers of one or two residual branches,
     attention over the full or the window group, state-space layers over
     the state group, routed experts all held or a share) over the pool
     geometry of ``kv``.  ``draft`` / ``verify``, int8 weights and a
@@ -211,7 +221,7 @@ def build_serve_programs(
     import jax
     import jax.numpy as jnp
 
-    from flexflow_tpu.ops.attention import rotate_half_rope_at
+    from flexflow_tpu.ops.attention import diff_combine, rotate_half_rope_at
     from flexflow_tpu.ops.moe import (
         gated_ffn,
         held_experts_part,
@@ -220,7 +230,7 @@ def build_serve_programs(
         shared_expert_part,
     )
     from flexflow_tpu.ops.norm import rms_norm_f32, rms_norm_zero_centered
-    from flexflow_tpu.ops.ssm import mamba2_mixer
+    from flexflow_tpu.ops.ssm import gated_memory, mamba1_mixer, mamba2_mixer
     from flexflow_tpu.ops.pallas.paged_attention import (
         paged_decode_attention,
         paged_kv_write,
@@ -229,6 +239,7 @@ def build_serve_programs(
 
     spec = GPTSpec.from_model(model)
     L, H, D = spec.num_layers, spec.heads, spec.head_dim
+    L_self = spec.self_layers  # layers a prefill runs on every row
     KVH = spec.kv_heads
     rep = H // KVH
     slots, MB, BS = kv.slots, kv.max_blocks_per_seq, kv.block_size
@@ -246,18 +257,23 @@ def build_serve_programs(
     rdt = cdt if spec.is_gpt else jnp.float32
     # which pool a branch's memory lives in, and where: a window layer's
     # K/V in the window group's ring, every other attention's in the full
-    # group, and a state-space layer's two states in the state group
+    # group, and a state-space layer's two states in the state group; a
+    # cross layer reads its source layer's rows of the full group
     where, n_full, n_win, n_state = {}, 0, 0, 0
+    full_of = {}  # layer -> its index in the full group
     for i, ls in enumerate(spec.layers):
         for j, br in enumerate(ls.branches):
-            if br.kind == "mamba2":
+            if br.kind in ("mamba2", "mamba1"):
                 where[(i, j)] = ("state", n_state)
                 n_state += 1
+            elif br.is_attention and br.kv_source >= 0:
+                where[(i, j)] = ("cross", full_of[br.kv_source])
             elif br.is_attention and br.window:
                 where[(i, j)] = ("window", n_win)
                 n_win += 1
             elif br.is_attention:
                 where[(i, j)] = ("full", n_full)
+                full_of[i] = n_full
                 n_full += 1
             else:
                 where[(i, j)] = None
@@ -318,7 +334,7 @@ def build_serve_programs(
         def norm(p, x, dtype=cdt):
             return rms_norm_zero_centered(x, p["weight"], eps).astype(dtype)
 
-    def attend(q, keys, vals, mask):
+    def attend(q, keys, vals, mask, scale=scale):
         # q (..., H, D) vs keys/vals (..., H, SV, D); mul+reduce
         # scores — the same contraction form as the dense session
         # (models/gpt_decode.py), so paged and dense decode agree
@@ -407,6 +423,22 @@ def build_serve_programs(
         # rows h (B * G, hidden) -> q (B, G, H, D), k, v (B, G, KVH, D),
         # the output gate (B * G, H * D) or None
         B = pos.shape[0]
+        if br.differential:
+            # a pair's two queries as two heads of the pool's head size,
+            # [q1 | 0] and [0 | q2]: over the pair's one K/V head [k1 | k2]
+            # they score q1 k1 and q2 k2, and both read [v1 | v2]
+            d = D // 2
+            if br.kv_source >= 0:
+                q, k, v = h @ p_at["wq"], None, None
+            else:
+                qkv = h @ p_at["wqkv"]
+                q, k, v = qkv[:, :H * d], qkv[:, H * d:H * d + KVH * D], qkv[:, H * d + KVH * D:]
+                k, v = k.reshape(B, G, KVH, D), v.reshape(B, G, KVH, D)
+            q = q.reshape(B, G, H // 2, 2, d)
+            z = jnp.zeros_like(q[..., 0, :])
+            q = jnp.stack([jnp.concatenate([q[..., 0, :], z], -1),
+                           jnp.concatenate([z, q[..., 1, :]], -1)], axis=3)
+            return q.reshape(B, G, H, D), k, v, None
         if br.kind == "mha":
             q = h @ p_at["wq"]
             k = h @ p_at["wk"]
@@ -466,16 +498,22 @@ def build_serve_programs(
         pk, pv = (wk, wv) if ring else (ck, cv)
         p_at = params[br.mixer[0]]
         pos = start[:, None] + jnp.arange(G)[None, :]
-        with jax.named_scope("ff.attn_window" if ring else "ff.attn_full"):
+        # a differential layer's scores are over the published head size,
+        # half the pool's
+        sc = 1.0 / math.sqrt(D // 2) if br.differential else scale
+        with jax.named_scope({"window": "ff.attn_window", "cross": "ff.attn_cross"}.get(
+                group, "ff.attn_full")):
             h = norm(params[br.norm_in], x)
             q, k, v, gate = project(br, p_at, h, pos, G)
             # write all G rows, THEN attend: row g's mask reaches rows 0..g
             # of this same program, freshly written — and under prefix
             # sharing a chunk never writes a still-shared block (commit
-            # happens post-chunk, CoW-audited by serve_cow)
-            pk, pv, sk, sv = write_kv(
-                pk, pv, sk, sv, gi, k, v, start, bt, n_valid, ring=ring,
-            )
+            # happens post-chunk, CoW-audited by serve_cow).  A cross
+            # layer writes nothing: it reads its source layer's rows
+            if group != "cross":
+                pk, pv, sk, sv = write_kv(
+                    pk, pv, sk, sv, gi, k, v, start, bt, n_valid, ring=ring,
+                )
             if paged:
                 # fused paged attention (docs/PERF.md), one call for all G
                 # rows: the kernel walks each lane's block table in SMEM and
@@ -487,7 +525,7 @@ def build_serve_programs(
                 # float32 tolerance and the greedy argmax streams are
                 # identical (pinned by tests/test_paged_attention.py)
                 o = attn(
-                    q, pk, pv, start, bt, scale=scale,
+                    q, pk, pv, start, bt, scale=sc,
                     scale_k=sk, scale_v=sv, layer=gi, block_size=BS,
                     window=br.window,
                 )
@@ -506,8 +544,16 @@ def build_serve_programs(
                 if rep > 1:
                     keys = jnp.repeat(keys, rep, axis=1)
                     vals = jnp.repeat(vals, rep, axis=1)
-                o = attend(q, keys[:, None], vals[:, None], mask)
-            o = o.reshape(-1, H * D)
+                o = attend(q, keys[:, None], vals[:, None], mask, sc)
+            if br.differential:
+                # A1 - lambda A2 a pair, normed: (rows, H / 2 pairs x D)
+                with jax.named_scope("ff.attn_diff"):
+                    o = o.reshape(-1, H // 2, 2, D)
+                    o = diff_combine(o[:, :, 0], o[:, :, 1], p_at, br.lambda_init,
+                                     br.qk_eps, br.lambda_scale)
+                    o = o.astype(cdt).reshape(-1, H // 2 * D)
+            else:
+                o = o.reshape(-1, H * D)
             if gate is not None:
                 o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
             o = o @ p_at["wo"]
@@ -527,32 +573,45 @@ def build_serve_programs(
         return jnp.arange(G)[None, :] < n_valid[:, None]
 
     def state_branch(br, si, params, x, pools, start, bts, n_valid, G):
-        # a state-space branch (ops/ssm.py::mamba2_mixer, the op's own
-        # function) from layer si's two states, which it hands back: a
-        # lane whose chunk starts at position 0 reads them as zero (a
-        # slot is recycled without touching the device), rows past
-        # n_valid change neither, and a lane with no live row keeps both
-        # bit for bit
+        # a state-space branch (ops/ssm.py::mamba2_mixer | mamba1_mixer,
+        # the op's own function) from layer si's two states, which it
+        # hands back: a lane whose chunk starts at position 0 reads them
+        # as zero (a slot is recycled without touching the device), rows
+        # past n_valid change neither, and a lane with no live row keeps
+        # both bit for bit.  Also the memory a Mamba-1 layer exports
+        # (rows, d_inner), else None
         conv, ssm = pools[6][si], pools[7][si]
+
+        def lanes(v, like):  # (B,) -> broadcast over a state's dims
+            return v[(slice(None),) + (None,) * (like.ndim - 1)]
+
         if n_valid is None:  # decode width: a live lane's one row
             n_live = (bts[0][:, 0] > 0).astype(jnp.int32)
             conv_in, ssm_in = conv, ssm
         else:
             n_live = n_valid
             fresh = (start == 0) & (n_live > 0)
-            conv_in = jnp.where(fresh[:, None, None], jnp.zeros_like(conv), conv)
-            ssm_in = jnp.where(fresh[:, None, None, None], jnp.zeros_like(ssm), ssm)
+            conv_in = jnp.where(lanes(fresh, conv), jnp.zeros_like(conv), conv)
+            ssm_in = jnp.where(lanes(fresh, ssm), jnp.zeros_like(ssm), ssm)
         h = norm(params[br.norm_in], x).reshape(start.shape[0], G, -1)
-        o, conv_out, ssm_out = mamba2_mixer(
-            params[br.mixer[0]], h, br.attrs, conv_in, ssm_in, n_live,
-        )
-        ssm_out = jnp.where((n_live > 0)[:, None, None, None], ssm_out, ssm)
+        mem = None
+        if br.kind == "mamba1":
+            o, conv_out, ssm_out, m = mamba1_mixer(
+                params[br.mixer[0]], h, br.attrs, conv_in, ssm_in, n_live,
+            )
+            if br.attrs.get("exports_memory"):
+                mem = m.reshape(x.shape[0], -1)
+        else:
+            o, conv_out, ssm_out = mamba2_mixer(
+                params[br.mixer[0]], h, br.attrs, conv_in, ssm_in, n_live,
+            )
+        ssm_out = jnp.where(lanes(n_live > 0, ssm), ssm_out, ssm)
         o = o.reshape(x.shape[0], -1)
         if br.norm_post is not None:
             o = norm(params[br.norm_post], o, rdt)
         convs, ssms = list(pools[6]), list(pools[7])
         convs[si], ssms[si] = conv_out.astype(conv.dtype), ssm_out
-        return o, pools[:6] + (tuple(convs), tuple(ssms))
+        return o, pools[:6] + (tuple(convs), tuple(ssms)), mem
 
     def ffn_branch(br, params, x, loads, bts, n_valid, G):
         moe = br.kind == "moe"
@@ -573,36 +632,54 @@ def build_serve_programs(
             f = norm(params[br.norm_post], f, rdt)
         return f, loads
 
-    def block(i, params, x, pools, loads, start, bts, n_valid, G, attn):
-        # layer i over rows x (B * G, hidden): its residual branches, in order
+    def gmu_branch(br, params, x, mem):
+        # a gated memory unit over the memory a Mamba-1 layer exported at
+        # the same rows
+        p = params[br.mixer[0]]
+        h = norm(params[br.norm_in], x)
+        m = jnp.ones_like(mem) if br.attrs.get("memory_ones") else mem
+        with jax.named_scope("ff.gmu"):
+            return gated_memory(h, m, p["w_in"], p["w_out"])
+
+    def block(i, params, x, mem, pools, loads, start, bts, n_valid, G, attn):
+        # layer i over rows x (B * G, hidden): its residual branches, in
+        # order; ``mem`` the memory the layers carry beside the stream
         for j, br in enumerate(spec.layers[i].branches):
             at = where[(i, j)]
             if br.is_attention:
                 f, pools = attention_branch(
                     br, at, params, x, pools, start, bts, n_valid, G, attn)
-            elif br.kind == "mamba2":
-                f, pools = state_branch(
+            elif at is not None:
+                f, pools, m = state_branch(
                     br, at[1], params, x, pools, start, bts, n_valid, G)
+                mem = mem if m is None else m
+            elif br.kind == "gmu":
+                f = gmu_branch(br, params, x, mem)
             else:
                 f, loads = ffn_branch(br, params, x, loads, bts, n_valid, G)
             x = x + f.astype(rdt)
-        return x, pools, loads
+        return x, mem, pools, loads
+
+    def run_layers(lo, hi, params, x, mem, pools, loads, start, bts, n_valid, G, attn):
+        for i in range(lo, hi):
+            x, mem, pools, loads = block(
+                i, params, x, mem, pools, loads, start, bts, n_valid, G, attn
+            )
+        return x, mem, pools, loads
 
     def trunk(params, pools, toks, start, bt, *, layers, n_valid=None, attn):
         # toks (B, G) int32, start / n_valid (B,), bt (B, MB) block
         # tables — with a window group the pair (bt, ring tables (B, R))
-        # -> rows (B * G, hidden) after ``layers`` blocks, pools, and the
-        # held experts' loads of each expert layer run
+        # -> rows (B * G, hidden) after ``layers`` blocks, the memory the
+        # layers carry (or None), pools, and the held experts' loads of
+        # each expert layer run
         G = toks.shape[1]
         bts = bt if isinstance(bt, (tuple, list)) else (bt, None)
         x = embed(params, toks, start[:, None] + jnp.arange(G)[None, :])
-        loads = ()
-        for i in range(layers):
-            x, pools, loads = block(
-                i, params, x, pools, loads, start, bts, n_valid, G, attn
-            )
+        x, mem, pools, loads = run_layers(
+            0, layers, params, x, None, pools, (), start, bts, n_valid, G, attn)
         # same boundary as the dense session
-        return jax.lax.optimization_barrier(x), pools, loads
+        return jax.lax.optimization_barrier(x), mem, pools, loads
 
     def moe_stats(loads):
         # the call's counters (MOE_STATS), summed over its expert layers
@@ -619,7 +696,12 @@ def build_serve_programs(
 
     def head(params, rows):
         x = norm(params[spec.final_norm], rows)
-        logits = (x @ params[spec.head]["kernel"]).astype(jnp.float32)
+        if spec.head_tied:  # x E^T, contracting the table's rows as they rest
+            logits = jax.lax.dot_general(
+                x, params[spec.embed]["kernel"], (((1,), (1,)), ((), ()))
+            ).astype(jnp.float32)
+        else:
+            logits = (x @ params[spec.head]["kernel"]).astype(jnp.float32)
         if return_probs:
             probs = jax.nn.softmax(logits, axis=-1)
             nxt = jnp.argmax(probs, axis=-1).astype(jnp.int32)
@@ -634,7 +716,7 @@ def build_serve_programs(
         return (nxt, probs, moe_stats(loads)) if spec.has_moe else (nxt, probs)
 
     def decode(params, pools, tok, pos, bt, layers=L):
-        x, pools, loads = trunk(
+        x, _, pools, loads = trunk(
             params, pools, tok[:, None], pos, bt,
             layers=layers, attn=paged_decode_attention,
         )
@@ -642,16 +724,28 @@ def build_serve_programs(
 
     def prefill_rows(params, pools, toks, start, n_valid, bt):
         # the lanes' chunks through the trunk -> each lane's next token
-        # (and distribution), the layers' expert loads, pools
+        # (and distribution), the layers' expert loads, pools.  The
+        # layers after the last one that writes a pool (``self_layers``)
+        # run on each lane's last valid row only, at decode width: no
+        # later row reads what they compute
         B, P = toks.shape
-        x, pools, loads = trunk(
+        x, mem, pools, loads = trunk(
             params, pools, toks, start, bt,
-            layers=L, n_valid=n_valid, attn=paged_prefill_attention,
+            layers=L_self, n_valid=n_valid, attn=paged_prefill_attention,
         )
         # distribution after each lane's LAST VALID row (layer norm
         # is per-row, so select-then-ln == ln-then-select)
         last = jnp.clip(n_valid - 1, 0, P - 1)
-        out = head(params, x.reshape(B, P, -1)[jnp.arange(B), last])
+        rows = x.reshape(B, P, -1)[jnp.arange(B), last]
+        if L_self < L:
+            if mem is not None:
+                mem = mem.reshape(B, P, -1)[jnp.arange(B), last]
+            bts = bt if isinstance(bt, (tuple, list)) else (bt, None)
+            rows, _, pools, loads = run_layers(
+                L_self, L, params, rows, mem, pools, loads, start + last, bts,
+                (n_valid > 0).astype(jnp.int32), 1, paged_decode_attention)
+            rows = jax.lax.optimization_barrier(rows)
+        out = head(params, rows)
         return out, loads, pools
 
     def prefill(params, pools, toks, start, n_valid, bt):
@@ -709,7 +803,7 @@ def build_serve_programs(
                 tuple(a + b for a, b in zip(loads, loads_g)),
             )
 
-        vocab = params[spec.head]["kernel"].shape[1]
+        vocab = spec.vocab
         carry = (
             pools[:6], pools[6], pools[7], jnp.zeros((slots,), jnp.int32),
             jnp.zeros((slots, vocab), jnp.float32) if return_probs else None,
@@ -743,7 +837,7 @@ def build_serve_programs(
         # toks (B, W): [current, draft_1..draft_k]; row j of slot b sits
         # at position pos0[b] + j, and its argmax is the full model's
         # decode step at that position, bit for bit
-        x, pools, _ = trunk(
+        x, _, pools, _ = trunk(
             params, pools, toks, pos0, bt,
             layers=L, attn=paged_decode_attention,
         )

@@ -15,7 +15,7 @@ from flexflow_tpu.serve.kvcache import PagedKVCache
 
 def cache(**kw):
     return PagedKVCache(1, 2, 16, slots=3, block_size=4, max_seq_len=64,
-                        state_layers=2, state_conv=(3, 12), state_ssm=(4, 8, 16), **kw)
+                        state_shapes=[((3, 12), (4, 8, 16))] * 2, **kw)
 
 
 def fill(kv, seed=0):
@@ -84,7 +84,7 @@ def test_spill_and_restore_carry_the_state_with_the_keys():
     with pytest.raises(ValueError, match="state layers"):
         cache().restore(0, plain.spill(0, 6), 40)
     other = PagedKVCache(1, 2, 16, slots=1, block_size=4, max_seq_len=64,
-                         state_layers=2, state_conv=(3, 12), state_ssm=(4, 8, 8))
+                         state_shapes=[((3, 12), (4, 8, 8))] * 2)
     with pytest.raises(ValueError, match="state payload ssm"):
         other.restore(0, payload, 40)
     other.check_invariants()

@@ -39,6 +39,8 @@ from flexflow_tpu.serve import (  # noqa: E402
     synthetic_requests,
 )
 
+import _traced_calls as TC  # noqa: E402
+
 SLOTS, SEQ, VOCAB = 4, 48, 31
 SHAPE = dict(hidden=32, heads=4, ff_dim=64, num_layers=2, vocab=VOCAB)
 
@@ -487,3 +489,9 @@ def test_bench_compare_gates_serve_metrics(tmp_path):
     cur_note = dict(base, serve_traffic="seed1/n12/p3-8/g3-24/r0/v256")
     cp.write_text(json.dumps(cur_note))
     assert bench_compare.main([str(cp), "--baseline", str(bp)]) == 0
+
+
+def test_programs_trace_no_cross_decoder(monkeypatch, engine):
+    """Every layer writes its pool, so the programs are those from before
+    the cross-decoder: every layer on every row of a prefill."""
+    TC.assert_no_cross_decoder(monkeypatch, engine)

@@ -33,6 +33,7 @@ from flexflow_tpu.serve.engine import UnsupportedServeConfig  # noqa: E402
 from flexflow_tpu.serve.kvcache import PagedKVCache  # noqa: E402
 
 import _compact_prefill as CP  # noqa: E402
+import _traced_calls as TC  # noqa: E402
 
 CFG = dict(
     hidden_size=64, vocab_size=128, num_hidden_layers=5, num_dense_layers=1,
@@ -445,3 +446,9 @@ def test_the_programs_tree_is_the_cast_of_the_references(ref_params):
             np.testing.assert_array_equal(np.asarray(have, np.float32), np.asarray(want, np.float32))
     with pytest.raises(KeyError, match="name different weights"):
         WL.fill_executor({k: v for k, v in SHAPES.items() if k != "lm_head"}, SEED, m.executor)
+
+
+def test_programs_trace_no_cross_decoder(monkeypatch, wide_engine):
+    """Every layer writes its pool, so the programs are those from before
+    the cross-decoder: every layer on every row of a prefill."""
+    TC.assert_no_cross_decoder(monkeypatch, wide_engine)

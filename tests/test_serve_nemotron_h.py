@@ -32,6 +32,7 @@ from flexflow_tpu.serve.engine import UnsupportedServeConfig  # noqa: E402
 from flexflow_tpu.serve.scheduler import RequestState  # noqa: E402
 
 import _compact_prefill as CP  # noqa: E402
+import _traced_calls as TC  # noqa: E402
 
 CFG = dict(
     hidden_size=64, vocab_size=128, num_hidden_layers=9, hybrid_override_pattern="EMEMEMEM*",
@@ -405,3 +406,9 @@ def test_the_programs_thread_every_state_array(model):
         "bytes_per_slot": eng.kv.state_bytes_per_slot, "slots_held": 0,
         "spills": 0, "restores": 0,
     }
+
+
+def test_programs_trace_no_cross_decoder(monkeypatch, wide_engine):
+    """Every layer writes its pool, so the programs are those from before
+    the cross-decoder: every layer on every row of a prefill."""
+    TC.assert_no_cross_decoder(monkeypatch, wide_engine)

@@ -300,8 +300,7 @@ def test_nemotron_cell_prefill_loops_over_groups_in_place(monkeypatch):
     cw = NH * NP + 2 * NG * NN
     kv = PagedKVCache(
         1, 2, 128, slots=slots, block_size=16, num_blocks=1025, max_seq_len=seq,
-        dtype=jnp.bfloat16, chunk=chunk, state_layers=1, state_conv=(3, cw),
-        state_ssm=(NH, NP, NN),
+        dtype=jnp.bfloat16, chunk=chunk, state_shapes=[((3, cw), (NH, NP, NN))],
     )
     progs = build_serve_programs(m, kv, attn_kernel="paged", return_probs=False)
     monkeypatch.setattr(gm, "INTERPRET", False)
@@ -646,3 +645,70 @@ def test_state_layer_updates_its_state_in_place_on_the_tpu(G):
     ).compile().as_text()
     assert "input_output_alias={ {1}: (8, {}, may-alias), {2}: (9, {}, may-alias) }" in text
     assert count_pool_relayouts(text, slots * NH * NP * NN * 4) == 0
+
+
+# Phi-4-mini-flash-reasoning's serving geometry: differential attention
+# as the pool serves it -- a K/V pair one head of 128 (10 K/V heads), a
+# query pair two heads of 128 (40 query heads, 4 a K/V head), the score
+# scale 1/8 -- over the full group's table (576 pages of 16 for 9,216
+# positions) and a window ring of 49 pages (window 512 + chunk 256, and
+# one); Mamba-1 with d_inner 5,120, state 16, conv 4, dt rank 160
+PQH, PKV, PD, PMB, PRING, PWIN = 40, 10, 128, 576, 49, 512
+
+
+@pytest.mark.parametrize("G", [1, 256])
+@pytest.mark.parametrize("window,mb", [(0, PMB), (PWIN, PRING)])
+def test_differential_attention_lowers_at_40_over_10_heads(G, window, mb):
+    slots = 4
+    n = slots * mb + 1
+    entry = pa.paged_prefill_attention if G > 1 else pa.paged_decode_attention
+    dt = jnp.bfloat16
+
+    def fn(q, pk, pv, pos, bt):
+        return entry(q, pk, pv, pos, bt, scale=0.125, layer=jnp.int32(0), block_size=BS,
+                     window=window)
+
+    pool = jax.ShapeDtypeStruct((1, n * BS, PKV * PD), dt)
+    _lower_for_tpu(
+        fn, jax.ShapeDtypeStruct((slots, G, PQH, PD), dt), pool, pool,
+        jax.ShapeDtypeStruct((slots,), jnp.int32), jax.ShapeDtypeStruct((slots, mb), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("G", [1, 128])
+def test_mamba1_layer_updates_its_state_in_place_on_the_tpu(G):
+    """The Mamba-1 mixer at the published widths, one step or a chunk
+    from a slot's state, both states donated and handed back in place.  A
+    decode step updates the (5,120, 16) float32 state with no copy of the
+    whole array; a chunk's scan stages the state it carries in the chip's
+    on-chip memory for the loop (a copy there and back, not a relayout),
+    which the engine's prefill pays on a group's gathered rows."""
+    from flexflow_tpu.ops import ssm
+
+    sh = _v5e_sharding()
+    if sh is None:
+        pytest.skip("no compile-only v5e topology")
+    slots, dt, d, n, r, e = 16, jnp.bfloat16, 5120, 16, 160, 2560
+    attrs = dict(d_inner=d, state_size=n, conv_kernel=4, dt_rank=r)
+    shapes = {
+        "in_proj": ((e, 2 * d), dt), "conv": ((d, 4), dt), "conv_bias": ((d,), dt),
+        "x_proj": ((d, r + 2 * n), dt), "dt_proj": ((r, d), dt),
+        "dt_bias": ((d,), jnp.float32), "A_log": ((d, n), jnp.float32),
+        "D": ((d,), jnp.float32), "out_proj": ((d, e), dt),
+    }
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    params = {k: place(*v) for k, v in shapes.items()}
+    conv, state = place((slots, 3, d), dt), place((slots, d, n), jnp.float32)
+
+    def layer(params, conv, state, u, n_valid):
+        o, c, s, _ = ssm.mamba1_mixer(params, u, attrs, conv, state, n_valid)
+        return o, c, jnp.where((n_valid > 0)[:, None, None], s, state)
+
+    text = jax.jit(layer, donate_argnums=(1, 2)).lower(
+        params, conv, state, place((slots, G, e), dt), place((slots,), jnp.int32),
+    ).compile().as_text()
+    assert "input_output_alias={ {1}: (9, {}, may-alias), {2}: (10, {}, may-alias) }" in text
+    assert count_pool_relayouts(text, slots * d * n * 4) == (0 if G == 1 else 1)

@@ -759,7 +759,10 @@ def run_phases(n_devices: int) -> dict:
         not fa.INTERPRET and not pa.INTERPRET,
         "FFTPU_PALLAS_INTERPRET is set: the kernels would be interpreted on the chip",
     )
-    info(f"batch loader: {'native (built from native/ffdl.cc)' if native_available() else 'python'}")
+    info(
+        "batch loader: views of the dataset in unshuffled fit; shuffled "
+        f"{'native (built from native/ffdl.cc)' if native_available() else 'python'}"
+    )
 
     phases = {"kernels": {}}
     for name, fn in (

@@ -163,14 +163,43 @@ class BatchIterator:
             t.join(timeout=5.0)
 
 
+class ViewIterator:
+    """The batches of an unshuffled epoch as views of the caller's arrays.
+
+    Batch ``i`` of an epoch in row order is rows ``[i*b, (i+1)*b)`` of
+    every array: one contiguous slice, so a numpy view of it is the whole
+    batch, with no gather, no ring, no producer thread and no copy.  Same
+    contract as :class:`BatchIterator` (``num_batches``, ``reset()``,
+    iteration from batch 0).  A view keeps its array alive for as long as
+    the placed batch holds it, so the step may still be queued when the
+    next view is taken (the reason ``NativeBatchIterator`` must copy does
+    not apply)."""
+
+    def __init__(self, arrays: Sequence[np.ndarray], batch_size: int) -> None:
+        self.arrays = [np.asarray(a) for a in arrays]
+        n = {a.shape[0] for a in self.arrays}
+        if len(n) != 1:
+            raise ValueError(f"arrays disagree on sample count: {sorted(n)}")
+        self.batch_size = batch_size
+        self.num_batches = n.pop() // batch_size
+
+    def reset(self) -> None:
+        """Nothing to reshuffle: the order is the arrays' own."""
+
+    def __iter__(self):
+        b = self.batch_size
+        for i in range(self.num_batches):
+            yield tuple(a[i * b:(i + 1) * b] for a in self.arrays)
+
+
 class DevicePrefetcher:
     """Look-ahead device placement: stage 2 of the 3-stage input pipeline
     (batch assembly -> H2D placement -> step).
 
     Wraps any batch iterable (:class:`BatchIterator`,
-    ``NativeBatchIterator``, or a generator) and applies ``place_fn`` —
-    typically ``Executor.place_batch`` — to batch i+1..i+depth-1 while the
-    consumer still runs step i.  ``jax.device_put`` dispatches transfers
+    :class:`ViewIterator`, ``NativeBatchIterator``, or a generator) and
+    applies ``place_fn`` — typically ``Executor.place_batch`` — to batch
+    i+1..i+depth-1 while the consumer still runs step i.  ``jax.device_put`` dispatches transfers
     asynchronously, so "placing ahead" just means issuing the H2D copy
     early enough that it overlaps device compute instead of sitting on the
     critical path (the role Legion's deferred index-task launches play in

@@ -39,6 +39,7 @@ from flexflow_tpu.dataloader import (
     BatchIterator,
     DevicePrefetcher,
     SingleDataLoader,
+    ViewIterator,
 )
 from flexflow_tpu.fftype import (
     ActiMode,
@@ -1630,10 +1631,13 @@ class FFModel:
         return int(k) if k and k > 0 else DEFAULT_METRICS_SYNC_EVERY
 
     def _flush_metrics(
-        self, acc: DeviceMetricAccumulator, pm: PerfMetrics, tracer
+        self, acc: DeviceMetricAccumulator, pm: PerfMetrics, tracer,
+        feed: str, batches: int,
     ) -> None:
         """Drain the device-side metric window into ``pm`` — the async
-        loop's ONE deliberate host sync per K steps, counted and timed."""
+        loop's ONE deliberate host sync per K steps, counted and timed.
+        The window's ``batches`` are counted under ``feed``
+        (``fit.batches_viewed`` or ``fit.batches_gathered``)."""
         if acc.count == 0:
             return
         with tracer.span("metric_flush", cat="fit"):
@@ -1649,6 +1653,7 @@ class FFModel:
                 elif k.startswith(GAUGE_PREFIX):
                     tracer.sample(k[len(GAUGE_PREFIX):], v / count, level="step")
         tracer.counter("fit.metric_flushes")
+        tracer.counter(feed, batches)
 
     def fit(
         self,
@@ -1673,8 +1678,9 @@ class FFModel:
         the host runs ahead of the devices and never blocks on a result
         it doesn't need yet.
 
-        Three-stage input pipeline: batch assembly (the native C++
-        prefetching loader ``native/ffdl.cc`` when its build is
+        Three-stage input pipeline: batch assembly (unshuffled, views of
+        the caller's arrays, :class:`ViewIterator`; shuffled, the native
+        C++ prefetching loader ``native/ffdl.cc`` when its build is
         available, else the pure-Python loaders with a background
         producer thread) -> device placement (:class:`DevicePrefetcher`
         dispatches the H2D transfer of batch i+1 while step i runs) ->
@@ -1725,7 +1731,11 @@ class FFModel:
         )
 
         depth = max(1, cfg.prefetch_depth)
-        if native_available():
+        if not shuffle:
+            # rows in order: each batch is one contiguous slice of every
+            # array, fed as a view (no gather, no copy)
+            it = ViewIterator(xs + [y], bs)
+        elif native_available():
             it = NativeBatchIterator(
                 [np.asarray(a) for a in xs] + [np.asarray(y)], bs,
                 shuffle=shuffle, seed=seed, prefetch_depth=depth,
@@ -1825,6 +1835,9 @@ class FFModel:
         pm = PerfMetrics()
         loss = None
         restores = 0
+        # how the batches reached placement: views (unshuffled) or rows
+        # gathered and copied by a loader (shuffled)
+        feed = "fit.batches_gathered" if shuffle else "fit.batches_viewed"
         with tracer.span(
             "fit", cat="fit", epochs=epochs, batches=nb, metrics_sync_every=K
         ):
@@ -1837,6 +1850,7 @@ class FFModel:
                 pm = PerfMetrics()
                 acc = DeviceMetricAccumulator()
                 window: List[Any] = []  # raw device (loss, metrics) for R17
+                fed = 0  # batches stepped since the last flush
                 with tracer.span("epoch", cat="fit", epoch=epoch):
                     for bi, (inputs, labels) in enumerate(prefetch):
                         if epoch == start_epoch and bi < skip_batches:
@@ -1932,6 +1946,7 @@ class FFModel:
                                 1, stall_s=time.perf_counter() - t0
                             )
                             pm.update(fm, bs)
+                            tracer.counter(feed)
                             # R17 recompile hook: per-iteration trigger/alter,
                             # like the reference's recompile_on_condition in
                             # the train loop (moe.cc:180)
@@ -1940,10 +1955,12 @@ class FFModel:
                                 recompile_state.maybe_recompile(self)
                             continue
                         acc.add(m, bs)
+                        fed += 1
                         if recompile_state is not None:
                             window.append((loss, m))
                         if (bi + 1) % K == 0 or bi + 1 == nb:
-                            self._flush_metrics(acc, pm, tracer)
+                            self._flush_metrics(acc, pm, tracer, feed, fed)
+                            fed = 0
                             if recompile_state is not None and window:
                                 recompile_state.observe_window(window, self)
                                 window = []

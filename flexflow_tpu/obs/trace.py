@@ -65,6 +65,8 @@ CORE_COUNTERS = (
     "jit.cache_miss",
     "executor.host_syncs",
     "fit.metric_flushes",
+    "fit.batches_viewed",
+    "fit.batches_gathered",
     "recompile.count",
     "search.candidates_explored",
     "search.rewrites_considered",

@@ -93,6 +93,13 @@ class NativeBatchIterator:
     observed as NaN weights in the round-1 DP-consistency test).  The copy
     is a memcpy; the producer thread still overlaps gather/shuffle with
     the step loop, which is where the win is.
+
+    ``fit`` builds this loader for shuffled epochs only.  An unshuffled
+    batch is a contiguous slice of the caller's arrays, and ``fit`` feeds
+    it as a numpy view (``dataloader.ViewIterator``) with no copy: a view
+    holds a reference to the caller's array, which nothing recycles or
+    frees while a placed batch still uses it, where a ring slot is
+    rewritten ``prefetch_depth - 1`` batches later.
     """
 
     def __init__(self, arrays: Sequence[np.ndarray], batch_size: int,

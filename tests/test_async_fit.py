@@ -12,7 +12,9 @@ Acceptance pins:
     zero-overhead guard, mirroring ``tests/test_health.py``'s);
   * the recompile trigger fires within K steps under windowed observe;
   * HealthMonitor NaN detection latency is unchanged (K forced to 1);
-  * eval's padded tail rows never enter the metrics.
+  * eval's padded tail rows never enter the metrics;
+  * an unshuffled epoch is fed as views of the dataset, bit-identical to
+    gathered rows; a shuffled one as owned copies from its loader.
 """
 
 import math
@@ -58,7 +60,7 @@ def _reset_obs():
     set_tracer(Tracer())
 
 
-def _mlp_model(**cfg_kw):
+def _mlp_model(mesh=None, **cfg_kw):
     cfg = FFConfig(batch_size=B, **cfg_kw)
     model = FFModel(cfg)
     t = model.create_tensor((B, 32), name="x")
@@ -72,6 +74,7 @@ def _mlp_model(**cfg_kw):
             MetricsType.ACCURACY,
             MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY,
         ],
+        mesh=mesh,
         seed=0,
     )
     return model
@@ -433,6 +436,161 @@ def test_fit_with_explicit_python_loader_prefetch_converges():
     pm = model.fit(x, yl, shuffle=True, verbose=False)
     assert pm.accuracy > 0.8
     assert model.executor.host_syncs == 3  # one flush per epoch (8 < K)
+
+
+# ------------------------------------ unshuffled epochs fed as views
+def _spy_placement(monkeypatch, model):
+    """Record each batch ``fit`` hands to placement, and the names of the
+    threads alive when it did."""
+    fed, threads = [], set()
+    place = model.executor.place_batch
+
+    def spy(batch):
+        fed.append(batch)
+        threads.update(t.name for t in threading.enumerate())
+        return place(batch)
+
+    monkeypatch.setattr(model.executor, "place_batch", spy)
+    return fed, threads
+
+
+@pytest.mark.parametrize(
+    "shuffle,native",
+    [(False, True), (False, False), (True, True), (True, False)],
+    ids=["views", "views-no-native-build", "native-ring", "python-producer"],
+)
+def test_fit_feeds_views_unshuffled_and_owned_copies_shuffled(
+    monkeypatch, shuffle, native
+):
+    """An unshuffled epoch hands placement views of the caller's arrays
+    and builds no native ring and no producer thread; a shuffled one
+    hands owned copies from the loader it always had."""
+    from flexflow_tpu.runtime import native as nat
+
+    if native and not nat.native_available():
+        pytest.skip("native loader not built in this environment")
+    if not native:
+        monkeypatch.setattr(nat, "native_available", lambda: False)
+    rings = []
+
+    class CountedRing(nat.NativeBatchIterator):
+        def __init__(self, *a, **k):
+            rings.append(k.get("shuffle"))
+            super().__init__(*a, **k)
+
+    monkeypatch.setattr(nat, "NativeBatchIterator", CountedRing)
+    x, y = _mlp_data(128)
+    m = _mlp_model()
+    fed, threads = _spy_placement(monkeypatch, m)
+    m.fit(x, y, epochs=1, shuffle=shuffle, verbose=False)
+    assert len(fed) == 8
+    if shuffle:
+        assert not any(
+            np.shares_memory(bx, x) or np.shares_memory(by, y) for bx, by in fed
+        )
+        assert rings == ([True] if native else [])
+        assert ("ffdl-py-prefetch" in threads) == (not native)
+    else:
+        for i, (bx, by) in enumerate(fed):
+            assert np.shares_memory(bx, x) and np.shares_memory(by, y)
+            np.testing.assert_array_equal(bx, x[i * B:(i + 1) * B])
+        assert rings == [] and "ffdl-py-prefetch" not in threads
+
+
+def test_view_iterator_contract():
+    """Whole batches only, every epoch from batch 0, and arrays whose row
+    counts differ are refused."""
+    from flexflow_tpu.dataloader import ViewIterator
+
+    x = np.arange(70, dtype=np.float32).reshape(35, 2)
+    y = np.arange(35, dtype=np.int32).reshape(35, 1)
+    it = ViewIterator([x, y], 8)
+    assert it.num_batches == 4
+    for _ in range(2):
+        it.reset()
+        got = list(it)
+        assert len(got) == 4
+        np.testing.assert_array_equal(got[3][1], y[24:32])
+    with pytest.raises(ValueError, match="sample count"):
+        ViewIterator([x, y[:34]], 8)
+
+
+def _gathered(kind):
+    """A loader that gathers and copies the rows in order, as ``fit``
+    did for unshuffled epochs before they were fed as views."""
+    from flexflow_tpu.runtime.native import NativeBatchIterator, native_available
+
+    if kind == "native":
+        if not native_available():
+            pytest.skip("native loader not built in this environment")
+        return lambda arrays, bs: NativeBatchIterator(arrays, bs, prefetch_depth=3)
+    return lambda arrays, bs: BatchIterator(
+        [SingleDataLoader(a, bs, None, None) for a in arrays], prefetch_depth=3
+    )
+
+
+@pytest.mark.parametrize(
+    "devices,gather", [(1, "native"), (4, "native"), (4, "python")]
+)
+def test_fit_on_views_bit_identical_to_gathered_rows(monkeypatch, devices, gather):
+    """Views and gathered copies of the same rows train to the same bits,
+    on one device and data parallel over four, with the metric sync
+    deferred so steps are still queued when the next batch is taken."""
+    import flexflow_tpu.model as model_mod
+
+    x, y = _mlp_data(128)
+    runs = []
+    for feed in ("views", gather):
+        if feed != "views":
+            monkeypatch.setattr(model_mod, "ViewIterator", _gathered(gather))
+        m = _mlp_model(mesh=MachineMesh((devices, 1), ("data", "model")))
+        pm = m.fit(x, y, epochs=2, verbose=False)
+        assert np.isfinite(pm.sparse_cce_loss)
+        runs.append((pm.sparse_cce_loss, m.get_weights()))
+    (loss_v, w_v), (loss_g, w_g) = runs
+    assert loss_v == loss_g
+    for lname in w_v:
+        for wname in w_v[lname]:
+            np.testing.assert_array_equal(w_v[lname][wname], w_g[lname][wname])
+
+
+def test_fit_on_views_keeps_nothing_of_the_dataset():
+    """Writing to ``x`` and ``y`` after ``fit`` returns changes nothing the
+    model holds: its weights, and the step it takes next."""
+    x, y = _mlp_data(128)
+    x2, y2 = _mlp_data(64)
+    ref, m = _mlp_model(), _mlp_model()
+    ref.fit(x.copy(), y.copy(), epochs=1, verbose=False)
+    m.fit(x, y, epochs=1, verbose=False)
+    held = m.get_weights()
+    x[:] = np.nan
+    y[:] = 0
+    for lname, ws in m.get_weights().items():
+        for wname, w in ws.items():
+            np.testing.assert_array_equal(w, held[lname][wname])
+    ref.fit(x2, y2, epochs=1, verbose=False)
+    m.fit(x2, y2, epochs=1, verbose=False)
+    w_ref = ref.get_weights()
+    for lname, ws in m.get_weights().items():
+        for wname, w in ws.items():
+            np.testing.assert_array_equal(w, w_ref[lname][wname])
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+@pytest.mark.parametrize("k", [4, 1])
+def test_fit_counts_batches_viewed_and_gathered(shuffle, k):
+    from flexflow_tpu.obs import configure
+
+    tracer = configure(level="step")
+    x, y = _mlp_data(128)  # 8 batches an epoch
+    _mlp_model().fit(
+        x, y, epochs=2, shuffle=shuffle, verbose=False, metrics_sync_every=k
+    )
+    c = tracer.summary()["counters"]
+    steps = 16.0
+    assert (c["fit.batches_viewed"], c["fit.batches_gathered"]) == (
+        (0.0, steps) if shuffle else (steps, 0.0)
+    )
 
 
 # ------------------------------------------------------------- config

@@ -107,8 +107,10 @@ def test_dlrm_example_trains_from_disk(criteo_files):
 
 
 def test_fit_uses_native_prefetcher_when_available():
-    """The fit loop's loader IS the native one when the build exists —
-    guards the 'through native/ffdl.cc' claim of the --data path."""
+    """The native loader builds and yields batches when its build exists.
+    ``fit`` routes shuffled epochs through ``native/ffdl.cc``; unshuffled
+    ones, the --data example's among them, are fed as views of the
+    dataset (``dataloader.ViewIterator``) and touch no loader."""
     from flexflow_tpu.runtime.native import native_available
 
     if not native_available():

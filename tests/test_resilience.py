@@ -319,6 +319,44 @@ def test_kill_and_resume_bit_identical(tmp_path):
     _assert_bit_identical(_flat_weights(ref), _flat_weights(resumed))
 
 
+@pytest.mark.parametrize("kill_at", [2, 6])
+def test_kill_and_resume_unshuffled_bit_identical(tmp_path, kill_at):
+    """The same pin for an unshuffled epoch, whose batches are views of
+    the dataset: killed mid-epoch (the first epoch's third batch, or the
+    second epoch's), the resumed run lands on the batch the cursor names
+    and ends bit-identical."""
+    x, y = _data()
+    ck = str(tmp_path / "ck.npz")
+    ref = _build()
+    ref.fit(x, y, epochs=2, verbose=False)
+
+    set_fault_plan(FaultPlan.parse(f"fit:device_loss@{kill_at}", seed=0))
+    killed = _build()
+    with pytest.raises(InjectedFault):
+        killed.fit(
+            x, y, epochs=2, verbose=False,
+            checkpoint_every=1, checkpoint_path=ck,
+        )
+    set_fault_plan(None)
+    cursor = _build().load_checkpoint(ck)["loader"]
+    assert 0 < cursor["batch"] < N // B and not cursor["shuffle"]
+
+    resumed = _build()
+    stepped = []
+    step = resumed.executor.train_step
+
+    def spy(inputs, labels):
+        stepped.append(np.asarray(labels))
+        return step(inputs, labels)
+
+    resumed.executor.train_step = spy
+    resumed.fit(x, y, epochs=2, verbose=False, resume=ck)
+    first = cursor["batch"] * B
+    np.testing.assert_array_equal(stepped[0].reshape(-1), y[first:first + B, 0])
+    assert resumed.executor._step_count == ref.executor._step_count == 8
+    _assert_bit_identical(_flat_weights(ref), _flat_weights(resumed))
+
+
 def test_resume_refuses_mismatched_data_order(tmp_path):
     """The manifest cursor is only valid for the original data order —
     resuming with a different shuffle seed must refuse truthfully, not

@@ -142,6 +142,8 @@ class OperatorType(enum.Enum):
     MAMBA1_MIXER = "mamba1_mixer"
     DIFF_ATTENTION = "diff_attention"
     GATED_MEMORY = "gated_memory"
+    LIGHTNING_ATTENTION = "lightning_attention"
+    SPARSE_ATTENTION = "sparse_attention"
     TIED_HEAD = "tied_head"
     TOPK = "topk"
     GROUP_BY = "group_by"

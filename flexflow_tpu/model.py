@@ -663,6 +663,61 @@ class FFModel:
         )
         return tuple(outs) if exports_kv else outs[0]
 
+    def lightning_attention(
+        self,
+        input: Tensor,
+        num_heads: int,
+        head_dim: int,
+        layer_idx: int,
+        num_layers: int,
+        rope_theta: float = 10000.0,
+        eps: float = 1e-6,
+        chunk: int = 128,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """Linear attention with a fixed decay a head, MiniMax-01's
+        lightning attention (:class:`flexflow_tpu.ops.linear_attention.
+        LightningAttention`); ``layer_idx`` of ``num_layers`` set the
+        decay as published."""
+        attrs = dict(num_heads=num_heads, head_dim=head_dim, layer_idx=int(layer_idx),
+                     num_layers=int(num_layers), rope_theta=float(rope_theta), eps=eps,
+                     chunk=int(chunk))
+        return self._add_layer(
+            OperatorType.LIGHTNING_ATTENTION, self._name("lightning_attention", name),
+            [input], attrs,
+        )[0]
+
+    def sparse_attention(
+        self,
+        input: Tensor,
+        num_heads: int,
+        num_kv_heads: int,
+        head_dim: int,
+        kernel: int = 32,
+        stride: int = 16,
+        block: int = 64,
+        topk: int = 64,
+        window: int = 2048,
+        dense_len: int = 8192,
+        eps: float = 1e-6,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """Causal grouped-query attention over the blocks an index of
+        compressed keys selects, InfLLM-v2
+        (:class:`flexflow_tpu.ops.sparse_attention.SparseBlockAttention`):
+        per-head q/k RMS norm, no positions, a sigmoid output gate."""
+        from flexflow_tpu.ops.sparse_attention import check_sizes
+
+        attrs = dict(num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
+                     rotary_dim=0, rope_theta=0.0, eps=eps, zero_centered=False,
+                     kernel=kernel, stride=stride, block=block, topk=topk, window=window,
+                     dense_len=dense_len)
+        check_sizes(attrs)
+        return self._add_layer(
+            OperatorType.SPARSE_ATTENTION, self._name("sparse_attention", name),
+            [input], attrs,
+        )[0]
+
     def gated_memory(
         self, input: Tensor, memory: Tensor, memory_ones: bool = False,
         name: Optional[str] = None,

@@ -44,9 +44,9 @@ import numpy as np
 __all__ = ["GPTSpec", "LayerSpec", "BranchSpec", "GPTDecodeSession", "gpt_generate_cached"]
 
 
-ATTENTION_KINDS = ("mha", "gated", "diff")
+ATTENTION_KINDS = ("mha", "gated", "diff", "sparse")
 FFN_KINDS = ("gelu", "gated_ffn", "moe")
-STATE_KINDS = ("mamba2", "mamba1")
+STATE_KINDS = ("mamba2", "mamba1", "lightning")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,13 +55,16 @@ class BranchSpec:
     programs run it: the names of the layers whose parameters it reads,
     and what the mixer is."""
 
-    # "mha" | "gated" | "diff" (attention) | "gelu" | "gated_ffn" | "moe"
-    # (FFN) | "mamba2" | "mamba1" (state) | "gmu" (a gated memory unit)
+    # "mha" | "gated" | "diff" | "sparse" (attention) | "gelu" |
+    # "gated_ffn" | "moe" (FFN) | "mamba2" | "mamba1" | "lightning" (state)
+    # | "gmu" (a gated memory unit)
     kind: str
     norm_in: str
     mixer: Tuple[str, ...]  # (ff0, ff1) for "gelu", one layer otherwise
     norm_post: Optional[str] = None  # sandwich norm on the mixer's output
-    attrs: Optional[Dict[str, Any]] = None  # "moe", "mamba*", "gmu": the op's attrs
+    # "moe", "mamba*", "lightning", "gmu", "sparse" (its sizes): the op's attrs
+    attrs: Optional[Dict[str, Any]] = None
+    scale: float = 1.0  # the branch's output times this before the residual add
     # --- attention ("mha": q/k/v/o, biases optional, K/V heads grouped or not)
     heads: int = 0
     kv_heads: int = 0
@@ -89,6 +92,14 @@ class BranchSpec:
     @property
     def differential(self) -> bool:
         return self.kind == "diff"
+
+    @property
+    def pool_layers(self) -> int:
+        """Layers of the pool an attention branch with K/V of its own
+        writes: one, or, for a sparse layer, one a K/V head (each head's
+        selected pages are walked on their own, so a pool row is one
+        head's)."""
+        return self.kv_heads if self.kind == "sparse" else 1
 
     @property
     def owns_memory(self) -> bool:
@@ -189,13 +200,16 @@ class GPTSpec:
     learned position table, or times a constant), then a chain of
     residual branches ``x + [norm](mixer(norm(x)))`` -- the mixer an
     attention op (:class:`MultiHeadAttention`, K/V heads grouped or not,
-    :class:`GatedAttention`, or :class:`DifferentialAttention` over its
-    own K/V or, a cross layer, another layer's), an FFN (two dense
-    layers around a GELU, a :class:`GatedFFN`, or :class:`RoutedExperts`
-    holding all or a share of its experts), a :class:`Mamba2Mixer` or
-    :class:`Mamba1Mixer`, or a :class:`GatedMemoryUnit` over the memory a
-    Mamba-1 layer exports -- and a final norm and a bias-free head, its
-    own or tied to the embedding's table.  A mixer branch and the FFN
+    :class:`GatedAttention`, :class:`DifferentialAttention` over its
+    own K/V or, a cross layer, another layer's, or
+    :class:`SparseBlockAttention`), an FFN (two dense layers around a
+    GELU, a :class:`GatedFFN`, or :class:`RoutedExperts` holding all or a
+    share of its experts), a :class:`Mamba2Mixer`, :class:`Mamba1Mixer`
+    or :class:`LightningAttention`, or a :class:`GatedMemoryUnit` over
+    the memory a Mamba-1 layer exports; a scalar multiply before the add
+    is the branch's ``scale`` -- and a final norm, a scalar multiply
+    (``head_scale``) or not, and a bias-free head, its own or tied to the
+    embedding's table.  A mixer branch and the FFN
     branch that follows it under the same name prefix are one layer
     (GPT-2, Trinity, phi4flash); every other branch is a layer of its
     own (Nemotron-H)."""
@@ -218,6 +232,7 @@ class GPTSpec:
     head: str = "lm_head"
     layers: Tuple[LayerSpec, ...] = ()
     head_tied: bool = False  # the head reads the embedding's table
+    head_scale: float = 1.0  # the final norm's output times this before the head
 
     @property
     def branches(self) -> Tuple[BranchSpec, ...]:
@@ -272,17 +287,19 @@ class GPTSpec:
             for t in l.inputs:
                 consumers.setdefault(t.guid, []).append(l)
         NORMS = (T.LAYERNORM, T.RMS_NORM)
-        MIXERS = (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.DIFF_ATTENTION, T.LINEAR,
-                  T.GATED_FFN, T.ROUTED_EXPERTS, T.MAMBA2_MIXER, T.MAMBA1_MIXER,
-                  T.GATED_MEMORY)
+        ATTENTIONS = (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.DIFF_ATTENTION,
+                      T.SPARSE_ATTENTION)
+        MIXERS = ATTENTIONS + (T.LINEAR, T.GATED_FFN, T.ROUTED_EXPERTS, T.MAMBA2_MIXER,
+                               T.MAMBA1_MIXER, T.LIGHTNING_ATTENTION, T.GATED_MEMORY)
 
         def refuse(why):
             raise ValueError(
                 "not a decoder the serve programs know (an embedding, then "
                 "residual branches norm -> MultiHeadAttention | GatedAttention "
-                "| DifferentialAttention | Mamba2Mixer | Mamba1Mixer | "
-                "GatedMemoryUnit | dense+GELU+dense | GatedFFN | RoutedExperts "
-                f"-> [norm] -> add, a final norm, a head): {why}"
+                "| DifferentialAttention | SparseBlockAttention | Mamba2Mixer | "
+                "Mamba1Mixer | LightningAttention | GatedMemoryUnit | "
+                "dense+GELU+dense | GatedFFN | RoutedExperts -> [norm] -> "
+                f"[scalar multiply] -> add, a final norm [times a scalar], a head): {why}"
             )
 
         def after(layer, *kinds):
@@ -311,6 +328,10 @@ class GPTSpec:
         if not tied and head.attrs.get("use_bias", True):
             refuse(f"the head {head.name!r} has a bias")
         final = producer.get(head.inputs[0].guid)
+        head_scale = 1.0
+        if final is not None and final.op_type == T.SCALAR_MULTIPLY:
+            head_scale = float(final.attrs["scalar"])
+            final = producer.get(final.inputs[0].guid)
         if final is None or final.op_type not in NORMS:
             refuse(f"the head {head.name!r} does not read a norm")
         pos_embed, embed_scale = None, 1.0
@@ -352,6 +373,11 @@ class GPTSpec:
                 return d
             if any(t.guid != at.inputs[0].guid for t in at.inputs):
                 refuse(f"{at.name!r} is not self-attention")
+            if at.op_type == T.SPARSE_ATTENTION:
+                sizes = ("kernel", "stride", "block", "topk", "window", "dense_len")
+                return dict(kind="sparse", heads=a["num_heads"], kv_heads=a["num_kv_heads"],
+                            head_dim=a["head_dim"], qk_eps=float(a["eps"]),
+                            attrs={n: int(a[n]) for n in sizes})
             if at.op_type == T.MULTIHEAD_ATTENTION:
                 if not a.get("causal"):
                     refuse(f"{at.name!r} is not causal")
@@ -380,7 +406,7 @@ class GPTSpec:
             if mix is None:
                 refuse(f"no mixer the serve programs know reads {n_in.name!r}")
             names, kind = (mix.name,), {}
-            if mix.op_type in (T.MULTIHEAD_ATTENTION, T.GATED_ATTENTION, T.DIFF_ATTENTION):
+            if mix.op_type in ATTENTIONS:
                 kind = attention_of(mix)
             elif mix.op_type == T.LINEAR:
                 f1 = after(mix, T.LINEAR)
@@ -398,10 +424,15 @@ class GPTSpec:
                 kind = dict(kind="gmu", attrs=dict(mix.attrs))
             elif mix.op_type == T.MAMBA1_MIXER:
                 kind = dict(kind="mamba1", attrs=dict(mix.attrs))
+            elif mix.op_type == T.LIGHTNING_ATTENTION:
+                kind = dict(kind="lightning", attrs=dict(mix.attrs))
             else:
                 kind = dict(kind="mamba2", attrs=dict(mix.attrs))
             post = after(mix, *NORMS)
-            res = after(post or mix, T.EW_ADD)
+            scaled = after(post or mix, T.SCALAR_MULTIPLY)
+            if scaled is not None:
+                kind["scale"] = float(scaled.attrs["scalar"])
+            res = after(scaled or post or mix, T.EW_ADD)
             if res is None or stream.outputs[0].guid not in [t.guid for t in res.inputs]:
                 refuse(f"no residual add after {mix.name!r}")
             branches.append(BranchSpec(
@@ -453,6 +484,7 @@ class GPTSpec:
             head=head.name,
             layers=tuple(specs),
             head_tied=tied,
+            head_scale=head_scale,
         )
 
 

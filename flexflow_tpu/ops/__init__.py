@@ -10,6 +10,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     moe,
     norm,
     parallel_ops,
+    sparse_attention,
     ssm,
     tensor_ops,
 )

@@ -216,3 +216,113 @@ class GatedDeltaNet(OpDef):
 
 
 register_op(GatedDeltaNet())
+
+
+# ------------------------------------------------------------ lightning
+def lightning_decay(num_heads: int, layer_idx: int, num_layers: int):
+    """MiniMax-01's lightning-attention decay a head: ``lambda_h = s_h (1
+    - l / (L - 1) + 1e-5)`` with ``s_h = 2^(-8 (h + 1) / num_heads)``,
+    ``l`` the layer's index in a stack of ``L`` (float32, (heads,))."""
+    import numpy as np
+
+    h = np.arange(num_heads, dtype=np.float64)
+    slope = 2.0 ** (-8.0 * (h + 1) / num_heads)
+    return (slope * (1.0 - layer_idx / (num_layers - 1) + 1e-5)).astype(np.float32)
+
+
+def lightning_mixer(params, u, a: dict, state=None, n_valid=None, pos=None):
+    """Lightning attention over ``u`` (batch, seq, hidden): per head a
+    (head_dim x head_dim) float32 state that every position decays by the
+    head's fixed ``exp(-lambda)`` and adds ``v (x) k`` to, read out by
+    the query::
+
+        S_t = exp(-lambda) S_{t-1} + v_t (x) k_t;   o_t = S_t q_t / sqrt(d)
+        out = W_o (RMSNorm(o over all heads) * sigmoid(W_g u))
+
+    q and k per-head RMS-normed, then rotary at ``pos`` (batch, seq)
+    (from 0 when None) when ``rope_theta`` is set.  The recurrence is SSD
+    (``ops/ssm.py``) with ``dt = 1``, ``A = -lambda``, ``B = k``, ``C =
+    q``, ``x = v`` and a group a head: :func:`~flexflow_tpu.ops.ssm.ssd_step`
+    for one row a lane with a state, :func:`~flexflow_tpu.ops.ssm.ssd_chunked`
+    otherwise.  ``n_valid`` (batch,): rows at or past it leave the state
+    alone (``dt`` 0).  Returns ``(out, state')``."""
+    from flexflow_tpu.ops.attention import rotate_half_rope_at
+    from flexflow_tpu.ops.norm import rms_norm_f32
+    from flexflow_tpu.ops.ssm import ssd_chunked, ssd_step
+
+    f32 = jnp.float32
+    H, d = a["num_heads"], a["head_dim"]
+    b, s, _ = u.shape
+    q = (u @ params["wq"]).reshape(b, s, H, d)
+    k = (u @ params["wk"]).reshape(b, s, H, d)
+    v = (u @ params["wv"]).reshape(b, s, H, d)
+    q = rms_norm_f32(q, params["q_norm"], a["eps"])
+    k = rms_norm_f32(k, params["k_norm"], a["eps"])
+    if a.get("rope_theta"):
+        if pos is None:
+            pos = jnp.arange(s)[None, :]
+        q = rotate_half_rope_at(q, pos, d, a["rope_theta"])
+        k = rotate_half_rope_at(k, pos, d, a["rope_theta"])
+    q, k = q.astype(u.dtype), k.astype(u.dtype)
+    dt = jnp.ones((b, s, H), f32)
+    if n_valid is not None:
+        dt = jnp.where(jnp.arange(s)[None, :, None] < n_valid[:, None, None], dt, 0.0)
+    A = -jnp.asarray(lightning_decay(H, a["layer_idx"], a["num_layers"]))
+    with jax.named_scope("ff.lightning"):
+        if s == 1 and state is not None:
+            y, state = ssd_step(v[:, 0], dt[:, 0], A, k[:, 0], q[:, 0], state)
+            y = y[:, None]
+        else:
+            y, state = ssd_chunked(v, dt, A, k, q, a.get("chunk", 128), state)
+    y = y.reshape(b, s, H * d) * (1.0 / math.sqrt(d))
+    y = rms_norm_f32(y, params["o_norm"], a["eps"])
+    y = y * jax.nn.sigmoid((u @ params["wg"]).astype(f32))
+    return y.astype(u.dtype) @ params["wo"], state
+
+
+class LightningAttention(OpDef):
+    """Input (B, S, E) -> output (B, S, E): :func:`lightning_mixer`.
+    Attrs: ``num_heads``, ``head_dim``, ``layer_idx`` and ``num_layers``
+    (the decay's, as published), ``rope_theta`` (0: no rotary), ``eps``,
+    ``chunk``.  Weights ``wq``, ``wk``, ``wv``, ``wg``
+    (E, H*d), ``wo`` (H*d, E), ``q_norm``, ``k_norm`` (d,), ``o_norm``
+    (H*d,)."""
+
+    op_type = OperatorType.LIGHTNING_ATTENTION
+
+    def infer(self, layer: Layer) -> List[ShapeDtype]:
+        t = layer.inputs[0]
+        return [(t.shape, t.dtype)]
+
+    def weights(self, layer: Layer) -> List[WeightSpec]:
+        t = layer.inputs[0]
+        e, dt = t.shape[-1], t.dtype
+        w = layer.attrs["num_heads"] * layer.attrs["head_dim"]
+        init = layer.attrs.get("kernel_initializer") or default_kernel_initializer()
+        return [
+            WeightSpec("wq", (e, w), dt, init),
+            WeightSpec("wk", (e, w), dt, init),
+            WeightSpec("wv", (e, w), dt, init),
+            WeightSpec("wg", (e, w), dt, init),
+            WeightSpec("wo", (w, e), dt, init),
+            WeightSpec("q_norm", (layer.attrs["head_dim"],), dt, OnesInitializer()),
+            WeightSpec("k_norm", (layer.attrs["head_dim"],), dt, OnesInitializer()),
+            WeightSpec("o_norm", (w,), dt, OnesInitializer()),
+        ]
+
+    def forward(self, layer, params, inputs, ctx: OpContext):
+        return [lightning_mixer(params, inputs[0], layer.attrs)[0]]
+
+    def flops(self, layer: Layer) -> float:
+        b, s, e = layer.inputs[0].shape
+        h, d = layer.attrs["num_heads"], layer.attrs["head_dim"]
+        # five projections; a position and head: the state's update and
+        # read-out (2 d d each) and the chunk's pairs (chunk / 2 on average)
+        chunk = layer.attrs.get("chunk", 128)
+        return 2.0 * b * s * (5 * e * h * d + h * (2 * d * d + chunk * d))
+
+    def partitionable_dims(self, layer):
+        return {0: "sample"}  # the recurrence runs along dim 1
+
+
+register_op(LightningAttention())

@@ -110,6 +110,7 @@ __all__ = [
     "paged_decode_attention",
     "paged_kv_write",
     "paged_prefill_attention",
+    "paged_sparse_attention",
     "supported",
     "resolve_serve_attn",
 ]
@@ -548,7 +549,8 @@ def _jitted_as(name):
     is ``%<name>.N`` in a compiled program and in the profiler's trace,
     which is how a reader of either tells a decode-width call from a
     prefill chunk's (the benchmark's ``paged_attention_roofline.*``
-    reads ``%decode.N`` and ``%prefill.N``)."""
+    reads ``%decode.N`` and ``%prefill.N``), and a sparse layer's walk of
+    its selected pages (``%sparse_attend.N``) from both."""
     def call(*args, **kwargs):
         return _paged_call(*args, **kwargs)
 
@@ -558,7 +560,7 @@ def _jitted_as(name):
     ))
 
 
-_JITTED = {name: _jitted_as(name) for name in ("decode", "prefill")}
+_JITTED = {name: _jitted_as(name) for name in ("decode", "prefill", "sparse_attend")}
 
 
 def paged_decode_attention(
@@ -681,6 +683,28 @@ def paged_prefill_attention(
     return _attention(
         "prefill", q, pool_k, pool_v, start, block_tables, scale,
         scale_k, scale_v, layer, block_size, window, tile_rows,
+    )
+
+
+def paged_sparse_attention(
+    q, pool_k, pool_v, positions, block_tables, scale=None, layer=None, *,
+    block_size, tile_rows=None,
+):
+    """The kernel over a sparse layer's SELECTED pages
+    (``ops/sparse_attention.py``): ``block_tables`` (B, MB) lists a unit
+    of queries' selected pages in position order (``MB`` the most a unit
+    selects), and ``positions`` (B,) where the unit's first row sits
+    among the keys of those pages taken in that order -- ``(n - 1) *
+    block_size`` plus its offset in its own block, the last of the ``n``.
+    The kernel then walks the ``n`` pages as if they were the lane's
+    whole table: every earlier selected block is seen whole, the rows'
+    own block up to each row.  The same kernel as
+    :func:`paged_decode_attention` (one K/V head a pool row: ``q`` (B, G,
+    QH, D) the head's query heads), named ``sparse_attend`` in a
+    compiled program and in the profiler's trace."""
+    return _attention(
+        "sparse_attend", q, pool_k, pool_v, positions, block_tables, scale,
+        None, None, layer, block_size, 0, tile_rows,
     )
 
 

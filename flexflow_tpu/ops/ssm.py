@@ -382,7 +382,11 @@ def mamba1_mixer(params, u, a: dict, conv_state=None, ssm_state=None, n_valid=No
 
 def state_shapes(kind: str, a: dict):
     """``(conv state, ssm state)`` shapes a slot of a state layer of
-    ``kind`` (``mamba2`` | ``mamba1``) with attrs ``a`` holds."""
+    ``kind`` (``mamba2`` | ``mamba1`` | ``lightning``, which has no conv:
+    None) with attrs ``a`` holds."""
+    if kind == "lightning":
+        d = a["head_dim"]
+        return None, (a["num_heads"], d, d)
     if kind == "mamba2":
         h, p, _, n, _, cw = mamba2_dims(a)
         return (a["conv_kernel"] - 1, cw), (h, p, n)

@@ -1895,4 +1895,6 @@ _REMAT_OPS = frozenset({
     OperatorType.MAMBA2_MIXER,
     OperatorType.MAMBA1_MIXER,
     OperatorType.DIFF_ATTENTION,
+    OperatorType.LIGHTNING_ATTENTION,
+    OperatorType.SPARSE_ATTENTION,
 })

@@ -371,6 +371,16 @@ class ServeReport:
     state_spills: int = 0
     state_restores: int = 0
     ssm_rows: Optional[int] = None
+    # --- sparse layers (the index group): keys their live rows read and
+    # the keys at and before those rows, a K/V head each, summed over the
+    # layers and the run's calls ON THE DEVICE and read at the windows'
+    # syncs (None without sparse layers); the same over decode calls
+    # alone; and the index's footprint
+    sparse_keys_attended: Optional[int] = None
+    sparse_keys_visible: Optional[int] = None
+    sparse_decode_keys_attended: Optional[int] = None
+    sparse_decode_keys_visible: Optional[int] = None
+    index_pool_bytes: int = 0
     # which grouped matmul the expert layers ran (ServeEngine.expert_matmul:
     # "kernel" | "xla"; None without routed experts)
     expert_matmul: Optional[str] = None
@@ -431,6 +441,7 @@ class ServeEngine:
         span_recorder: Optional[SpanRecorder] = None,
         metrics_max_mb: float = 0.0,
         slo=None,
+        token_logits: bool = False,
     ) -> None:
         import jax
         import jax.numpy as jnp
@@ -479,6 +490,14 @@ class ServeEngine:
             self.spec_draft_layers = max(1, self.spec.num_layers // 2)
         if self.temperature > 0.0:
             self.spec_k = 0
+        # each served token's own logit, kept on its request
+        # (``Request.token_logits``): the greedy argmax's, read with the
+        # tokens at the window's one sync
+        self.token_logits = bool(token_logits)
+        if self.token_logits and (self.temperature > 0.0 or self.spec_k):
+            raise UnsupportedServeConfig(
+                "token_logits are the greedy argmax's own logits: not with "
+                "sampling or speculation")
         # decode-attention kernel (docs/PERF.md "Paged decode
         # attention"): "auto" resolves to the fused Pallas paged kernel
         # wherever it can run (TPU, or interpreter mode forced) and
@@ -523,8 +542,14 @@ class ServeEngine:
 
         attn = [b for b in self.spec.branches if b.is_attention and b.kv_source < 0]
         n_win = sum(1 for b in attn if b.window)
+        # a sparse layer's pool rows are one K/V head's, a pool layer a
+        # head, and its index of compressed keys is a group of its own
+        sparse = [b for b in attn if b.kind == "sparse"]
+        if sparse:
+            self._check_sparse(sparse, attn, block_size)
         self.kv = PagedKVCache(
-            len(attn) - n_win, self.spec.kv_heads,
+            sum(b.pool_layers for b in attn if not b.window),
+            1 if sparse else self.spec.kv_heads,
             self.spec.head_dim,
             slots=self.slots, block_size=block_size,
             num_blocks=num_blocks, max_seq_len=self.spec.seq, dtype=dt,
@@ -532,6 +557,9 @@ class ServeEngine:
             window_layers=n_win, window=self.spec.window,
             chunk=self.prefill_chunk,
             state_shapes=[state_shapes(b.kind, b.attrs) for b in self.spec.state_layers],
+            index_shapes=[
+                (self.spec.seq // b.attrs["stride"], b.kv_heads, b.head_dim) for b in sparse
+            ],
         )
         # attention layers that read the full group's cache without owning
         # any of it
@@ -598,12 +626,16 @@ class ServeEngine:
                 # greedy decoding needs the argmax alone: the distribution
                 # stays on the device (and out of the program)
                 return_probs=self.temperature > 0.0,
+                return_logit=self.token_logits,
             )
         self._n_head = progs.n_head
         self._moe_layers = sum(1 for b in self.spec.branches if b.kind == "moe")
-        # the expert layers' counters, summed on the device call by call
+        # the expert layers' counters and the sparse layers' keys (a
+        # prefill's and a decode's), summed on the device call by call
         # and read with the window's tokens
         self._moe_acc = None
+        self._sparse_acc = [None, None] if self.kv.index_layers else None
+        self._sparse_tot = np.zeros((2, 2), np.float64)  # (prefill, decode) x (read, visible)
         self._params_arg = progs.params_arg
         self._texts = None  # (params, the two programs' compiled text): _program_texts
         self._decode, self._prefill = progs.decode, progs.prefill
@@ -718,6 +750,26 @@ class ServeEngine:
         self.drain_payload: Optional[Dict[str, Any]] = None
         self.watchdog_fires = 0
         self._slo_breach_windows = 0  # consecutive over-SLO windows
+
+    def _check_sparse(self, sparse, attn, block_size: int) -> None:
+        """What the sparse layers' serving asks of the engine: a page is a
+        selected block, a prefill chunk whole blocks (its blocks of
+        queries share a selection), the compiled length whole blocks, and
+        no attention of another kind beside them (a pool row is one K/V
+        head's)."""
+        a = sparse[0].attrs
+        why = None
+        if len(sparse) != len(attn):
+            why = "beside attention layers of another kind"
+        elif any(b.attrs != a for b in sparse):
+            why = "of different sizes in one model"
+        elif block_size != a["block"]:
+            why = f"at block_size {block_size}: a page has to be the selection block, {a['block']}"
+        elif self.prefill_chunk % a["block"] or self.spec.seq % a["block"]:
+            why = (f"at prefill_chunk {self.prefill_chunk} and a compiled length "
+                   f"{self.spec.seq} that are not both whole blocks of {a['block']}")
+        if why:
+            raise UnsupportedServeConfig(f"sparse layers are not served {why}")
 
     # --- submission --------------------------------------------------------
     def submit(
@@ -932,7 +984,9 @@ class ServeEngine:
         reckoned under speculation (a request's lengths do not say what
         its draft and verify calls read) or on the gather arm."""
         walk = self.attn_walk()
-        if walk is None or self.spec_k:
+        if walk is None or self.spec_k or self.kv.index_layers:
+            # (a sparse layer walks the pages it selected: its keys are
+            # counted on the device, ServeReport.sparse_keys_attended)
             return None, None
         from flexflow_tpu.ops.pallas.paged_attention import lane_blocks
 
@@ -973,7 +1027,8 @@ class ServeEngine:
         kv = self.kv
         return (
             tuple(getattr(kv, name) for name in self._pool_names())
-            + tuple(kv.state_conv) + tuple(kv.state_ssm)
+            + tuple(c for c in kv.state_conv if c is not None) + tuple(kv.state_ssm)
+            + tuple(kv.index_k)
         )
 
     def _pool_names(self) -> Tuple[str, ...]:
@@ -988,21 +1043,41 @@ class ServeEngine:
     def _store_kvs(self, bufs) -> None:
         """Write a program's returned pool buffers back as the live
         pool (the counterpart of :meth:`_kvs`)."""
-        names, n = self._pool_names(), self.kv.state_layers
-        assert len(bufs) == len(names) + 2 * n, (len(bufs), names, n)
+        kv = self.kv
+        names, n, ni = self._pool_names(), kv.state_layers, kv.index_layers
+        nc = sum(c is not None for c in kv.state_conv)
+        assert len(bufs) == len(names) + nc + n + ni, (len(bufs), names, nc, n, ni)
         for name, buf in zip(names, bufs):
-            setattr(self.kv, name, buf)
-        self.kv.state_conv = list(bufs[len(names):len(names) + n])
-        self.kv.state_ssm = list(bufs[len(names) + n:])
+            setattr(kv, name, buf)
+        convs = iter(bufs[len(names):len(names) + nc])
+        kv.state_conv = [None if c is None else next(convs) for c in kv.state_conv]
+        at = len(names) + nc
+        kv.state_ssm = list(bufs[at:at + n])
+        kv.index_k = list(bufs[at + n:])
 
-    def _take(self, res):
-        """A decode or prefill call's results: keep its pools (and add
-        its expert counters to the device-side sum), return ``(nxt,
-        probs)``."""
+    def _take(self, res, phase: int):
+        """A prefill (``phase`` 0) or decode (1) call's results: keep its
+        pools (and add its expert counters and sparse keys to the
+        device-side sums), return ``(nxt, probs)``."""
         self._store_kvs(res[self._n_head:])
-        if self._n_head == 3:
+        at = 2
+        if self._moe_layers:
             self._moe_acc = res[2] if self._moe_acc is None else self._moe_acc + res[2]
+            at = 3
+        if self._sparse_acc is not None:
+            acc = self._sparse_acc[phase]
+            self._sparse_acc[phase] = res[at] if acc is None else acc + res[at]
         return res[0], res[1]
+
+    def _read_counters(self) -> None:
+        """Fold the device-side sums into the host's totals."""
+        if self._moe_acc is not None:
+            self._moe_tot += np.asarray(self._moe_acc, np.float64)
+            self._moe_acc = None
+        for ph, acc in enumerate(self._sparse_acc or ()):
+            if acc is not None:
+                self._sparse_tot[ph] += np.asarray(acc, np.float64)
+                self._sparse_acc[ph] = None
 
     # --- the serve loop ----------------------------------------------------
     def run(self, requests: Optional[Sequence[Request]] = None) -> ServeReport:
@@ -1031,6 +1106,7 @@ class ServeEngine:
         self.watchdog_fires = 0
         self._slo_breach_windows = 0
         self._moe_tot[:] = 0.0
+        self._sparse_tot[:] = 0.0
         self._pages_peak = {"full": 0, "window": 0}
         self._state_slots_peak = 0
         self._state0 = (self.kv.state_spills, self.kv.state_restores)
@@ -1311,7 +1387,7 @@ class ServeEngine:
                         staged = staged[:3] + (staged[3:],)
                     t_c0 = spans.now() if spans is not None else 0.0
                     pf_nxt, pf_probs = self._take(
-                        self._prefill(self._params_arg, *self._kvs(), *staged)
+                        self._prefill(self._params_arg, *self._kvs(), *staged), 0
                     )
                     self.prefill_chunks += len(chunks)
                     self.prefill_dispatches += 1
@@ -1355,6 +1431,7 @@ class ServeEngine:
             spec_w: Dict[int, List[int]] = {}
             t_dec0 = spans.now() if spans is not None else 0.0
             buffered: List[Any] = []  # per-step (B,) next-token device arrays
+            buffered_logit: List[Any] = []  # and their logits (token_logits)
             spec_buf: List[Any] = []  # per-macro (n (B,W), acc (B,)) pairs
             probs_last = None
             steps = 0
@@ -1423,8 +1500,10 @@ class ServeEngine:
                             nxt, probs_last = self._take(self._decode(
                                 self._params_arg, *self._kvs(),
                                 cur_d, jnp.asarray(pos.copy()), bt_d,
-                            ))
+                            ), 1)
                             buffered.append(nxt)
+                            if self.token_logits:
+                                buffered_logit.append(probs_last)
                             cur_d = nxt  # device-to-device chain: NO host fetch
                             for s in dec_slots:
                                 pos[s] += 1
@@ -1436,6 +1515,7 @@ class ServeEngine:
             with tracer.span("sync", cat="serve"):
                 t_sync = self._now()
                 host_tok = [np.asarray(b) for b in buffered]
+                host_logit = [np.asarray(b) for b in buffered_logit]
                 host_spec = [
                     (np.asarray(n), np.asarray(a)) for n, a in spec_buf
                 ]
@@ -1454,12 +1534,12 @@ class ServeEngine:
                     ]
                 else:
                     host_pre = []
-                if self._moe_acc is not None and (buffered or prefill_done):
-                    # the expert layers' counters ride this same sync (a
-                    # window that fetches nothing leaves them on the device
-                    # for the next: it does not wait for its programs)
-                    self._moe_tot += np.asarray(self._moe_acc, np.float64)
-                    self._moe_acc = None
+                if buffered or prefill_done:
+                    # the expert layers' counters and the sparse layers'
+                    # keys ride this same sync (a window that fetches
+                    # nothing leaves them on the device for the next: it
+                    # does not wait for its programs)
+                    self._read_counters()
                 stall = self._now() - t_sync
             with tracer.span("flush", cat="serve"):
                 ex.count_host_sync(1, stall)
@@ -1487,6 +1567,8 @@ class ServeEngine:
                         else:
                             tok = int(host_tok[ki][s])
                         req.tokens.append(tok)
+                        if host_logit:
+                            req.token_logits.append(float(host_logit[ki][s]))
                         flushed_tokens += 1
                         self._finish_if_done(req, tok)
 
@@ -1526,6 +1608,8 @@ class ServeEngine:
                         )[0])
                     req.state = RequestState.DECODE
                     req.tokens.append(int(tok))
+                    if self.token_logits:
+                        req.token_logits.append(float(probs))
                     flushed_tokens += 1
                     req.t_first_token = self._now()
                     if spans is not None:
@@ -1783,9 +1867,11 @@ class ServeEngine:
             per_tenant[tenant] = d
         blocks_walked, blocks_full_table = self._attn_blocks(fin)
         rows_visible, rows_context = self._kv_rows(fin)
-        if self._moe_acc is not None:  # what the last windows left behind
-            self._moe_tot += np.asarray(self._moe_acc, np.float64)
-            self._moe_acc = None
+        self._read_counters()  # what the last windows left behind
+        keys_attended = keys_visible = dec_attended = dec_visible = None
+        if self._sparse_acc is not None:
+            keys_attended, keys_visible = map(int, self._sparse_tot.sum(axis=0))
+            dec_attended, dec_visible = map(int, self._sparse_tot[1])
         moe = self._moe_report() or {}
         n_state = self.kv.state_layers
         ssm_rows = n_state * sum(
@@ -1796,6 +1882,9 @@ class ServeEngine:
             if n_state:
                 tracer.counter("ssm.rows", float(ssm_rows))
                 tracer.counter("kv.state_pool_bytes", float(self.kv.state_bytes()))
+            if keys_visible is not None:
+                tracer.counter("sparse.keys_attended", float(keys_attended))
+                tracer.counter("sparse.keys_visible", float(keys_visible))
             tracer.counter("kv.rows_visible", float(rows_visible))
             tracer.counter("kv.rows_context", float(rows_context))
             for g, n in self._pages_peak.items():
@@ -1865,6 +1954,11 @@ class ServeEngine:
             attn_interpret=self.attn_interpret,
             device=self.device_info(),
             kv_rows_visible=rows_visible,
+            sparse_keys_attended=keys_attended,
+            sparse_keys_visible=keys_visible,
+            sparse_decode_keys_attended=dec_attended,
+            sparse_decode_keys_visible=dec_visible,
+            index_pool_bytes=self.kv.index_bytes(),
             kv_rows_context=rows_context,
             kv_pages_held_full=self._pages_peak["full"],
             kv_pages_held_window=self._pages_peak["window"],

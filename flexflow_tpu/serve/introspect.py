@@ -339,7 +339,8 @@ class StatusServer:
         if eng.kv.state_layers:
             # the state group: two arrays a layer, a row a slot
             out["state_layers"] = eng.kv.state_layers
-            out["state_conv_shape"] = list(eng.kv.state_conv[0].shape)
+            conv = eng.kv.state_conv[0]
+            out["state_conv_shape"] = None if conv is None else list(conv.shape)
             out["state_ssm_shape"] = list(eng.kv.state_ssm[0].shape)
             out["state_pool_bytes"] = eng.kv.state_bytes()
             out["state_bytes_per_slot"] = eng.kv.state_bytes_per_slot

@@ -104,6 +104,22 @@ request resumes from both), :meth:`restore` writes them back through a
 donated update (no copy of the pool).  Prefix sharing is declined for
 such a model: a re-attached prefix has no state at its boundary.
 
+A lightning-attention layer's state is a (heads, head_dim, head_dim)
+float32 matrix a head and it has no convolution: its ``state_conv`` entry
+is None (in the programs' arguments it takes no place).
+
+**A fourth group, INDEX (docs/SERVING.md "Sparse layers").**  A sparse
+layer (``ops/sparse_attention.py``) keeps, beside its K/V in the full
+group, an index of compressed keys a slot: ``index_k[i]`` ``(slots,
+entries, kv_heads, head_dim)`` in the compute dtype, entry ``j`` the mean
+of the keys of positions ``stride j .. stride j + kernel - 1``, written by
+the program that writes the last of them.  Provisioned a slot like a
+state (a recycled slot's stale entries are never read: a query reads the
+entries its own request has completed), spilled and restored with the
+slot's K/V, counted by ``check_invariants`` / ``hbm_bytes`` /
+``index_bytes()``; prefix sharing is declined (a re-attached prefix has
+no index of its own).
+
 **Quantized pools (PR 19, docs/SERVING.md "Quantized KV cache").**
 ``kv_dtype="int8" | "fp8"`` stores the pools in 1-byte elements with
 per-block symmetric scale arrays ``scale_k``/``scale_v`` of shape
@@ -238,15 +254,19 @@ class PagedKVCache:
         window_layers: int = 0,
         window: int = 0,
         chunk: int = 1,
-        state_shapes: Sequence[Tuple[Tuple[int, ...], Tuple[int, ...]]] = (),
+        state_shapes: Sequence[Tuple[Optional[Tuple[int, ...]], Tuple[int, ...]]] = (),
+        index_shapes: Sequence[Tuple[int, ...]] = (),
     ) -> None:
         """``num_layers`` full layers of ``heads`` K/V heads; with
         ``window_layers`` > 0 also that many layers that see ``window``
         positions back and are written at most ``chunk`` rows a call;
-        and a state layer for each ``(conv, ssm)`` pair of
+        a state layer for each ``(conv, ssm)`` pair of
         ``state_shapes``: a slot's ``conv`` = (taps - 1, channels) conv
-        inputs and its float32 ``ssm`` state (``(heads, head_dim,
-        state)`` for Mamba-2, ``(channels, state)`` for Mamba-1)."""
+        inputs (None: the layer has no conv) and its float32 ``ssm``
+        state (``(heads, head_dim, state)`` for Mamba-2 and lightning
+        attention, ``(channels, state)`` for Mamba-1); and a sparse
+        layer's index of compressed keys for each shape of
+        ``index_shapes`` (``(entries, kv_heads, head_dim)`` a slot)."""
         import jax.numpy as jnp
 
         assert block_size >= 1 and slots >= 1
@@ -258,11 +278,14 @@ class PagedKVCache:
         self.window_layers = int(window_layers)
         self.window = int(window) if self.window_layers else 0
         assert not self.window_layers or self.window >= 1
-        state_shapes = [(tuple(c), tuple(m)) for c, m in state_shapes]
+        state_shapes = [(None if c is None else tuple(c), tuple(m)) for c, m in state_shapes]
         self.state_layers = len(state_shapes)
-        # declined for a model with window or state layers (module docstring)
+        self.index_layers = len(index_shapes)
+        # declined for a model with window, state or index layers (module
+        # docstring)
         self.prefix_sharing = (
             bool(prefix_sharing) and not self.window_layers and not self.state_layers
+            and not self.index_layers
         )
         if max_blocks_per_seq is None:
             assert max_seq_len is not None, (
@@ -355,9 +378,15 @@ class PagedKVCache:
                 raise ValueError("a quantized pool is not built for state layers")
             cdt = dtype if dtype is not None else jnp.float32
             for conv, ssm in state_shapes:
-                assert min(conv) >= 1 and min(ssm) >= 1, (conv, ssm)
-                self.state_conv.append(jnp.zeros((slots,) + conv, cdt))
+                assert (conv is None or min(conv) >= 1) and min(ssm) >= 1, (conv, ssm)
+                self.state_conv.append(None if conv is None else jnp.zeros((slots,) + conv, cdt))
                 self.state_ssm.append(jnp.zeros((slots,) + ssm, jnp.float32))
+        # the index group: a sparse layer's compressed keys, one array a
+        # layer, provisioned a slot like a state
+        self.index_k: List[Any] = [
+            jnp.zeros((slots,) + tuple(shape), dtype if dtype is not None else jnp.float32)
+            for shape in index_shapes
+        ]
         self._set_row = None  # the jitted donated write of one slot's state
 
     # --- capacity queries --------------------------------------------------
@@ -686,11 +715,16 @@ class PagedKVCache:
             with get_tracer().span("state_spill", cat="serve"):
                 payload["state"] = {
                     "layers": {
-                        f"layer{i}": {"conv": np.asarray(c[slot]), "ssm": np.asarray(s[slot])}
+                        f"layer{i}": {"conv": None if c is None else np.asarray(c[slot]),
+                                      "ssm": np.asarray(s[slot])}
                         for i, (c, s) in enumerate(zip(self.state_conv, self.state_ssm))
                     },
                 }
                 self.state_spills += 1
+        if self.index_layers:
+            payload["index"] = {
+                f"layer{i}": np.asarray(a[slot]) for i, a in enumerate(self.index_k)
+            }
         self.release(slot)
         return payload
 
@@ -767,16 +801,20 @@ class PagedKVCache:
                 "KV payload and pool disagree on window layers: a payload "
                 "restores into a pool of the same layer groups"
             )
-        if ("state" in payload) != bool(self.state_layers):
-            self.release(slot)
-            raise ValueError(
-                "KV payload and pool disagree on state layers: a payload "
-                "restores into a pool of the same layer groups"
-            )
+        for group, held in (("state", self.state_layers), ("index", self.index_layers)):
+            if (group in payload) != bool(held):
+                self.release(slot)
+                raise ValueError(
+                    f"KV payload and pool disagree on {group} layers: a payload "
+                    "restores into a pool of the same layer groups"
+                )
         if self.window_layers:
             self._restore_window(slot, payload["window"], length)
         if self.state_layers:
             self._restore_state(slot, payload["state"])
+        if self.index_layers:
+            rows = [payload["index"][f"layer{i}"] for i in range(self.index_layers)]
+            self._set_rows(slot, self.index_k, rows, "index payload")
         if length <= shared_pos:
             return shared_pos
         L, H, BS, D = (
@@ -860,8 +898,17 @@ class PagedKVCache:
         self.win_v = self.win_v.at[:, rows].set(jnp.asarray(v, self.dtype))
 
     def _restore_state(self, slot: int, state: Dict[str, Any]) -> None:
-        """Write a spilled slot's states back, each through a donated
-        update of its array (in place: no copy of a layer's pool)."""
+        """Write a spilled slot's states back."""
+        with get_tracer().span("state_restore", cat="serve"):
+            layers = [state["layers"][f"layer{i}"] for i in range(self.state_layers)]
+            self._set_rows(slot, self.state_conv, [g["conv"] for g in layers], "state payload conv")
+            self._set_rows(slot, self.state_ssm, [g["ssm"] for g in layers], "state payload ssm")
+            self.state_restores += 1
+
+    def _set_rows(self, slot: int, pools: List[Any], rows, name: str) -> None:
+        """``pools[i][slot] = rows[i]``, each through a donated update of
+        its array (in place: no copy of a layer's pool); an absent array
+        (a layer without a conv) takes nothing."""
         import jax
 
         if self._set_row is None:
@@ -869,19 +916,18 @@ class PagedKVCache:
                 lambda pool, at, row: pool.at[at].set(row.astype(pool.dtype)),
                 donate_argnums=0,
             )
-        with get_tracer().span("state_restore", cat="serve"):
-            for i in range(self.state_layers):
-                got = state["layers"][f"layer{i}"]
-                for pools, name in ((self.state_conv, "conv"), (self.state_ssm, "ssm")):
-                    row = np.asarray(got[name])
-                    if row.shape != tuple(pools[i].shape[1:]):
-                        self.release(slot)
-                        raise ValueError(
-                            f"state payload {name} {row.shape} does not match this "
-                            f"pool's {tuple(pools[i].shape[1:])} a slot"
-                        )
-                    pools[i] = self._set_row(pools[i], np.int32(slot), row)
-            self.state_restores += 1
+        for i, row in enumerate(rows):
+            if pools[i] is None and row is None:
+                continue
+            row = None if row is None else np.asarray(row)
+            if pools[i] is None or row is None or row.shape != tuple(pools[i].shape[1:]):
+                self.release(slot)
+                want = None if pools[i] is None else tuple(pools[i].shape[1:])
+                raise ValueError(
+                    f"{name} {None if row is None else row.shape} does not "
+                    f"match this pool's {want} a slot"
+                )
+            pools[i] = self._set_row(pools[i], np.int32(slot), row)
 
     @property
     def state_slots_held(self) -> int:
@@ -894,12 +940,16 @@ class PagedKVCache:
         whatever its request's length."""
         return sum(
             (a.size // self.slots) * a.dtype.itemsize
-            for a in self.state_conv + self.state_ssm
+            for a in self.state_conv + self.state_ssm if a is not None
         )
 
     def state_bytes(self) -> int:
         """Physical footprint of the state group."""
         return self.state_bytes_per_slot * self.slots
+
+    def index_bytes(self) -> int:
+        """Physical footprint of the index group."""
+        return sum(a.size * a.dtype.itemsize for a in self.index_k)
 
     def pages_held(self) -> Dict[str, int]:
         """Pages mapped into slots' tables right now, a layer group: the
@@ -946,10 +996,11 @@ class PagedKVCache:
                     f"{'its ring' if slot in self._owned else 'the trash block'}"
                 )
         assert len(self.state_conv) == len(self.state_ssm) == self.state_layers
-        for a in self.state_conv + self.state_ssm:
-            assert a.shape[0] == self.slots and not a.is_deleted(), (
-                "a state layer's array is not one row a slot, or was donated "
-                "and not stored back"
+        assert len(self.index_k) == self.index_layers
+        for a in self.state_conv + self.state_ssm + self.index_k:
+            assert a is None or (a.shape[0] == self.slots and not a.is_deleted()), (
+                "a state layer's or an index layer's array is not one row a "
+                "slot, or was donated and not stored back"
             )
 
     # --- device-side views -------------------------------------------------
@@ -1026,11 +1077,11 @@ class PagedKVCache:
         return n
 
     def hbm_bytes(self) -> int:
-        """Physical pool footprint (both caches + scales, all three
+        """Physical pool footprint (both caches + scales, all four
         layer groups)."""
         n = 2 * self.cache_k.size * self.cache_k.dtype.itemsize
         if self.quantized:
             n += 2 * self.scale_k.size * 4
         if self.window_layers:
             n += 2 * self.win_k.size * self.win_k.dtype.itemsize
-        return n + self.state_bytes()
+        return n + self.state_bytes() + self.index_bytes()

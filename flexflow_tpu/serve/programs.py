@@ -28,7 +28,14 @@ slot's state (zero where the chunk starts at position 0), the arrays
 donated and updated in place, rows past ``n_valid`` and idle lanes
 changing neither; a Mamba-1 layer may export its gated scan output, a
 memory the trunk carries beside the stream to the gated memory units
-that read it.  Differential attention rides the kernel with a K/V pair
+that read it; or lightning attention (``ops/linear_attention.py``, SSD's
+functions with a fixed decay a head), a (heads, d, d) state a slot in
+the same group with no conv.  A sparse (InfLLM-v2) attention layer
+writes a pool layer a K/V head, keeps an index of compressed keys a slot
+(the pool's INDEX group), selects its blocks on the device -- a query at
+a time in a decode, a block of queries at a time in a prefill -- and has
+the kernel walk each unit's selected pages as its table
+(``sparse_branch``).  Differential attention rides the kernel with a K/V pair
 one pool head and a query pair two heads (``project``); a cross layer
 reads its source layer's rows of the full group and writes none.  The
 layers after the last one that writes a pool (``GPTSpec.self_layers``)
@@ -49,8 +56,9 @@ program with another compile time (ROADMAP S4).
 What reads the programs from outside, and so may not move: the jitted
 functions' names (``jit_decode`` ... in a trace and in the compile
 cache), their arguments ``(params, ck, cv[, sk, sv][, wk, wv][, conv
-states, ssm states], ...)`` and outputs ``(nxt, probs | None, [moe
-stats,] ck, cv[, sk, sv][, wk, wv][, conv states, ssm states])``
+states, ssm states][, indexes], ...)`` and outputs ``(nxt, probs | logit
+| None, [moe stats,] [sparse keys,] ck, cv[, sk, sv][, wk, wv][, conv
+states, ssm states][, indexes])``
 (analysis/capture.py, the benchmark's tests),
 and the paged kernel's two names — ``prefill`` calls it as
 ``%prefill.N``, the other three as ``%decode.N``, which is how the
@@ -197,6 +205,7 @@ def build_serve_programs(
     spec_k: int = 0,
     spec_draft_layers: int = 0,
     return_probs: bool = True,
+    return_logit: bool = False,
 ) -> ServePrograms:
     """Jit the serve programs of a compiled decoder (anything
     ``GPTSpec.from_model`` reads: ``gpt_decoder``, ``afmoe_decoder``,
@@ -209,7 +218,8 @@ def build_serve_programs(
     resolved decision (``paged`` | ``gather``); ``draft`` and ``verify``
     are built only with ``spec_k``; ``return_probs`` false (greedy
     decoding) leaves the float32 distribution out of the outputs
-    (``None`` in its place).  ``params_arg``, what every program takes
+    (``None`` in its place, or with ``return_logit`` the served token's
+    own logit, (slots,) float32).  ``params_arg``, what every program takes
     first, is the weights as the programs multiply them
     (:func:`weights_as_consumed`): cast here, once, where the compute
     dtype is not the dtype at rest -- ``prep_params`` inside a program
@@ -222,6 +232,7 @@ def build_serve_programs(
     import jax.numpy as jnp
 
     from flexflow_tpu.ops.attention import diff_combine, rotate_half_rope_at
+    from flexflow_tpu.ops.linear_attention import lightning_mixer
     from flexflow_tpu.ops.moe import (
         gated_ffn,
         held_experts_part,
@@ -230,18 +241,25 @@ def build_serve_programs(
         shared_expert_part,
     )
     from flexflow_tpu.ops.norm import rms_norm_f32, rms_norm_zero_centered
-    from flexflow_tpu.ops.ssm import gated_memory, mamba1_mixer, mamba2_mixer
+    from flexflow_tpu.ops.ssm import gated_memory, mamba1_mixer, mamba2_mixer, state_shapes
     from flexflow_tpu.ops.pallas.paged_attention import (
         paged_decode_attention,
         paged_kv_write,
         paged_prefill_attention,
+        paged_sparse_attention,
+    )
+    from flexflow_tpu.ops.sparse_attention import (
+        block_scores,
+        kernels_at,
+        select_blocks,
+        selected_table,
     )
 
     spec = GPTSpec.from_model(model)
     L, H, D = spec.num_layers, spec.heads, spec.head_dim
     L_self = spec.self_layers  # layers a prefill runs on every row
-    KVH = spec.kv_heads
-    rep = H // KVH
+    KVH = kv.heads  # the pool's K/V heads a row (one a pool layer for sparse layers)
+    rep = H // spec.kv_heads
     slots, MB, BS = kv.slots, kv.max_blocks_per_seq, kv.block_size
     SV = MB * BS  # virtual (paged) sequence length
     S_pos = spec.seq  # pos_embed table height
@@ -259,11 +277,13 @@ def build_serve_programs(
     # K/V in the window group's ring, every other attention's in the full
     # group, and a state-space layer's two states in the state group; a
     # cross layer reads its source layer's rows of the full group
-    where, n_full, n_win, n_state = {}, 0, 0, 0
-    full_of = {}  # layer -> its index in the full group
+    # (a sparse layer's K/V take a full-group layer a K/V head, and its
+    # index of compressed keys a place in the index group)
+    where, n_full, n_win, n_state, n_index = {}, 0, 0, 0, 0
+    full_of, index_of = {}, {}  # layer -> its index in the full / index group
     for i, ls in enumerate(spec.layers):
         for j, br in enumerate(ls.branches):
-            if br.kind in ("mamba2", "mamba1"):
+            if br.kind in ("mamba2", "mamba1", "lightning"):
                 where[(i, j)] = ("state", n_state)
                 n_state += 1
             elif br.is_attention and br.kv_source >= 0:
@@ -274,13 +294,18 @@ def build_serve_programs(
             elif br.is_attention:
                 where[(i, j)] = ("full", n_full)
                 full_of[i] = n_full
-                n_full += 1
+                n_full += br.pool_layers
+                if br.kind == "sparse":
+                    index_of[(i, j)] = n_index
+                    n_index += 1
             else:
                 where[(i, j)] = None
-    assert (n_full, n_win, n_state) == (kv.num_layers, kv.window_layers, kv.state_layers), (
-        "the pool's layer groups are not the model's",
-        (n_full, n_win, n_state), (kv.num_layers, kv.window_layers, kv.state_layers),
+    groups = (kv.num_layers, kv.window_layers, kv.state_layers, kv.index_layers)
+    assert (n_full, n_win, n_state, n_index) == groups, (
+        "the pool's layer groups are not the model's", (n_full, n_win, n_state, n_index), groups,
     )
+    # state layers with a conv (a lightning layer has none: no argument)
+    has_conv = [state_shapes(b.kind, b.attrs)[0] is not None for b in spec.state_layers]
     R = kv.ring_blocks
     # quantized-pool trace-time switch: with ``quant`` the programs
     # take/donate/return the two scale pools beside the K/V pools and
@@ -422,7 +447,7 @@ def build_serve_programs(
     def project(br, p_at, h, pos, G):
         # rows h (B * G, hidden) -> q (B, G, H, D), k, v (B, G, KVH, D),
         # the output gate (B * G, H * D) or None
-        B = pos.shape[0]
+        B, KVH = pos.shape[0], br.kv_heads
         if br.differential:
             # a pair's two queries as two heads of the pool's head size,
             # [q1 | 0] and [0 | q2]: over the pair's one K/V head [k1 | k2]
@@ -564,6 +589,111 @@ def build_serve_programs(
         kvs = (ck, cv, sk, sv, pk, pv) if ring else (pk, pv, sk, sv, wk, wv)
         return o, kvs + pools[6:]
 
+    def sparse_branch(br, at, ii, params, x, pools, start, bts, n_valid, G):
+        # a sparse (InfLLM-v2) branch over rows x (B * G, hidden): its K/V
+        # into the full group, a pool layer a K/V head from ``at``; the
+        # index of compressed keys ``pools[8][ii]`` brought up to the rows
+        # written; each unit of queries -- a decode row, or an aligned
+        # block of a prefill chunk's rows, which share one selection --
+        # selects its blocks; the paged kernel then walks, a K/V head at a
+        # time, a unit's selected pages in order as if they were its
+        # whole table, the unit's rows at their place in that order.
+        # Returns the branch, the pools and the call's keys read
+        a = br.attrs
+        blk, st, kern = a["block"], a["stride"], a["kernel"]
+        kvh, hd = br.kv_heads, br.head_dim
+        qh = br.heads // kvh  # query heads a K/V head
+        gi = at[1]
+        bt = bts[0]
+        ck, cv, sk, sv = pools[:4]
+        B = start.shape[0]
+        pos = start[:, None] + jnp.arange(G)[None, :]
+        n_rows = (bt[:, 0] > 0).astype(jnp.int32) if n_valid is None else n_valid
+        width = min(max(a["topk"], a["dense_len"] // blk), MB)  # a unit's table
+        with jax.named_scope("ff.sparse"):
+            h = norm(params[br.norm_in], x)
+            q, k, v, gate = project(br, params[br.mixer[0]], h, pos, G)
+            for g in range(kvh):
+                ck, cv, sk, sv = write_kv(ck, cv, sk, sv, gi + g, k[:, :, g:g + 1],
+                                          v[:, :, g:g + 1], start, bt, n_valid)
+            with jax.named_scope("ff.sparse.index"):
+                # the entries whose last position this call wrote: the mean
+                # of their ``kern`` keys, read back from the pool
+                nj = G // st + 1
+                j = (start - kern + st) // st
+                j = j[:, None] + jnp.arange(nj)[None, :]  # (B, nj)
+                end = st * j + kern - 1
+                ok = (j >= 0) & (end >= start[:, None]) & (end < (start + n_rows)[:, None])
+                p = jnp.maximum(st * j[..., None] + jnp.arange(kern), 0)  # (B, nj, kern)
+                page = bt[jnp.arange(B)[:, None, None], jnp.clip(p // BS, 0, MB - 1)]
+                rows = page * BS + p % BS
+                kbar = jnp.stack([
+                    jnp.mean(ck[gi + g, rows].astype(jnp.float32), axis=2) for g in range(kvh)
+                ], axis=2)  # (B, nj, kvh, hd)
+                index = pools[8][ii]  # (B, J, kvh, hd): an entry's heads together
+                J = index.shape[1]
+                index = index.at[jnp.arange(B)[:, None], jnp.where(ok, j, J)].set(
+                    kbar.astype(index.dtype), mode="drop")
+            with jax.named_scope("ff.sparse.select"):
+                nk = kernels_at(pos, kern, st)
+                score = block_scores(q, index, nk, kernel=kern, stride=st, block=blk)
+                sizes = dict(block=blk, topk=a["topk"], window=a["window"],
+                             dense_len=a["dense_len"])
+                if n_valid is None:  # a unit a row: (B, G = 1, kvh, NB)
+                    t_first = t_last = pos[..., None]
+                    off = pos % blk
+                else:  # a unit a block of rows: their scores summed
+                    nt = G // blk
+                    live = (jnp.arange(G)[None, :] < n_valid[:, None]).astype(score.dtype)
+                    score = (score * live[..., None, None]).reshape(
+                        B, nt, blk, kvh, -1).sum(2)
+                    t_first = start[:, None] + blk * jnp.arange(nt)[None, :]
+                    t_last = jnp.maximum(
+                        start[:, None] + jnp.minimum(blk * jnp.arange(1, nt + 1)[None, :],
+                                                     n_valid[:, None]) - 1, t_first)
+                    t_first, t_last = t_first[..., None], t_last[..., None]
+                    off = jnp.zeros_like(t_first[..., 0])
+                sel = select_blocks(score, t_first, t_last, **sizes)
+                table, n_sel = selected_table(sel, width)  # (B, U, kvh, width)
+                table = jnp.where(jnp.arange(width) < n_sel[..., None],
+                                  bt[jnp.arange(B)[:, None, None, None], table], 0)
+                # where a unit's first row sits among its selected keys
+                vpos = (n_sel - 1) * blk + off[..., None]  # (B, U, kvh)
+            U = table.shape[1]
+            gr = G // U  # rows a unit
+            # the keys each live row reads (row r of a unit: its first
+            # row's place + r + 1), and the keys at and before it, a K/V
+            # head each: the call's (attended, visible)
+            f32 = jnp.float32
+            live = (jnp.arange(G).reshape(1, U, gr) < n_rows[:, None, None]).astype(f32)
+            seen = vpos[..., None].astype(f32) + 1.0 + jnp.arange(gr, dtype=f32)
+            n_keys = jnp.stack([
+                jnp.sum(seen * live[:, :, None, :]),
+                kvh * jnp.sum((pos.reshape(B, U, gr) + 1).astype(f32) * live),
+            ])
+            outs = []
+            with jax.named_scope("ff.sparse.attend"):
+                for g in range(kvh):
+                    qg = q[:, :, g * qh:(g + 1) * qh].reshape(B * U, gr, qh, hd)
+                    tg, vg = table[:, :, g].reshape(B * U, width), vpos[:, :, g].reshape(B * U)
+                    if paged:
+                        o = paged_sparse_attention(qg, ck, cv, vg, tg, scale=scale,
+                                                   layer=gi + g, block_size=BS)
+                    else:
+                        keys = ck[gi + g].reshape(-1, BS, hd)[tg].reshape(B * U, -1, hd)
+                        vals = cv[gi + g].reshape(-1, BS, hd)[tg].reshape(B * U, -1, hd)
+                        row = vg[:, None] + jnp.arange(gr)[None, :]
+                        mask = (jnp.arange(keys.shape[1])[None, None, :] <= row[..., None])
+                        o = attend(qg, keys[:, None, None], vals[:, None, None],
+                                   mask[:, :, None, :])
+                    outs.append(o.reshape(B, G, qh, hd))
+            o = jnp.concatenate(outs, axis=2).reshape(-1, br.heads * hd)
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            o = o @ params[br.mixer[0]]["wo"]
+        idx = list(pools[8])
+        idx[ii] = index
+        return o, (ck, cv, sk, sv) + pools[4:8] + (tuple(idx),), n_keys
+
     def live_rows(bts, n_valid, G):
         # (B, G) bool: the rows that belong to a request.  A decode-width
         # program has no n_valid: a live lane holds a reservation, so its
@@ -585,17 +715,28 @@ def build_serve_programs(
         def lanes(v, like):  # (B,) -> broadcast over a state's dims
             return v[(slice(None),) + (None,) * (like.ndim - 1)]
 
+        def fresh_or(state, fresh):
+            if state is None:
+                return None
+            return jnp.where(lanes(fresh, state), jnp.zeros_like(state), state)
+
         if n_valid is None:  # decode width: a live lane's one row
             n_live = (bts[0][:, 0] > 0).astype(jnp.int32)
             conv_in, ssm_in = conv, ssm
         else:
             n_live = n_valid
             fresh = (start == 0) & (n_live > 0)
-            conv_in = jnp.where(lanes(fresh, conv), jnp.zeros_like(conv), conv)
-            ssm_in = jnp.where(lanes(fresh, ssm), jnp.zeros_like(ssm), ssm)
+            conv_in, ssm_in = fresh_or(conv, fresh), fresh_or(ssm, fresh)
         h = norm(params[br.norm_in], x).reshape(start.shape[0], G, -1)
         mem = None
-        if br.kind == "mamba1":
+        if br.kind == "lightning":
+            # rows past n_valid and idle lanes: dt 0, the state as it was
+            o, ssm_out = lightning_mixer(
+                params[br.mixer[0]], h, br.attrs, ssm_in, n_live,
+                start[:, None] + jnp.arange(G)[None, :],
+            )
+            conv_out = None
+        elif br.kind == "mamba1":
             o, conv_out, ssm_out, m = mamba1_mixer(
                 params[br.mixer[0]], h, br.attrs, conv_in, ssm_in, n_live,
             )
@@ -610,8 +751,9 @@ def build_serve_programs(
         if br.norm_post is not None:
             o = norm(params[br.norm_post], o, rdt)
         convs, ssms = list(pools[6]), list(pools[7])
-        convs[si], ssms[si] = conv_out.astype(conv.dtype), ssm_out
-        return o, pools[:6] + (tuple(convs), tuple(ssms)), mem
+        convs[si] = None if conv is None else conv_out.astype(conv.dtype)
+        ssms[si] = ssm_out
+        return o, pools[:6] + (tuple(convs), tuple(ssms)) + pools[8:], mem
 
     def ffn_branch(br, params, x, loads, bts, n_valid, G):
         moe = br.kind == "moe"
@@ -646,7 +788,11 @@ def build_serve_programs(
         # order; ``mem`` the memory the layers carry beside the stream
         for j, br in enumerate(spec.layers[i].branches):
             at = where[(i, j)]
-            if br.is_attention:
+            if br.kind == "sparse":
+                f, pools, n_keys = sparse_branch(br, at, index_of[(i, j)], params, x, pools,
+                                                 start, bts, n_valid, G)
+                loads = loads + (n_keys,)
+            elif br.is_attention:
                 f, pools = attention_branch(
                     br, at, params, x, pools, start, bts, n_valid, G, attn)
             elif at is not None:
@@ -657,7 +803,8 @@ def build_serve_programs(
                 f = gmu_branch(br, params, x, mem)
             else:
                 f, loads = ffn_branch(br, params, x, loads, bts, n_valid, G)
-            x = x + f.astype(rdt)
+            f = f.astype(rdt)
+            x = x + (f * jnp.asarray(br.scale, rdt) if br.scale != 1.0 else f)
         return x, mem, pools, loads
 
     def run_layers(lo, hi, params, x, mem, pools, loads, start, bts, n_valid, G, attn):
@@ -681,6 +828,11 @@ def build_serve_programs(
         # same boundary as the dense session
         return jax.lax.optimization_barrier(x), mem, pools, loads
 
+    # what a call's ``loads`` hold, in the order its layers add them: an
+    # expert layer's rows an expert, a sparse layer's (keys its live rows
+    # read, keys before those rows) -- both summed over a prefill's groups
+    load_kinds = tuple(br.kind for br in spec.branches if br.kind in ("moe", "sparse"))
+
     def moe_stats(loads):
         # the call's counters (MOE_STATS), summed over its expert layers
         stats = jnp.zeros((len(MOE_STATS),), jnp.float32)
@@ -696,6 +848,8 @@ def build_serve_programs(
 
     def head(params, rows):
         x = norm(params[spec.final_norm], rows)
+        if spec.head_scale != 1.0:
+            x = x * jnp.asarray(spec.head_scale, x.dtype)
         if spec.head_tied:  # x E^T, contracting the table's rows as they rest
             logits = jax.lax.dot_general(
                 x, params[spec.embed]["kernel"], (((1,), (1,)), ((), ()))
@@ -707,13 +861,21 @@ def build_serve_programs(
             nxt = jnp.argmax(probs, axis=-1).astype(jnp.int32)
         else:
             # greedy: the argmax is all the host needs, and a
-            # (slots, vocab) float32 array does not leave the program
-            probs, nxt = None, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # (slots, vocab) float32 array does not leave the program --
+            # at most the argmax's own logit does
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            probs = jnp.max(logits, axis=-1) if return_logit else None
         return nxt, probs
 
     def head_out(nxt, probs, loads):
-        # a program's outputs before its pools
-        return (nxt, probs, moe_stats(loads)) if spec.has_moe else (nxt, probs)
+        # a program's outputs before its pools: the expert layers'
+        # counters, then the sparse layers' keys read and keys before them
+        out = (nxt, probs)
+        if spec.has_moe:
+            out += (moe_stats([c for c, k in zip(loads, load_kinds) if k == "moe"]),)
+        if index_of:
+            out += (sum(c for c, k in zip(loads, load_kinds) if k == "sparse"),)
+        return out
 
     def decode(params, pools, tok, pos, bt, layers=L):
         x, _, pools, loads = trunk(
@@ -777,7 +939,7 @@ def build_serve_programs(
         lanes = lanes.reshape(n_groups, Bp)
 
         def group(g, carry):
-            kvs, convs, ssms, nxt, probs, loads = carry
+            kvs, slot_rows, nxt, probs, loads = carry
             idx = lanes[g]
             live = idx < slots
             at = jnp.minimum(idx, slots - 1)
@@ -786,18 +948,21 @@ def build_serve_programs(
                 rows = a[at]
                 return jnp.where(live.reshape((-1,) + (1,) * (a.ndim - 1)), rows, 0)
 
-            bt_g = tuple(map(take, bt)) if isinstance(bt, (tuple, list)) else take(bt)
-            pools_g = kvs + (tuple(c[at] for c in convs), tuple(c[at] for c in ssms))
-            (nxt_g, probs_g), loads_g, pools_g = prefill_rows(
-                params, pools_g, take(toks), take(start), take(n_valid), bt_g)
-
             def put(a, rows):  # scatter a group's rows back by slot
                 return a.at[idx].set(rows, mode="drop")
 
+            bt_g = tuple(map(take, bt)) if isinstance(bt, (tuple, list)) else take(bt)
+            # the group's rows of every array kept a slot (states, indexes)
+            pools_g = kvs + tuple(
+                tuple(None if c is None else c[at] for c in arrs) for arrs in slot_rows)
+            (nxt_g, probs_g), loads_g, pools_g = prefill_rows(
+                params, pools_g, take(toks), take(start), take(n_valid), bt_g)
             return (
                 pools_g[:6],
-                tuple(map(put, convs, pools_g[6])),
-                tuple(map(put, ssms, pools_g[7])),
+                tuple(
+                    tuple(None if c is None else put(c, r) for c, r in zip(arrs, rows))
+                    for arrs, rows in zip(slot_rows, pools_g[6:])
+                ),
                 put(nxt, nxt_g),
                 None if probs is None else put(probs, probs_g),
                 tuple(a + b for a, b in zip(loads, loads_g)),
@@ -805,16 +970,18 @@ def build_serve_programs(
 
         vocab = spec.vocab
         carry = (
-            pools[:6], pools[6], pools[7], jnp.zeros((slots,), jnp.int32),
-            jnp.zeros((slots, vocab), jnp.float32) if return_probs else None,
+            pools[:6], pools[6:], jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots, vocab), jnp.float32) if return_probs
+            else jnp.zeros((slots,), jnp.float32) if return_logit else None,
             tuple(
-                jnp.zeros(params[br.mixer[0]]["w_up"].shape[:1], jnp.int32)
-                for br in spec.branches if br.kind == "moe"
+                jnp.zeros((2,), jnp.float32) if br.kind == "sparse"
+                else jnp.zeros(params[br.mixer[0]]["w_up"].shape[:1], jnp.int32)
+                for br in spec.branches if br.kind in ("moe", "sparse")
             ),
         )
-        kvs, convs, ssms, nxt, probs, loads = jax.lax.fori_loop(
+        kvs, slot_rows, nxt, probs, loads = jax.lax.fori_loop(
             0, prefill_lanes(n_mid, slots, P) // Bp, group, carry)
-        return head_out(nxt, probs, loads), kvs + (convs, ssms)
+        return head_out(nxt, probs, loads), kvs + slot_rows
 
     # --- speculative decoding (docs/SERVING.md): the chain layout makes
     # a depth-Ld draft model a SLICE of the params (layers 0..Ld-1 plus
@@ -852,23 +1019,28 @@ def build_serve_programs(
 
     # which of (ck, cv, sk, sv, wk, wv) this engine's programs thread
     have = (True, True, quant, quant, bool(n_win), bool(n_win))
-    n_kv = sum(have)
-    n_pools = n_kv + 2 * n_state
+    n_kv, n_conv = sum(have), sum(has_conv)
+    n_pools = n_kv + n_conv + n_state + n_index
     donate = tuple(range(1, 1 + n_pools))
 
     def program(body):
         # the jitted signature of every program: a quantized pool threads
         # its two scale pools right after the K/V pools, a model with
         # window layers its window group's pools after those, one with
-        # state layers every layer's conv state and then every layer's
-        # ssm state last; all donated and returned in that order
+        # state layers every layer's conv state (those that have one) and
+        # then every layer's ssm state, one with sparse layers every
+        # layer's index last; all donated and returned in that order
         def run(params, *args):
             given = iter(args[:n_kv])
+            convs = iter(args[n_kv:n_kv + n_conv])
+            at = n_kv + n_conv
             pools = tuple(next(given) if h else None for h in have) + (
-                tuple(args[n_kv:n_kv + n_state]), tuple(args[n_kv + n_state:n_pools]),
+                tuple(next(convs) if c else None for c in has_conv),
+                tuple(args[at:at + n_state]), tuple(args[at + n_state:n_pools]),
             )
             outs, pools = body(prep_params(params), pools, *args[n_pools:])
-            return (*outs, *(x for x, h in zip(pools, have) if h), *pools[6], *pools[7])
+            return (*outs, *(x for x, h in zip(pools, have) if h),
+                    *(c for c in pools[6] if c is not None), *pools[7], *pools[8])
 
         run.__name__ = run.__qualname__ = body.__name__
         return jax.jit(run, donate_argnums=donate)
@@ -880,5 +1052,5 @@ def build_serve_programs(
         verify=program(verify) if spec_k else None,
         params_arg=params_arg,
         donate=donate,
-        n_head=3 if spec.has_moe else 2,
+        n_head=2 + spec.has_moe + bool(index_of),
     )

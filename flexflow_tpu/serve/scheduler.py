@@ -93,6 +93,8 @@ class Request:
     state: RequestState = RequestState.QUEUED
     slot: int = -1
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # each served token's logit, where the engine keeps them (token_logits)
+    token_logits: List[float] = dataclasses.field(default_factory=list)
     prefill_pos: int = 0  # prompt positions with live KV so far
     finish_reason: Optional[str] = None  # "eos" | "length" | "rejected:*"
     preemptions: int = 0

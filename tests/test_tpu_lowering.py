@@ -712,3 +712,63 @@ def test_mamba1_layer_updates_its_state_in_place_on_the_tpu(G):
     ).compile().as_text()
     assert "input_output_alias={ {1}: (9, {}, may-alias), {2}: (10, {}, may-alias) }" in text
     assert count_pool_relayouts(text, slots * d * n * 4) == (0 if G == 1 else 1)
+
+
+# MiniCPM-SALA's serving geometry: a sparse layer's pool row is one K/V
+# head of 128 (16 query heads read it), pages of 64 -- a page is a
+# selected block -- and a unit's table of at most 128 selected pages (64
+# past dense_len, every block before it); a decode call takes 48 lanes a
+# K/V head, a prefill group's call 4 lanes x 8 blocks of 64 queries
+SQH, SD, SBS, STW = 16, 128, 64, 128
+
+
+@pytest.mark.parametrize("lanes,G", [(48, 1), (32, 64)])
+def test_sparse_kernel_lowers_at_16_query_heads_over_one_kv_head(lanes, G):
+    n = 48 * 288 + 1
+    dt = jnp.bfloat16
+
+    def fn(q, pk, pv, pos, bt):
+        return pa.paged_sparse_attention(q, pk, pv, pos, bt, layer=jnp.int32(1), block_size=SBS)
+
+    pool = jax.ShapeDtypeStruct((4, n * SBS, SD), dt)
+    _lower_for_tpu(
+        fn, jax.ShapeDtypeStruct((lanes, G, SQH, SD), dt), pool, pool,
+        jax.ShapeDtypeStruct((lanes,), jnp.int32), jax.ShapeDtypeStruct((lanes, STW), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("G", [1, 512])
+def test_lightning_layer_updates_its_state_in_place_on_the_tpu(G):
+    """The lightning mixer at the published widths (32 heads of 128 over
+    4,096), one step or a chunk from a slot's (32, 128, 128) float32
+    state, donated: the compiled module hands the state back in place.  A
+    decode step updates it with no copy of the whole array; a chunk's
+    scan stages the state it carries for the loop (one copy, as the
+    Mamba-1 scan's), which the engine's prefill pays on a group's
+    gathered rows."""
+    from flexflow_tpu.ops.linear_attention import lightning_mixer
+
+    sh = _v5e_sharding()
+    if sh is None:
+        pytest.skip("no compile-only v5e topology")
+    slots, dt, e = 8, jnp.bfloat16, 4096
+    attrs = dict(num_heads=32, head_dim=128, layer_idx=10, num_layers=32, rope_theta=1e4,
+                 eps=1e-6, chunk=128)
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    params = {w: place((e, e), dt) for w in ("wq", "wk", "wv", "wg", "wo")}
+    params.update(q_norm=place((128,), dt), k_norm=place((128,), dt), o_norm=place((e,), dt))
+    state = place((slots, 32, 128, 128), jnp.float32)
+
+    def layer(params, state, u, n_valid, pos):
+        o, s = lightning_mixer(params, u, attrs, state, n_valid, pos)
+        return o, jnp.where((n_valid > 0)[:, None, None, None], s, state)
+
+    text = jax.jit(layer, donate_argnums=(1,)).lower(
+        params, state, place((slots, G, e), dt), place((slots,), jnp.int32),
+        place((slots, G), jnp.int32),
+    ).compile().as_text()
+    assert "input_output_alias={ {1}: (8, {}, may-alias) }" in text
+    assert count_pool_relayouts(text, slots * 32 * 128 * 128 * 4) == (0 if G == 1 else 1)

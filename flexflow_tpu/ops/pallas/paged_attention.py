@@ -73,13 +73,28 @@ pool out (a (..., BS, D) page with D = 64 cost four whole-pool layout
 copies a call, an XLA scatter and a ``pool[i]`` slice more; PERF.md
 PR 27 and PR 29).
 
-Heads are 64-lane groups of one row here, not a leading dimension, so
-the attention kernel contracts over the whole row against a
-block-diagonal query: row ``(g, h)`` of ``q_bd`` holds ``q[g, h, :]``
-in head ``h``'s lanes and zeros elsewhere; ``q_bd @ k.T`` is the
-(G*H, PPB*BS) scores of a block, ``p @ v`` a (G*H, H*D) accumulator of
-which only the row's own head's lanes mean anything, picked once when
-the lane ends.  Two MXU products a block, ``p @ v`` contracting over the
+Heads are ``D``-lane groups of one row here, not a leading dimension,
+and the kernel contracts a lane's query rows against a block's pages in
+one of two ways (:func:`rows_tile` picks one from the call's shapes):
+
+* **block-diagonal** — row ``(g, h)`` of ``q_bd`` holds ``q[g, h, :]``
+  in its K/V head's ``D`` lanes and zeros elsewhere; ``q_bd @ k.T`` is
+  the (G*QH, PPB*BS) scores of a block over the whole row, ``p @ v`` a
+  (G*QH, H*D) accumulator of which only the row's own head's lanes mean
+  anything, picked once when the lane ends.  Several query heads' few
+  rows share one MXU pass: the arm of every decode-width call and of
+  every call whose heads are not grouped;
+* **per head** — a K/V head at a time: its ``QH / H`` query heads over
+  the tile's positions, ``(G*QH/H, D)``, against that head's ``D``
+  lanes of the block (``s_h = q_h @ k_h.T``, ``p_h @ v_h`` into a
+  (G*QH/H, D) accumulator), the block fetched once for all ``H`` heads.
+  No product multiplies the zeros of the other heads' lanes, so it is
+  taken where heads are grouped, ``D`` is whole 128-lane vregs, and a
+  K/V head brings at least the MXU's 128 rows to a product — a chunk
+  of a prefill.  The query goes in, and the output comes out, head-major
+  (a transpose of each in front of and behind the call).
+
+Two MXU products a block either way, ``p @ v`` contracting over the
 block's ~128 key positions; float32 accumulation, softmax and carry.
 
 Off-TPU the kernels run in interpreter mode only (``INTERPRET``,
@@ -180,24 +195,44 @@ def resolve_serve_attn(mode: str, block_size=None, pool_dtype=None) -> str:
 _BLOCK_KEYS = 128
 
 
-# rows of the block-diagonal query and of the accumulator one grid step
-# holds in VMEM when query heads share K/V heads: 512 rows of 512 lanes
-# are 0.5 MB (bfloat16) and 1 MB (float32)
-_TILE_ROWS = 512
+# the float32 accumulator one grid step holds in VMEM, in bytes: 1 MB,
+# 512 rows of 512 lanes (16 positions of 32 query heads over 4 K/V heads
+# of 128, block-diagonal)
+_TILE_BYTES = 1 << 20
+
+# rows the MXU takes a pass (128 x 128 on the v5e), and the lanes of a
+# vreg: a K/V head's own product is worth taking where it brings at least
+# that many query rows and its head is whole vregs of lanes
+_MXU_ROWS = 128
+_LANES = 128
 
 
-def rows_tile(G, q_heads, kv_heads, tile_rows=None):
-    """Query positions of a lane one grid step takes.  With one head
-    count (``q_heads == kv_heads``) all ``G``: the geometry the kernel
-    has always had.  With grouped heads the ``(G * q_heads, kv_heads *
-    D)`` query and accumulator of a whole chunk pass VMEM (64 MB at 256
-    positions of 32 / 4 heads of 128), so a lane's rows are cut into
-    tiles of at most ``tile_rows`` rows (the largest divisor of ``G``
-    that fits), each a grid step with its own walk."""
+def rows_tile(G, q_heads, kv_heads, head_dim, tile_bytes=None):
+    """(positions of a lane one grid step takes, how the step contracts
+    its heads: ``"per_head"`` or ``"block_diagonal"``).  With one head
+    count (``q_heads == kv_heads``) all ``G``, block-diagonal: the
+    geometry the kernel has always had.  With grouped heads a lane's rows
+    are cut into tiles whose float32 accumulator fits ``tile_bytes``
+    (1 MB; the largest divisor of ``G`` that fits), each a grid step
+    with its own walk — ``G * q_heads`` rows of ``kv_heads * head_dim``
+    lanes block-diagonal, of ``head_dim`` lanes per head, so the
+    per-head arm takes ``kv_heads`` times the positions a step.  It is
+    taken where ``head_dim`` is whole vregs of lanes and a K/V head's
+    ``q_heads / kv_heads`` query heads over the tile's positions make at
+    least the MXU's 128 rows."""
+    budget = (tile_bytes or _TILE_BYTES) // 4
+
+    def fits(lanes):
+        limit = max(1, budget // (q_heads * lanes))
+        return max(g for g in range(1, min(G, limit) + 1) if G % g == 0)
+
     if q_heads == kv_heads:
-        return G
-    limit = max(1, (tile_rows or _TILE_ROWS) // q_heads)
-    return max(g for g in range(1, min(G, limit) + 1) if G % g == 0)
+        return G, "block_diagonal"
+    if head_dim % _LANES == 0:
+        gt = fits(head_dim)
+        if gt * (q_heads // kv_heads) >= _MXU_ROWS:
+            return gt, "per_head"
+    return fits(kv_heads * head_dim), "block_diagonal"
 
 
 def attention_walk(slots, block_size, max_blocks_per_seq, tiles=1):
@@ -248,7 +283,7 @@ def _kernel(
     layer_ref,  # SMEM (1,) int32 — the layer of the pools to read
     pos_ref,  # SMEM (B,) int32 — row-0 position per lane
     bt_ref,  # SMEM (B, MB) int32 — block tables
-    q_ref,  # VMEM (1, G, H*D); grouped heads: (1, G*QH, D)
+    q_ref,  # VMEM (1, G, H*D); grouped: (1, G*QH, D), per head (1, H, G*QH/H, D)
     k_hbm,  # the WHOLE pool (L, N * BS, H*D), where it lies (HBM)
     v_hbm,
     *rest,  # [sk_ref, sv_ref (VMEM (NB, PPB*BS) f32)], o_ref, scratch refs
@@ -262,12 +297,16 @@ def _kernel(
     quantized: bool,
     window: int,
     tiled: bool,
+    per_head: bool,
 ):
     if quantized:
         sk_ref, sv_ref, *rest = rest
-    o_ref, kbuf, vbuf, sem, qbd_ref, acc_ref, m_ref, l_ref = rest
-    GH, HD = acc_ref.shape  # G * QH rows of H * D lanes
-    D = HD // H
+    o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref, *qbd = rest
+    # per head: H accumulators of (G * QH / H) rows of D lanes, a K/V
+    # head's query heads over the tile's positions; block-diagonal: one
+    # of G * QH rows of H * D lanes
+    NH, GH, HD = acc_ref.shape
+    D = kbuf.shape[-1] // H
     rep = QH // H  # query heads a K/V head serves
     W = PPB * BS  # key positions a compute block
     b = pl.program_id(0)
@@ -289,7 +328,7 @@ def _kernel(
     # float32 pool, a dequantized page, the float32 probabilities) takes
     # the full-precision passes
     exact = jax.lax.Precision.HIGHEST
-    qk_exact = None if qbd_ref.dtype == jnp.bfloat16 else exact
+    one_pass = q_ref.dtype == kbuf.dtype == jnp.bfloat16
 
     def own_head():
         # (G*QH, H*D): lane c lies in the K/V head of row (g, h) = g * QH + h
@@ -334,27 +373,29 @@ def _kernel(
 
     block_copies(0, 0, lambda c: c.start())
 
-    # while the first block is in flight — the block-diagonal query,
-    # once a lane: every query row H times over, each copy keeping one
-    # head's lanes
-    q = q_ref[0].astype(jnp.float32)
-    if QH != H:
-        # grouped heads: the rows come in as (G*QH, D), a query head a
-        # row; each goes into its K/V head's D lanes
-        r = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
-        for kvh in range(H):
-            qbd_ref[:, kvh * D:(kvh + 1) * D] = jnp.where(
-                (r % QH) // rep == kvh, q, 0.0
-            ).astype(qbd_ref.dtype)
-    else:
-        if G == 1:
-            rows = jnp.broadcast_to(q, (GH, HD))  # q is (G, H*D)
+    if not per_head:
+        # while the first block is in flight — the block-diagonal query,
+        # once a lane: every query row H times over, each copy keeping
+        # one head's lanes
+        (qbd_ref,) = qbd
+        q = q_ref[0].astype(jnp.float32)
+        if QH != H:
+            # grouped heads: the rows come in as (G*QH, D), a query head
+            # a row; each goes into its K/V head's D lanes
+            r = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
+            for kvh in range(H):
+                qbd_ref[:, kvh * D:(kvh + 1) * D] = jnp.where(
+                    (r % QH) // rep == kvh, q, 0.0
+                ).astype(qbd_ref.dtype)
         else:
-            rows = jax.lax.dot_general(
-                own_row(), q, (((1,), (0,)), ((), ())),
-                precision=exact, preferred_element_type=jnp.float32,
-            )
-        qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
+            if G == 1:
+                rows = jnp.broadcast_to(q, (GH, HD))  # q is (G, H*D)
+            else:
+                rows = jax.lax.dot_general(
+                    own_row(), q, (((1,), (0,)), ((), ())),
+                    precision=exact, preferred_element_type=jnp.float32,
+                )
+            qbd_ref[...] = jnp.where(own_head(), rows, 0.0).astype(qbd_ref.dtype)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -367,27 +408,15 @@ def _kernel(
             block_copies(j + 1, 1 - slot, lambda c: c.start())
 
         block_copies(j, slot, lambda c: c.wait())
-        k = kbuf[slot]  # (PPB*BS, H*D)
-        v = vbuf[slot].astype(jnp.float32)
-        if qk_exact is not None:
-            k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qbd_ref[...], k, (((1,), (1,)), ((), ())),
-            precision=qk_exact, preferred_element_type=jnp.float32,
-        ) * scale  # (G*QH, PPB*BS)
-        if quantized:
-            # the pool's ``int.astype(f32) * scale`` rule
-            # (kvcache.dequantize_kv) with the positions' scales applied
-            # to the products instead of the pages: a key's scale to its
-            # score, a value's to its probability
-            s = s * sk_ref[pl.ds(j, 1), :]
+        # the masks, one for every head: row r of an accumulator is the
+        # tile's position r // (its rows a position)
         k_pos = first_page * BS + j * W + jax.lax.broadcasted_iota(
             jnp.int32, (GH, W), 1
         )
         # (a chunk's padded rows may lie past the table's end: they see
         # no further than its last page, as when the grid ended there)
         row_pos = jnp.minimum(
-            pos0 + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 0) // QH,
+            pos0 + jax.lax.broadcasted_iota(jnp.int32, (GH, W), 0) // (GH // G),
             (first_page + n_pages) * BS - 1,
         )
         seen = k_pos <= row_pos
@@ -397,25 +426,52 @@ def _kernel(
             # max is then the mask's floor, and the first block that
             # holds one scales what came before to exactly nothing.)
             seen &= k_pos > row_pos - window
-        s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])  # (G*QH, PPB*BS) float32
-        l_ref[:, 0] = l_ref[:, 0] * alpha + p.sum(axis=-1)
-        if quantized:
-            p = jnp.where(seen, p * sv_ref[pl.ds(j, 1), :], 0.0)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            precision=exact, preferred_element_type=jnp.float32,
-        )  # (G*QH, H*D); row (g, h) means something in its K/V head's lanes
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-        m_ref[:, 0] = m_new
+        for h in range(NH):
+            # per head: K/V head h's D lanes of the block against its
+            # own query rows; block-diagonal: the whole row against all
+            lanes = slice(h * D, (h + 1) * D) if per_head else slice(None)
+            k = kbuf[slot, :, lanes]  # (PPB*BS, D | H*D)
+            v = vbuf[slot, :, lanes].astype(jnp.float32)
+            q = q_ref[0, h] if per_head else qbd_ref[...]
+            if not one_pass:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                precision=None if one_pass else exact,
+                preferred_element_type=jnp.float32,
+            ) * scale  # (rows, PPB*BS)
+            if quantized:
+                # the pool's ``int.astype(f32) * scale`` rule
+                # (kvcache.dequantize_kv) with the positions' scales
+                # applied to the products instead of the pages: a key's
+                # scale to its score, a value's to its probability
+                s = s * sk_ref[pl.ds(j, 1), :]
+            s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
+            m_prev = m_ref[h, :, 0]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, None])  # (rows, PPB*BS) float32
+            l_ref[h, :, 0] = l_ref[h, :, 0] * alpha + p.sum(axis=-1)
+            if quantized:
+                p = jnp.where(seen, p * sv_ref[pl.ds(j, 1), :], 0.0)
+            # (rows, D | H*D); block-diagonal, row (g, h) means something
+            # in its K/V head's lanes only
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                precision=exact, preferred_element_type=jnp.float32,
+            )
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + pv
+            m_ref[h, :, 0] = m_new
         return carry
 
     jax.lax.fori_loop(0, n_blocks, block, None)
 
-    out = jnp.where(own_head(), acc_ref[...] / l_ref[:, 0][:, None], 0.0)
+    if per_head:
+        # every lane of every row is its own head's: no fold
+        for h in range(H):
+            o_ref[0, h] = (acc_ref[h] / l_ref[h, :, 0][:, None]).astype(o_ref.dtype)
+        return
+    out = jnp.where(own_head(), acc_ref[0] / l_ref[0, :, 0][:, None], 0.0)
     if QH != H:
         # a row keeps its own K/V head's D lanes (the others hold zeros)
         o_ref[0] = sum(
@@ -449,7 +505,7 @@ def _lane_scales(scales, layer, block_tables, ppb):
 
 def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
                 scale_k, scale_v, *, BS, scale, interpret, window=0,
-                tile_rows=None):
+                tile_bytes=None):
     # Called through ``_JITTED``: jitted on its own, with the
     # interpreter flag among the static arguments (tests flip it per
     # engine build).  A serve program calls this once a layer with the
@@ -466,38 +522,44 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
     H = HD // D  # K/V heads
     assert H * D == HD and QH % H == 0, (q.shape, pool_k.shape)
     MB = block_tables.shape[1]
-    GT = rows_tile(G, QH, H, tile_rows)
+    GT, heads = rows_tile(G, QH, H, D, tile_bytes)
+    per_head = heads == "per_head"
     NT = G // GT
     walk = attention_walk(B, BS, MB, NT)
     PPB = walk["pages_per_block"]
     quantized = scale_k is not None
     assert not (quantized and window), "no quantized pool behind a window"
-    # the block-diagonal query multiplies a bfloat16 page as bfloat16
-    # (the kernel reads the choice off the scratch's dtype)
-    qbd_dtype = (
-        jnp.bfloat16 if q.dtype == pool_k.dtype == jnp.bfloat16
-        else jnp.float32
-    )
-    grouped = QH != H
-    # grouped heads go in and come out a query head a row, (G*QH, D):
-    # both are the caller's (B, G, QH, D) seen otherwise, no copy
-    width, rows = (D, G * QH) if grouped else (QH * D, G)
-    tile = GT * QH if grouped else G
+    rep = QH // H
+    if per_head:
+        # head-major: K/V head h's query heads over the lane's positions
+        # are the rows of [b, h], a tile of them a grid step
+        block, out_shape = (1, H, GT * rep, D), (B, H, G * rep, D)
+        q_in = q.reshape(B, G, H, rep, D).transpose(0, 2, 1, 3, 4).reshape(out_shape)
+        acc = (H, GT * rep)
+    else:
+        # grouped heads go in and come out a query head a row, (G*QH,
+        # D), otherwise a position a row: both are the caller's (B, G,
+        # QH, D) seen otherwise, no copy
+        grouped = QH != H
+        width, rows = (D, G * QH) if grouped else (QH * D, G)
+        block, out_shape = (1, GT * QH if grouped else G, width), (B, rows, width)
+        q_in = q.reshape(out_shape)
+        acc = (1, GT * QH)
 
     def lane_map(b, *rest):
         return (b, 0, 0)
 
     def tile_map(b, *rest):
-        return (b, rest[0], 0) if NT > 1 else (b, 0, 0)
+        t = rest[0] if NT > 1 else 0
+        return (b, 0, t, 0) if per_head else (b, t, 0)
 
     in_specs = [
-        pl.BlockSpec((1, tile, width), tile_map),
+        pl.BlockSpec(block, tile_map),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [
-        layer.reshape(1), positions, block_tables, q.reshape(B, rows, width),
-        pool_k, pool_v,
+        layer.reshape(1), positions, block_tables, q_in, pool_k, pool_v,
     ]
     if quantized:
         lane_scales = pl.BlockSpec(
@@ -509,39 +571,50 @@ def _paged_call(q, pool_k, pool_v, positions, block_tables, layer,
             for s in (scale_k, scale_v)
         ]
 
+    scratch = [
+        # two compute blocks of K and of V: one contracted while the
+        # next one's pages arrive
+        pltpu.VMEM((2, PPB * BS, HD), pool_k.dtype),
+        pltpu.VMEM((2, PPB * BS, HD), pool_v.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
+        # the accumulator, and the running max and sum of its rows
+        pltpu.VMEM(acc + ((D if per_head else HD),), jnp.float32),
+        pltpu.VMEM(acc + (128,), jnp.float32),
+        pltpu.VMEM(acc + (128,), jnp.float32),
+    ]
+    if not per_head:
+        # the block-diagonal query, bfloat16 against a bfloat16 page
+        scratch.append(pltpu.VMEM(
+            (GT * QH, HD),
+            jnp.bfloat16 if q.dtype == pool_k.dtype == jnp.bfloat16
+            else jnp.float32,
+        ))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=tuple(walk["grid"]),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tile, width), tile_map),
-        scratch_shapes=[
-            # two compute blocks of K and of V: one contracted while the
-            # next one's pages arrive
-            pltpu.VMEM((2, PPB * BS, HD), pool_k.dtype),
-            pltpu.VMEM((2, PPB * BS, HD), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
-            pltpu.VMEM((GT * QH, HD), qbd_dtype),
-            pltpu.VMEM((GT * QH, HD), jnp.float32),
-            pltpu.VMEM((GT * QH, 128), jnp.float32),
-            pltpu.VMEM((GT * QH, 128), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec(block, tile_map),
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _kernel, G=GT, QH=QH, H=H, BS=BS, MB=MB, PPB=PPB, scale=scale,
-        quantized=quantized, window=window, tiled=NT > 1,
+        quantized=quantized, window=window, tiled=NT > 1, per_head=per_head,
     )
     # no ``name=``: a compiled program and the profiler's trace name the
     # call after the jitted function around it (``_jitted_as`` below)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, rows, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         # lanes run in turn: the value buffer is cleared by the first
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(walk["grid"])
         ),
         interpret=interpret,
-    )(*operands).reshape(B, G, QH, D)
+    )(*operands)
+    if per_head:
+        out = out.reshape(B, H, G, rep, D).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, G, QH, D)
 
 
 def _jitted_as(name):
@@ -556,7 +629,7 @@ def _jitted_as(name):
 
     call.__name__ = call.__qualname__ = name
     return jax.jit(call, static_argnames=(
-        "BS", "scale", "interpret", "window", "tile_rows",
+        "BS", "scale", "interpret", "window", "tile_bytes",
     ))
 
 
@@ -566,7 +639,7 @@ _JITTED = {name: _jitted_as(name) for name in ("decode", "prefill", "sparse_atte
 def paged_decode_attention(
     q, pool_k, pool_v, positions, block_tables, scale=None,
     scale_k=None, scale_v=None, layer=None, *, block_size, window=0,
-    tile_rows=None,
+    tile_bytes=None,
 ):
     """Fused paged decode attention over one layer's K/V pool.
 
@@ -576,7 +649,7 @@ def paged_decode_attention(
         be a multiple of the pool's ``H`` K/V heads (grouped-query
         attention: ``QH // H`` query heads read one K/V head, and a
         lane's rows are then taken :func:`rows_tile` positions a grid
-        step).
+        step, block-diagonally or a K/V head at a time).
       pool_k / pool_v: (num_blocks * BS, H * D) — the layer's paged pool,
         position-major (physical block ``n`` is rows ``n * BS ..``; block
         0 is the allocator's trash block); or, with ``layer`` given, the
@@ -606,6 +679,8 @@ def paged_decode_attention(
         (logical page ``j`` at entry ``j % MB``: the window group of
         ``PagedKVCache``), which has to hold ``window + G`` positions
         and a page.
+      tile_bytes: the float32 accumulator one grid step may hold
+        (:func:`rows_tile`; default 1 MB).
 
     Returns (B, G, QH, D) in ``q.dtype``.  Numerics: float32 scores,
     online softmax and accumulation; the two contractions run on the
@@ -615,12 +690,12 @@ def paged_decode_attention(
     """
     return _attention(
         "decode", q, pool_k, pool_v, positions, block_tables, scale,
-        scale_k, scale_v, layer, block_size, window, tile_rows,
+        scale_k, scale_v, layer, block_size, window, tile_bytes,
     )
 
 
 def _attention(name, q, pool_k, pool_v, positions, block_tables, scale,
-               scale_k, scale_v, layer, block_size, window=0, tile_rows=None):
+               scale_k, scale_v, layer, block_size, window=0, tile_bytes=None):
     if (scale_k is None) != (scale_v is None):
         raise ValueError("pass both scale_k and scale_v, or neither")
     if scale is None:
@@ -637,14 +712,14 @@ def _attention(name, q, pool_k, pool_v, positions, block_tables, scale,
         q, pool_k, pool_v, positions, block_tables,
         jnp.asarray(layer, jnp.int32), scale_k, scale_v,
         BS=int(block_size), scale=float(scale), interpret=bool(INTERPRET),
-        window=int(window), tile_rows=tile_rows,
+        window=int(window), tile_bytes=tile_bytes,
     )
 
 
 def paged_prefill_attention(
     q, pool_k, pool_v, start, block_tables, scale=None,
     scale_k=None, scale_v=None, layer=None, *, block_size, window=0,
-    tile_rows=None,
+    tile_bytes=None,
 ):
     """Fused paged CHUNKED-PREFILL attention over one layer's K/V pool.
 
@@ -682,13 +757,13 @@ def paged_prefill_attention(
     # contract, no second code path to drift
     return _attention(
         "prefill", q, pool_k, pool_v, start, block_tables, scale,
-        scale_k, scale_v, layer, block_size, window, tile_rows,
+        scale_k, scale_v, layer, block_size, window, tile_bytes,
     )
 
 
 def paged_sparse_attention(
     q, pool_k, pool_v, positions, block_tables, scale=None, layer=None, *,
-    block_size, tile_rows=None,
+    block_size, tile_bytes=None,
 ):
     """The kernel over a sparse layer's SELECTED pages
     (``ops/sparse_attention.py``): ``block_tables`` (B, MB) lists a unit
@@ -704,7 +779,7 @@ def paged_sparse_attention(
     compiled program and in the profiler's trace."""
     return _attention(
         "sparse_attend", q, pool_k, pool_v, positions, block_tables, scale,
-        None, None, layer, block_size, 0, tile_rows,
+        None, None, layer, block_size, 0, tile_bytes,
     )
 
 

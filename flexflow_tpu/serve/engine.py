@@ -930,26 +930,48 @@ class ServeEngine:
         """How the paged kernel of this engine's programs walks a block
         table (``paged_attention.attention_walk``, which the kernel
         builds its grid and buffers from): ``grid`` (one step a lane),
-        ``pages_per_block``, ``max_blocks``.  None on the gather arm."""
+        ``pages_per_block``, ``max_blocks``; and, for each width the
+        programs call it at, how a lane's rows are taken
+        (``paged_attention.rows_tile``): ``tiles`` (grid steps a lane,
+        each with its own walk) and ``heads`` (``"per_head"`` or
+        ``"block_diagonal"``) -- under ``decode`` and ``prefill`` (the
+        full and the window layers' calls alike; ``verify`` under
+        speculation), or, for sparse layers, under ``sparse_attend``'s
+        ``decode`` (a row a unit) and ``prefill`` (a block of queries a
+        unit).  None on the gather arm."""
         if self.attn_kernel != "paged":
             return None
-        from flexflow_tpu.ops.pallas.paged_attention import attention_walk
+        from flexflow_tpu.ops.pallas.paged_attention import attention_walk, rows_tile
 
-        walk = attention_walk(
-            self.slots, self.kv.block_size, self.kv.max_blocks_per_seq
-        )
-        if self.kv.window_layers:
+        kv, sp = self.kv, self.spec
+        walk = attention_walk(self.slots, kv.block_size, kv.max_blocks_per_seq)
+
+        def at(G, q_heads):
+            # the pool's row is ``kv.heads`` K/V heads of ``kv.head_dim``
+            gt, heads = rows_tile(G, q_heads, kv.heads, kv.head_dim)
+            return {"tiles": G // gt, "heads": heads}
+
+        if kv.index_layers:
+            # a sparse layer's query heads over each K/V head, a pool
+            # layer a head; a prefill unit is a block (a page) of queries
+            qh = sp.heads // sp.kv_heads
+            walk["sparse_attend"] = {
+                "decode": at(1, qh), "prefill": at(kv.block_size, qh),
+            }
+            return walk
+        widths = {"decode": 1, "prefill": self.prefill_chunk}
+        if self.spec_k:
+            widths["verify"] = self.spec_k + 1
+        walk.update({name: at(G, sp.heads) for name, G in widths.items()})
+        if kv.window_layers:
             # the window group's walk: over a ring, from the first page a
-            # row still sees; a chunk's rows in tiles where heads are grouped
-            from flexflow_tpu.ops.pallas.paged_attention import rows_tile
-
-            P, sp = self.prefill_chunk, self.spec
+            # row still sees; a chunk's rows in the prefill's tiles
             walk["window"] = dict(
                 attention_walk(
-                    self.slots, self.kv.block_size, self.kv.ring_blocks,
-                    P // rows_tile(P, sp.heads, sp.kv_heads),
+                    self.slots, kv.block_size, kv.ring_blocks,
+                    walk["prefill"]["tiles"],
                 ),
-                window=self.kv.window, ring_blocks=self.kv.ring_blocks,
+                window=kv.window, ring_blocks=kv.ring_blocks,
             )
         return walk
 

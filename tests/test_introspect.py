@@ -484,6 +484,26 @@ def test_poolz_reports_the_pool_and_its_relayouts(model):
         assert srv._poolz is first
 
 
+def test_poolz_names_how_the_kernel_takes_each_width(model, monkeypatch):
+    """/poolz on the paged arm: the kernel's walk, and for each width the
+    programs call it at, its tiles a lane and how it contracts the heads
+    (``ServeEngine.attn_walk``) -- one head count: block-diagonal, a
+    grid step a lane."""
+    from flexflow_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "INTERPRET", True)
+    eng = ServeEngine(model, slots=SLOTS, block_size=8, sync_every=4,
+                      attn="paged")
+    with StatusServer(0) as srv:
+        srv.start()
+        srv.attach(eng)
+        code, _, body = _get(f"http://127.0.0.1:{srv.port}", "/poolz", timeout=60.0)
+    walk = json.loads(body)["attn_walk"]
+    assert code == 200 and walk == eng.attn_walk()
+    for width in ("decode", "prefill"):
+        assert walk[width] == {"tiles": 1, "heads": "block_diagonal"}
+
+
 # ------------------------------------------------- driver truthful startup
 def _free_port():
     s = socket.socket()

@@ -247,6 +247,13 @@ def served_logit_gap(model, ref_params, monkeypatch, attn):
     CP.group_rows(monkeypatch, 2, 32)
     eng = engine_of(model, attn=attn, temperature=1e-4)
     assert eng.attn_kernel == attn and eng.prefill_group_lanes == 2
+    if attn == "paged":
+        # the sparse layers' walks, a query and a block of queries a unit:
+        # 2 query heads of 64 lanes over a K/V head, block-diagonal
+        one = {"tiles": 1, "heads": "block_diagonal"}
+        walk = eng.attn_walk()
+        assert walk["sparse_attend"] == {"decode": one, "prefill": one}
+        assert "prefill" not in walk  # no attention of another kind
     seen = {}
 
     def capture(prog, pos_of):

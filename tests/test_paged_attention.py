@@ -390,7 +390,10 @@ def test_engine_reports_the_walk_and_the_blocks_walked(model, interpret):
                       sync_every=3, attn="paged")
     walk = eng.attn_walk()
     MB = eng.kv.max_blocks_per_seq
-    assert walk == pa.attention_walk(SLOTS, 4, MB)
+    one_walk = {"tiles": 1, "heads": "block_diagonal"}  # one head count
+    assert walk == dict(
+        pa.attention_walk(SLOTS, 4, MB), decode=one_walk, prefill=one_walk
+    )
     assert walk["grid"] == [SLOTS] and walk["pages_per_block"] == MB
     rep = eng.run(synthetic_requests(TrafficSpec(
         n_requests=SLOTS + 2, seed=5, rate_rps=0.0, prompt_len=(3, 12),
@@ -431,6 +434,8 @@ def test_blocks_walked_follow_the_requests_lengths(
                       sync_every=2, attn="paged")
     assert eng.attn_walk() == {
         "grid": [SLOTS], "pages_per_block": 2, "max_blocks": 6,
+        "decode": {"tiles": 1, "heads": "block_diagonal"},
+        "prefill": {"tiles": 1, "heads": "block_diagonal"},
     }
 
     def one():

@@ -1,7 +1,8 @@
 """The paged attention kernel with grouped heads (``q_heads !=
-kv_heads``), a first visible position (``window``) and a ring table,
-and the page-write kernel through a ring -- in the Pallas interpreter,
-against a dense masked softmax (ISSUE 32)."""
+kv_heads``), block-diagonal and a K/V head at a time, a first visible
+position (``window``) and a ring table, and the page-write kernel
+through a ring -- in the Pallas interpreter, against a dense masked
+softmax."""
 
 from __future__ import annotations
 
@@ -70,6 +71,11 @@ def _case(rng, *, B, G, QH, KVH, D, BS, window, pos, dtype, chunk):
     return q, keys, vals, jnp.asarray(pk, dtype), jnp.asarray(pv, dtype), tables, layer
 
 
+def _as_held(dtype, *xs):
+    """What a pool (and a query) of ``dtype`` hold of float32 ``xs``."""
+    return [np.asarray(jnp.asarray(x, dtype).astype(jnp.float32)) for x in xs]
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
 @pytest.mark.parametrize("G,pos,tile_rows", [
     (1, [0, 5, 7, 8, 30], None),        # decode: below, at, above the window
@@ -87,16 +93,104 @@ def test_grouped_heads_and_window_against_dense(dtype, tol, G, pos, tile_rows, w
     )
     if dtype == jnp.bfloat16:
         # the reference sees what the pool holds
-        keys = np.asarray(jnp.asarray(keys, dtype).astype(jnp.float32))
-        vals = np.asarray(jnp.asarray(vals, dtype).astype(jnp.float32))
-        q = np.asarray(jnp.asarray(q, dtype).astype(jnp.float32))
+        keys, vals, q = _as_held(dtype, keys, vals, q)
+    # ``tile_rows`` block-diagonal rows of KVH * D float32 lanes a step
+    tile_bytes = tile_rows and tile_rows * KVH * D * 4
+    assert pa.rows_tile(G, QH, KVH, D, tile_bytes)[1] == "block_diagonal"
     fn = pa.paged_prefill_attention if G > 1 else pa.paged_decode_attention
     out = fn(
         jnp.asarray(q, dtype), pk, pv, jnp.asarray(pos, jnp.int32), jnp.asarray(tables),
-        layer=layer, block_size=BS, window=window, tile_rows=tile_rows,
+        layer=layer, block_size=BS, window=window, tile_bytes=tile_bytes,
     )
     want = _dense(q, keys, vals, np.asarray(pos), window)
     np.testing.assert_allclose(np.asarray(out, np.float64), want, atol=tol, rtol=tol)
+
+
+# a K/V head at a time at a small cost: 8 query over 2 K/V heads of 128
+# lanes, so that 32 positions bring a K/V head the MXU's 128 rows
+HQH, HKVH, HD, HBS = 8, 2, 128, 16
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("G,pos,tile_bytes,tiles", [
+    (32, [37, 5], None, 1),              # the whole chunk a grid step
+    (64, [21, 0], 32 * HQH * HD * 4, 2),  # two tiles of 32 positions
+])
+@pytest.mark.parametrize("window", [0, 24])
+def test_per_head_arm_against_dense(dtype, tol, G, pos, tile_bytes, tiles, window):
+    """A chunk a K/V head at a time, and a third lane idle on the trash
+    block: its former pages hold NaN, which reaches no output."""
+    assert pa.rows_tile(G, HQH, HKVH, HD, tile_bytes) == (G // tiles, "per_head")
+    rng = np.random.default_rng(G + window)
+    q, keys, vals, pk, pv, tables, layer = _case(
+        rng, B=3, G=G, QH=HQH, KVH=HKVH, D=HD, BS=HBS, window=window,
+        pos=pos + [0], dtype=jnp.float32, chunk=G,
+    )
+    tables = tables.copy()
+    pk = np.asarray(pk).copy()
+    for blk in tables[2]:  # the idle lane's former pages: poison
+        pk[:, blk * HBS:(blk + 1) * HBS] = np.nan
+    tables[2] = 0
+    pk[:, :HBS] = 0.5  # the trash block holds something finite
+    pk, pv = jnp.asarray(pk, dtype), jnp.asarray(pv, dtype)
+    if dtype == jnp.bfloat16:
+        keys, vals, q = _as_held(dtype, keys, vals, q)
+    out = pa.paged_prefill_attention(
+        jnp.asarray(q, dtype), pk, pv, jnp.asarray(pos + [0], jnp.int32),
+        jnp.asarray(tables), layer=layer, block_size=HBS, window=window,
+        tile_bytes=tile_bytes,
+    )
+    out = np.asarray(out, np.float64)
+    assert np.isfinite(out).all()
+    want = _dense(q[:2], keys[:2], vals[:2], np.asarray(pos), window)
+    np.testing.assert_allclose(out[:2], want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("G,tile_bytes,rows", [
+    (16, None, 16),               # 64 rows a K/V head: under the MXU's 128
+    (32, 32 * HQH * HD * 2, 8),   # a budget of 16 positions a head: the same
+])
+def test_below_the_mxus_rows_the_block_diagonal_arm_is_taken(G, tile_bytes, rows):
+    """Where a K/V head's query rows would not fill an MXU pass the call
+    stays block-diagonal (its tile then counted in H * D lanes), and
+    agrees with the reference as well."""
+    assert pa.rows_tile(G, HQH, HKVH, HD, tile_bytes) == (rows, "block_diagonal")
+    rng = np.random.default_rng(G)
+    pos = [3, 40]
+    q, keys, vals, pk, pv, tables, layer = _case(
+        rng, B=2, G=G, QH=HQH, KVH=HKVH, D=HD, BS=HBS, window=0, pos=pos,
+        dtype=jnp.float32, chunk=G,
+    )
+    out = pa.paged_prefill_attention(
+        jnp.asarray(q), pk, pv, jnp.asarray(pos, jnp.int32), jnp.asarray(tables),
+        layer=layer, block_size=HBS, tile_bytes=tile_bytes,
+    )
+    want = _dense(q, keys, vals, np.asarray(pos), 0)
+    np.testing.assert_allclose(np.asarray(out, np.float64), want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("G,q_heads,kv_heads,head_dim,positions,heads", [
+    (128, 32, 4, 128, 64, "per_head"),        # trinity_mini prefill: 2 walks
+    (256, 32, 2, 128, 64, "per_head"),        # nemotron3_nano prefill: 4 walks
+    (256, 40, 10, 128, 32, "per_head"),       # phi4 prefill: 8 walks, 128 rows a head
+    (64, 16, 1, 128, 64, "per_head"),         # minicpm_sala sparse_attend, a unit: 1 walk
+    (1, 32, 4, 128, 1, "block_diagonal"),     # trinity_mini decode: 8 rows a head
+    (1, 32, 2, 128, 1, "block_diagonal"),     # nemotron3_nano decode: 16
+    (1, 40, 10, 128, 1, "block_diagonal"),    # phi4 decode: 4
+    (1, 16, 1, 128, 1, "block_diagonal"),     # minicpm_sala sparse_attend decode
+    (256, 12, 12, 64, 256, "block_diagonal"),  # gpt2_small: one head count
+    (4, 12, 12, 64, 4, "block_diagonal"),      # ... and its verify width
+    (256, 32, 4, 64, 32, "block_diagonal"),   # heads of half a vreg: 1 MB of 256 lanes
+])
+def test_each_cells_calls_take_their_arm_and_tiles(
+    G, q_heads, kv_heads, head_dim, positions, heads
+):
+    """``rows_tile`` at each served geometry: a prefill chunk of grouped
+    heads of 128 a K/V head at a time, in tiles whose float32
+    accumulator is at most 1 MB; every decode width block-diagonal."""
+    assert pa.rows_tile(G, q_heads, kv_heads, head_dim) == (positions, heads)
+    lanes = head_dim if heads == "per_head" else kv_heads * head_dim
+    assert q_heads == kv_heads or positions * q_heads * lanes * 4 <= 1 << 20
 
 
 def test_idle_lanes_read_the_trash_block_and_nothing_else():
@@ -138,8 +232,8 @@ def test_walk_starts_at_the_first_visible_page():
     # no window: from page 0, within the table
     f, n, _ = pa._live(np, np.asarray([100]), 8, BS, 40, 32, 0)
     assert (f[0], n[0]) == (0, 27)
-    assert pa.rows_tile(256, 32, 4) == 16 and pa.rows_tile(32, 12, 12) == 32
-    assert pa.rows_tile(6, 4, 2, 4) == 1
+    # a budget of one block-diagonal position: a tile a position
+    assert pa.rows_tile(6, 4, 2, 16, 4 * 32 * 4) == (1, "block_diagonal")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -61,13 +61,28 @@ def _v5e_sharding():
 
 
 def _lower_for_tpu(fn, *avals):
+    """Lower ``fn`` for the TPU, and compile it for a described v5e where
+    libtpu can describe one: the compiled module's text, else None."""
     jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
     sh = _v5e_sharding()
-    if sh is not None:
-        placed = [
-            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh) for a in avals
-        ]
-        jax.jit(fn).lower(*placed).compile()
+    if sh is None:
+        return None
+    placed = [
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh) for a in avals
+    ]
+    return jax.jit(fn).lower(*placed).compile().as_text()
+
+
+def _attention_lowers(fn, q, pool, positions, tables, heads):
+    """The paged kernel at ``q``'s width contracts its heads as
+    ``heads`` says (``rows_tile``), lowers and compiles for the TPU, and
+    the compiled module copies and transposes no pool."""
+    G, QH, D = q.shape[1:]
+    assert pa.rows_tile(G, QH, pool.shape[-1] // D, D)[1] == heads
+    text = _lower_for_tpu(fn, q, pool, pool, positions, tables)
+    if text is not None:
+        nbytes = int(np.prod(pool.shape)) * jnp.dtype(pool.dtype).itemsize
+        assert count_pool_relayouts(text, nbytes) == 0
 
 
 @pytest.fixture(autouse=True)
@@ -527,13 +542,14 @@ def test_widths_with_a_factor_of_512_keep_xlas_ragged_dot(monkeypatch, rows, exp
 
 
 # Trinity-Mini's serving geometry (ISSUE 32): 32 query / 4 K/V heads of
-# 128, a chunk of 256 rows in tiles of 16 positions, the full group's
-# table (528 pages of 16 for 8,448 positions) and the window group's ring
-# (145 pages: window 2,048 + chunk 256, and one)
+# 128, the cell's chunk of 128 rows (and 256) a K/V head at a time in
+# tiles of 64 positions, the full group's table (528 pages of 16 for
+# 8,448 positions) and the window group's ring (145 pages: window 2,048 +
+# chunk 256, and one)
 TQH, TKV, TD, TMB, TRING, TWIN = 32, 4, 128, 528, 145, 2048
 
 
-@pytest.mark.parametrize("G", [1, 256])
+@pytest.mark.parametrize("G", [1, 128, 256])
 @pytest.mark.parametrize("window,mb", [(0, TMB), (TWIN, TRING)])
 def test_grouped_head_attention_lowers_for_tpu(G, window, mb):
     slots = 4
@@ -544,13 +560,13 @@ def test_grouped_head_attention_lowers_for_tpu(G, window, mb):
     def fn(q, pk, pv, pos, bt):
         return entry(q, pk, pv, pos, bt, layer=jnp.int32(1), block_size=BS, window=window)
 
-    _lower_for_tpu(
+    _attention_lowers(
         fn,
         jax.ShapeDtypeStruct((slots, G, TQH, TD), dt),
         jax.ShapeDtypeStruct((2, n * BS, TKV * TD), dt),
-        jax.ShapeDtypeStruct((2, n * BS, TKV * TD), dt),
         jax.ShapeDtypeStruct((slots,), jnp.int32),
         jax.ShapeDtypeStruct((slots, mb), jnp.int32),
+        "per_head" if G > 1 else "block_diagonal",
     )
 
 
@@ -598,7 +614,9 @@ def test_attention_and_page_write_lower_at_16_query_heads_a_kv_head(G):
     def attend(q, pk, pv, pos, bt):
         return entry(q, pk, pv, pos, bt, layer=jnp.int32(0), block_size=BS)
 
-    _lower_for_tpu(attend, jax.ShapeDtypeStruct((slots, G, NQH, ND), dt), pool, pool, ints, bt)
+    # the cell's chunk of 256 a K/V head at a time, in 4 tiles of 64
+    _attention_lowers(attend, jax.ShapeDtypeStruct((slots, G, NQH, ND), dt), pool, ints, bt,
+                      "per_head" if G > 1 else "block_diagonal")
 
     def write(pk, pv, k, v, start, bt, n_valid):
         return pa.paged_kv_write(pk, pv, jnp.int32(0), k, v, start, bt, n_valid, block_size=BS)
@@ -668,10 +686,13 @@ def test_differential_attention_lowers_at_40_over_10_heads(G, window, mb):
         return entry(q, pk, pv, pos, bt, scale=0.125, layer=jnp.int32(0), block_size=BS,
                      window=window)
 
+    # the cell's chunk of 256 a K/V head at a time (4 query heads x 32
+    # positions = 128 rows a head), in 8 tiles
     pool = jax.ShapeDtypeStruct((1, n * BS, PKV * PD), dt)
-    _lower_for_tpu(
-        fn, jax.ShapeDtypeStruct((slots, G, PQH, PD), dt), pool, pool,
+    _attention_lowers(
+        fn, jax.ShapeDtypeStruct((slots, G, PQH, PD), dt), pool,
         jax.ShapeDtypeStruct((slots,), jnp.int32), jax.ShapeDtypeStruct((slots, mb), jnp.int32),
+        "per_head" if G > 1 else "block_diagonal",
     )
 
 
@@ -730,10 +751,13 @@ def test_sparse_kernel_lowers_at_16_query_heads_over_one_kv_head(lanes, G):
     def fn(q, pk, pv, pos, bt):
         return pa.paged_sparse_attention(q, pk, pv, pos, bt, layer=jnp.int32(1), block_size=SBS)
 
+    # a prefill unit of 64 queries in one walk: 1,024 rows of its one
+    # K/V head
     pool = jax.ShapeDtypeStruct((4, n * SBS, SD), dt)
-    _lower_for_tpu(
-        fn, jax.ShapeDtypeStruct((lanes, G, SQH, SD), dt), pool, pool,
+    _attention_lowers(
+        fn, jax.ShapeDtypeStruct((lanes, G, SQH, SD), dt), pool,
         jax.ShapeDtypeStruct((lanes,), jnp.int32), jax.ShapeDtypeStruct((lanes, STW), jnp.int32),
+        "per_head" if G > 1 else "block_diagonal",
     )
 
 
